@@ -661,10 +661,11 @@ flags.DEFINE_bool('pbt_vectorized', _DEFAULTS.pbt_vectorized,
                   'a model-axis mesh falls back to the serial loop.')
 flags.DEFINE_string('compile_cache_dir', _DEFAULTS.compile_cache_dir,
                     'Persistent XLA compilation cache, armed before '
-                    "backend spin-up. 'auto' = <logdir>/.jax_cache "
+                    'the first compile. Ignored where '
+                    'JAX_COMPILATION_CACHE_DIR is set (the environment '
+                    "places the cache). 'auto' = <checkout>/.jax_cache "
                     'on accelerator hosts (skipped on CPU-pinned '
-                    'processes, where executable reload is '
-                    "unreliable); '' disables; else the cache dir "
+                    "processes); '' disables; else the cache dir "
                     'itself (shareable across runs and processes, '
                     'armed on any backend).')
 

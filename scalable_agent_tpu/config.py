@@ -7,7 +7,16 @@ DMLab values. A dataclass + absl-flags overlay replaces TF1 app flags
 """
 
 import dataclasses
+import os
 from typing import List, Optional
+
+# Where the persistent compilation cache lives when nothing outside
+# places it: ONE fixed path inside the checkout. The directory is how
+# a later run finds the entries again, so it never derives from a
+# logdir, a temp name, a pid or a time.
+REPO_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    '.jax_cache')
 
 
 @dataclasses.dataclass
@@ -680,15 +689,16 @@ class Config:
   # mesh degrades to the serial member loop with a warning.
   pbt_vectorized: bool = False
   # Persistent XLA compilation cache (round 23): armed in
-  # distributed.maybe_initialize BEFORE backend spin-up, so repeat
+  # distributed.maybe_initialize before the first compile, so repeat
   # spin-ups of identical programs (population rounds, elastic
   # rejoin, serving flips, plain restarts) skip retrace+compile.
-  # 'auto' = <logdir>/.jax_cache, armed on accelerator hosts only
-  # (CPU-pinned processes skip auto-arming: jaxlib's XLA:CPU
-  # executable reload can kill the process at driver scale); ''
-  # disables; any other value is the cache dir itself, armed on any
-  # backend (shareable across runs/processes — entries are keyed,
-  # concurrent writers are safe).
+  # Where JAX_COMPILATION_CACHE_DIR is set the environment places the
+  # cache and this flag is ignored. Otherwise 'auto' =
+  # REPO_COMPILE_CACHE_DIR, armed on accelerator hosts only
+  # (CPU-pinned processes skip auto-arming, see
+  # distributed.arm_compile_cache); '' disables; any other value is
+  # the cache dir itself, armed on any backend (shareable across
+  # runs/processes — entries are keyed, concurrent writers are safe).
   compile_cache_dir: str = 'auto'
 
   @property
@@ -794,12 +804,16 @@ class Config:
 
   @property
   def resolved_compile_cache_dir(self) -> str:
-    """The persistent-compilation-cache dir with the 'auto' rule
-    applied ('' = disabled). Resolved here so the driver, bench.py,
-    and distributed.maybe_initialize can never disagree on where a
-    run's cache lives."""
+    """The directory the PROGRAM sets for the persistent compilation
+    cache; '' = it sets none. Where JAX_COMPILATION_CACHE_DIR is set
+    the cache is placed from outside (jax reads the variable itself)
+    and the program sets nothing, whatever the flag says. Resolved
+    here so every entry (driver.train, chip_smoke.py, the CI smoke)
+    agrees on where the cache lives."""
+    if os.environ.get('JAX_COMPILATION_CACHE_DIR'):
+      return ''
     if self.compile_cache_dir == 'auto':
-      return self.logdir + '/.jax_cache'
+      return REPO_COMPILE_CACHE_DIR
     return self.compile_cache_dir
 
 

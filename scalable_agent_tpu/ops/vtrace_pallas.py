@@ -25,13 +25,12 @@ reassociation tolerance (the doubling recursion reorders the
 accumulation; ~1e-5 absolute at T=100) — vtrace_test.py's ground-truth
 applies.
 
-Measured on TPU v5e (1 chip, T=100, B=32, async-dispatch chain,
-round 2): XLA scan 851 µs, associative_scan 807 µs, **this kernel
-604 µs** per call — the pointer-doubling recursion (see
-`_vtrace_kernel`) keeps all operands VMEM-resident across the whole
-computation and uses the full 8-sublane VPU, beating both XLA forms.
-(Round 1's row-at-a-time `fori_loop` version measured 1490 µs; the
-fix was vectorizing the recursion, not more blocking.)
+The pointer-doubling recursion (see `_vtrace_kernel`) keeps all
+operands VMEM-resident across the whole computation and uses the full
+8-sublane VPU; a row-at-a-time `fori_loop` would use 1/8 of it and
+pay per-iteration overhead. Kernel time on the current chip: not
+measured — which of the three V-trace forms stays is ROADMAP D2's
+paired chip run.
 `pallas_call` has no SPMD partitioning rule, so the kernel cannot be
 left to GSPMD under a sharded step — but V-trace is per-batch-column
 INDEPENDENT, so `sharded_from_importance_weights` (round 8) wraps the
@@ -50,7 +49,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.experimental.shard_map import shard_map
 
 LANE = 128  # TPU lane width: batch block size
 
@@ -67,10 +65,7 @@ def _vtrace_kernel(clips_ref, log_rhos_ref, discounts_ref, rewards_ref,
   composes each row with the row `offset` below it (identity padding
   past the end), doubling coverage per pass: after ceil(log2 T) fully
   vectorized [T, LANE] passes, B_r holds the whole suffix — i.e.
-  vs_r − v_r. A first version looped `fori_loop` row-at-a-time
-  instead (1/8 sublane utilization + per-iteration overhead) and LOST
-  to the XLA scan; this form is what makes the kernel win (timings in
-  the module docstring).
+  vs_r − v_r.
   """
   t = log_rhos_ref.shape[0]
   rhos = jnp.exp(log_rhos_ref[:])                       # [T, LANE]
@@ -104,6 +99,21 @@ def _vtrace_kernel(clips_ref, log_rhos_ref, discounts_ref, rewards_ref,
                                  values)
 
 
+def _interpret_on(platform: str) -> bool:
+  """Whether the kernel runs interpreted on `platform`: compiled by
+  Mosaic on 'tpu', interpreted on 'cpu'. A platform that is neither
+  has no path for this kernel — interpreting there would hide the
+  device behind interpreter numbers."""
+  if platform == 'tpu':
+    return False
+  if platform == 'cpu':
+    return True
+  raise RuntimeError(
+      f'the Pallas V-trace kernel runs compiled on tpu or interpreted '
+      f'on cpu; platform {platform!r} is neither (use the scan form: '
+      'use_pallas_vtrace=False)')
+
+
 def from_importance_weights(log_rhos, discounts, rewards, values,
                             bootstrap_value, clip_rho_threshold=1.0,
                             clip_pg_rho_threshold=1.0, interpret=None):
@@ -113,11 +123,12 @@ def from_importance_weights(log_rhos, discounts, rewards, values,
 
   Rank-generic like the reference: trailing dims beyond [T, B] are
   flattened into the lane axis (each lane is an independent recursion,
-  so this is exact). `interpret=None` auto-selects interpreter mode off
-  TPU (CI runs the same kernel code path).
+  so this is exact). `interpret=None` compiles the kernel on TPU and
+  interprets it on CPU (CI runs the same kernel code path); any other
+  platform is an error, never a silent interpreter run.
   """
   if interpret is None:
-    interpret = jax.default_backend() != 'tpu'
+    interpret = _interpret_on(jax.default_backend())
 
   log_rhos = jnp.asarray(log_rhos, jnp.float32)
   discounts = jnp.asarray(discounts, jnp.float32)
@@ -193,7 +204,7 @@ def sharded_from_importance_weights(mesh, log_rhos, discounts, rewards,
 
   B must divide the `batch_axis` width — the same divisibility the
   driver's mesh choice already guarantees for the learner batch.
-  `check_rep=False`: outputs are replicated over the unmentioned axes
+  `check_vma=False`: outputs are replicated over the unmentioned axes
   by construction (pure per-shard math), but shard_map's replication
   checker cannot see through `pallas_call` to prove it.
   """
@@ -206,9 +217,9 @@ def sharded_from_importance_weights(mesh, log_rhos, discounts, rewards,
       clip_rho_threshold=clip_rho_threshold,
       clip_pg_rho_threshold=clip_pg_rho_threshold,
       interpret=interpret)
-  return shard_map(
+  return jax.shard_map(
       fn, mesh=mesh,
       in_specs=(spec_t, spec_t, spec_t, spec_t, spec_b),
       out_specs=(spec_t, spec_t),
-      check_rep=False)(log_rhos, discounts, rewards, values,
+      check_vma=False)(log_rhos, discounts, rewards, values,
                        bootstrap_value)

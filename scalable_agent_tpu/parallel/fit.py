@@ -8,19 +8,20 @@ terms); this module turns it into a compiled fact: AOT-lower the
 sharded train step over a pure-DP ``{'data': N}`` mesh, compile it,
 and read per-device buffer sizes out of XLA's ``memory_analysis()``.
 
-Caveat, stated where the numbers are made: when no 16-device TPU
-platform exists the compile runs on N *virtual CPU devices*, so the
-figure is the CPU backend's buffer assignment for the per-device
-shapes — layout padding and fusion choices differ from the TPU
-emitter's (CPU also computes bf16 matmuls via f32 temporaries, which
-*overstates* temp. vs a real v5e). It bounds the shape arithmetic
-with a compiled buffer assignment rather than hand-waving; the gate
-uses a conservative budget margin and the artifact records the
-backend it compiled for.
+`__graft_entry__.dryrun_multichip` passes the 16 compile-only devices
+of a `v5e:4x4` topology description (libtpu needs no chip for that),
+so its figure is the TPU compiler's buffer assignment. Caveat for the
+other callers, stated where the numbers are made: on N *virtual CPU
+devices* the figure is the CPU backend's buffer assignment for the
+per-device shapes — layout padding and fusion choices differ from
+the TPU emitter's (CPU also computes bf16 matmuls via f32
+temporaries, which *overstates* temp. vs a real v5e), so it checks
+the mechanics, not the fit. The result records the backend it
+compiled for.
 
 Consumed by:
-- ``__graft_entry__.dryrun_multichip`` — the MULTICHIP_rN artifact
-  records the fit figures for B=32 and B=16;
+- ``__graft_entry__.dryrun_multichip`` — prints the fit figures for
+  B=32 and B=16;
 - ``scripts/aot_fit.py`` — the <60 s CPU CI smoke (scripts/ci.sh);
 - ``tests/test_parallel.py`` — mechanics gate on the 8-device mesh.
 """
@@ -136,7 +137,7 @@ def aot_memory_fit(devices: Optional[Sequence[Any]] = None,
 
 
 def format_fit(fit: Dict[str, Any]) -> str:
-  """One tail-capture-friendly line for the MULTICHIP artifact."""
+  """One tail-capture-friendly line."""
   gib = 1 / 2**30
   return (
       'aot_fit(v5e16): B=%d (per-device %d) T=%d mesh=%s live=%.3f GiB'

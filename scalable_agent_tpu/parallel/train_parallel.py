@@ -184,6 +184,8 @@ def make_sharded_train_step(agent, config: Config, mesh: Mesh,
 
   step.donation_fallback = False
   step.tp_gathered = bool(gathered_tp)
+  # The shardings the step is jitted with: how the batch is split.
+  step.batch_shardings = batch_shard
 
   def _log_gathered():
     log.info(
@@ -262,7 +264,7 @@ def make_sdc_fingerprint_fn(mesh: Mesh):
   detector's per-replica view instead, driving the identical
   detection → incident → rollback path.
 
-  check_rep=False: params enter replicated but the per-replica
+  check_vma=False: params enter replicated but the per-replica
   fingerprints are deliberately per-shard — the whole point is that
   'replicated' is an assumption the hardware can break, which is not
   a claim shard_map's replication checker can express.
@@ -277,8 +279,6 @@ def make_sdc_fingerprint_fn(mesh: Mesh):
   dispatched from the lockstep driver path (per health check, every
   host), so it is barrier-safe by the same argument as the step
   itself."""
-  from jax.experimental.shard_map import shard_map
-
   num_replicas = int(mesh.shape[sharding_lib.DATA_AXIS])
   probe_sharding = sharding_lib.data_sharding(mesh)
 
@@ -291,11 +291,11 @@ def make_sdc_fingerprint_fn(mesh: Mesh):
         (fp + probe.reshape(())).reshape(()), sharding_lib.DATA_AXIS,
         tiled=False)
 
-  sharded = jax.jit(shard_map(
+  sharded = jax.jit(jax.shard_map(
       per_replica, mesh=mesh,
       in_specs=(sharding_lib.spec_replicated(),
                 sharding_lib.spec_data()),
-      out_specs=sharding_lib.spec_replicated(), check_rep=False))
+      out_specs=sharding_lib.spec_replicated(), check_vma=False))
 
   def fingerprint_fn(params, probe_host=None):
     if probe_host is None:

@@ -39,6 +39,8 @@ interpreter.
 """
 
 import multiprocessing
+import os
+import sys
 import threading
 import traceback
 from multiprocessing.pool import ThreadPool
@@ -50,9 +52,37 @@ DEFAULT_START_METHOD = 'forkserver'
 
 def warm_forkserver():
   """Start the forkserver process now (idempotent). Best called before
-  any JAX import/initialization — see the module docstring."""
+  any JAX import/initialization — see the module docstring.
+
+  The server imports this package once, so every child forks with
+  jax/flax already loaded. A child otherwise spends seconds importing
+  them before it can unpickle its env class, and fleet.start() waits
+  for each actor's first observation in turn: start-up linear in the
+  fleet size (about 5 s per actor measured on the chip machine).
+  `python experiment.py` got this by accident — the default preload is
+  `__main__`, and experiment.py imports the package — while bench.py,
+  the tests and chip_smoke.py did not."""
   from multiprocessing import forkserver
+  multiprocessing.set_forkserver_preload(
+      ['__main__', 'scalable_agent_tpu.envs.factory'])
   forkserver.ensure_running()
+
+
+def stop_forkserver():
+  """Stop the forkserver and the resource tracker now and wait for both
+  (idempotent; the next start brings them back). Left alone they end by
+  themselves once this process has gone, but only afterwards — the
+  server not before its preload imports finish — so a caller that must
+  leave nothing running behind it stops them first. Stop the env
+  processes before: the server does not take its children with it.
+
+  The stdlib has no public call for this; `_stop` is what its own test
+  clean-up (multiprocessing.util._cleanup_tests) uses."""
+  from multiprocessing import forkserver, resource_tracker
+  forkserver._forkserver._stop()
+  # The tracker ends when the last copy of its pipe closes; the server
+  # held one, so it goes second.
+  resource_tracker._resource_tracker._stop()
 
 
 class ProcessClosed(Exception):
@@ -73,8 +103,27 @@ class SpecMismatchError(Exception):
 _CLOSE = '__process_close__'
 
 
+def pin_process_to_cpu():
+  """Keep THIS process off the accelerator: a chip belongs to one
+  process, the learner, and a child that initialises the default
+  backend while the parent holds the chip fails or hangs.
+
+  Call it first thing in a child, before anything can use JAX. The
+  environment variable covers a `jax` not imported yet; the config
+  update covers one imported already (unpickling a hosted class
+  imports its module before the worker body runs, and jax reads the
+  variable at import only). Whatever JAX_PLATFORMS the parent was
+  launched with — unset, `tpu`, anything — the child then finds
+  exactly the CPU backend (envs/jittable.py steps its cores on it)."""
+  os.environ['JAX_PLATFORMS'] = 'cpu'
+  jax = sys.modules.get('jax')
+  if jax is not None:
+    jax.config.update('jax_platforms', 'cpu')
+
+
 def _worker(conn, type_, constructor_kwargs):
   """Worker loop: construct, then serve (method, args, kwargs) requests."""
+  pin_process_to_cpu()
   try:
     obj = type_(**constructor_kwargs)
   except Exception as e:  # ctor failure → reported on first proxy call

@@ -140,7 +140,7 @@ def from_importance_weights(log_rhos, discounts, rewards, values,
 
   `use_pallas=True` runs the whole computation as one fused Pallas TPU
   kernel (ops/vtrace_pallas.py) — no HBM intermediates; interpreter
-  mode off-TPU keeps CI on the same code path. Under a sharded step,
+  mode on CPU keeps CI on the same code path. Under a sharded step,
   pass the step's `mesh`: pallas_call has no SPMD partitioning rule,
   so the kernel is shard_map'ped over `batch_axis` instead (exact —
   each batch column is an independent recursion).

@@ -2,13 +2,19 @@
 
 Two-process protocol, driven by scripts/ci.sh:
 
-  CI_CACHE_PHASE=fill  — arm the cache at CI_CACHE_DIR through the
-      production seam (distributed.maybe_initialize), compile a small
-      program, and assert the cache dir gained entries.
+  CI_CACHE_PHASE=fill  — arm the cache through the production seam
+      (distributed.maybe_initialize), compile a small program, and
+      assert the cache dir holds entries.
   CI_CACHE_PHASE=hit   — a FRESH interpreter arms the same dir,
       compiles the identical program, and proves the executable came
       from the cache via jax's monitoring events (entry-count
       equality proves nothing: a miss rewrites the same key).
+
+The directory follows the program's one placement rule
+(Config.resolved_compile_cache_dir): JAX_COMPILATION_CACHE_DIR where
+it is set — the code then sets nothing — else the fixed path inside
+the checkout. It is passed as an explicit --compile_cache_dir because
+'auto' declines to arm on a CPU-pinned process.
 
 This is the cross-process claim the unit tests cannot make: the
 second *process* skips XLA compilation entirely — the mechanism that
@@ -23,7 +29,6 @@ sys.path.insert(0, os.getcwd())
 
 
 def main():
-  cache_dir = os.environ['CI_CACHE_DIR']
   phase = os.environ['CI_CACHE_PHASE']
 
   import jax
@@ -32,21 +37,22 @@ def main():
   jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)
   import jax.numpy as jnp
 
-  from scalable_agent_tpu.config import Config
+  from scalable_agent_tpu import config as config_lib
   from scalable_agent_tpu.parallel import distributed
 
-  cfg = Config(compile_cache_dir=cache_dir)
-  distributed.maybe_initialize(cfg)
-  assert jax.config.jax_compilation_cache_dir == cache_dir, (
-      jax.config.jax_compilation_cache_dir)
+  resolved = config_lib.Config().resolved_compile_cache_dir
+  distributed.maybe_initialize(
+      config_lib.Config(compile_cache_dir=resolved))
+  cache_dir = jax.config.jax_compilation_cache_dir
+  assert cache_dir == (os.environ.get('JAX_COMPILATION_CACHE_DIR')
+                       or config_lib.REPO_COMPILE_CACHE_DIR), cache_dir
 
   events = []
 
   def listener(event, **kwargs):
     events.append(event)
 
-  from jax._src import monitoring
-  monitoring.register_event_listener(listener)
+  jax.monitoring.register_event_listener(listener)
 
   @jax.jit
   def program(x):

@@ -363,9 +363,10 @@ echo '   members in ONE Anakin program, on-device weight inheritance,'
 echo '   persistent compilation cache — the compile-cache unit tests,'
 echo '   a tiny N=2 fused driver run asserting PBT_LOG.json records'
 echo '   vectorized=true + verdict PASS + per-member ladders, and a'
-echo '   two-process cache smoke: process A compiles into a shared'
-echo '   dir, process B proves a cache HIT via the jax monitoring'
-echo '   events — <300 s CPU) =='
+echo '   two-process cache smoke: process A compiles into the one'
+echo '   placed cache dir (JAX_COMPILATION_CACHE_DIR, else'
+echo '   <checkout>/.jax_cache), process B proves a cache HIT via the'
+echo '   jax monitoring events — <300 s CPU) =='
 JAX_PLATFORMS=cpu python -m pytest tests/test_compile_cache.py -q \
   -p no:cacheprovider
 JAX_PLATFORMS=cpu python - <<'FUSED_EOF'
@@ -397,10 +398,7 @@ print('fused population OK: one program, %d round(s), winner member '
       '%d, verdict PASS' % (len(log['rounds']),
                             log['winner']['member']))
 FUSED_EOF
-CACHE_DIR=$(mktemp -d)/ci_jax_cache
-JAX_PLATFORMS=cpu CI_CACHE_DIR="$CACHE_DIR" CI_CACHE_PHASE=fill \
-  python scripts/_compile_cache_smoke.py
-JAX_PLATFORMS=cpu CI_CACHE_DIR="$CACHE_DIR" CI_CACHE_PHASE=hit \
-  python scripts/_compile_cache_smoke.py
+JAX_PLATFORMS=cpu CI_CACHE_PHASE=fill python scripts/_compile_cache_smoke.py
+JAX_PLATFORMS=cpu CI_CACHE_PHASE=hit python scripts/_compile_cache_smoke.py
 
 echo 'CI OK'

@@ -111,14 +111,6 @@ def convert(logdir, out=None):
   become a `trace`/`trace_pN` run of hop-latency and policy-lag
   scalars so TensorBoard operators keep their view of the new
   telemetry plane."""
-  try:
-    from torch.utils.tensorboard import SummaryWriter
-  except ImportError as e:
-    raise ImportError(
-        'scripts/to_tensorboard.py writes events via '
-        'torch.utils.tensorboard (`pip install torch tensorboard`); '
-        'the training path itself never requires either') from e
-
   out = out or os.path.join(logdir, 'tb')
   streams = sorted(glob.glob(os.path.join(logdir, '*summaries*.jsonl')))
   trace_streams = sorted(glob.glob(os.path.join(logdir,
@@ -126,6 +118,15 @@ def convert(logdir, out=None):
   if not streams and not trace_streams:
     raise FileNotFoundError(
         f'no *summaries*.jsonl or traces*.jsonl under {logdir!r}')
+  # After the input check: this import takes ~15 s (it drags
+  # TensorFlow in through tensorboard).
+  try:
+    from torch.utils.tensorboard import SummaryWriter
+  except ImportError as e:
+    raise ImportError(
+        'scripts/to_tensorboard.py writes events via '
+        'torch.utils.tensorboard (`pip install torch tensorboard`); '
+        'the training path itself never requires either') from e
   written = {}
   for path in trace_streams:
     base = os.path.basename(path)

@@ -63,18 +63,13 @@ def main():
       f'--xla_force_host_platform_device_count={ndev}')
   import jax
   jax.config.update('jax_platforms', 'cpu')
-  # The runtime's own spin-up seam (round 17): enables the CPU
-  # backend's cross-process collectives (gloo) BEFORE the backend is
-  # built — a raw jax.distributed.initialize leaves collectives=none
-  # and every cross-process computation then fails with 'Multiprocess
-  # computations aren't implemented on the CPU backend'.
+  # The runtime's own spin-up seam (round 17), before the backend is
+  # built.
   from scalable_agent_tpu.parallel import distributed
-  # Tight failure detection (1 s x 8): the SIGKILL drill's survivors
-  # must abort in seconds, not jax's production default ~100 s.
+  # Tight failure detection (8 s): the SIGKILL drill's survivors must
+  # abort in seconds, not jax's production default 100 s.
   distributed.initialize(f'localhost:{port}', num_processes=nprocs,
-                         process_id=proc,
-                         heartbeat_interval_secs=1,
-                         max_missing_heartbeats=8)
+                         process_id=proc, heartbeat_timeout_secs=8)
   assert jax.device_count() == nprocs * ndev
   assert jax.local_device_count() == ndev
 

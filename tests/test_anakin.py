@@ -185,6 +185,7 @@ def test_anakin_train_artifacts_and_resume(tmp_path):
   assert int(carry3.train_state.update_steps) == 12
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_anakin_train_restore_mismatch_does_not_overwrite(tmp_path):
   """A structure-mismatch on resume must raise (with the flag
   guidance), not tail-save a fresh incompatible state into the logdir."""

@@ -122,6 +122,7 @@ def test_episode_stats_rejects_unknown_benchmark():
     obs.EpisodeStats(['x'], benchmark='atari58')
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_evaluate_atari57_scores(tmp_path):
   """Full evaluate() wiring for the 57-game suite (bandit stand-in
   envs, mirroring test_driver's dmlab30 eval test): every game reaches

@@ -10,6 +10,7 @@ import pytest
 import bench
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_transport_bench_smoke():
   results = bench.bench_transport(smoke=True)
   assert results['unroll_mb'] > 0
@@ -71,6 +72,7 @@ def test_emit_writes_artifact_and_prints_headline_last(tmp_path,
   assert len(lines[-1]) < 1000  # compact: survives tail truncation
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_inference_plane_bench_smoke():
   """The round-7 actor-plane instrument: all cache×depth variants run
   and report calls/s + latency percentiles (the accept/reject rows for

@@ -346,6 +346,7 @@ def test_restore_last_good_none_when_empty(setup, tmp_path):
     ckpt.close()
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_sharded_state_roundtrip(setup, tmp_path):
   """The docstring's multi-chip claim: a DP-sharded TrainState saves
   and restores onto the same mesh placements (SURVEY §5.4 → Orbax)."""

@@ -331,6 +331,7 @@ def test_bounded_moves_never_leave_the_registered_range(tmp_path):
 # --------------------------------------------------------------------
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_acting_controller_rides_drain_manifest(tmp_path):
   from scalable_agent_tpu import driver
   from scalable_agent_tpu.config import Config

@@ -135,6 +135,7 @@ def test_validate_runtime_knob_group():
       'bandit'
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_hybrid_filler_freezes_the_fleet_clocks():
   """The clock contract (the PR 7 serve-time attribution, extended):
   a filler update mutates params but never advances update_steps — so
@@ -197,6 +198,7 @@ def test_hybrid_filler_rejects_model_axis_mesh():
 # --- Driver integration. ---
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_filler_yield_and_frame_accounting(tmp_path):
   """Under an env-throttled feed: every staged batch still trains
   (max_steps reached — the filler never starves the real stream), the
@@ -232,6 +234,7 @@ def test_filler_yield_and_frame_accounting(tmp_path):
   assert 'env_plane_utilization' in tags
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_filler_off_parity(tmp_path):
   """Filler OFF under the same throttled feed: identical fresh-frame
   accounting (the budget/LR/fps clocks are invariant to the knob) and

@@ -126,7 +126,9 @@ class TestVtraceFormsInLearner:
   flags are actually consumed)."""
 
   @pytest.mark.parametrize('variant', [
-      dict(use_associative_scan=True),
+      # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
+      pytest.param(dict(use_associative_scan=True),
+                   marks=pytest.mark.slow),
       dict(use_pallas_vtrace=True),
   ])
   def test_matches_default_scan(self, variant):

@@ -451,6 +451,7 @@ class TestDriverIntegration:
 
 class TestBenchStage:
 
+  @pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
   def test_learner_plane_smoke_rows(self, monkeypatch):
     """Bench mechanics gate (CI): the stage produces every cell of the
     {batch, unroll} × depth grid plus the sharded-vtrace and

@@ -203,7 +203,7 @@ def test_maybe_initialize_is_a_no_op_without_coordinator():
 
 def test_maybe_initialize_is_a_no_op_when_already_joined(monkeypatch):
   calls = []
-  monkeypatch.setattr(distributed, 'is_initialized', lambda: True)
+  monkeypatch.setattr(jax.distributed, 'is_initialized', lambda: True)
   monkeypatch.setattr(distributed, 'initialize',
                       lambda *a, **k: calls.append((a, k)))
   assert distributed.maybe_initialize(
@@ -213,7 +213,7 @@ def test_maybe_initialize_is_a_no_op_when_already_joined(monkeypatch):
 
 def test_maybe_initialize_resolves_process_id_from_task(monkeypatch):
   calls = []
-  monkeypatch.setattr(distributed, 'is_initialized', lambda: False)
+  monkeypatch.setattr(jax.distributed, 'is_initialized', lambda: False)
   monkeypatch.setattr(
       distributed, 'initialize',
       lambda addr, num_processes, process_id: calls.append(

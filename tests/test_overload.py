@@ -389,6 +389,7 @@ def _frame_steps(logdir, filename='summaries.jsonl'):
           if e.get('tag') == 'env_frames_per_sec']
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_drain_resume_parity_vs_uninterrupted(tmp_path):
   """THE acceptance gate: same seeds, same frame budget — a run
   preempted mid-way (deterministic preempt_signal fault), drained and

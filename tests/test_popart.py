@@ -8,6 +8,7 @@ EMA updates and the preservation identity σ'·(w'x+b')+μ' == σ·(wx+b)+μ.
 """
 
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
@@ -132,6 +133,7 @@ def test_learner_with_popart_trains_and_preserves():
   assert new_mu[2] == prev_mu[2]
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_popart_unnormalized_values_continuous_across_update():
   """The preservation property end-to-end in the learner: after a
   train step changes the stats, the NEW params + NEW stats must give

@@ -118,6 +118,7 @@ def test_curriculum_metrics_keys_and_entropy():
   assert float(m['curriculum_levels_visited']) == 3.0
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_fused_anakin_step_folds_curriculum_in_graph():
   """The tentpole mechanics at unit scale: one fused procgen step with
   --curriculum=regret carries the per-level tables in the env state,
@@ -146,6 +147,7 @@ def test_fused_anakin_step_folds_curriculum_in_graph():
   assert np.isfinite(np.asarray(carry.env_state.level_scores)).all()
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_uniform_curriculum_emits_no_curriculum_metrics():
   from scalable_agent_tpu import driver
   from scalable_agent_tpu.parallel import anakin
@@ -532,6 +534,7 @@ def test_population_two_suites_per_task_curves(tmp_path):
 # --- Round 23: fused (vmapped) population, on-device inheritance. ---
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_vectorized_anakin_member0_matches_serial_step():
   """The parity contract behind --pbt_vectorized: member 0 of the
   vmapped N=2 program, fed the config's own hypers as traced scalars,
@@ -589,6 +592,7 @@ def test_vectorized_anakin_member0_matches_serial_step():
   assert int(np.asarray(stacked.train_state.update_steps)[1]) == 3
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_inherit_member_dir_failed_copy_preserves_loser_ladder(
     tmp_path, monkeypatch):
   """ISSUE r23 satellite: an exploit whose filesystem copy FAILS must

@@ -5,12 +5,15 @@ constructor and methods, close semantics on clean and error paths,
 fleet lifecycle, and dead-pipe → ProcessClosed."""
 
 import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
 
 from scalable_agent_tpu.envs import base
 from scalable_agent_tpu.envs.fake import FakeEnv
+from scalable_agent_tpu.envs.jittable import ProcgenEnv
 from scalable_agent_tpu.runtime import py_process
 from scalable_agent_tpu.runtime.py_process import (
     ProcessClosed, ProxyEnv, PyProcess, RemoteError, SpecMismatchError)
@@ -71,6 +74,31 @@ class FailsInMethod:
     if self._marker_path:
       with open(self._marker_path, 'w') as f:
         f.write('closed')
+
+
+class PlatformProbeEnv(ProcgenEnv):
+  """A real jittable host env that can also say where its JAX runs."""
+
+  def jax_platforms(self):
+    import jax
+    return (jax.config.jax_platforms, jax.devices()[0].platform)
+
+
+def _platform_probe_main():
+  """Body of the child-pinning test's PARENT process (this file run as
+  a script, under whatever JAX_PLATFORMS the test chose): host the
+  probe env in a PyProcess exactly as the fleet does and print what
+  the env child reports. The parent itself never touches a backend."""
+  py_process.warm_forkserver()
+  p = PyProcess(PlatformProbeEnv, dict(
+      height=24, width=32, num_actions=4, episode_length=5)).start()
+  try:
+    frame, _ = p.proxy.initial()
+    reward, done, _ = p.proxy.step(1)
+    print('CHILD_PLATFORMS', *p.proxy.jax_platforms(), frame.shape,
+          flush=True)
+  finally:
+    p.close()
 
 
 def test_proxy_arg_passing():
@@ -181,3 +209,73 @@ def test_py_process_hook_lifecycle():
   finally:
     hook.end()
   assert all(not p.running for p in processes)
+
+
+def test_env_child_is_pinned_to_cpu_whatever_the_parent_runs_on():
+  """One process per chip: the learner holds it, so a process-hosted
+  jittable env (it steps its core through JAX) must come up on the CPU
+  backend whether the parent was launched with JAX_PLATFORMS unset
+  (every backend would initialise in the child, the chip included) or
+  `tpu` (the child's CPU device lookup would raise). The two parents
+  run side by side."""
+  repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+  parents = {}
+  for parent_platforms in (None, 'tpu'):
+    env = {k: v for k, v in os.environ.items()
+           if k not in ('JAX_PLATFORMS', 'XLA_FLAGS')}
+    if parent_platforms is not None:
+      env['JAX_PLATFORMS'] = parent_platforms
+    env['PYTHONPATH'] = repo + os.pathsep + env.get('PYTHONPATH', '')
+    parents[parent_platforms] = subprocess.Popen(
+        [sys.executable, os.path.abspath(__file__)], cwd=repo, env=env,
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+  for parent_platforms, parent in parents.items():
+    stdout, stderr = parent.communicate(timeout=120)
+    assert parent.returncode == 0, (parent_platforms, stderr[-2000:])
+    assert 'CHILD_PLATFORMS cpu cpu (24, 32, 3)' in stdout, (
+        parent_platforms, stdout)
+
+
+_STOP_PROBE = """
+import os
+from scalable_agent_tpu.envs.fake import FakeEnv
+from scalable_agent_tpu.runtime import py_process
+
+def children():
+  mine = []
+  for name in os.listdir('/proc'):
+    if name.isdigit():
+      try:
+        with open(f'/proc/{name}/stat') as f:
+          ppid = int(f.read().rsplit(')', 1)[1].split()[1])
+      except OSError:
+        continue
+      if ppid == os.getpid():
+        mine.append(int(name))
+  return mine
+
+py_process.warm_forkserver()
+with py_process.hosted([py_process.PyProcess(
+    FakeEnv, constructor_kwargs=dict(height=8, width=8))]):
+  started = children()
+py_process.stop_forkserver()
+py_process.stop_forkserver()  # idempotent
+print('STARTED', len(started), 'LEFT', children())
+"""
+
+
+def test_stop_forkserver_leaves_no_child_behind():
+  """The forkserver and the resource tracker outlive their parent by a
+  moment when left alone; stopped, they are gone (and reaped) before
+  the call returns."""
+  repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+  env = dict(os.environ, JAX_PLATFORMS='cpu',
+             PYTHONPATH=repo + os.pathsep + os.environ.get('PYTHONPATH', ''))
+  out = subprocess.run([sys.executable, '-c', _STOP_PROBE], cwd=repo,
+                       env=env, capture_output=True, text=True, timeout=120)
+  assert out.returncode == 0, out.stderr[-2000:]
+  assert 'STARTED 2 LEFT []' in out.stdout, out.stdout
+
+
+if __name__ == '__main__':
+  _platform_probe_main()

@@ -13,6 +13,7 @@ import threading
 import time
 
 import numpy as np
+import pytest
 
 from scalable_agent_tpu.runtime import remote, ring_buffer
 from scalable_agent_tpu.structs import (
@@ -797,6 +798,7 @@ def test_remote_actor_feeds_training(tmp_path):
                                  max_steps=3)
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_remote_actor_feeds_sharded_training(tmp_path):
   """Remote ingest composed with the 8-device mesh path: remote-fed
   host unrolls flow through make_array_from_process_local_data into

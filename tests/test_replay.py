@@ -304,6 +304,7 @@ class TestImpactSurrogate:
     _assert_close(state_i.target_params, state_i.params, rtol=0,
                   atol=0)
 
+  @pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
   def test_parity_gate_sharded_step(self):
     """Acceptance: the same gate through the 8-virtual-device sharded
     step (impact-sharded vs vtrace-sharded, 2 steps)."""
@@ -329,6 +330,7 @@ class TestImpactSurrogate:
     _assert_close(state_v.params, state_i.params, rtol=5e-4,
                   atol=5e-6)
 
+  @pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
   def test_target_refresh_cadence(self):
     """interval=3: the anchor holds still for 3 steps, then snapshots
     the just-updated params — the version-gated publish pattern
@@ -351,6 +353,7 @@ class TestImpactSurrogate:
       want = initial if anchor_step == 0 else params_after[anchor_step]
       _assert_close(state.target_params, want, rtol=0, atol=0)
 
+  @pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
   def test_popart_anchor_stats_snapshot_with_target(self):
     """impact + PopArt (interval > 1): the anchor's PopArt stats
     snapshot refreshes WITH the anchor head. Preservation rewrites
@@ -390,6 +393,7 @@ class TestImpactSurrogate:
         assert np.any(np.asarray(state.popart.mu) !=
                       np.asarray(state.target_popart.mu))
 
+  @pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
   def test_impact_changes_updates_off_the_anchor_point(self):
     """Sanity: with a LAGGING anchor (interval > 1) the surrogate is a
     different objective — updates must actually diverge from vtrace
@@ -412,6 +416,7 @@ class TestImpactSurrogate:
                              jax.tree_util.tree_leaves(state_i.params))]
     assert max(diffs) > 1e-6
 
+  @pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
   def test_checkpoint_roundtrip_preserves_target(self, tmp_path):
     from scalable_agent_tpu import checkpoint as checkpoint_lib
     _, cfg_i = self._configs()
@@ -498,6 +503,7 @@ class TestDriverIntegration:
 
     return factory
 
+  @pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
   def test_parity_gate_driver_run(self, tmp_path):
     """Acceptance: impact at the parity point vs vtrace over a
     MULTI-STEP DRIVER RUN (deterministic feed) — final params within
@@ -514,6 +520,7 @@ class TestDriverIntegration:
       finals[name] = jax.device_get(run.state.params)
     _assert_close(finals['vtrace'], finals['impact'])
 
+  @pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
   def test_replay_run_telemetry_reaches_jsonl(self, tmp_path):
     """replay_k x replay_ratio through driver.train: training
     advances, re-serves and replays happen, and every round-10
@@ -574,6 +581,7 @@ class TestDriverIntegration:
     # to (at least) its env-frame budget.
     assert run.frames >= cfg.total_environment_frames
 
+  @pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
   def test_episode_stats_not_double_counted(self, tmp_path):
     """A re-served batch must contribute ZERO episode events: with
     replay_k=2 every batch rides twice, so episode-return events must
@@ -620,6 +628,7 @@ class TestDriverIntegration:
 
 class TestBenchStage:
 
+  @pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
   def test_replay_smoke_rows(self, monkeypatch):
     """Bench mechanics gate (CI): every replay_k x ratio cell lands
     with its reuse/H2D accounting; the k2_r0 cell carries the >=1.8x

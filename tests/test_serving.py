@@ -332,6 +332,7 @@ class TestShadowAndAot:
     finally:
       server.close()
 
+  @pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
   def test_aot_flip_serves_without_recompile(self):
     # int8-resident publishes change the params leaf DTYPES — without
     # AOT the first post-flip serve pays a full retrace. serving_aot

@@ -262,6 +262,7 @@ def test_consumers_agree_on_placements():
       mesh_lib.make_mesh(model_parallelism=1))
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_checkpoint_sharding_manifest_and_resharded_restore(tmp_path):
   """The save-side manifest (SHARDING_{step}.json: rule set, specs,
   digest) + the restore path onto registry-resolved placements for a
@@ -461,6 +462,7 @@ def test_resharded_opt_state_follows_param_specs(tmp_path):
   ckpt.close()
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_same_topology_restore_stays_byte_identical(tmp_path):
   """Regression guard for the elastic gate: when the live mesh equals
   the manifest's, the driver takes the UNCHANGED restore_latest path

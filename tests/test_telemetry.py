@@ -476,6 +476,7 @@ def test_to_tensorboard_skips_skewed_hop_points():
 # --------------------------------------------------------------------
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_e2e_remote_fleet_traces_and_report(tmp_path):
   """The acceptance bar: a learner + a no-accelerator remote actor
   child (2 OS processes) train with tracing on; traces.jsonl then

@@ -60,6 +60,7 @@ def test_missing_dir_raises(tmp_path):
     to_tensorboard.convert(str(tmp_path / 'nope'))
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_truncated_final_line_is_skipped(tmp_path):
   """A crashed trainer can leave a partial last line; the valid events
   before it must still convert."""
@@ -72,6 +73,7 @@ def test_truncated_final_line_is_skipped(tmp_path):
   assert written == {'train': 1}
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_trace_stream_converts_to_scalars(tmp_path):
   """traces.jsonl (round 13) -> a `trace` TB run with hop-latency and
   policy-lag scalars, read back through the EventAccumulator."""

@@ -115,6 +115,7 @@ def test_learner_with_pixel_control_trains():
   assert not np.allclose(before, np.asarray(after))
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_head_odd_cell_grid():
   """84x84 Atari with cell 4 → 21x21 cells (odd): the deconv stack
   rounds up and crops rather than crashing."""
@@ -142,6 +143,7 @@ def test_rewards_indivisible_frame_raises():
 # --- Round-6 fast-path parity gates (docs/PERF.md itemization). ---
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_integer_rewards_parity_with_f32_reference():
   """The integer-domain pseudo-rewards (uint8 |Δ| + int32 cell sums)
   must match the f32 reference form on random uint8 frames — including
@@ -221,6 +223,7 @@ def test_head_impl_golden_parity_fwd_and_grad():
                                  rtol=2e-4, atol=2e-5)
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_full_loss_parity_across_fast_paths():
   """End-to-end gate: the full learner loss with every round-6
   numerics-preserving lever ON (integer rewards + d2s head) matches
@@ -252,6 +255,7 @@ def test_full_loss_parity_across_fast_paths():
   np.testing.assert_allclose(r6[1], ref[1], rtol=1e-5)
 
 
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_bf16_q_lever_close_to_f32():
   """The opt-in pixel_control_q_f32=False lever keeps the Q-map in the
   compute dtype until the loss gather — numerics-AFFECTING by design,

@@ -343,7 +343,19 @@ class TestVtracePallas:
                                      use_associative_scan=True,
                                      **values)
 
+  def test_interprets_on_cpu_only_and_rejects_unknown_platforms(
+      self, monkeypatch):
+    """Compiled on tpu, interpreted on cpu; a platform that is neither
+    must raise instead of silently running the interpreter."""
+    from scalable_agent_tpu.ops import vtrace_pallas
+    assert vtrace_pallas._interpret_on('tpu') is False
+    assert vtrace_pallas._interpret_on('cpu') is True
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'gpu')
+    with pytest.raises(RuntimeError, match="platform 'gpu'"):
+      vtrace.from_importance_weights(use_pallas=True, **_make_inputs(1))
 
+
+@pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
 def test_associative_scan_long_sequence():
   """Long-T readiness (SURVEY §5.7): the associative-scan V-trace is
   the sequence-scaling door — verify it matches the sequential scan at
