@@ -95,14 +95,16 @@ def _class_assign(tree: ast.AST, cls: str, name: str
 _METRIC_NAME = re.compile(r'[a-z0-9_]+(?:/[a-z0-9_]+)+')
 
 
-def registered_metric_names(ctx: CheckContext
+def registered_metric_names(ctx: CheckContext,
+                            kinds=('counter', 'gauge', 'histogram')
                             ) -> Dict[str, Tuple[str, int]]:
   """Every literal-string telemetry registration in the package:
   {metric_name: (path, line)}. A registration is a call to
   `counter`/`gauge`/`histogram` either bare (telemetry.py itself) or
   as an attribute of `telemetry`/`_telemetry` — `writer.histogram`
   (the summary stream API) is a different surface and excluded, same
-  as the ci.sh heredoc this replaces."""
+  as the ci.sh heredoc this replaces. With `kinds=('span', 'park')`:
+  the span recorder's sites, whose names share the docs' spelling."""
   out: Dict[str, Tuple[str, int]] = {}
   for rel in ctx.package_sources():
     for node in ast.walk(ctx.tree(rel)):
@@ -110,10 +112,10 @@ def registered_metric_names(ctx: CheckContext
         continue
       fn = node.func
       if isinstance(fn, ast.Name):
-        if fn.id not in ('counter', 'gauge', 'histogram'):
+        if fn.id not in kinds:
           continue
       elif isinstance(fn, ast.Attribute):
-        if fn.attr not in ('counter', 'gauge', 'histogram'):
+        if fn.attr not in kinds:
           continue
         if not (isinstance(fn.value, ast.Name)
                 and fn.value.id in ('telemetry', '_telemetry')):
@@ -135,20 +137,25 @@ def _documented_metric_names(ctx: CheckContext) -> Set[str]:
 
 
 @checker('metric-names',
-         'every telemetry counter/gauge/histogram registration in '
-         'scalable_agent_tpu/ appears in the docs/OBSERVABILITY.md '
-         'inventory, and no documented name is orphaned')
+         'every telemetry counter/gauge/histogram registration and '
+         'every span/park site in scalable_agent_tpu/ appears in '
+         'docs/OBSERVABILITY.md (the inventory, the span table), and '
+         'no documented name is orphaned')
 def check_metric_names(ctx: CheckContext) -> List[Finding]:
   registered = registered_metric_names(ctx)
+  spans = registered_metric_names(ctx, kinds=('span', 'park'))
   documented = _documented_metric_names(ctx)
   findings = []
-  for name in sorted(set(registered) - documented):
-    path, line = registered[name]
-    findings.append(Finding(
-        'metric-names', path, line, name,
-        f'registered metric {name!r} is missing from the '
-        'docs/OBSERVABILITY.md inventory'))
-  for name in sorted(documented - set(registered)):
+  for names, what, where in ((registered, 'registered metric',
+                              'inventory'),
+                             (spans, 'span', 'span table')):
+    for name in sorted(set(names) - documented):
+      path, line = names[name]
+      findings.append(Finding(
+          'metric-names', path, line, name,
+          f'{what} {name!r} is missing from the '
+          f'docs/OBSERVABILITY.md {where}'))
+  for name in sorted(documented - set(registered) - set(spans)):
     findings.append(Finding(
         'metric-names', 'docs/OBSERVABILITY.md', 1, name,
         f'documented metric {name!r} is no longer registered '

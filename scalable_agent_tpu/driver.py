@@ -1145,6 +1145,9 @@ def train(config: Config, max_steps: Optional[int] = None,
   # supports one trace at a time, so this and the config.profile_dir
   # window are mutually exclusive in the loop below.
   slo_profile = None
+  # The capture under way, either kind (observability.ProfilerCapture:
+  # device-only profiler + the span recorder armed beside it).
+  capture = None
   errors: List[BaseException] = []
   # Unified-registry view of the loop itself (round 13): the step and
   # frame clocks every other counter is read against. Lazy closures
@@ -1226,8 +1229,16 @@ def train(config: Config, max_steps: Optional[int] = None,
   last_filler_skipped = 0
   poll_secs = 10.0 if stall_timeout_secs is None else min(
       10.0, stall_timeout_secs)
+  # Recorder span 'learner/iteration': one per learner STEP, from the
+  # end of the last step's pass of this loop to the end of this one's,
+  # whatever passes without a batch (a timed-out get, a filler slice)
+  # lie between. A `park`, as the wait it holds: seconds long, so kept
+  # where it straddles an end of a capture.
+  iteration = None
   try:
     while True:
+      if iteration is None:
+        iteration = telemetry.park('learner/iteration')
       # --- Preemption drain request (SIGTERM via drain_event, or the
       # deterministic 'preempt_signal' fault site): quiesce instead of
       # dying mid-step. The fault site is consulted every loop
@@ -1353,14 +1364,15 @@ def train(config: Config, max_steps: Optional[int] = None,
         if (profile_dir_pending and not profiling
             and slo_profile is None
             and steps_done >= config.profile_start_step):
-          jax.profiler.start_trace(config.profile_dir)
+          capture = observability.ProfilerCapture(config.profile_dir)
           profiling = True
           profile_dir_pending = False
           profile_stop_step = steps_done + config.profile_num_steps
         elif profiling and steps_done >= profile_stop_step:
-          jax.profiler.stop_trace()
+          capture.stop()
           profiling = False
-          log.info('profiler trace written to %s', config.profile_dir)
+          log.info('profiler trace and spans.json written to %s',
+                   config.profile_dir)
       # SLO-triggered deep diagnostics (round 14): a page-severity
       # burn queued a bounded profiler capture — the next
       # slo_capture_steps learner steps trace into
@@ -1372,7 +1384,7 @@ def train(config: Config, max_steps: Optional[int] = None,
         if slo_profile is not None:
           name, end_step = slo_profile
           if steps_done >= end_step:
-            jax.profiler.stop_trace()
+            capture.stop()
             slo_profile = None
             log.info('SLO diagnostic profile for %r complete', name)
         else:
@@ -1382,7 +1394,7 @@ def train(config: Config, max_steps: Optional[int] = None,
                                         f'slo_profile_{req}')
             os.makedirs(slo_prof_dir, exist_ok=True)
             try:
-              jax.profiler.start_trace(slo_prof_dir)
+              capture = observability.ProfilerCapture(slo_prof_dir)
             except Exception:
               log.exception('SLO profiler capture failed to start')
               slo_engine.note_profile(req, None)
@@ -1410,7 +1422,8 @@ def train(config: Config, max_steps: Optional[int] = None,
       slow = faults_lib.fire('slow_learner')
       if slow is not None and slow.kind == 'hang':
         time.sleep(float(slow.param))
-      state, metrics = train_step(run.state, batch_device)
+      with telemetry.span('learner/step_dispatch'):
+        state, metrics = train_step(run.state, batch_device)
       run.state = state
       steps_done += 1
       if env_frames_fn is None:
@@ -1590,6 +1603,7 @@ def train(config: Config, max_steps: Optional[int] = None,
               bundle_path=bundle)
 
       if steps_done % config.publish_params_every == 0:
+        publish = telemetry.span('learner/publish')
         # actor_params is a cross-host collective in multi-host-TP
         # mode: it must run UNCONDITIONALLY here (lockstep branch),
         # never inside the per-host time-gated ingest publish below.
@@ -1625,10 +1639,12 @@ def train(config: Config, max_steps: Optional[int] = None,
         # join keys on it.
         if tracer is not None:
           tracer.on_publish(step_now, remote_version=remote_version)
+        publish.end()
 
       now = time.monotonic()
       if now - last_summary >= config.summary_secs:
         last_summary = now
+        summaries = telemetry.span('learner/summaries')
         # One-step-delayed stacked read (round 8): the previous step's
         # metrics land in a single transfer of already-computed values.
         # Written at step_now — one step stale, immaterial at summary
@@ -2052,6 +2068,7 @@ def train(config: Config, max_steps: Optional[int] = None,
         # steps left to profile).
         if slo_engine is not None:
           slo_engine.observe()
+        summaries.end()
       # Checkpoint cadence: Orbax saves are collective across hosts;
       # clocks differ, so all hosts act on PROCESS 0's decision (a
       # host-local clock here would desync the barrier and deadlock).
@@ -2074,6 +2091,8 @@ def train(config: Config, max_steps: Optional[int] = None,
             jnp.asarray(checkpointer.should_save()))) and healthy_now
         checkpointer.maybe_save(state, decision=decision)
       fleet.check_health(stall_timeout_secs=stall_timeout_secs)
+      iteration.end()
+      iteration = None
     if draining:
       # --- Drain finalize: quiesce → flush already happened in the
       # loop; now join the fleet (bounded), close the prefetcher
@@ -2161,6 +2180,8 @@ def train(config: Config, max_steps: Optional[int] = None,
                     step_final)
   finally:
     exiting_clean = sys.exc_info()[0] is None
+    if iteration is not None:
+      iteration.end()  # the loop was left mid-pass
     # One robustness roll-up while the fleet still runs (stats after
     # stop() would read an all-dead fleet): what the run's failure
     # domain absorbed, in the same counters the summaries carry.
@@ -2218,7 +2239,7 @@ def train(config: Config, max_steps: Optional[int] = None,
       except Exception:
         log.exception('SLO verdict write failed')
     if profiling or slo_profile is not None:
-      jax.profiler.stop_trace()
+      capture.stop()
     elif config.profile_dir and profile_dir_pending:
       log.warning(
           'profile_dir set but the run ended at step %d before the '
@@ -2488,6 +2509,7 @@ def train_anakin(config: Config, max_steps: Optional[int] = None,
   pending_sentinel = None
   bad_count_in_burst = 0
   slo_profile = None
+  capture = None  # the ProfilerCapture under way
   loop_start = time.monotonic()
   last_summary = loop_start
   try:
@@ -2529,7 +2551,7 @@ def train_anakin(config: Config, max_steps: Optional[int] = None,
         if slo_profile is not None:
           name, end_step = slo_profile
           if steps_done >= end_step:
-            jax.profiler.stop_trace()
+            capture.stop()
             slo_profile = None
             log.info('SLO diagnostic profile for %r complete', name)
         else:
@@ -2539,7 +2561,7 @@ def train_anakin(config: Config, max_steps: Optional[int] = None,
                                         f'slo_profile_{req}')
             os.makedirs(slo_prof_dir, exist_ok=True)
             try:
-              jax.profiler.start_trace(slo_prof_dir)
+              capture = observability.ProfilerCapture(slo_prof_dir)
             except Exception:
               log.exception('SLO profiler capture failed to start')
               slo_engine.note_profile(req, None)
@@ -2656,7 +2678,7 @@ def train_anakin(config: Config, max_steps: Optional[int] = None,
       except Exception:
         log.exception('SLO verdict write failed')
     if slo_profile is not None:
-      jax.profiler.stop_trace()
+      capture.stop()
     try:
       # Final summary flush: short runs end inside one window and
       # would otherwise ship empty curves (anakin.train's contract).

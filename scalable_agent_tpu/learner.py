@@ -198,44 +198,48 @@ def loss_fn(params, agent, batch: ActorOutput, config: Config,
                              target_for_align, config)
   else:
     vtrace_src = inputs
-  vtrace_returns = vtrace.from_logits(
-      behaviour_policy_logits=vtrace_src.behaviour_logits,
-      target_policy_logits=vtrace_src.target_logits,
-      actions=vtrace_src.actions,
-      discounts=vtrace_src.discounts,
-      rewards=vtrace_src.rewards,
-      values=vtrace_src.values,
-      bootstrap_value=vtrace_src.bootstrap_value,
-      use_associative_scan=config.use_associative_scan,
-      use_pallas=config.use_pallas_vtrace,
-      mesh=mesh)
-  if use_impact:
-    log_ratio = (vtrace.log_probs_from_logits_and_actions(
-        inputs.target_logits, inputs.actions) -
-        vtrace_returns.target_action_log_probs)
-    pg_loss = losses_lib.compute_impact_surrogate_loss(
-        log_ratio, vtrace_returns.pg_advantages, config.impact_epsilon)
-    metrics_extra['impact_clip_fraction'] = losses_lib.\
-        impact_clip_fraction(log_ratio, config.impact_epsilon)
-  else:
-    pg_loss = losses_lib.compute_policy_gradient_loss(
-        inputs.target_logits, inputs.actions,
-        vtrace_returns.pg_advantages)
-  if popart_state is not None:
-    # Regress the normalized head toward normalized targets.
-    norm_targets = popart_lib.normalize(
-        popart_state, vtrace_returns.vs, task_ids)
-    baseline_loss = losses_lib.compute_baseline_loss(
-        jax.lax.stop_gradient(norm_targets) -
-        learner_outputs.baseline[:-1])
-  else:
-    baseline_loss = losses_lib.compute_baseline_loss(
-        vtrace_returns.vs - inputs.values)
-  entropy_loss = losses_lib.compute_entropy_loss(inputs.target_logits)
+  # (`vtrace`, `loss` and `optimizer` are scopes in the compiled step's
+  # operation names, beside the agent's `torso`, `core` and `heads`.)
+  with jax.named_scope('vtrace'):
+    vtrace_returns = vtrace.from_logits(
+        behaviour_policy_logits=vtrace_src.behaviour_logits,
+        target_policy_logits=vtrace_src.target_logits,
+        actions=vtrace_src.actions,
+        discounts=vtrace_src.discounts,
+        rewards=vtrace_src.rewards,
+        values=vtrace_src.values,
+        bootstrap_value=vtrace_src.bootstrap_value,
+        use_associative_scan=config.use_associative_scan,
+        use_pallas=config.use_pallas_vtrace,
+        mesh=mesh)
+  with jax.named_scope('loss'):
+    if use_impact:
+      log_ratio = (vtrace.log_probs_from_logits_and_actions(
+          inputs.target_logits, inputs.actions) -
+          vtrace_returns.target_action_log_probs)
+      pg_loss = losses_lib.compute_impact_surrogate_loss(
+          log_ratio, vtrace_returns.pg_advantages, config.impact_epsilon)
+      metrics_extra['impact_clip_fraction'] = losses_lib.\
+          impact_clip_fraction(log_ratio, config.impact_epsilon)
+    else:
+      pg_loss = losses_lib.compute_policy_gradient_loss(
+          inputs.target_logits, inputs.actions,
+          vtrace_returns.pg_advantages)
+    if popart_state is not None:
+      # Regress the normalized head toward normalized targets.
+      norm_targets = popart_lib.normalize(
+          popart_state, vtrace_returns.vs, task_ids)
+      baseline_loss = losses_lib.compute_baseline_loss(
+          jax.lax.stop_gradient(norm_targets) -
+          learner_outputs.baseline[:-1])
+    else:
+      baseline_loss = losses_lib.compute_baseline_loss(
+          vtrace_returns.vs - inputs.values)
+    entropy_loss = losses_lib.compute_entropy_loss(inputs.target_logits)
 
-  ec = config.entropy_cost if entropy_cost is None else entropy_cost
-  total_loss = (pg_loss + config.baseline_cost * baseline_loss +
-                ec * entropy_loss)
+    ec = config.entropy_cost if entropy_cost is None else entropy_cost
+    total_loss = (pg_loss + config.baseline_cost * baseline_loss +
+                  ec * entropy_loss)
   metrics = {
       'total_loss': total_loss,
       'pg_loss': pg_loss,
@@ -416,11 +420,12 @@ def make_train_step_fn(agent, config: Config, mesh=None,
                                state.target_popart, ec)
     # Pre-clip norm: explosions must stay visible even with clipping on.
     metrics['grad_norm'] = optax.global_norm(grads)
-    updates, new_opt_state = optimizer.update(
-        grads, state.opt_state, state.params)
-    if traced_hypers:
-      updates = jax.tree_util.tree_map(lambda u: lr * u, updates)
-    new_params = optax.apply_updates(state.params, updates)
+    with jax.named_scope('optimizer'):
+      updates, new_opt_state = optimizer.update(
+          grads, state.opt_state, state.params)
+      if traced_hypers:
+        updates = jax.tree_util.tree_map(lambda u: lr * u, updates)
+      new_params = optax.apply_updates(state.params, updates)
     new_popart = state.popart
     if state.popart is not None:
       # PopArt: EMA the per-task moments toward this batch's targets,

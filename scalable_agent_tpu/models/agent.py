@@ -110,8 +110,13 @@ class ImpalaAgent(nn.Module):
     # (Torso rematerialization was tried and REJECTED: +20% step time
     # at [T=100, B=32] — XLA's remat re-reads more bytes than it
     # saves here. Measurements in docs/PERF.md.)
+    # (`torso`, `core` and `heads` are scopes in the compiled program's
+    # operation names, which the device trace's per-scope shares read:
+    # Flax names its modules by class inside them, and not the scan's
+    # own slicing or the reshapes around the heads.)
     flat_frame = frame.reshape((t * b,) + frame.shape[2:])
-    torso_out = TORSOS[self.torso](dtype=self.dtype)(flat_frame)
+    with jax.named_scope('torso'):
+      torso_out = TORSOS[self.torso](dtype=self.dtype)(flat_frame)
 
     clipped_reward = jnp.clip(reward, -1.0, 1.0).reshape(t * b, 1)
     one_hot_action = jax.nn.one_hot(
@@ -131,7 +136,8 @@ class ImpalaAgent(nn.Module):
     core = _ResetCore(self.hidden_size, dtype=self.dtype)
     core_state = jax.tree_util.tree_map(
         lambda s: s.astype(self.dtype), core_state)
-    new_state, core_out = scan(core, core_state, (core_input, done))
+    with jax.named_scope('core'):
+      new_state, core_out = scan(core, core_state, (core_input, done))
     new_state = jax.tree_util.tree_map(
         lambda s: s.astype(jnp.float32), new_state)
 
@@ -148,27 +154,28 @@ class ImpalaAgent(nn.Module):
                               name='pixel_control')(flat_core)
       self.sow('intermediates', 'pixel_control_q',
                pc_q.reshape(t, b, hc, wc, self.num_actions))
-    policy_logits = nn.Dense(self.num_actions, dtype=self.dtype,
-                             name='policy_logits')(flat_core)
-    num_values = max(self.num_popart_tasks, 1)
-    baseline = nn.Dense(num_values, dtype=self.dtype,
-                        name='baseline')(flat_core)
-    policy_logits = policy_logits.astype(jnp.float32).reshape(
-        t, b, self.num_actions)
-    baseline = baseline.astype(jnp.float32).reshape(t, b, num_values)
-    if self.num_popart_tasks:
-      if level_ids is None:
-        level_ids = jnp.zeros((b,), jnp.int32)
-      baseline = jnp.take_along_axis(
-          baseline, level_ids[None, :, None].astype(jnp.int32),
-          axis=2)
-    baseline = baseline[..., 0]
+    with jax.named_scope('heads'):
+      policy_logits = nn.Dense(self.num_actions, dtype=self.dtype,
+                               name='policy_logits')(flat_core)
+      num_values = max(self.num_popart_tasks, 1)
+      baseline = nn.Dense(num_values, dtype=self.dtype,
+                          name='baseline')(flat_core)
+      policy_logits = policy_logits.astype(jnp.float32).reshape(
+          t, b, self.num_actions)
+      baseline = baseline.astype(jnp.float32).reshape(t, b, num_values)
+      if self.num_popart_tasks:
+        if level_ids is None:
+          level_ids = jnp.zeros((b,), jnp.int32)
+        baseline = jnp.take_along_axis(
+            baseline, level_ids[None, :, None].astype(jnp.int32),
+            axis=2)
+      baseline = baseline[..., 0]
 
-    if sample_rng is not None:
-      action = jax.random.categorical(sample_rng, policy_logits, axis=-1)
-    else:
-      action = jnp.argmax(policy_logits, axis=-1)
-    action = action.astype(jnp.int32)
+      if sample_rng is not None:
+        action = jax.random.categorical(sample_rng, policy_logits, axis=-1)
+      else:
+        action = jnp.argmax(policy_logits, axis=-1)
+      action = action.astype(jnp.int32)
 
     return AgentOutput(action, policy_logits, baseline), new_state
 

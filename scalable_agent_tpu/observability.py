@@ -269,6 +269,69 @@ def read_stacked_metrics(handle) -> Dict[str, float]:
   return {k: float(v) for k, v in zip(keys, values)}
 
 
+CAPTURE_LANDMARK = 'capture_clock_sync'
+_landmark = None  # (the jitted landmark, its argument), once warmed
+
+
+def _run_landmark() -> int:
+  """Runs the landmark program to its end; the host's clock then."""
+  global _landmark
+  import jax
+  if _landmark is None:
+    import jax.numpy as jnp
+
+    def capture_clock_sync(x):
+      return x + 1
+
+    _landmark = (jax.jit(capture_clock_sync), jnp.zeros((), jnp.int32))
+  jax.block_until_ready(_landmark[0](_landmark[1]))
+  return time.perf_counter_ns()
+
+
+class ProfilerCapture:
+  """One bounded `jax.profiler` capture into `directory`: THE way this
+  program starts and stops the profiler.
+
+  The profiler records the DEVICE only (`host_tracer_level` and
+  `python_tracer_level` 0). With its host tracer on, a 32-actor fleet
+  ran at 420 policy calls/s instead of 965 and the capture took 250 s
+  to stop (PERF.md, PR 22): what it showed was the tracer. The host's
+  side comes from the span recorder instead (telemetry.arm_spans),
+  armed for the capture; `stop` writes it to `<directory>/spans.json`
+  as `telemetry.take_spans()` gives it, plus `landmark`: the name of
+  a tiny program run right after the profiler started and the host
+  clock (`perf_counter_ns`) at which the host saw it end. The device
+  trace records that program's end too (the first `XLA Modules` event
+  named `jit_capture_clock_sync`), which puts both on one clock.
+  `scripts/trace_report.py <directory>/spans.json` summarizes the
+  spans. One capture at a time: the profiler and the recorder are
+  both process-wide."""
+
+  def __init__(self, directory: str):
+    import jax
+    self.directory = directory
+    os.makedirs(directory, exist_ok=True)
+    _run_landmark()  # compiled before the profiler looks
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = 0
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(directory, profiler_options=options)
+    self._landmark_ns = _run_landmark()
+    telemetry.arm_spans()
+
+  def stop(self) -> str:
+    """Stops the profiler and writes spans.json; returns its path."""
+    import jax
+    taken = telemetry.take_spans() or {}
+    jax.profiler.stop_trace()
+    taken['landmark'] = {'module': f'jit_{CAPTURE_LANDMARK}',
+                         'host_perf_ns': self._landmark_ns}
+    path = os.path.join(self.directory, 'spans.json')
+    with open(path, 'w') as f:
+      json.dump(taken, f)
+    return path
+
+
 def extract_episodes(batch) -> List[Tuple[int, float, int]]:
   """Finished episodes in a dequeued [T+1, B] batch.
 

@@ -440,13 +440,18 @@ def make_anakin_step(agent, env_core, config: Config,
           acting_carry)
       rng, sample_rng = jax.random.split(rng)
       # T=1 apply of the SAME agent the learner unrolls — one model.
-      out_t, new_core = agent.apply(
-          params, agent_output.action[None],
-          jax.tree_util.tree_map(lambda x: x[None], env_output),
-          core_state, sample_rng=sample_rng)
+      # (`acting`, `env` and `learn` are scopes in the fused program's
+      # operation names: the device trace's per-scope shares tell the
+      # environment from the agent by them.)
+      with jax.named_scope('acting'):
+        out_t, new_core = agent.apply(
+            params, agent_output.action[None],
+            jax.tree_util.tree_map(lambda x: x[None], env_output),
+            core_state, sample_rng=sample_rng)
       new_agent_output = jax.tree_util.tree_map(lambda x: x[0], out_t)
-      new_env_state, new_env_output = env_core.step(
-          env_state, new_agent_output.action)
+      with jax.named_scope('env'):
+        new_env_state, new_env_output = env_core.step(
+            env_state, new_agent_output.action)
       # Pre-step level ids: the level each transition was PLAYED in
       # (step resamples at done, so the post-step id may already be
       # next episode's).
@@ -472,12 +477,13 @@ def make_anakin_step(agent, env_core, config: Config,
         agent_outputs=jax.tree_util.tree_map(
             lambda first, rest: jnp.concatenate([first[None], rest]),
             carry.agent_output, tail[1]))
-    if traced_hypers:
-      new_train_state, metrics = train_step_fn(carry.train_state,
-                                               batch, hypers)
-    else:
-      new_train_state, metrics = train_step_fn(carry.train_state,
-                                               batch)
+    with jax.named_scope('learn'):
+      if traced_hypers:
+        new_train_state, metrics = train_step_fn(carry.train_state,
+                                                 batch, hypers)
+      else:
+        new_train_state, metrics = train_step_fn(carry.train_state,
+                                                 batch)
     if not advance_steps:
       new_train_state = new_train_state._replace(
           update_steps=carry.train_state.update_steps)

@@ -86,8 +86,14 @@ class Actor:
     self._episode_return = np.float32(0.0)
     self._episode_step = np.int32(0)
 
-  def unroll(self) -> ActorOutput:
-    """Produce one ActorOutput of [T+1] time-major numpy arrays."""
+  def unroll(self, span_id=None) -> ActorOutput:
+    """Produce one ActorOutput of [T+1] time-major numpy arrays.
+    `span_id` is the `id` its recorder spans carry (telemetry.span):
+    the actor loop passes the unroll's `(actor, seq)`."""
+    with telemetry.span('actor/unroll', id=span_id):
+      return self._unroll()
+
+  def _unroll(self) -> ActorOutput:
     # Device-resident policy state (InferenceServer state-cache mode)
     # is an opaque handle: the learner still needs the NUMERIC carry
     # at the unroll start, so snapshot it here — the once-per-unroll
@@ -115,36 +121,43 @@ class Actor:
     agent_outputs = [self._agent_output]
 
     for _ in range(self._unroll_length):
-      agent_output, core_state = self._policy(
-          self._agent_output.action, self._env_output, self._core_state)
-      agent_output = AgentOutput(
-          *[np.asarray(x) for x in agent_output])
-      reward, done, observation = self._env.step(
-          int(agent_output.action))
+      with telemetry.span('actor/step'):
+        with telemetry.span('actor/policy_call'):
+          agent_output, core_state = self._policy(
+              self._agent_output.action, self._env_output,
+              self._core_state)
+        agent_output = AgentOutput(
+            *[np.asarray(x) for x in agent_output])
+        with telemetry.span('actor/env_step'):
+          reward, done, observation = self._env.step(
+              int(agent_output.action))
 
-      # Flow-style episode accounting (output carries final stats at
-      # done; carried state resets).
-      self._episode_return = np.float32(self._episode_return + reward)
-      self._episode_step = np.int32(
-          self._episode_step + self._num_action_repeats)
-      info = StepOutputInfo(self._episode_return, self._episode_step)
-      if done:
-        self._episode_return = np.float32(0.0)
-        self._episode_step = np.int32(0)
+        # Flow-style episode accounting (output carries final stats at
+        # done; carried state resets).
+        self._episode_return = np.float32(self._episode_return + reward)
+        self._episode_step = np.int32(
+            self._episode_step + self._num_action_repeats)
+        info = StepOutputInfo(self._episode_return, self._episode_step)
+        if done:
+          self._episode_return = np.float32(0.0)
+          self._episode_step = np.int32(0)
 
-      env_output = StepOutput(np.float32(reward), info, np.bool_(done),
-                              observation)
-      env_outputs.append(env_output)
-      agent_outputs.append(agent_output)
-      self._env_output = env_output
-      self._agent_output = agent_output
-      self._core_state = core_state
+        env_output = StepOutput(np.float32(reward), info, np.bool_(done),
+                                observation)
+        env_outputs.append(env_output)
+        agent_outputs.append(agent_output)
+        self._env_output = env_output
+        self._agent_output = agent_output
+        self._core_state = core_state
 
+    with telemetry.span('actor/assemble'):
+      env_outputs = _tree_stack(env_outputs)
+      agent_outputs = _tree_stack(agent_outputs)
     return ActorOutput(
         level_name=self._level_name_id,
         agent_state=initial_core_state,
-        env_outputs=_tree_stack(env_outputs),
-        agent_outputs=_tree_stack(agent_outputs))
+        env_outputs=env_outputs,
+        agent_outputs=agent_outputs)
 
   def release_policy_state(self):
     """Return device-resident policy state (a state-arena slot) to its
@@ -214,7 +227,10 @@ def run_actor_loop(actor: Actor, buffer, stop_event,
 
   try:
     while not stop_event.is_set():
-      unroll = actor.unroll()
+      # The recorder's spans of this unroll carry the (actor, seq) of
+      # its trace context, so spans and traces.jsonl hops join.
+      span_id = (actor_name, unroll_seq)
+      unroll = actor.unroll(span_id=span_id)
       trace = telemetry.begin_unroll_trace(actor_name, unroll_seq)
       if trace is not None:
         telemetry.stamp(trace, telemetry.HOP_DONE)
@@ -229,17 +245,18 @@ def run_actor_loop(actor: Actor, buffer, stop_event,
       # normally does) and is then dropped — a joined thread with a
       # named lost unroll beats a wedged one.
       stop_deadline = None
-      while True:
-        try:
-          buffer.put(unroll, timeout=_PUT_POLL_SECS)
-          break
-        except TimeoutError:
-          if not stop_event.is_set():
-            continue
-          if stop_deadline is None:
-            stop_deadline = time.monotonic() + _STOP_PUT_GRACE_SECS
-          elif time.monotonic() > stop_deadline:
-            return  # stopping and nobody is draining: drop + exit
+      with telemetry.park('actor/put', id=span_id):
+        while True:
+          try:
+            buffer.put(unroll, timeout=_PUT_POLL_SECS)
+            break
+          except TimeoutError:
+            if not stop_event.is_set():
+              continue
+            if stop_deadline is None:
+              stop_deadline = time.monotonic() + _STOP_PUT_GRACE_SECS
+            elif time.monotonic() > stop_deadline:
+              return  # stopping and nobody is draining: drop + exit
       if on_unroll is not None and not on_unroll():
         return  # orphaned: a replacement owns this actor's slot
   except (ring_buffer.Closed, BatcherCancelled) as e:

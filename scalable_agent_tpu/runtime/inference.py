@@ -914,8 +914,11 @@ class InferenceServer:
       try:
         # Late-bound: _staging_for is resolved per batch, after the
         # (long) park in get_batch — not captured at loop entry.
-        item = self._batcher.get_batch_into(
-            lambda rows: self._staging_for(rows))
+        with telemetry.park('inference/wait_batch') as wait:
+          item = self._batcher.get_batch_into(
+              lambda rows: self._staging_for(rows))
+          if item is not None:
+            wait.id = item[0]  # the batch this wait ended with
       except Exception:
         # Staging-buffer construction failed; get_batch_into answers
         # the batch's callers with the error before re-raising (its
@@ -930,6 +933,7 @@ class InferenceServer:
         return
       batch_id, n, bufs = item
       t0 = time.perf_counter()
+      dispatch = telemetry.span('inference/dispatch', id=batch_id)
       try:
         if self._state_cache:
           # The staging ring reuses buffers: rows [n:] may hold slot
@@ -965,6 +969,8 @@ class InferenceServer:
         self._completion_q.put((batch_id, n, t0, payload, shadow_out))
       except Exception as e:  # propagate to the parked callers
         self._batcher.set_error(batch_id, f'{type(e).__name__}: {e}')
+      finally:
+        dispatch.end()
 
   def _completion_loop(self):
     while True:
@@ -983,9 +989,11 @@ class InferenceServer:
         # ONE device_get for all outputs: each separate device→host
         # readback is a full round trip, so batching the transfer is
         # strictly better.
-        host = jax.device_get(payload)
-        self._batcher.set_outputs(
-            batch_id, [np.asarray(o)[:n] for o in host])
+        with telemetry.span('inference/readback', id=batch_id):
+          host = jax.device_get(payload)
+        with telemetry.span('inference/unpark', id=batch_id):
+          self._batcher.set_outputs(
+              batch_id, [np.asarray(o)[:n] for o in host])
         if shadow_out is not None:
           # Shadow scoring AFTER the callers are answered: the gauge
           # must never add device_get latency to the live path. Logits
@@ -1587,25 +1595,30 @@ class InferenceServer:
         # A respawned actor owns this slot's successor; a straggler
         # thread must fail here, not scatter into someone else's slot.
         raise RuntimeError('policy() called with a released state slot')
-      action, logits, baseline = self._batcher.compute([
+      inputs = [
           np.asarray([core_state.slot], np.int32),
           np.asarray([prev_action], np.int32),
           np.asarray([env_output.reward], np.float32),
           np.asarray([env_output.done], bool),
           np.asarray(frame)[None],
-          np.asarray(instr)[None]])
+          np.asarray(instr)[None]]
+      with telemetry.span('batcher/compute'):
+        action, logits, baseline = self._batcher.compute(inputs)
       out = AgentOutput(action=action[0], policy_logits=logits[0],
                         baseline=baseline[0])
       return out, core_state
     core_c, core_h = core_state
-    action, logits, baseline, new_c, new_h = self._batcher.compute([
+    inputs = [
         np.asarray([prev_action], np.int32),
         np.asarray([env_output.reward], np.float32),
         np.asarray([env_output.done], bool),
         np.asarray(frame)[None],
         np.asarray(instr)[None],
         np.asarray(core_c, np.float32),
-        np.asarray(core_h, np.float32)])
+        np.asarray(core_h, np.float32)]
+    with telemetry.span('batcher/compute'):
+      action, logits, baseline, new_c, new_h = self._batcher.compute(
+          inputs)
     out = AgentOutput(action=action[0], policy_logits=logits[0],
                       baseline=baseline[0])
     return out, (new_c, new_h)

@@ -47,6 +47,8 @@ from multiprocessing.pool import ThreadPool
 
 import numpy as np
 
+from scalable_agent_tpu import telemetry
+
 DEFAULT_START_METHOD = 'forkserver'
 
 
@@ -265,6 +267,7 @@ class PyProcess:
         return buffered
 
       reply = None
+      pipe = telemetry.span('env/pipe')  # send -> reply in hand
       try:
         self._conn.send((method, args, kwargs))
       except (EOFError, OSError, BrokenPipeError) as e:
@@ -289,6 +292,7 @@ class PyProcess:
           raise RemoteError(
               f'in hosted {self._type.__name__}.{method}: reply could '
               f'not be deserialized ({e!r})') from e
+      pipe.end()
       status, payload = reply
     if status == 'exception':
       exc, tb = payload

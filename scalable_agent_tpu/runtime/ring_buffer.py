@@ -829,17 +829,19 @@ class BatchPrefetcher:
     peel."""
     tracer = telemetry.get_tracer()
     if self._stager is None:
-      items, n_fresh = self._buffer.get_unrolls(self._batch_size)
+      with telemetry.park('staging/wait_unrolls'):
+        items, n_fresh = self._buffer.get_unrolls(self._batch_size)
       if tracer is not None:
         # Trace hop (round 13): this batch's fresh unrolls were
         # picked for staging — completes each sidecar span's STAGED
         # stamp and opens the batch's entry in the tracer's FIFO
         # (serve/step stamps follow in this same FIFO order).
         tracer.on_batch(items, n_fresh)
-      batch = batch_unrolls(items)
-      if self._fresh_aware:
-        return self._place_fn(batch, n_fresh), n_fresh
-      return self._place_fn(batch), n_fresh  # async put: overlaps
+      with telemetry.span('staging/stage'):
+        batch = batch_unrolls(items)
+        if self._fresh_aware:
+          return self._place_fn(batch, n_fresh), n_fresh
+        return self._place_fn(batch), n_fresh  # async put: overlaps
     # Unroll mode stays INCREMENTAL: each fresh unroll stages (and
     # starts its H2D) the moment it dequeues — batching the dequeue
     # would turn the trickle back into a step-boundary burst. Replayed
@@ -847,15 +849,20 @@ class BatchPrefetcher:
     replayed = self._buffer.sample_replay(self._batch_size)
     n_fresh = self._batch_size - len(replayed)
     fresh_items = []
+    # (So here the two spans alternate per UNROLL, and a batch's
+    # staging is the sum of its `staging/stage` spans.)
     for _ in range(n_fresh):
-      unroll = self._buffer.get()
+      with telemetry.park('staging/wait_unrolls'):
+        unroll = self._buffer.get()
       fresh_items.append(unroll)
-      self._stager.add(unroll)
-    for unroll in replayed:
-      self._stager.add(unroll, peel_view=False)
-    if tracer is not None:
-      tracer.on_batch(fresh_items + replayed, n_fresh)
-    return self._stager.finish(), n_fresh
+      with telemetry.span('staging/stage'):
+        self._stager.add(unroll)
+    with telemetry.span('staging/stage'):
+      for unroll in replayed:
+        self._stager.add(unroll, peel_view=False)
+      if tracer is not None:
+        tracer.on_batch(fresh_items + replayed, n_fresh)
+      return self._stager.finish(), n_fresh
 
   def _loop(self):
     try:
@@ -914,14 +921,16 @@ class BatchPrefetcher:
       blocked = not self._out and not self._closed
       if blocked:
         self._blocked_gets += 1
-      while not self._out and not self._closed:
-        remaining = (None if deadline is None
-                     else deadline - time.monotonic())
-        if remaining is not None and remaining <= 0:
-          self._wait_secs += time.monotonic() - t0
-          raise TimeoutError('BatchPrefetcher.get timed out')
-        self._ready.wait(remaining)
-      if blocked:
+        # The learner's wait as a recorder span, over exactly what
+        # `_wait_secs` sums.
+        with telemetry.park('learner/wait_batch'):
+          while not self._out and not self._closed:
+            remaining = (None if deadline is None
+                         else deadline - time.monotonic())
+            if remaining is not None and remaining <= 0:
+              self._wait_secs += time.monotonic() - t0
+              raise TimeoutError('BatchPrefetcher.get timed out')
+            self._ready.wait(remaining)
         self._wait_secs += time.monotonic() - t0
       if self._error is not None:
         raise self._error

@@ -56,6 +56,16 @@ telemetry behind it. This module is that layer, in three pieces:
    of a point-in-time counter dump (health.write_halt_bundle /
    driver.train's rollback incident path).
 
+4. **Span recorder** — where pieces 1-3 count and stamp per UNROLL on
+   the wall clock, the recorder times the layer boundaries of the
+   fleet path per ENV STEP on `time.perf_counter_ns()`, in memory, and
+   only while armed (`arm_spans` .. `take_spans`). Off is the default
+   and costs a site one global read and one branch. A profiler capture
+   arms it (observability.ProfilerCapture, the benchmark's traced
+   slice); the clock pair read at arming joins its rows to the hop
+   stamps above, a landmark program joins them to the device trace.
+   Span names are listed in docs/OBSERVABILITY.md ("Spans").
+
 Costs are measured, not assumed: bench.py's `telemetry` stage runs
 the feed pipeline with tracing on vs off and the always-on default is
 an accept/reject call recorded in docs/PERF.md.
@@ -263,6 +273,194 @@ def gauge(name: str, fn: Optional[Callable] = None) -> Gauge:
 
 def histogram(name: str, maxlen: int = 4096) -> Histogram:
   return _REGISTRY.histogram(name, maxlen=maxlen)
+
+
+# --------------------------------------------------------------------
+# Span recorder.
+# --------------------------------------------------------------------
+
+# Rows refused because the armed recorder was full (`max_spans`).
+_SPANS_DROPPED = counter('trace/spans_dropped')
+
+
+class _SpanRecorder:
+  """One armed interval: its rows, its bound, and the clock pair."""
+
+  def __init__(self, max_spans: int):
+    self.rows: List[Tuple] = []
+    self.threads: Dict[int, str] = {}  # ident -> name, as rows arrive
+    self.max_spans = max_spans
+    self.dropped_before = _SPANS_DROPPED.value
+    self.wall_ns = time.time_ns()
+    self.perf_ns = time.perf_counter_ns()
+
+  def add(self, name, t0, t1, span_id, thread=None):
+    # list.append is atomic under the GIL; the bound may be passed by
+    # at most one row per racing thread.
+    if len(self.rows) < self.max_spans:
+      if thread is None:
+        thread = threading.get_ident()
+        if thread not in self.threads:  # a thread may end before take
+          self.threads[thread] = threading.current_thread().name
+      self.rows.append((name, t0, t1, thread, span_id))
+    else:
+      _SPANS_DROPPED.inc()
+
+
+# The armed recorder, or None: THE global every span site reads.
+_recorder: Optional[_SpanRecorder] = None
+# The `id` spans of this thread inherit from their enclosing span
+# (only ever touched while a recorder is armed).
+_span_ids = threading.local()
+# Parks under way, armed or not: a set of _Park (add/discard and
+# list() are atomic under the GIL).
+_open_parks = set()
+
+
+class _Site:
+  """What a span site holds: ended by `end()` or by leaving its `with`
+  block."""
+  __slots__ = ()
+
+  def __enter__(self):
+    return self
+
+  def __exit__(self, *exc):
+    self.end()
+    return False
+
+
+class _NoSpan(_Site):
+  """What a span site gets while the recorder is off: one shared
+  object that does nothing."""
+  __slots__ = ()
+
+  def __exit__(self, *exc):
+    return False
+
+  def end(self):
+    pass
+
+
+NO_SPAN = _NoSpan()
+_NOTHING_TO_RESTORE = object()
+
+
+class _Span(_Site):
+  """A span under way. Ends once; kept if the recorder it began under
+  is still the armed one."""
+  __slots__ = ('_recorder', '_name', '_id', '_t0', '_outer_id')
+
+  def __init__(self, recorder, name, span_id):
+    self._recorder = recorder
+    self._name = name
+    outer = getattr(_span_ids, 'id', None)
+    if span_id is None:
+      self._id = outer
+      self._outer_id = _NOTHING_TO_RESTORE
+    else:
+      self._id = _span_ids.id = span_id
+      self._outer_id = outer
+    self._t0 = time.perf_counter_ns()
+
+  def end(self):
+    t1 = time.perf_counter_ns()
+    recorder, self._recorder = self._recorder, None
+    if recorder is None:
+      return
+    if self._outer_id is not _NOTHING_TO_RESTORE:
+      _span_ids.id = self._outer_id
+    if recorder is _recorder:
+      recorder.add(self._name, self._t0, t1, self._id)
+
+
+def span(name: str, id=None):  # noqa: A002 — the row's field name
+  """A span site: `with telemetry.span('actor/step'):`, or
+  `s = telemetry.span(...)` .. `s.end()` where a block will not do.
+  Off (the default) this is one global read and one branch: no clock
+  read, no allocation, no lock. Armed, the row `(name, t0_ns, t1_ns,
+  thread_ident, id)` is kept when the span ends. `id` names the
+  request the span belongs to (a batcher `batch_id`, an unroll's
+  `(actor, seq)`); a span given none inherits its enclosing span's on
+  the same thread. A span that began before arming, or is still open
+  at `take_spans`, is not kept: see `park` for the waits that must be."""
+  recorder = _recorder
+  if recorder is None:
+    return NO_SPAN
+  return _Span(recorder, name, id)
+
+
+class _Park(_Site):
+  __slots__ = ('name', 'id', 't0', 'thread')
+
+  def __init__(self, name, span_id):
+    self.name = name
+    self.id = span_id
+    self.thread = threading.get_ident()
+    self.t0 = time.perf_counter_ns()
+    _open_parks.add(self)
+
+  def end(self):
+    _open_parks.discard(self)
+    recorder = _recorder
+    if recorder is not None:
+      span_id = self.id
+      if span_id is None:
+        span_id = getattr(_span_ids, 'id', None)
+      # A wait that began before arming is kept from arming on.
+      recorder.add(self.name, max(self.t0, recorder.perf_ns),
+                   time.perf_counter_ns(), span_id)
+
+
+def park(name: str, id=None):  # noqa: A002
+  """A span site for a WAIT (a thread parked on a queue, a condition,
+  a full buffer) or for the one long span that holds such a wait
+  (`learner/iteration`), used like `span`. Unlike `span` it reads the
+  clock and notes itself as under way even while the recorder is off,
+  so that a wait which straddles either end of the armed interval is
+  kept, clipped to it: one that began before `arm_spans` starts at the
+  arming instant, and one still parked at `take_spans` is closed there
+  (its later real end, recorder off again, adds nothing). A learner's
+  wait of seconds straddles an end of a 15 s capture more often than
+  not. Only for the few sites that run under ~100 times a second. Its
+  `id` may be set inside the block (`with park(..) as p: p.id = ..`)."""
+  return _Park(name, id)
+
+
+def arm_spans(max_spans: int = 400_000) -> Dict:
+  """Arms a fresh recorder (dropping an armed one's rows) and returns
+  the clock pair `{'perf_ns', 'wall_ns'}`, both read now: span rows
+  are on the first clock, the hop stamps of traces.jsonl on the
+  second. Rows past `max_spans` are dropped and counted on
+  'trace/spans_dropped'."""
+  global _recorder
+  recorder = _SpanRecorder(max_spans)
+  _recorder = recorder
+  return {'perf_ns': recorder.perf_ns, 'wall_ns': recorder.wall_ns}
+
+
+def take_spans() -> Optional[Dict]:
+  """Disarms the recorder and hands its rows over whole, or None when
+  none was armed: {'clock': {'perf_ns', 'wall_ns'} (at arming),
+  'taken_ns' (perf clock, now), 'spans': [(name, t0_ns, t1_ns,
+  thread_ident, id), ...], 'threads': {thread_ident: name},
+  'dropped': rows this interval refused}. Parks still under way are
+  closed at `taken_ns`."""
+  global _recorder
+  recorder, _recorder = _recorder, None
+  if recorder is None:
+    return None
+  taken_ns = time.perf_counter_ns()
+  for p in list(_open_parks):
+    recorder.add(p.name, max(p.t0, recorder.perf_ns), taken_ns, p.id,
+                 thread=p.thread)
+  for t in threading.enumerate():  # the threads still parked
+    recorder.threads.setdefault(t.ident, t.name)
+  return {'clock': {'perf_ns': recorder.perf_ns,
+                    'wall_ns': recorder.wall_ns},
+          'taken_ns': taken_ns, 'spans': recorder.rows,
+          'threads': recorder.threads,
+          'dropped': _SPANS_DROPPED.value - recorder.dropped_before}
 
 
 # --------------------------------------------------------------------

@@ -19,6 +19,16 @@ process) plus `incidents.jsonl` when present, and reports:
   (rollbacks, partitions, reattaches) interleaved, so a chaos fault's
   window is visible as the gap/lag excursion it caused.
 
+Given a FILE instead of a directory, it summarizes a span capture
+(`spans.json`, written beside a profiler capture by
+observability.ProfilerCapture: the rows of telemetry.take_spans()):
+
+    python scripts/trace_report.py LOGDIR/profile/spans.json
+
+per span name the count, the rate over the capture, p50 / p95 / total
+duration, and the SELF time: the duration less what the spans nested
+inside it on the same thread cover (docs/OBSERVABILITY.md, "Spans").
+
 Missing values render '-' (the NaN-on-empty contract of the round-13
 observability satellites). Cross-host hop deltas carry NTP skew —
 within a host they are exact (docs/OBSERVABILITY.md).
@@ -269,14 +279,77 @@ def render(summary):
   return '\n'.join(out)
 
 
+def summarize_spans(taken):
+  """The span capture's data model: {'seconds' (the armed interval),
+  'dropped', 'spans': [{'name', 'count', 'per_s', 'p50_ms', 'p95_ms',
+  'total_s', 'self_s', 'self_ms'}, ...] by total self time}. A span's
+  children are the spans that lie inside it on its own thread; its
+  self time is its duration less what its direct children cover."""
+  by_thread = collections.defaultdict(list)
+  for name, t0, t1, thread, _ in taken.get('spans', ()):
+    by_thread[thread].append((t0, -(t1 - t0), name))
+  durations = collections.defaultdict(list)
+  self_ns = collections.Counter()
+  for rows in by_thread.values():
+    rows.sort()
+    stack = []  # [end, name] of the spans open around this one
+    for t0, neg, name in rows:
+      end = t0 - neg
+      while stack and stack[-1][0] <= t0:
+        stack.pop()
+      if stack and end <= stack[-1][0]:
+        self_ns[stack[-1][1]] += neg  # taken out of its parent
+      durations[name].append(-neg)
+      self_ns[name] -= neg
+      stack.append((end, name))
+  seconds = (taken['taken_ns'] - taken['clock']['perf_ns']) / 1e9
+  spans = []
+  for name, values in durations.items():
+    p50, p95 = _percentiles(values, 0.5, 0.95)
+    spans.append({
+        'name': name, 'count': len(values),
+        'per_s': len(values) / seconds if seconds > 0 else None,
+        'p50_ms': p50 / 1e6, 'p95_ms': p95 / 1e6,
+        'total_s': sum(values) / 1e9, 'self_s': self_ns[name] / 1e9,
+        'self_ms': self_ns[name] / 1e6 / len(values)})
+  spans.sort(key=lambda row: -row['self_s'])
+  return {'seconds': seconds, 'dropped': taken.get('dropped', 0),
+          'threads': len(by_thread), 'spans': spans}
+
+
+def render_spans(summary):
+  out = [f"== span report: {_fmt(summary['seconds'], 3)} s armed, "
+         f"{summary['threads']} threads, {summary['dropped']} rows "
+         'dropped ==',
+         f"{'span':>24} {'count':>8} {'per s':>9} {'p50 ms':>10} "
+         f"{'p95 ms':>10} {'total s':>10} {'self s':>10} "
+         f"{'self ms':>10}"]
+  for row in summary['spans']:
+    out.append(
+        f"{row['name']:>24} {row['count']:>8} "
+        f"{_fmt(row['per_s'], 1):>9} {_fmt(row['p50_ms'], 3):>10} "
+        f"{_fmt(row['p95_ms'], 3):>10} {_fmt(row['total_s'], 3):>10} "
+        f"{_fmt(row['self_s'], 3):>10} {_fmt(row['self_ms'], 3):>10}")
+  return '\n'.join(out)
+
+
 def main(argv=None):
   parser = argparse.ArgumentParser(
       description='per-unroll trace + policy-lag report from '
-                  'traces.jsonl')
-  parser.add_argument('logdir', help='run directory (has traces.jsonl)')
+                  'traces.jsonl, or a span report from a spans.json')
+  parser.add_argument('logdir', help='run directory (has traces.jsonl)'
+                      ', or a spans.json file')
   parser.add_argument('--json', default=None,
                       help='also write the summary as JSON here')
   args = parser.parse_args(argv)
+  if os.path.isfile(args.logdir):
+    with open(args.logdir) as f:
+      summary = summarize_spans(json.load(f))
+    print(render_spans(summary))
+    if args.json:
+      with open(args.json, 'w') as f:
+        json.dump(summary, f, indent=2)
+    return 0
   records = load_traces(args.logdir)
   if not records:
     print(f'no traces*.jsonl records under {args.logdir!r} — was the '

@@ -58,11 +58,15 @@ def run_only(root, check):
 # --- seeded violations: every checker proven able to fire ------------
 
 
-def test_metric_names_fires_both_directions(tmp_path):
+@pytest.mark.parametrize('site', [
+    "c = telemetry.counter('ghost/metric')",
+    # The span recorder's sites are names of the same interface.
+    "with telemetry.span('ghost/metric', id=3): pass",
+    "p = telemetry.park('ghost/metric')"])
+def test_metric_names_fires_both_directions(tmp_path, site):
   root = mini_repo(tmp_path, {
       'scalable_agent_tpu/foo.py':
-          "from scalable_agent_tpu import telemetry\n"
-          "c = telemetry.counter('ghost/metric')\n",
+          "from scalable_agent_tpu import telemetry\n" + site + "\n",
       'docs/OBSERVABILITY.md': OBS_DOC,
   })
   findings = run_only(root, 'metric-names')
@@ -70,7 +74,7 @@ def test_metric_names_fires_both_directions(tmp_path):
   assert 'ghost/metric' in symbols          # registered, undocumented
   assert 'x/y' in symbols                   # documented, unregistered
   # The line points at the registration site.
-  reg = next(f for f in findings if f.symbol == 'ghost/metric')
+  (reg,) = [f for f in findings if f.symbol == 'ghost/metric']
   assert reg.path == 'scalable_agent_tpu/foo.py' and reg.line == 2
 
 

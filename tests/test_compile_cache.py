@@ -248,6 +248,39 @@ def test_second_spinup_of_identical_program_hits_cache(tmp_path):
                         prev_min)
 
 
+def test_the_cache_key_follows_scope_names_and_not_source_lines(tmp_path):
+  """A cached executable carries the operation names of the source
+  that compiled it, and the device trace's per-scope shares read
+  them: a renamed scope must miss the cache, a moved line must not."""
+  d = os.path.join(str(tmp_path), 'cache')
+  cfg = _base_config(str(tmp_path), compile_cache_dir=d)
+
+  def entries_added_by(scope, blank_lines):
+    source = '\n' * blank_lines + (
+        'def scoped_step(x):\n'
+        f'  with jax.named_scope({scope!r}):\n'
+        '    return jnp.tanh(x) @ x + 29.0\n')
+    namespace = {'jax': jax, 'jnp': jnp}
+    exec(compile(source, 'model.py', 'exec'), namespace)  # noqa: S102
+    before = set(os.listdir(d))
+    jax.jit(namespace['scoped_step'])(
+        jnp.ones((8, 8))).block_until_ready()
+    return len(set(os.listdir(d)) - before)
+
+  with _armed(d):
+    jax.config.update('jax_compilation_cache_dir', None)
+    distributed.arm_compile_cache(cfg)
+    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)
+    try:
+      assert entries_added_by('torso', 0) >= 1
+      assert entries_added_by('torso', 7) == 0
+      assert entries_added_by('core', 0) >= 1
+    finally:
+      jax.config.update('jax_persistent_cache_min_compile_time_secs',
+                        prev_min)
+
+
 def test_concurrent_members_share_one_cache_dir_safely(tmp_path):
   # Two "members" compiling into the same armed dir at once: writes
   # are keyed and atomic on the JAX side; nothing may raise and the

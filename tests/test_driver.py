@@ -200,9 +200,21 @@ def test_evaluate_multitask_parallel(tmp_path):
     assert len(rs) == 1, name
 
 
-def test_profiler_trace_capture(tmp_path):
+def test_profiler_trace_capture(tmp_path, monkeypatch):
   """jax.profiler hooks (SURVEY §5.1 — absent upstream): a capture
-  window writes a trace the standard tooling can open."""
+  window writes a trace the standard tooling can open. Every capture
+  goes through observability.ProfilerCapture: the profiler records
+  the device only (its host tracer halves a fleet's speed), and the
+  host's side is the span recorder's, in spans.json."""
+  import jax
+  started = []
+  real_start = jax.profiler.start_trace
+
+  def start_trace(log_dir, **kwargs):
+    started.append((log_dir, kwargs.get('profiler_options')))
+    return real_start(log_dir, **kwargs)
+
+  monkeypatch.setattr(jax.profiler, 'start_trace', start_trace)
   prof_dir = str(tmp_path / 'profile')
   cfg = _config(tmp_path, profile_dir=prof_dir, profile_start_step=1,
                 profile_num_steps=1)
@@ -210,6 +222,109 @@ def test_profiler_trace_capture(tmp_path):
   traces = glob.glob(os.path.join(prof_dir, '**', '*.xplane.pb'),
                      recursive=True)
   assert traces, f'no trace under {prof_dir}'
+  ((log_dir, options),) = started
+  assert log_dir == prof_dir
+  assert options.host_tracer_level == 0
+  assert options.python_tracer_level == 0
+  with open(os.path.join(prof_dir, 'spans.json')) as f:
+    spans = json.load(f)
+  assert spans['landmark']['module'] == 'jit_capture_clock_sync'
+  clock = spans['clock']
+  assert clock['perf_ns'] >= spans['landmark']['host_perf_ns']
+  names = {row[0] for row in spans['spans']}
+  # One learner step's worth (the actors may sit on a full buffer).
+  assert {'learner/iteration', 'learner/step_dispatch'} <= names
+  assert all(clock['perf_ns'] <= t0 <= t1 <= spans['taken_ns']
+             for _, t0, t1, _, _ in spans['spans'])
+  from scalable_agent_tpu import telemetry
+  assert telemetry.take_spans() is None  # the capture disarmed it
+
+
+SPAN_NAMES = {
+    'actor/unroll', 'actor/step', 'actor/policy_call', 'batcher/compute',
+    'actor/env_step', 'env/pipe', 'actor/assemble', 'actor/put',
+    'inference/wait_batch', 'inference/dispatch', 'inference/readback',
+    'inference/unpark', 'staging/wait_unrolls', 'staging/stage',
+    'learner/wait_batch', 'learner/iteration', 'learner/step_dispatch',
+    'learner/publish', 'learner/summaries'}
+
+
+def test_a_fleet_run_records_every_span_at_its_boundary(tmp_path):
+  """The recorder armed over one short fleet run with process-hosted
+  envs: every span name of docs/OBSERVABILITY.md's table at least
+  once, nested as the table says, ids shared as it says."""
+  from scalable_agent_tpu import telemetry
+  # (Batches of 4 from 2 actors, and steps enough to outrun what the
+  # actors made while the first step compiled: the learner waits.)
+  cfg = _config(tmp_path, use_py_process=True, num_actors=2,
+                batch_size=4)
+  telemetry.arm_spans()
+  try:
+    run = driver.train(cfg, max_steps=8, stall_timeout_secs=120)
+  finally:
+    taken = telemetry.take_spans()
+  assert int(run.state.update_steps) == 8
+  assert taken['dropped'] == 0
+  rows = taken['spans']
+  assert {row[0] for row in rows} == SPAN_NAMES
+  by_thread = {}
+  for row in rows:
+    by_thread.setdefault(row[3], []).append(row)
+
+  def children(parent, name):
+    return [r for r in by_thread[parent[3]] if r[0] == name and
+            parent[1] <= r[1] and r[2] <= parent[2]]
+
+  def all_of(name):
+    return [row for row in rows if row[0] == name]
+
+  # actor/unroll > actor/step > (actor/policy_call > batcher/compute,
+  # actor/env_step > env/pipe); actor/assemble beside the steps.
+  for unroll in all_of('actor/unroll'):
+    steps = children(unroll, 'actor/step')
+    assert len(steps) == cfg.unroll_length
+    assert len(children(unroll, 'actor/assemble')) == 1
+    actor, seq = unroll[4]
+    assert actor == taken['threads'][unroll[3]] and seq >= 0
+    for step in steps:
+      (call,) = children(step, 'actor/policy_call')
+      (env_step,) = children(step, 'actor/env_step')
+      assert len(children(call, 'batcher/compute')) == 1
+      assert len(children(env_step, 'env/pipe')) == 1
+      assert call[2] <= env_step[1]
+      assert step[4] == call[4] == env_step[4] == unroll[4]
+  # The hand-over carries the id of the unroll it hands over.
+  unroll_ids = {tuple(r[4]) for r in all_of('actor/unroll')}
+  assert {tuple(r[4]) for r in all_of('actor/put')} <= unroll_ids
+  # One merged call: dispatch -> readback -> unpark, one batch_id.
+  by_batch = {}
+  for row in rows:
+    if row[0].startswith('inference/') and row[4] is not None:
+      by_batch.setdefault(row[4], {})[row[0]] = row
+  complete = [b for b in by_batch.values() if len(b) == 4]
+  assert complete
+  for batch in complete:
+    assert (batch['inference/wait_batch'][2] <=
+            batch['inference/dispatch'][1] <=
+            batch['inference/dispatch'][2] <=
+            batch['inference/readback'][1] <=
+            batch['inference/readback'][2] <=
+            batch['inference/unpark'][1])
+  # The learner thread: everything per step inside the iteration.
+  iterations = all_of('learner/iteration')
+  assert len({r[3] for r in iterations}) == 1
+  whole = [it for it in iterations
+           if len(children(it, 'learner/step_dispatch')) == 1]
+  assert len(whole) == 8
+  for iteration in whole:
+    assert len(children(iteration, 'learner/publish')) == 1
+    assert len(children(iteration, 'learner/summaries')) == 1
+  waits = all_of('learner/wait_batch')
+  assert all(any(it[1] <= w[1] and w[2] <= it[2] for it in iterations)
+             for w in waits)
+  # Staging is a thread of its own: wait, then stage, per batch.
+  assert len({r[3] for r in all_of('staging/stage')}) == 1
+  assert len(all_of('staging/stage')) >= 8
 
 
 @pytest.mark.slow  # tier-1 wall trim (round 20); ci.sh full-suite lane runs it

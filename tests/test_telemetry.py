@@ -668,3 +668,236 @@ def test_publish_install_join_uses_ingest_lane_version(tmp_path):
   summary = trace_report.summarize(
       trace_report.load_traces(str(tmp_path)))
   assert summary['publish_to_install_secs']['count'] == 1
+
+
+# --------------------------------------------------------------------
+# Span recorder.
+# --------------------------------------------------------------------
+
+
+@pytest.fixture
+def recorder_off():
+  """Whatever a test arms, the next one starts with the recorder off."""
+  telemetry.take_spans()
+  yield
+  telemetry.take_spans()
+
+
+def test_recorder_off_records_nothing_and_a_site_allocates_nothing(
+    recorder_off):
+  import tracemalloc
+  assert telemetry.take_spans() is None
+  assert telemetry.span('actor/step') is telemetry.NO_SPAN
+  assert telemetry.span('x', id=('a', 1)) is telemetry.NO_SPAN
+
+  def sites(n):
+    for _ in range(n):
+      with telemetry.span('actor/step'):
+        pass
+      s = telemetry.span('learner/publish')
+      s.end()
+
+  sites(100)  # whatever the first pass caches
+  only = [tracemalloc.Filter(True, telemetry.__file__)]
+  tracemalloc.start()
+  try:
+    before = tracemalloc.take_snapshot().filter_traces(only)
+    sites(1000)
+    after = tracemalloc.take_snapshot().filter_traces(only)
+  finally:
+    tracemalloc.stop()
+  grown = [s for s in after.compare_to(before, 'lineno')
+           if s.size_diff > 0]
+  assert not grown, grown
+  assert telemetry.take_spans() is None
+
+
+def test_armed_rows_nest_and_share_their_id(recorder_off):
+  clock = telemetry.arm_spans()
+  with telemetry.span('actor/unroll', id=('actor-0', 7)):
+    with telemetry.span('actor/step'):
+      with telemetry.span('batcher/compute'):
+        time.sleep(0.001)
+    explicit = telemetry.span('inference/dispatch', id=41)
+    explicit.end()
+    explicit.end()  # ends once
+    with telemetry.span('actor/assemble'):
+      pass
+  with telemetry.span('learner/publish'):
+    pass
+  taken = telemetry.take_spans()
+  assert taken['clock'] == clock and taken['dropped'] == 0
+  assert taken['taken_ns'] > clock['perf_ns']
+  rows = {row[0]: row for row in taken['spans']}
+  assert [row[0] for row in taken['spans']] == [
+      'batcher/compute', 'actor/step', 'inference/dispatch',
+      'actor/assemble', 'actor/unroll', 'learner/publish']
+  # Inner spans inherit the enclosing span's id; one given its own
+  # keeps it and hands the outer one back; outside, none.
+  for name in ('actor/unroll', 'actor/step', 'batcher/compute',
+               'actor/assemble'):
+    assert rows[name][4] == ('actor-0', 7), name
+  assert rows['inference/dispatch'][4] == 41
+  assert rows['learner/publish'][4] is None
+  outer, inner = rows['actor/unroll'], rows['batcher/compute']
+  assert outer[1] <= rows['actor/step'][1] <= inner[1]
+  assert inner[2] <= rows['actor/step'][2] <= outer[2]
+  assert inner[2] - inner[1] >= 1_000_000
+  me = threading.get_ident()
+  assert {row[3] for row in taken['spans']} == {me}
+  assert taken['threads'][me] == threading.current_thread().name
+  # The clock pair puts a row on the wall clock of traces.jsonl.
+  wall = (outer[1] - clock['perf_ns'] + clock['wall_ns']) / 1e9
+  assert abs(wall - time.time()) < 5.0
+  # Taken means off: nothing is kept afterwards.
+  with telemetry.span('late'):
+    pass
+  assert telemetry.take_spans() is None
+
+
+def test_a_span_open_across_an_end_of_the_armed_interval_is_dropped(
+    recorder_off):
+  telemetry.arm_spans()
+  straddles_stop = telemetry.span('a')
+  telemetry.take_spans()
+  telemetry.arm_spans()
+  straddles_stop.end()  # began under another arming
+  assert telemetry.take_spans()['spans'] == []
+
+
+def test_a_park_that_straddles_either_end_is_kept_and_clipped(
+    recorder_off):
+  """A learner's wait of seconds straddles an end of a capture more
+  often than not (PERF.md: seed 57 lost 8.4 of 14.7 idle seconds)."""
+  began_before = telemetry.park('learner/wait_batch')
+  never_armed = telemetry.park('staging/wait_unrolls')
+  never_armed.end()
+  time.sleep(0.002)
+  clock = telemetry.arm_spans()
+  with telemetry.park('inference/wait_batch') as inside:
+    inside.id = 9  # known only once the wait is over
+  began_before.end()
+  open_at_take = telemetry.park('actor/put', id=('actor-1', 3))
+  taken = telemetry.take_spans()
+  open_at_take.end()  # its real end, recorder off: adds nothing
+  rows = {row[0]: row for row in taken['spans']}
+  assert sorted(rows) == ['actor/put', 'inference/wait_batch',
+                          'learner/wait_batch']
+  assert rows['learner/wait_batch'][1] == clock['perf_ns']  # clipped
+  assert rows['learner/wait_batch'][2] > clock['perf_ns']
+  assert rows['inference/wait_batch'][4] == 9
+  assert rows['actor/put'][2] == taken['taken_ns']  # closed at take
+  assert rows['actor/put'][4] == ('actor-1', 3)
+  assert all(t0 <= t1 for _, t0, t1, _, _ in taken['spans'])
+  assert not telemetry._open_parks
+  # Still parked when the next capture arms: kept again, from there.
+  long_wait = telemetry.park('learner/wait_batch')
+  telemetry.arm_spans()
+  telemetry.take_spans()
+  second = telemetry.arm_spans()
+  long_wait.end()
+  (row,) = telemetry.take_spans()['spans']
+  assert row[0] == 'learner/wait_batch' and row[1] == second['perf_ns']
+
+
+def test_the_bound_drops_rows_and_counts_them(recorder_off):
+  counter = telemetry.registry().get('trace/spans_dropped')
+  before = counter.value
+  telemetry.arm_spans(max_spans=3)
+  for _ in range(5):
+    with telemetry.span('actor/step'):
+      pass
+  parked = telemetry.park('actor/put')
+  taken = telemetry.take_spans()
+  parked.end()
+  assert len(taken['spans']) == 3 and taken['dropped'] == 3
+  assert counter.value == before + 3
+  assert 'trace/spans_dropped' in telemetry.registry().snapshot()
+
+
+def test_spans_of_other_threads_carry_their_thread(recorder_off):
+  telemetry.arm_spans()
+
+  def work():
+    with telemetry.span('actor/step'):
+      pass
+
+  threads = [threading.Thread(target=work, name=f'actor-{i}')
+             for i in range(3)]
+  for t in threads:
+    t.start()
+  idents = {t.ident for t in threads}
+  for t in threads:
+    t.join()
+  taken = telemetry.take_spans()
+  assert {row[3] for row in taken['spans']} == idents
+
+
+def test_trace_report_summarizes_a_span_capture(tmp_path, capsys):
+  ms = 1_000_000
+  taken = {
+      'clock': {'perf_ns': 0, 'wall_ns': 0}, 'taken_ns': 100 * ms,
+      'dropped': 0, 'threads': {'1': 'actor-0', '2': 'learner'},
+      'spans': [
+          # thread 1: a step of 10 ms holding a 6 ms park and, in a
+          # 3 ms env step, a 2 ms pipe; then a step of 20 ms alone
+          ['batcher/compute', 1 * ms, 7 * ms, 1, ['actor-0', 0]],
+          ['env/pipe', 7 * ms, 9 * ms, 1, ['actor-0', 0]],
+          ['actor/env_step', 7 * ms, 10 * ms, 1, ['actor-0', 0]],
+          ['actor/step', 0, 10 * ms, 1, ['actor-0', 0]],
+          ['actor/step', 10 * ms, 30 * ms, 1, ['actor-0', 0]],
+          # thread 2 overlaps thread 1 in time: not its child
+          ['learner/publish', 2 * ms, 6 * ms, 2, None]]}
+  summary = trace_report.summarize_spans(taken)
+  rows = {row['name']: row for row in summary['spans']}
+  assert summary['seconds'] == pytest.approx(0.1)
+  assert rows['actor/step']['count'] == 2
+  assert rows['actor/step']['total_s'] == pytest.approx(0.030)
+  # 30 ms less the park's 6 and the env step's 3 (its DIRECT children).
+  assert rows['actor/step']['self_s'] == pytest.approx(0.021)
+  assert rows['actor/env_step']['self_s'] == pytest.approx(0.001)
+  assert rows['env/pipe']['self_s'] == pytest.approx(0.002)
+  assert rows['learner/publish']['self_s'] == pytest.approx(0.004)
+  assert rows['actor/step']['p50_ms'] in (10.0, 20.0)
+  assert rows['actor/step']['per_s'] == pytest.approx(20.0)
+  path = tmp_path / 'spans.json'
+  path.write_text(json.dumps(taken))
+  assert trace_report.main([str(path)]) == 0
+  out = capsys.readouterr().out
+  assert 'span report' in out and 'batcher/compute' in out
+
+
+def test_the_feed_pipeline_records_its_waits_and_its_staging(
+    recorder_off):
+  """`learner/wait_batch` covers what `wait_secs` sums; the staging
+  thread's wait for unrolls, parked since before the arming, is kept
+  from the arming on."""
+  buffer = ring_buffer.TrajectoryBuffer(4)
+  prefetcher = ring_buffer.BatchPrefetcher(buffer, 2)
+  try:
+    time.sleep(0.01)  # the staging thread parks on the empty buffer
+    clock = telemetry.arm_spans()
+    with pytest.raises(TimeoutError):
+      prefetcher.get(timeout=0.02)
+    for seed in range(2):
+      buffer.put(_tiny_unroll(seed))
+    batch = prefetcher.get(timeout=5)
+    assert batch.env_outputs.reward.shape == (3, 2)
+    taken = telemetry.take_spans()
+  finally:
+    prefetcher.close()
+    buffer.close()
+  rows = taken['spans']
+  waits = [r for r in rows if r[0] == 'learner/wait_batch']
+  assert len(waits) == 2 and waits[0][2] - waits[0][1] >= 20_000_000
+  waited = sum(t1 - t0 for _, t0, t1, _, _ in waits) / 1e9
+  assert waited == pytest.approx(prefetcher.stats()['wait_secs'],
+                                 abs=0.01)
+  staging = [r for r in rows if r[0].startswith('staging/')]
+  assert [r[0] for r in staging[:2]] == ['staging/wait_unrolls',
+                                         'staging/stage']
+  assert staging[0][1] == clock['perf_ns']  # parked before the arming
+  assert staging[0][3] == staging[1][3] != waits[0][3]
+  # Its next wait was still under way at the take: closed there.
+  assert staging[-1][0] == 'staging/wait_unrolls'
+  assert staging[-1][2] == taken['taken_ns']
