@@ -240,7 +240,8 @@ def make_fleet(config: Config, agent, policy, buffer, levels,
 
   return ActorFleet(make_actor, buffer, n,
                     quarantine_after=config.fleet_quarantine_after,
-                    probation_secs=config.fleet_probation_secs)
+                    probation_secs=config.fleet_probation_secs,
+                    max_policy_rows=config.inference_max_batch)
 
 
 def _choose_eval_mesh():
@@ -1307,6 +1308,8 @@ def train(config: Config, max_steps: Optional[int] = None,
           last_filler_check = now_fill
           errors = fleet.errors() or errors
           fleet.check_health(stall_timeout_secs=stall_timeout_secs)
+          # (A respawn's rebuild on this thread: see the get below.)
+          last_batch_time += time.monotonic() - now_fill
           if (stall_timeout_secs is not None and
               now_fill - last_batch_time >
               max(3 * stall_timeout_secs, 30.0)):
@@ -1327,7 +1330,12 @@ def train(config: Config, max_steps: Optional[int] = None,
         # a crash-looping actor's root cause must survive to the stall
         # raise below (same ordering as evaluate()).
         errors = fleet.errors() or errors
+        checked = time.monotonic()
         fleet.check_health(stall_timeout_secs=stall_timeout_secs)
+        # A respawn rebuilds envs on THIS thread (a group's k, in
+        # turn): the time that took is not the fleet's silence, and
+        # must not run the deadline out before the new envs can feed.
+        last_batch_time += time.monotonic() - checked
         if (stall_timeout_secs is not None and
             time.monotonic() - last_batch_time >
             max(3 * stall_timeout_secs, 30.0)):
@@ -1688,6 +1696,12 @@ def train(config: Config, max_steps: Optional[int] = None,
                       fleet_stats['healthy_fraction'], step_now)
         writer.scalar('actor_respawns', fleet_stats['respawns'],
                       step_now)
+        # Threads that carry the fleet (PR 26): process-hosted envs
+        # are stepped k to a thread, one batcher request per group.
+        writer.scalar('actor_threads',
+                      fleet_stats.get('actor_threads', 0), step_now)
+        writer.scalar('envs_per_thread',
+                      fleet_stats.get('envs_per_thread', 0.0), step_now)
         # Learner failure-domain counters (health.py / checkpoint.py).
         if health is not None:
           hs = health.stats()
@@ -1722,6 +1736,14 @@ def train(config: Config, max_steps: Optional[int] = None,
         last_inference_snap = snap
         writer.scalar('inference_mean_batch',
                       (d_reqs / d_calls) if d_calls else 0.0, step_now)
+        # Rows per policy() call: 1 for lone actors, k where an actor
+        # thread steps k envs. Over the whole run: rows are counted
+        # when a merged call is dispatched and calls when they are
+        # made, so an interval's ratio wobbles by the calls in flight.
+        policy_calls = snap.get('batcher_requests', 0)
+        writer.scalar('inference_rows_per_request',
+                      (snap['requests'] / policy_calls) if policy_calls
+                      else 0.0, step_now)
         # Staleness: how many snapshots actors have been served (the
         # reference's "actions within one unroll may span weight
         # versions" caveat, made observable).

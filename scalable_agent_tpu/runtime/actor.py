@@ -18,6 +18,8 @@ Faithfully preserved semantics:
   stats while the carried state resets to zero.
 """
 
+import collections
+import contextlib
 import threading
 import time
 from typing import Callable, Optional
@@ -86,76 +88,80 @@ class Actor:
     self._episode_return = np.float32(0.0)
     self._episode_step = np.int32(0)
 
-  def unroll(self, span_id=None) -> ActorOutput:
-    """Produce one ActorOutput of [T+1] time-major numpy arrays.
-    `span_id` is the `id` its recorder spans carry (telemetry.span):
-    the actor loop passes the unroll's `(actor, seq)`."""
-    with telemetry.span('actor/unroll', id=span_id):
-      return self._unroll()
+  def group_key(self):
+    """What two Actors must agree on to be stepped as one ActorGroup:
+    the policy, the unroll length and the observation's shapes and
+    dtypes (a k-row policy call needs one trailing shape)."""
+    import jax
+    leaves = jax.tree_util.tree_leaves(self._env_output.observation)
+    return (self._policy, self._unroll_length,
+            tuple((np.shape(x), np.asarray(x).dtype.str) for x in leaves))
 
-  def _unroll(self) -> ActorOutput:
+  def unroll(self, span_id=None) -> ActorOutput:
+    """Produce one ActorOutput of [T+1] time-major numpy arrays: a
+    group of one (`ActorGroup.unroll` is THE loop). `span_id` is the
+    `id` its recorder spans carry (telemetry.span): the actor loop
+    passes the unroll's `(actor, seq)`."""
+    return ActorGroup([self]).unroll([span_id])[0]
+
+  def _begin_unroll(self):
+    """The numeric carry at the unroll start, and the lists the
+    unroll's T+1 steps collect in (the overlap frame first)."""
     # Device-resident policy state (InferenceServer state-cache mode)
     # is an opaque handle: the learner still needs the NUMERIC carry
     # at the unroll start, so snapshot it here — the once-per-unroll
     # host read that replaces the old once-per-step carry round trip.
     core0 = self._core_state
     if hasattr(core0, 'snapshot'):
-      initial_core_state = core0.snapshot()
+      self._initial_core_state = core0.snapshot()
     else:
-      initial_core_state = core0
-    env_outputs = [self._env_output]
-    if self._agent_output is None:
-      # Prime lazily so we know num_actions from the first policy call.
-      out, _ = self._policy(np.int32(0), self._env_output,
-                            self._core_state)
-      if hasattr(core0, 'write'):
-        # The carry-passing path DISCARDS the priming call's new state;
-        # a device-resident state advanced in-graph must be put back,
-        # or the cache path would start the unroll one step ahead
-        # (parity gate in tests/test_runtime.py).
-        core0.write(initial_core_state)
-      self._agent_output = AgentOutput(
-          action=np.int32(0),
-          policy_logits=np.zeros_like(np.asarray(out.policy_logits)),
-          baseline=np.float32(0.0))
-    agent_outputs = [self._agent_output]
+      self._initial_core_state = core0
+    self._env_outputs = [self._env_output]
+    self._agent_outputs = []
 
-    for _ in range(self._unroll_length):
-      with telemetry.span('actor/step'):
-        with telemetry.span('actor/policy_call'):
-          agent_output, core_state = self._policy(
-              self._agent_output.action, self._env_output,
-              self._core_state)
-        agent_output = AgentOutput(
-            *[np.asarray(x) for x in agent_output])
-        with telemetry.span('actor/env_step'):
-          reward, done, observation = self._env.step(
-              int(agent_output.action))
+  def _primed(self, out):
+    """After the priming policy call (made lazily, so num_actions is
+    known from its logits)."""
+    core0 = self._core_state
+    if hasattr(core0, 'write'):
+      # The carry-passing path DISCARDS the priming call's new state;
+      # a device-resident state advanced in-graph must be put back,
+      # or the cache path would start the unroll one step ahead
+      # (parity gate in tests/test_runtime.py).
+      core0.write(self._initial_core_state)
+    self._agent_output = AgentOutput(
+        action=np.int32(0),
+        policy_logits=np.zeros_like(np.asarray(out.policy_logits)),
+        baseline=np.float32(0.0))
 
-        # Flow-style episode accounting (output carries final stats at
-        # done; carried state resets).
-        self._episode_return = np.float32(self._episode_return + reward)
-        self._episode_step = np.int32(
-            self._episode_step + self._num_action_repeats)
-        info = StepOutputInfo(self._episode_return, self._episode_step)
-        if done:
-          self._episode_return = np.float32(0.0)
-          self._episode_step = np.int32(0)
+  def _record_step(self, agent_output, core_state, reward, done,
+                   observation):
+    # Flow-style episode accounting (output carries final stats at
+    # done; carried state resets).
+    self._episode_return = np.float32(self._episode_return + reward)
+    self._episode_step = np.int32(
+        self._episode_step + self._num_action_repeats)
+    info = StepOutputInfo(self._episode_return, self._episode_step)
+    if done:
+      self._episode_return = np.float32(0.0)
+      self._episode_step = np.int32(0)
 
-        env_output = StepOutput(np.float32(reward), info, np.bool_(done),
-                                observation)
-        env_outputs.append(env_output)
-        agent_outputs.append(agent_output)
-        self._env_output = env_output
-        self._agent_output = agent_output
-        self._core_state = core_state
+    env_output = StepOutput(np.float32(reward), info, np.bool_(done),
+                            observation)
+    self._env_outputs.append(env_output)
+    self._agent_outputs.append(agent_output)
+    self._env_output = env_output
+    self._agent_output = agent_output
+    self._core_state = core_state
 
-    with telemetry.span('actor/assemble'):
-      env_outputs = _tree_stack(env_outputs)
-      agent_outputs = _tree_stack(agent_outputs)
+  def _assemble(self, span_id=None) -> ActorOutput:
+    with telemetry.span('actor/assemble', id=span_id):
+      env_outputs = _tree_stack(self._env_outputs)
+      agent_outputs = _tree_stack(self._agent_outputs)
+    self._env_outputs = self._agent_outputs = None
     return ActorOutput(
         level_name=self._level_name_id,
-        agent_state=initial_core_state,
+        agent_state=self._initial_core_state,
         env_outputs=env_outputs,
         agent_outputs=agent_outputs)
 
@@ -176,8 +182,178 @@ class Actor:
     self._env.close()
 
 
-def run_actor_loop(actor: Actor, buffer, stop_event,
-                   on_unroll: Optional[Callable[[], bool]] = None,
+class ActorGroup:
+  """k Actors that ONE thread steps in lockstep: per step one policy
+  call with a leading axis of k, then all k env steps at once.
+
+  The unit that parks in the batcher is the group, so a merged
+  inference call wakes one thread per group instead of one per env
+  (PERF.md, PR 26: 32 threads took 26 of the 33 ms cycle to come back
+  one by one). What an unroll contains does not depend on the group:
+  each member keeps its own episode accounting, overlap frame, carry
+  and priming, and for the same policy outputs its ActorOutput is
+  bitwise what it produces alone. A group of one IS the single-actor
+  loop, with the scalar policy call.
+
+  With k > 1 the members' shared `policy` takes the k-row form of the
+  Actor contract: `(prev_action i32[k], env_output with [k, ...]
+  leaves, core_state for k) -> (AgentOutput with [k, ...] leaves,
+  core_state for k)`, where a numeric core state is the members'
+  concatenated on axis 0 and opaque handles (`snapshot`) go as a list
+  (`InferenceServer.policy` honours both). Members whose env has
+  `step_send`/`step_receive` (process-hosted: `py_process.ProxyEnv`)
+  step concurrently; any other env steps in turn on this thread.
+
+  Membership moves only between unrolls, on the rolling thread: a
+  member `leave`s (and is closed) there, and an Actor that any thread
+  hands to `join` is taken in by the next `admit`. Because each member
+  keeps its own state, the others' unrolls do not see either.
+  """
+
+  def __init__(self, actors, names=None):
+    self.actors = list(actors)
+    # The members' actor ids in trace contexts and span ids (the fleet
+    # gives `actor-<slot>`); None: from the rolling thread's name.
+    self.names = list(names) if names is not None else None
+    # The member whose env failed the group's last unroll, if the
+    # failure was one env's (else None): the fleet charges that slot.
+    self.failed: Optional[Actor] = None
+    # The member whose env this thread is blocked on right now, if it
+    # is: of a group that stalls, the one that hangs.
+    self.waiting_on: Optional[Actor] = None
+    self._joining = collections.deque()  # (actor, name), any thread
+
+  def join(self, actor, name):
+    """Hand the group (one that has `names`) one more member, from
+    any thread: it steps with the others from the rolling thread's
+    next `admit` on."""
+    self._joining.append((actor, name))
+
+  def admit(self):
+    """Take in what `join` brought (the rolling thread, between
+    unrolls)."""
+    while self._joining:
+      actor, name = self._joining.popleft()
+      self.actors.append(actor)
+      self.names.append(name)
+
+  def leave(self, actor):
+    """Drop a member and close it (the rolling thread, between
+    unrolls); the others go on."""
+    j = self.actors.index(actor)
+    del self.actors[j], self.names[j]
+    _close_quietly(actor)
+
+  def unroll(self, span_ids=None):
+    """One unroll of every member -> a list of k ActorOutputs.
+    `span_ids` are the members' `(actor, seq)` ids: `actor/unroll`,
+    `actor/assemble` and `env/pipe` are recorded per env, the spans of
+    a step (`actor/step`, `actor/policy_call`, `batcher/compute`,
+    `actor/env_step`) once per GROUP step."""
+    actors = self.actors
+    if span_ids is None:
+      span_ids = [None] * len(actors)
+    self.failed = None
+    with contextlib.ExitStack() as spans:
+      for span_id in span_ids:
+        spans.enter_context(telemetry.span('actor/unroll', id=span_id))
+      for actor in actors:
+        actor._begin_unroll()
+      unprimed = [a for a in actors if a._agent_output is None]
+      if unprimed:
+        outs, _ = self._policy_call(unprimed,
+                                    [np.int32(0)] * len(unprimed))
+        for actor, out in zip(unprimed, outs):
+          actor._primed(out)
+      for actor in actors:
+        actor._agent_outputs.append(actor._agent_output)
+
+      for _ in range(actors[0]._unroll_length):
+        with telemetry.span('actor/step'):
+          with telemetry.span('actor/policy_call'):
+            agent_outputs, core_states = self._policy_call(
+                actors, [a._agent_output.action for a in actors])
+          with telemetry.span('actor/env_step'):
+            steps = self._env_step(
+                [int(out.action) for out in agent_outputs])
+          for actor, out, core_state, step in zip(
+              actors, agent_outputs, core_states, steps):
+            actor._record_step(out, core_state, *step)
+
+      return [actor._assemble(span_id)
+              for actor, span_id in zip(actors, span_ids)]
+
+  @staticmethod
+  def _policy_call(actors, prev_actions):
+    """One policy call for `actors` -> (their AgentOutputs of numpy
+    scalars, their new core states)."""
+    policy = actors[0]._policy
+    if len(actors) == 1:
+      out, core_state = policy(prev_actions[0], actors[0]._env_output,
+                               actors[0]._core_state)
+      return [AgentOutput(*[np.asarray(x) for x in out])], [core_state]
+    import jax
+    states = [a._core_state for a in actors]
+    handles = hasattr(states[0], 'snapshot')
+    out, new_states = policy(
+        np.asarray(prev_actions, np.int32),
+        _tree_stack([a._env_output for a in actors]),
+        states if handles else jax.tree_util.tree_map(
+            lambda *xs: np.concatenate(xs, axis=0), *states))
+    out = [np.asarray(x) for x in out]
+    rows = range(len(actors))
+    if not handles:
+      new_states = [jax.tree_util.tree_map(lambda x, j=j: x[j:j + 1],
+                                           new_states) for j in rows]
+    return ([AgentOutput(*[np.asarray(x[j]) for x in out]) for j in rows],
+            new_states)
+
+  def _env_step(self, actions):
+    """Step every member's env -> [(reward, done, observation)]. Every
+    send goes out before the first reply is waited for, so hosted envs
+    step at once; every reply sent for is collected, whatever failed,
+    so no child is left mid-call. The first failure is raised."""
+    results, sent, failure = [None] * len(actions), [], None
+    for j, (actor, action) in enumerate(zip(self.actors, actions)):
+      self.waiting_on = actor
+      try:
+        send = getattr(actor._env, 'step_send', None)
+        if send is None:
+          results[j] = actor._env.step(action)
+        else:
+          send(action)
+          sent.append(j)
+      except BaseException as e:
+        failure = (actor, e)
+        break
+    for j in sent:
+      self.waiting_on = self.actors[j]
+      try:
+        results[j] = self.actors[j]._env.step_receive()
+      except BaseException as e:
+        failure = failure or (self.actors[j], e)
+    self.waiting_on = None
+    if failure is not None:
+      self.failed, exc = failure
+      raise exc
+    return results
+
+  def close(self):
+    """Close every member, those `join` brought and no unroll took in
+    among them."""
+    for actor in self.actors + [actor for actor, _ in self._joining]:
+      _close_quietly(actor)
+
+
+def _close_quietly(actor):
+  try:
+    actor.close()
+  except Exception:
+    pass
+
+
+def run_actor_loop(actor, buffer, stop_event,
+                   on_unroll: Optional[Callable[[Actor], bool]] = None,
                    on_failure: Optional[Callable] = None) -> None:
   """Produce unrolls into `buffer` until stopped (thread target).
 
@@ -197,16 +373,22 @@ def run_actor_loop(actor: Actor, buffer, stop_event,
     open for the other actors).
 
   Args:
-    actor: the Actor to roll (closed on exit, always).
+    actor: the Actor to roll, or an ActorGroup whose members this
+      thread rolls in lockstep (closed on exit, always). A group's
+      unrolls are put one by one, each with its own trace context.
     buffer: TrajectoryBuffer receiving unrolls.
     stop_event: threading.Event signalling shutdown.
-    on_unroll: called after each successful put; returning False ends
-      the loop (the fleet's orphaned-slot check). None = run forever.
+    on_unroll: called after each successful put with the Actor whose
+      unroll it was; on a False that member leaves the loop (it is
+      closed; the fleet's orphaned-slot and parked-slot check), and
+      the loop ends when none is left. None = run forever.
     on_failure: called with the failure exception instead of the
       default poison-and-raise.
   """
   from scalable_agent_tpu.ops.dynamic_batching import BatcherCancelled
   from scalable_agent_tpu.runtime import ring_buffer
+
+  group = actor if isinstance(actor, ActorGroup) else ActorGroup([actor])
 
   def fail(exc):
     if on_failure is None:
@@ -214,61 +396,76 @@ def run_actor_loop(actor: Actor, buffer, stop_event,
       raise exc
     on_failure(exc)
 
+  def put(unroll, span_id):
+    """False: stopping, and nobody drained the buffer within the
+    grace: the unroll is dropped."""
+    # Poll-put with a stop-aware grace (round 11): an actor parked
+    # on a full buffer used to block UNBOUNDED — quiesce() (which
+    # deliberately keeps the buffer open so in-flight unrolls land)
+    # could never join it unless the learner drained. Now the park
+    # re-checks the stop event every poll; once stopping, the unroll
+    # gets a bounded grace to land (the drain path drains, so it
+    # normally does) and is then dropped — a joined thread with a
+    # named lost unroll beats a wedged one.
+    stop_deadline = None
+    with telemetry.park('actor/put', id=span_id):
+      while True:
+        try:
+          buffer.put(unroll, timeout=_PUT_POLL_SECS)
+          return True
+        except TimeoutError:
+          if not stop_event.is_set():
+            continue
+          if stop_deadline is None:
+            stop_deadline = time.monotonic() + _STOP_PUT_GRACE_SECS
+          elif time.monotonic() > stop_deadline:
+            return False
+
   # Trace-span stamping (round 13, telemetry.py): when tracing is on
   # in this process, each completed unroll gets a fresh trace context
-  # — actor id (the fleet's thread name), per-loop sequence, the
-  # behaviour params version — stamped HOP_DONE here at env-step
-  # completion and carried beside the unroll (identity-keyed sidecar;
-  # the pytree itself cannot grow a leaf without breaking the wire
-  # contract). Downstream hops stamp at ingest/staging/step; a remote
-  # pump pops the tag and ships it on the v8 wire.
-  actor_name = threading.current_thread().name
-  unroll_seq = 0
+  # — actor id (the group's `names`; without them the thread's name,
+  # a group's further members `<thread>+1`, ...), the actor's sequence
+  # in this loop, the behaviour params version — stamped HOP_DONE
+  # here at env-step completion (every member's when the group's
+  # unroll returns, before the first put can park) and carried beside
+  # the unroll (identity-keyed sidecar; the pytree itself cannot grow
+  # a leaf without breaking the wire contract). Downstream hops stamp
+  # at ingest/staging/step; a remote pump pops the tag and ships it
+  # on the v8 wire.
+  if group.names is None:
+    thread_name = threading.current_thread().name
+    group.names = [thread_name if j == 0 else f'{thread_name}+{j}'
+                   for j in range(len(group.actors))]
+  unroll_seqs = collections.Counter()  # by actor id
 
   try:
     while not stop_event.is_set():
-      # The recorder's spans of this unroll carry the (actor, seq) of
+      group.admit()
+      actors = list(group.actors)
+      if not actors:
+        return  # every member left
+      # The recorder's spans of an unroll carry the (actor, seq) of
       # its trace context, so spans and traces.jsonl hops join.
-      span_id = (actor_name, unroll_seq)
-      unroll = actor.unroll(span_id=span_id)
-      trace = telemetry.begin_unroll_trace(actor_name, unroll_seq)
-      if trace is not None:
-        telemetry.stamp(trace, telemetry.HOP_DONE)
-        telemetry.tag_unroll(unroll, trace)
-      unroll_seq += 1
-      # Poll-put with a stop-aware grace (round 11): an actor parked
-      # on a full buffer used to block UNBOUNDED — quiesce() (which
-      # deliberately keeps the buffer open so in-flight unrolls land)
-      # could never join it unless the learner drained. Now the park
-      # re-checks the stop event every poll; once stopping, the unroll
-      # gets a bounded grace to land (the drain path drains, so it
-      # normally does) and is then dropped — a joined thread with a
-      # named lost unroll beats a wedged one.
-      stop_deadline = None
-      with telemetry.park('actor/put', id=span_id):
-        while True:
-          try:
-            buffer.put(unroll, timeout=_PUT_POLL_SECS)
-            break
-          except TimeoutError:
-            if not stop_event.is_set():
-              continue
-            if stop_deadline is None:
-              stop_deadline = time.monotonic() + _STOP_PUT_GRACE_SECS
-            elif time.monotonic() > stop_deadline:
-              return  # stopping and nobody is draining: drop + exit
-      if on_unroll is not None and not on_unroll():
-        return  # orphaned: a replacement owns this actor's slot
+      span_ids = [(name, unroll_seqs[name]) for name in group.names]
+      unrolls = group.unroll(span_ids)
+      for unroll, span_id in zip(unrolls, span_ids):
+        trace = telemetry.begin_unroll_trace(*span_id)
+        if trace is not None:
+          telemetry.stamp(trace, telemetry.HOP_DONE)
+          telemetry.tag_unroll(unroll, trace)
+        unroll_seqs[span_id[0]] += 1
+      for actor, unroll, span_id in zip(actors, unrolls, span_ids):
+        if not put(unroll, span_id):
+          return  # stopping and nobody is draining: drop + exit
+        if on_unroll is not None and not on_unroll(actor):
+          group.leave(actor)  # e.g. a replacement owns its slot now
   except (ring_buffer.Closed, BatcherCancelled) as e:
     if not stop_event.is_set():
       fail(e)
   except BaseException as e:
     fail(e)
   finally:
-    try:
-      actor.close()
-    except Exception:
-      pass
+    group.close()
 
 
 def batch_unrolls(unrolls):

@@ -357,11 +357,18 @@ class FaultyEnv:
 
   def __init__(self, env):
     self._env = env
+    if hasattr(env, 'step_send'):
+      # A process-hosted env stepped in two halves (py_process.ProxyEnv)
+      # takes its fault where the step starts; `step_receive` reaches
+      # the env through __getattr__. An env without the halves must
+      # not grow one here: the actor asks by hasattr.
+      self.step_send = self._step_send
 
   def initial(self):
     return self._env.initial()
 
-  def step(self, action):
+  @staticmethod
+  def _fire():
     fault = fire('env_step')
     if fault is not None:
       if fault.kind == 'raise':
@@ -370,7 +377,14 @@ class FaultyEnv:
         time.sleep(float(fault.param))
       # unknown kinds fall through: a typo'd schedule should not
       # silently change the no-fault behavior mid-run
+
+  def step(self, action):
+    self._fire()
     return self._env.step(action)
+
+  def _step_send(self, action):
+    self._fire()
+    return self._env.step_send(action)
 
   def close(self):
     return self._env.close()

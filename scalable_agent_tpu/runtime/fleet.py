@@ -22,16 +22,29 @@ fleet explicit and adds what upstream lacks:
 Trajectories from a respawned actor restart from a fresh episode —
 consistent with the reference's crash story (unrolls straddling a
 restart are lost, SURVEY §5.4).
+
+A SLOT is the unit of env identity (index, seed, level, heartbeat,
+error, quarantine, probation, parking); a GROUP is the unit of thread
+(PR 26): process-hosted envs of one spec are stepped k to a thread in
+lockstep (`runtime.actor.ActorGroup`), so one merged inference call
+wakes one thread per group and not one per env. k follows what the
+fleet observes — the env's hosting, its spec, `num_actors` and the
+rows the policy takes in one call — and is 1 for envs hosted in this
+process. A slot joins and leaves its group on its own, between two
+unrolls: parked, it leaves and the thread goes on with the rest;
+spawned, it joins a running group of its spec that has room, or
+starts a thread.
 """
 
+import collections
 import logging
 import threading
 import time
 from typing import Callable, Dict, List, Optional
 
 from scalable_agent_tpu.analysis.runtime import guarded_by, make_lock
-from scalable_agent_tpu.runtime import ring_buffer
-from scalable_agent_tpu.runtime.actor import Actor
+from scalable_agent_tpu.runtime import py_process, ring_buffer
+from scalable_agent_tpu.runtime.actor import Actor, ActorGroup
 from scalable_agent_tpu.runtime.remote import Backoff
 
 log = logging.getLogger('scalable_agent_tpu')
@@ -46,6 +59,44 @@ def _is_admission_error(e: BaseException) -> bool:
   return isinstance(e, (SlotUnavailable, InferenceClosed))
 
 
+# Envs to an actor thread, at most: as far as the measurement goes.
+# `deep_dmlab.fleet32` (32 envs, 13 cores, TPU v5e; PERF.md, PR 26)
+# got faster with every thread fewer, frames/s by thread count: 32:
+# 3,845; 16: 5,010; 8: 6,534; 6: 7,519; 4: 8,382; 3: 8,700; 2: 10,922;
+# 1: 13,503. Under the GIL an actor thread adds no parallel work (the
+# env steps run in the children, whichever thread sent them); it adds
+# a wake-up per merged inference call, and the threads come back from
+# that wake one after another. Past 32 nothing was measured: one
+# thread's serial share of a step (a send, a receive and the
+# bookkeeping per env) comes near the time the children take to step.
+# What a group costs: an env that fails takes its mates' unrolls in
+# flight with it, and one that hangs holds them all up until the stall
+# check respawns the group (docs/ROBUSTNESS.md).
+_MAX_ENVS_PER_THREAD = 32
+
+# One env on a thread: its slot, the slot's generation at the spawn,
+# its Actor and its PyProcess (None: hosted in this process).
+_Member = collections.namedtuple('_Member',
+                                 'slot generation actor process')
+
+
+class _Group:
+  """One actor thread and the slots it carries."""
+
+  def __init__(self, key, members):
+    self.key = key  # `Actor.group_key()`; None: takes no one else
+    self.actors = ActorGroup(
+        [m.actor for m in members],
+        names=[f'actor-{m.slot.index}' for m in members])
+    # Actor -> _Member, under the fleet's lock: those of `actors` and
+    # those it has yet to admit.
+    self.members = {m.actor: m for m in members}
+    self.thread: Optional[threading.Thread] = None
+    # Takes no more members (under the fleet's lock): the thread has
+    # ended, failed or stalled.
+    self.closed = False
+
+
 class _Slot:
   """One actor's mutable runtime state (env, thread, health)."""
 
@@ -54,12 +105,19 @@ class _Slot:
     self.env = None
     self.process = None          # PyProcess when process-hosted
     self.actor: Optional[Actor] = None
+    # The thread that steps this slot's env, and the group it carries
+    # (the slots of one group hold the same two).
     self.thread: Optional[threading.Thread] = None
+    self.group: Optional[_Group] = None
     self.generation: int = 0     # bumped on every (re)spawn
     self.last_heartbeat: float = time.monotonic()
     self.unrolls_done: int = 0
     self.respawns: int = 0
     self.error: Optional[BaseException] = None
+    # The group's thread ended or hung on ANOTHER member's env: this
+    # slot respawns with it, off the respawn ladder (no streak, no
+    # backoff: the slot did nothing wrong).
+    self.collateral: bool = False
     # Respawn pacing (round 9): consecutive respawns since the last
     # COMPLETED unroll (a spawn that crash-loops before producing is
     # still a failure), the per-slot jittered backoff, the earliest
@@ -89,6 +147,10 @@ class ActorFleet:
     num_actors: fleet size.
     quarantine_after: consecutive respawns without one completed
       unroll before the slot gives up and quarantines (0 = never).
+    max_policy_rows: the most rows `policy` takes in one call (the
+      inference server's `inference_max_batch`: the batcher never
+      splits a request), so the most envs to a thread; None: no such
+      limit.
   """
 
   # Lock discipline (round 18, checked by the guarded-by lint): slot
@@ -96,10 +158,12 @@ class ActorFleet:
   # _Slot objects themselves are reached only through _slots.
   _slots_rehabilitated: guarded_by('_lock')
   _rehabilitations: guarded_by('_lock')
+  _groups: guarded_by('_lock')
 
   def __init__(self, make_actor: Callable, buffer, num_actors: int,
                quarantine_after: int = 5,
-               probation_secs: float = 30.0):
+               probation_secs: float = 30.0,
+               max_policy_rows: Optional[int] = None):
     self._make_actor = make_actor
     self._buffer = buffer
     self._quarantine_after = int(quarantine_after)
@@ -109,112 +173,218 @@ class ActorFleet:
     self._slots: List[_Slot] = [_Slot(i) for i in range(num_actors)]
     self._slots_rehabilitated = 0  # probation cleared by an unroll
     self._rehabilitations = 0      # probation attempts started
+    # Process-hosted envs to a thread, at most (`_MAX_ENVS_PER_THREAD`).
+    self._envs_per_thread = max(1, min(
+        _MAX_ENVS_PER_THREAD, max_policy_rows or _MAX_ENVS_PER_THREAD))
+    self._groups: List[_Group] = []  # running, with a key
 
   @property
   def stop_event(self):
     return self._stop
 
   def start(self):
-    for slot in self._slots:
-      if slot.parked:
-        continue  # parked before start (elastic fleets spin up small)
-      try:
-        self._spawn(slot)
-      except Exception as e:
-        # Overload degrade (round 9): a start-time acquire denied by
-        # inference-slot admission is NOT a setup error — record it on
-        # the slot and let the health loop retry on the slot's backoff
-        # instead of crashing the run before it begins. Anything else
-        # (env construction, bad config) still raises to the caller.
-        if not _is_admission_error(e):
-          raise
-        with self._lock:
-          slot.error = e
-          slot.thread = None
-          slot.respawn_streak += 1
-          slot.next_respawn_time = (time.monotonic()
-                                    + slot.backoff.next_delay())
-        log.warning(
-            'actor %d: start-time slot admission denied (%s) — '
-            'degrading to pause-and-retry', slot.index, e)
+    def on_error(slot, e):
+      # Overload degrade (round 9): a start-time acquire denied by
+      # inference-slot admission is NOT a setup error — record it on
+      # the slot and let the health loop retry on the slot's backoff
+      # instead of crashing the run before it begins. Anything else
+      # (env construction, bad config) still raises to the caller.
+      if not _is_admission_error(e):
+        raise e
+      with self._lock:
+        slot.error = e
+        slot.thread = None
+        slot.respawn_streak += 1
+        slot.next_respawn_time = (time.monotonic()
+                                  + slot.backoff.next_delay())
+      log.warning(
+          'actor %d: start-time slot admission denied (%s) — '
+          'degrading to pause-and-retry', slot.index, e)
+
+    # Parked before start: elastic fleets spin up small.
+    self._spawn_slots([s for s in self._slots if not s.parked],
+                      on_error)
 
   def _spawn(self, slot: _Slot):
-    env, process, actor = self._make_actor(slot.index)
-    with self._lock:
-      slot.generation += 1
-      generation = slot.generation
-      slot.env, slot.process, slot.actor = env, process, actor
-      slot.error = None
-      slot.last_heartbeat = time.monotonic()
-    slot.thread = threading.Thread(
-        target=self._run, args=(slot, generation, actor, process),
-        name=f'actor-{slot.index}', daemon=True)
-    slot.thread.start()
+    def on_error(slot, e):
+      raise e
+    self._spawn_slots([slot], on_error)
 
-  def _run(self, slot: _Slot, generation: int, actor: Actor, process):
+  def _spawn_slots(self, slots: List[_Slot], on_error: Callable):
+    """Build each slot's env and actor, in turn, then give each a
+    thread (`_place`): its own for an env that lives in this process,
+    one shared with its `Actor.group_key`'s others for a process-hosted
+    env. `on_error(slot, exc)` takes a failed build; if it raises, the
+    slots built so far run and the rest is not built."""
+    built = {}  # group key -> [_Member]
+    try:
+      for slot in slots:
+        try:
+          env, process, actor = self._make_actor(slot.index)
+        except Exception as e:
+          on_error(slot, e)
+          continue
+        with self._lock:
+          slot.generation += 1
+          generation = slot.generation
+          slot.env, slot.process, slot.actor = env, process, actor
+          slot.error = None
+          slot.collateral = False
+          slot.last_heartbeat = time.monotonic()
+        group_key = getattr(actor, 'group_key', None)
+        key = group_key() if process is not None and group_key else None
+        built.setdefault(key, []).append(
+            _Member(slot, generation, actor, process))
+    finally:
+      for key, members in built.items():
+        self._place(key, members)
+
+  def _place(self, key, members: List[_Member]):
+    """A thread for each new member. With a `key` (process-hosted
+    envs that can share one): room in a running group of that key
+    first, where the member steps with the others from their next
+    unroll on, then new threads in even shares of at most
+    `_envs_per_thread`. Without: a thread of its own."""
+    room = self._envs_per_thread if key is not None else 1
+    with self._lock:
+      self._groups = [g for g in self._groups if not g.closed]
+      for group in self._groups:
+        if group.key != key:
+          continue
+        while members and len(group.members) < room:
+          member = members.pop(0)
+          group.members[member.actor] = member
+          self._carry(member.slot, group)
+          group.actors.join(member.actor, f'actor-{member.slot.index}')
+    threads = -(-len(members) // room)
+    share, larger = divmod(len(members), threads or 1)
+    while members:
+      size = share + (larger > 0)
+      larger -= 1
+      self._start_thread(key, members[:size])
+      members = members[size:]
+
+  def _start_thread(self, key, members: List[_Member]):
+    group = _Group(key, members)
+    group.thread = threading.Thread(
+        target=self._run, args=(group,),
+        name=f'actor-{members[0].slot.index}', daemon=True)
+    with self._lock:
+      for member in members:
+        self._carry(member.slot, group)
+      if key is not None:
+        self._groups.append(group)
+    group.thread.start()
+
+  @staticmethod
+  def _carry(slot: _Slot, group: _Group):
+    """`group`'s thread steps `slot`'s env from here on (under the
+    lock). Its heartbeat starts now and not when its env was built:
+    a group's envs are built in turn before their thread starts, and
+    the first must not look stalled for the time the others took."""
+    slot.thread, slot.group = group.thread, group
+    slot.last_heartbeat = time.monotonic()
+
+  def _run(self, group: _Group):
     """Thread body: `actor.run_actor_loop` (the one shutdown/poison
     contract) with fleet bookkeeping hooked in. Touches only ITS OWN
-    actor/process objects and writes slot state only while it is still
-    the slot's current generation — an orphaned thread (replaced after
-    a stall) must not mark the healthy replacement dead or close its
-    process. Failures are recorded on the slot (the shared buffer
-    stays open for the other actors); the learner surfaces them via
-    errors() on its stall path."""
+    actor/process objects and writes a slot's state only while it is
+    still that slot's current generation — an orphaned thread
+    (replaced after a stall) must not mark the healthy replacement
+    dead or close its process. Failures are recorded on the slot whose
+    env failed, or on every member when the failure was not one env's
+    (the shared buffer stays open for the other actors); the learner
+    surfaces them via errors() on its stall path."""
     from scalable_agent_tpu.runtime.actor import run_actor_loop
 
-    def still_current():
-      return slot.generation == generation
-
-    def on_unroll():
+    def on_unroll(actor):
       with self._lock:
-        if not still_current():
-          return False  # orphaned: a replacement owns the slot now
-        slot.last_heartbeat = time.monotonic()
-        slot.unrolls_done += 1
-        # A completed unroll is the success signal that resets the
-        # respawn ladder: streak, backoff, and pacing all clear — and
-        # it is what clears PROBATION: a rehabilitated slot has
-        # proven itself only once it lands real data (round 15,
-        # counted as slots_rehabilitated).
-        slot.respawn_streak = 0
-        slot.backoff.reset()
-        slot.next_respawn_time = 0.0
-        if slot.probation:
-          slot.probation = False
-          self._slots_rehabilitated += 1
-          log.info('actor %d REHABILITATED: probation unroll '
-                   'completed; the slot rejoins the fleet',
-                   slot.index)
-        if slot.parked:
-          # The controller shrank the fleet under us: land this
-          # unroll (already put), then exit the loop cleanly.
-          return False
-        return True
+        slot, generation, _, process = group.members[actor]
+        stays = slot.generation == generation
+        if stays:
+          slot.last_heartbeat = time.monotonic()
+          slot.unrolls_done += 1
+          # A completed unroll is the success signal that resets the
+          # respawn ladder: streak, backoff, and pacing all clear — and
+          # it is what clears PROBATION: a rehabilitated slot has
+          # proven itself only once it lands real data (round 15,
+          # counted as slots_rehabilitated).
+          slot.respawn_streak = 0
+          slot.backoff.reset()
+          slot.next_respawn_time = 0.0
+          if slot.probation:
+            slot.probation = False
+            self._slots_rehabilitated += 1
+            log.info('actor %d REHABILITATED: probation unroll '
+                     'completed; the slot rejoins the fleet',
+                     slot.index)
+          if slot.parked:
+            # The controller shrank the fleet under us: this unroll
+            # landed (already put), and the slot leaves its thread,
+            # which goes on with the rest of the group.
+            stays = False
+            slot.thread = slot.group = None
+        # Else orphaned: a replacement owns the slot now.
+        if not stays:
+          del group.members[actor]
+      if not stays and process is not None:
+        self._close_processes([process])
+      return stays
 
     def on_failure(exc):
+      failed = group.actors.failed
       with self._lock:
-        if still_current():
-          slot.error = exc
+        group.closed = True
+        for actor, (slot, generation, _, _) in group.members.items():
+          if slot.generation != generation:
+            continue
+          if failed is None or failed is actor:
+            slot.error = exc
+          else:
+            slot.collateral = True
 
     try:
-      run_actor_loop(actor, self._buffer, self._stop,
+      run_actor_loop(group.actors, self._buffer, self._stop,
                      on_unroll=on_unroll, on_failure=on_failure)
     finally:
-      if process is not None:
-        try:
-          process.close(timeout=2.0)
-        except Exception:
-          pass
+      with self._lock:
+        group.closed = True
+        members = list(group.members.values())
+        if not self._stop.is_set():
+          # Whoever is still here goes down with the thread: a slot
+          # that joined as the loop ended, if the failure path above
+          # charged no one.
+          for slot, generation, _, _ in members:
+            if slot.generation == generation and slot.error is None:
+              slot.collateral = True
+      self._close_processes([m.process for m in members
+                             if m.process is not None], timeout=2.0)
+
+  @staticmethod
+  def _close_processes(processes, timeout=1.0):
+    """Close env processes, all at once: a close that finds a call in
+    flight waits out its timeout before it kills the child, and a
+    stalled group's thread holds a call on each of its children."""
+    try:
+      py_process.close_all(processes, timeout=timeout)
+    except Exception:  # the callers go on: these processes are done
+      log.exception('closing %d env processes', len(processes))
 
   def check_health(self, stall_timeout_secs: Optional[float] = None,
                    respawn: bool = True) -> List[int]:
     """Detect failed/stalled actors; respawn them. Returns the indices
     acted upon. Call periodically from the learner loop (the reference
-    has no equivalent — SURVEY §5.3 greenfield)."""
+    has no equivalent — SURVEY §5.3 greenfield).
+
+    A thread stalls as a whole: the members of a stalled slot's group
+    are respawned with it. Where the thread hangs in an env step, the
+    member whose reply it waits for is the one at fault and climbs
+    the respawn ladder; the others are `collateral`."""
     if self._stop.is_set():
       return []
     now = time.monotonic()
     bad: List[_Slot] = []
+    stalled_groups: List[_Group] = []
     with self._lock:
       for slot in self._slots:
         if slot.quarantined or slot.parked:
@@ -222,8 +392,8 @@ class ActorFleet:
         # thread-None counts as dead (round 15): a slot unparked after
         # never spawning (elastic grow) has no thread and no error —
         # it must still be picked up here and spawned.
-        dead = (slot.error is not None or slot.thread is None
-                or not slot.thread.is_alive())
+        dead = (slot.error is not None or slot.collateral
+                or slot.thread is None or not slot.thread.is_alive())
         stalled = (stall_timeout_secs is not None and
                    now - slot.last_heartbeat > stall_timeout_secs)
         # Respawn pacing: a failing slot is retried only once its
@@ -232,19 +402,52 @@ class ActorFleet:
         # the learner thread through every health check.
         if (dead or stalled) and now >= slot.next_respawn_time:
           bad.append(slot)
-    for slot in bad:
-      if respawn:
-        self._respawn(slot)
+          if (not dead and slot.group is not None
+              and slot.group not in stalled_groups):
+            stalled_groups.append(slot.group)
+      for group in stalled_groups if respawn else ():
+        group.closed = True
+        hung = group.actors.waiting_on
+        for actor, (mate, generation, _, _) in group.members.items():
+          if (mate.generation != generation or mate.quarantined
+              or mate.parked or mate.error is not None):
+            continue
+          # A mate whose own heartbeat is stale is charged, as a lone
+          # actor is, unless the thread waits for another's env.
+          own = mate in bad
+          if not own:
+            bad.append(mate)
+          mate.collateral = (actor is not hung and
+                             (hung is not None or not own))
+      bad.sort(key=lambda s: s.index)
+    if respawn and bad:
+      self._close_processes(
+          [s.process for s in bad if s.process is not None])
+      # The slots found bad together respawn together: the members of
+      # a failed group share a thread again.
+      self._spawn_slots([s for s in bad if self._retire(s)],
+                        self._spawn_failed)
     return [s.index for s in bad]
 
-  def _respawn(self, slot: _Slot):
+  def _spawn_failed(self, slot: _Slot, e: Exception):
+    # A failed respawn (env construction, denied inference-slot
+    # admission) must not propagate into the learner loop that
+    # called check_health — start()-time spawn failures still raise
+    # for setup errors (admission denials degrade; see start()), but
+    # a mid-run respawn records the error on the slot: the next
+    # health check retries after the slot's backoff, and the learner
+    # surfaces it via errors() only if the pipeline actually stalls
+    # (the same containment as any other actor-side failure).
+    with self._lock:
+      slot.error = e
+      slot.thread = None
+
+  def _retire(self, slot: _Slot) -> bool:
+    """The first half of a respawn (its env process is closed): let go
+    of the slot's old thread and move it along the respawn ladder.
+    False: the slot gave up (quarantined) and is not spawned again."""
     old_thread = slot.thread
     old_actor = slot.actor
-    if slot.process is not None:
-      try:
-        slot.process.close(timeout=1.0)
-      except Exception:
-        pass
     if old_thread is not None and old_thread.is_alive():
       # A stalled thread blocked in env.step can't be killed; it is
       # orphaned (daemon) and a fresh actor takes over the slot. Its
@@ -266,6 +469,8 @@ class ActorFleet:
         pass
     with self._lock:
       slot.respawns += 1
+      if slot.collateral:
+        return True  # its group's failure, not its own: no ladder
       slot.respawn_streak += 1
       # Pace the NEXT attempt now, so a spawn that fails (or succeeds
       # and immediately crash-loops) waits out the jittered backoff
@@ -290,21 +495,7 @@ class ActorFleet:
           'a completed unroll (last error: %s) — the slot is marked '
           'dead; the rest of the fleet keeps feeding', slot.index,
           slot.respawn_streak, slot.error)
-      return
-    try:
-      self._spawn(slot)
-    except Exception as e:
-      # A failed respawn (env construction, denied inference-slot
-      # admission) must not propagate into the learner loop that
-      # called check_health — start()-time spawn failures still raise
-      # for setup errors (admission denials degrade; see start()), but
-      # a mid-run respawn records the error on the slot: the next
-      # health check retries after the slot's backoff, and the learner
-      # surfaces it via errors() only if the pipeline actually stalls
-      # (the same containment as any other actor-side failure).
-      with self._lock:
-        slot.error = e
-        slot.thread = None
+    return not give_up
 
   # --- elastic fleet size (round 15): the controller's actuator ---
 
@@ -320,16 +511,19 @@ class ActorFleet:
     """Thread-safe elastic resize toward `n` contributing slots.
 
     Shrink parks the highest-index contributing slots (each actor
-    exits cleanly after its current unroll — the on_unroll seam; a
-    parked slot leaves the quorum denominator, so shedding load never
-    reads as a dying fleet). Grow first UNPARKS parked slots, then
-    REHABILITATES quarantined ones whose probation cool-down has
-    elapsed: quarantine cleared, probation armed, respawn ladder
-    reset — the next check_health runs the probe spawn, and ONE
-    completed unroll clears probation (slots_rehabilitated); a repeat
-    failure re-quarantines immediately. The fleet never grows past
-    its constructed slot count (the bounded-move guarantee — the
-    controller's actuator registers that as the hard max).
+    exits cleanly after its current unroll — the on_unroll seam: a
+    lone actor's thread ends, a group's goes on with its other
+    members; a parked slot leaves the quorum denominator, so shedding
+    load never reads as a dying fleet). Grow first UNPARKS parked
+    slots, then REHABILITATES quarantined ones whose probation
+    cool-down has elapsed: quarantine cleared, probation armed,
+    respawn ladder reset — the next check_health runs the probe spawn
+    (into a running group of the slot's spec that has room, else on a
+    new thread), and ONE completed unroll clears probation
+    (slots_rehabilitated); a repeat failure re-quarantines
+    immediately. The fleet never grows past its constructed slot
+    count (the bounded-move guarantee — the controller's actuator
+    registers that as the hard max).
 
     Returns {'parked': [...], 'unparked': [...], 'rehabilitated':
     [...]} slot indices. May deliver fewer than requested when every
@@ -441,7 +635,12 @@ class ActorFleet:
       # reading as unhealthy would make every deliberate shed look
       # like a dying plane to the fleet_healthy_fraction objective.
       active = sum(1 for s in self._slots if not s.parked)
+      threads = len({id(s.thread) for s in alive})
       return {
+          # Slots alive per running thread (PR 26): 1.0 while every
+          # env has a thread of its own, k where groups of k share one.
+          'actor_threads': threads,
+          'envs_per_thread': len(alive) / threads if threads else 0.0,
           'unrolls': sum(s.unrolls_done for s in self._slots),
           'respawns': sum(s.respawns for s in self._slots),
           'alive': len(alive),

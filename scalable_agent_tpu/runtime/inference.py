@@ -472,6 +472,7 @@ class InferenceServer:
     self._aot_misses = 0
     self._calls = 0
     self._merged_requests = 0
+    self._batcher_requests = 0
     self._params_version = 0
     self._publishes_skipped = 0
     self._devices_last_call = 0
@@ -1179,7 +1180,8 @@ class InferenceServer:
   def stats(self):
     """Merge + service telemetry.
 
-    {'calls', 'requests', 'mean_batch', 'params_version',
+    {'calls', 'requests', 'batcher_requests', 'mean_batch',
+     'params_version',
      'publishes_skipped', 'devices_last_call', 'latency_p50_ms',
      'latency_p99_ms', 'pipeline_depth', 'state_cache',
      'inflight_peak', 'slots_free'}.
@@ -1227,6 +1229,10 @@ class InferenceServer:
     return {
         'calls': calls,
         'requests': reqs,
+        # `requests` counts ROWS (one per env step); this counts the
+        # policy() calls that carried them: rows per call is 1 for
+        # lone actors, k for an ActorGroup of k.
+        'batcher_requests': self._batcher_requests,
         'mean_batch': (reqs / calls) if calls else 0.0,
         'params_version': version,
         'publishes_skipped': skipped,
@@ -1578,50 +1584,64 @@ class InferenceServer:
     }
 
   def policy(self, prev_action, env_output, core_state):
-    """`runtime.actor.Actor`-contract policy: scalars in, scalars out.
+    """`runtime.actor.Actor`-contract policy, in both of its forms.
 
-    Carry-passing mode: core_state is the numeric (c, h) carry and the
-    new carry rides the wire back. State-cache mode: core_state is a
-    `_SlotHandle` and only its slot id rides the wire — the carry
-    advances in-graph on the device."""
+    Scalar form (one env): scalars in, scalars out. k-row form (an
+    `ActorGroup` of k envs; told by `prev_action` being i32[k]): every
+    leaf of `env_output` and of the returned AgentOutput has a leading
+    axis of k, and the k rows ride the batcher as ONE request (one
+    park, one wake, one copy of the results), never split across
+    merged calls. Row by row the two forms compute the same.
+
+    Carry-passing mode: core_state is the numeric (c, h) carry,
+    `[1, H]` each (`[k, H]` for k rows), and the new carry rides the
+    wire back. State-cache mode: core_state is a `_SlotHandle` (a list
+    of k for k rows) and only the slot ids ride the wire — the carries
+    advance in-graph on the device."""
     frame, instr = env_output.observation
-    if self._state_cache:
-      if not isinstance(core_state, _SlotHandle):
-        raise TypeError(
-            'state-cache mode: core_state must be the slot handle '
-            'from initial_core_state(), got '
-            f'{type(core_state).__name__}')
-      if core_state.released:
-        # A respawned actor owns this slot's successor; a straggler
-        # thread must fail here, not scatter into someone else's slot.
-        raise RuntimeError('policy() called with a released state slot')
-      inputs = [
-          np.asarray([core_state.slot], np.int32),
-          np.asarray([prev_action], np.int32),
-          np.asarray([env_output.reward], np.float32),
-          np.asarray([env_output.done], bool),
-          np.asarray(frame)[None],
-          np.asarray(instr)[None]]
-      with telemetry.span('batcher/compute'):
-        action, logits, baseline = self._batcher.compute(inputs)
-      out = AgentOutput(action=action[0], policy_logits=logits[0],
-                        baseline=baseline[0])
-      return out, core_state
-    core_c, core_h = core_state
+    grouped = np.ndim(prev_action) > 0
+
+    def rows(x, dtype=None):
+      x = np.asarray(x, dtype)
+      return x if grouped else x[None]
+
     inputs = [
-        np.asarray([prev_action], np.int32),
-        np.asarray([env_output.reward], np.float32),
-        np.asarray([env_output.done], bool),
-        np.asarray(frame)[None],
-        np.asarray(instr)[None],
-        np.asarray(core_c, np.float32),
-        np.asarray(core_h, np.float32)]
+        rows(prev_action, np.int32),
+        rows(env_output.reward, np.float32),
+        rows(env_output.done, bool),
+        rows(frame),
+        rows(instr)]
+    if self._state_cache:
+      handles = core_state if grouped else [core_state]
+      for handle in handles:
+        if not isinstance(handle, _SlotHandle):
+          raise TypeError(
+              'state-cache mode: core_state must be the slot handle '
+              'from initial_core_state(), got '
+              f'{type(handle).__name__}')
+        if handle.released:
+          # A respawned actor owns this slot's successor; a straggler
+          # thread must fail here, not scatter into someone else's slot.
+          raise RuntimeError(
+              'policy() called with a released state slot')
+      inputs.insert(0, np.asarray([h.slot for h in handles], np.int32))
+      new_state = core_state
+    else:
+      core_c, core_h = core_state
+      inputs += [np.asarray(core_c, np.float32),
+                 np.asarray(core_h, np.float32)]
+    # No lock for a counter on every caller's path: a count lost to
+    # two callers' race is within what stats() promises of it.
+    self._batcher_requests += 1
     with telemetry.span('batcher/compute'):
-      action, logits, baseline, new_c, new_h = self._batcher.compute(
-          inputs)
-    out = AgentOutput(action=action[0], policy_logits=logits[0],
-                      baseline=baseline[0])
-    return out, (new_c, new_h)
+      outs = self._batcher.compute(inputs)
+    if not self._state_cache:
+      new_state = tuple(outs[3:])
+    action, logits, baseline = outs[:3]
+    if not grouped:
+      action, logits, baseline = action[0], logits[0], baseline[0]
+    return AgentOutput(action=action, policy_logits=logits,
+                       baseline=baseline), new_state
 
   def close(self):
     with self._slot_lock:

@@ -232,6 +232,10 @@ class PyProcess:
     self._conn = None
     self._process = None
     self._lock = threading.Lock()  # pipes are not thread-safe
+    # A call between its two halves (_send, _receive): (the thread
+    # that holds the lock for it, method, kwargs, a reply already in
+    # hand, the env/pipe span).
+    self._pending = None
     self._closed = False
 
   @property
@@ -251,38 +255,61 @@ class PyProcess:
     return self
 
   def _call(self, method, args, kwargs):
-    with self._lock:
+    self._send(method, args, kwargs)
+    return self._receive()
+
+  def _send(self, method, args, kwargs):
+    """First half of a call: the request goes down the pipe and the
+    child starts on it; `_receive` is the second half. The per-process
+    lock is taken here and kept until `_receive` has the reply (or a
+    failure here gives it back), so calls still never interleave on
+    the pipe and `close()` still finds a call in flight. Between the
+    halves the caller may send to OTHER processes: that is how one
+    actor thread has k children stepping at once."""
+    me = threading.get_ident()
+    pending = self._pending
+    if pending is not None and pending[0] == me:
+      # The lock is not reentrant: this would park the thread for ever.
+      raise RuntimeError(
+          f'{self._type.__name__}.{method}: send before the receive of '
+          f'{pending[1]!r}')
+    self._lock.acquire()
+    try:
       if self._closed or self._conn is None:
         raise ProcessClosed(f'{self._type.__name__} process not running')
-      def handle_closed_pipe(e):
-        # A child whose ctor failed sends ('exception', ...) and closes
-        # its end; if it closed before our send/recv, the buffered ctor
-        # error would be lost. Drain it so the documented "ctor failure
-        # reported on first proxy call" contract holds regardless of
-        # timing.
-        buffered = self._drain_buffered_reply()
-        if buffered is None:
-          raise ProcessClosed(
-              f'{self._type.__name__} process pipe closed') from e
-        return buffered
-
       reply = None
       pipe = telemetry.span('env/pipe')  # send -> reply in hand
       try:
         self._conn.send((method, args, kwargs))
       except (EOFError, OSError, BrokenPipeError) as e:
-        reply = handle_closed_pipe(e)
+        reply = self._buffered_reply_or_closed(e)
       except Exception as e:
         # send() failed locally (e.g. unpicklable argument) — nothing
         # reached the child; blame the caller, not the remote side.
         raise TypeError(
             f'could not serialize request for '
             f'{self._type.__name__}.{method}: {e!r}') from e
+    except BaseException:
+      self._lock.release()
+      raise
+    self._pending = (me, method, kwargs, reply, pipe)
+
+  def _receive(self):
+    """Second half of a call: block for the reply to this thread's
+    `_send`, give the lock back, and turn the reply into the result
+    or the remote exception."""
+    pending = self._pending
+    if pending is None or pending[0] != threading.get_ident():
+      raise RuntimeError(
+          f'{self._type.__name__}: receive without a send from this '
+          'thread')
+    _, method, kwargs, reply, pipe = pending
+    try:
       if reply is None:
         try:
           reply = self._conn.recv()
         except (EOFError, OSError, BrokenPipeError) as e:
-          reply = handle_closed_pipe(e)
+          reply = self._buffered_reply_or_closed(e)
         except Exception as e:
           # The reply arrived but failed to unpickle (e.g. an exception
           # class whose __reduce__ pickles but can't reconstruct). The
@@ -293,7 +320,10 @@ class PyProcess:
               f'in hosted {self._type.__name__}.{method}: reply could '
               f'not be deserialized ({e!r})') from e
       pipe.end()
-      status, payload = reply
+    finally:
+      self._pending = None
+      self._lock.release()
+    status, payload = reply
     if status == 'exception':
       exc, tb = payload
       err = RemoteError(
@@ -306,6 +336,18 @@ class PyProcess:
                                        self._constructor_kwargs)
       _validate_specs(payload, specs, f'{self._type.__name__}.{method}')
     return payload
+
+  def _buffered_reply_or_closed(self, e):
+    # A child whose ctor failed sends ('exception', ...) and closes
+    # its end; if it closed before our send/recv, the buffered ctor
+    # error would be lost. Drain it so the documented "ctor failure
+    # reported on first proxy call" contract holds regardless of
+    # timing.
+    buffered = self._drain_buffered_reply()
+    if buffered is None:
+      raise ProcessClosed(
+          f'{self._type.__name__} process pipe closed') from e
+    return buffered
 
   def _drain_buffered_reply(self):
     """Return a reply the child pipelined before dying, if any."""
@@ -424,6 +466,14 @@ class ProxyEnv:
 
   def step(self, action):
     return self._proxy.step(action)
+
+  def step_send(self, action):
+    """`step` in two halves, for an actor thread that steps several
+    hosted envs at once: send to each, then `step_receive` from each."""
+    self._process._send('step', (action,), {})
+
+  def step_receive(self):
+    return self._process._receive()
 
   def close(self):
     self._process.close()

@@ -2228,9 +2228,11 @@ class TrajectoryIngestServer:
         self._conns.append(wrapped)
         self._threads = [x for x in self._threads if x.is_alive()]
         self._threads.append(t)
+        # Started under the lock: close() snapshots `_threads` under
+        # it and joins them, and an unstarted thread cannot be joined.
+        t.start()
       with self._stats_lock:
         self._connections += 1
-      t.start()
 
   def _snapshot_frame(
       self, proto: int = PROTOCOL_VERSION) -> Tuple[List[bytes], bytes]:

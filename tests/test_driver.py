@@ -249,11 +249,15 @@ SPAN_NAMES = {
     'learner/publish', 'learner/summaries'}
 
 
-def test_a_fleet_run_records_every_span_at_its_boundary(tmp_path):
+def test_a_fleet_run_records_every_span_at_its_boundary(tmp_path,
+                                                        monkeypatch):
   """The recorder armed over one short fleet run with process-hosted
   envs: every span name of docs/OBSERVABILITY.md's table at least
-  once, nested as the table says, ids shared as it says."""
+  once, nested as the table says, ids shared as it says. A thread per
+  env here (the grouped nesting is the next test's)."""
   from scalable_agent_tpu import telemetry
+  from scalable_agent_tpu.runtime import fleet as fleet_lib
+  monkeypatch.setattr(fleet_lib, '_MAX_ENVS_PER_THREAD', 1)
   # (Batches of 4 from 2 actors, and steps enough to outrun what the
   # actors made while the first step compiled: the learner waits.)
   cfg = _config(tmp_path, use_py_process=True, num_actors=2,
@@ -325,6 +329,137 @@ def test_a_fleet_run_records_every_span_at_its_boundary(tmp_path):
   # Staging is a thread of its own: wait, then stage, per batch.
   assert len({r[3] for r in all_of('staging/stage')}) == 1
   assert len(all_of('staging/stage')) >= 8
+
+
+@pytest.mark.parametrize('state_cache', [False, True],
+                         ids=['carry_passing', 'state_cache'])
+def test_a_grouped_fleet_run_counts_and_spans(tmp_path, state_cache):
+  """Process-hosted envs share an actor thread (PR 26):
+  `driver.train` end to end through the k-row policy
+  call, with the counters that say the mechanism engaged and the
+  spans at their granularity: per env (`actor/unroll`, `env/pipe`,
+  `actor/assemble`, `actor/put`) or per group step (the rest)."""
+  from scalable_agent_tpu import telemetry
+  cfg = _config(tmp_path, use_py_process=True, num_actors=2,
+                batch_size=4, inference_state_cache=state_cache)
+  telemetry.arm_spans()
+  try:
+    run = driver.train(cfg, max_steps=6, stall_timeout_secs=120)
+  finally:
+    taken = telemetry.take_spans()
+  assert int(run.state.update_steps) == 6
+  server, fleet = run.server.stats(), run.fleet.stats()
+  assert server['requests'] == 2 * server['batcher_requests'] > 0
+  assert server['mean_batch'] == 2.0  # rows, as before
+  assert fleet['respawns'] == 0 and fleet['slots_quarantined'] == 0
+  tags = {}
+  with open(os.path.join(cfg.logdir, 'summaries.jsonl')) as f:
+    for line in f:
+      row = json.loads(line)
+      if 'value' in row:  # histograms carry none
+        tags.setdefault(row['tag'], []).append(row['value'])
+  assert set(tags['actor_threads']) == {1.0}
+  assert set(tags['envs_per_thread']) == {2.0}
+  # (Rows are counted at dispatch, calls when made: one in flight.)
+  assert tags['inference_rows_per_request'][-1] == pytest.approx(
+      2.0, rel=0.05)
+  # (0.0: a summary interval in which no merged call was dispatched.)
+  assert set(tags['inference_mean_batch']) - {0.0} == {2.0}
+
+  rows = taken['spans']
+  assert {row[0] for row in rows} == SPAN_NAMES
+  actor_threads = {r[3] for r in rows if r[0].startswith('actor/')}
+  assert [taken['threads'][t] for t in actor_threads] == ['actor-0']
+
+  def inside(parent, name):
+    return [r for r in rows if r[0] == name and r[3] == parent[3] and
+            parent[1] <= r[1] and r[2] <= parent[2]]
+
+  unrolls = [r for r in rows if r[0] == 'actor/unroll']
+  assert {r[4][0] for r in unrolls} == {'actor-0', 'actor-1'}
+  for unroll in unrolls:
+    steps = inside(unroll, 'actor/step')
+    assert len(steps) == cfg.unroll_length
+    # Both members' assemblies fall inside either member's unroll.
+    assert ({r[4][0] for r in inside(unroll, 'actor/assemble')} ==
+            {'actor-0', 'actor-1'})
+    for step in steps:
+      (call,) = inside(step, 'actor/policy_call')
+      assert len(inside(call, 'batcher/compute')) == 1
+      (env_step,) = inside(step, 'actor/env_step')
+      pipes = inside(env_step, 'env/pipe')
+      assert len(pipes) == 2
+      # Both children had their action before either reply was read.
+      assert max(p[1] for p in pipes) <= min(p[2] for p in pipes)
+  puts = [r for r in rows if r[0] == 'actor/put']
+  assert {tuple(r[4]) for r in puts} <= {tuple(r[4]) for r in unrolls}
+  assert {r[4][0] for r in puts} == {'actor-0', 'actor-1'}
+
+
+def test_groups_stay_within_inference_max_batch(tmp_path):
+  """`--inference_max_batch` below the fleet size: the batcher refuses
+  a larger request, so the hosted fleet runs as groups of that many
+  rows (here two threads of two) and not as one group whose every
+  policy call raises."""
+  cfg = _config(tmp_path, use_py_process=True, num_actors=4,
+                batch_size=4, inference_max_batch=2)
+  run = driver.train(cfg, max_steps=4, stall_timeout_secs=120)
+  assert int(run.state.update_steps) == 4
+  server, fleet = run.server.stats(), run.fleet.stats()
+  assert server['requests'] == 2 * server['batcher_requests'] > 0
+  assert fleet['respawns'] == 0 and fleet['slots_quarantined'] == 0
+  with open(os.path.join(cfg.logdir, 'summaries.jsonl')) as f:
+    rows = [json.loads(line) for line in f]
+  assert {r['value'] for r in rows if r['tag'] == 'actor_threads'} == {2.0}
+  assert {r['value'] for r in rows
+          if r['tag'] == 'envs_per_thread'} == {2.0}
+
+
+def test_a_slow_rebuild_does_not_run_out_the_no_batch_deadline(tmp_path):
+  """A respawn rebuilds envs on the learner's own thread (a group's k,
+  in turn). Where that outlasts the no-batch deadline, the time is
+  the rebuild's and not the fleet's silence: the run goes on once the
+  new envs feed, instead of raising on return from the rebuild."""
+  import time
+
+  class SlowRebuild:
+    """The fleet, with every slot parked after the first learner step
+    and a 31 s rebuild (deadline: 30 s) once the feed has run dry."""
+
+    def __init__(self, fleet):
+      self._fleet, self.calls, self.rebuilt = fleet, 0, False
+      self.last_call = time.monotonic()
+
+    def __getattr__(self, name):
+      return getattr(self._fleet, name)
+
+    def check_health(self, **kw):
+      self.calls += 1
+      # A second since the last call: the learner's get timed out (the
+      # call after a step follows the one before it at once).
+      dry = time.monotonic() - self.last_call > 0.9
+      if self.calls == 1:
+        self._fleet.set_target_size(0)
+      elif (dry and not self.rebuilt and
+            self._fleet.stats()['alive'] == 0):
+        self.rebuilt = True
+        time.sleep(31)
+        self._fleet.set_target_size(2)
+      self.last_call = time.monotonic()
+      return self._fleet.check_health(**kw)
+
+  fleets = []
+
+  def fleet_factory(config, agent, policy, buffer, levels):
+    fleets.append(SlowRebuild(driver.make_fleet(
+        config, agent, policy, buffer, levels)))
+    return fleets[-1]
+
+  cfg = _config(tmp_path)
+  run = driver.train(cfg, max_steps=12, stall_timeout_secs=1,
+                     fleet_factory=fleet_factory)
+  assert fleets[0].rebuilt
+  assert int(run.state.update_steps) == 12
 
 
 @pytest.mark.slow  # tier-1 wall trim (round 20); ci.sh full-suite lane runs it

@@ -490,3 +490,474 @@ def test_parked_slot_errors_do_not_surface():
     assert fleet.errors() == []
   finally:
     fleet.stop()
+
+
+# --- Groups: one thread steps k process-hosted envs in lockstep (PR 26)
+
+
+class FlagCrashEnv(FakeEnv):
+  """Process-hosted crash fixture: raises at its third step while its
+  flag file exists. `once` removes the flag first, so only the first
+  life crashes (a child cannot count its lives in a class variable)."""
+
+  def __init__(self, crash_flag=None, once=True, **kw):
+    super().__init__(**kw)
+    self._crash_flag, self._once, self._steps = crash_flag, once, 0
+
+  def step(self, action):
+    import os
+    self._steps += 1
+    if (self._steps == 3 and self._crash_flag and
+        os.path.exists(self._crash_flag)):
+      if self._once:
+        os.remove(self._crash_flag)
+      if self._crash_flag.endswith('hang'):
+        time.sleep(60)  # a wedged simulator
+      raise RuntimeError('hosted env crashed')
+    return super().step(action)
+
+
+def _group_policy(prev_action, env_output, core_state):
+  """The dummy policy in both forms of the Actor contract."""
+  from scalable_agent_tpu.structs import AgentOutput
+  lead = np.shape(prev_action)
+  return AgentOutput(action=np.zeros(lead, np.int32),
+                     policy_logits=np.zeros(lead + (A,), np.float32),
+                     baseline=np.zeros(lead, np.float32)), core_state
+
+
+def _hosted_factory(processes, env_kwargs, policy=_group_policy,
+                    state_fn=None, env_class=FlagCrashEnv):
+  """make_actor for process-hosted envs; every PyProcess it starts is
+  appended to `processes`."""
+  from scalable_agent_tpu.runtime import py_process
+
+  def make_actor(i):
+    process = py_process.PyProcess(env_class, env_kwargs(i)).start()
+    processes.append(process)
+    env = py_process.ProxyEnv(process)
+    state = (state_fn() if state_fn else
+             (np.zeros((1, 4), np.float32),) * 2)
+    actor = Actor(env, policy, state, unroll_length=4, level_name_id=i)
+    return env, process, actor
+  return make_actor
+
+
+def _none_running(processes):
+  return not any(p._process.is_alive() for p in processes)
+
+
+def test_group_member_failure_respawns_the_group(monkeypatch, tmp_path):
+  """One env of a group raises: the group's thread ends through
+  run_actor_loop's one failure path, the error lands on that env's
+  slot alone, the slots respawn together and share a thread again,
+  the buffer stays open, and no child outlives the fleet."""
+  flag = tmp_path / 'crash'
+  flag.write_text('armed')
+  processes = []
+  buffer = ring_buffer.TrajectoryBuffer(64)
+  fleet = ActorFleet(
+      _hosted_factory(processes, lambda i: dict(
+          height=H, width=W, num_actions=A, seed=i,
+          crash_flag=str(flag) if i == 1 else None)),
+      buffer, num_actors=3)
+  fleet.start()
+  try:
+    stats = fleet.stats()
+    assert stats['actor_threads'] == 1
+    assert stats['envs_per_thread'] == 3.0
+    first_thread = fleet._slots[0].thread
+    assert first_thread.name.startswith('actor-')
+    assert _wait(lambda: any(s.error or s.collateral
+                             for s in fleet._slots))
+    with fleet._lock:
+      assert [s.error is not None for s in fleet._slots] == [
+          False, True, False]
+      assert 'hosted env crashed' in str(fleet._slots[1].error)
+      assert [s.collateral for s in fleet._slots] == [True, False, True]
+    assert sorted(fleet.check_health()) == [0, 1, 2]
+    # Charged to the slot whose env failed, not to its mates.
+    assert [s.respawn_streak for s in fleet._slots][0::2] == [0, 0]
+    assert [s.respawns for s in fleet._slots] == [1, 1, 1]
+    threads = {id(s.thread) for s in fleet._slots}
+    assert len(threads) == 1 and fleet._slots[0].thread is not first_thread
+    # The shared buffer was never closed: all three levels feed again.
+    seen, deadline = set(), time.monotonic() + 30
+    while len(seen) < 3 and time.monotonic() < deadline:
+      seen.add(int(buffer.get(timeout=10).level_name))
+    assert seen == {0, 1, 2}
+    assert fleet.errors() == []
+    assert fleet.stats()['slots_quarantined'] == 0
+    assert len(processes) == 6
+  finally:
+    report = fleet.stop()
+  assert report['unjoined_actors'] == []
+  assert _wait(lambda: _none_running(processes), timeout=10)
+
+
+def test_wedged_group_member_respawns_the_group(monkeypatch, tmp_path):
+  """One env of a group hangs mid-step: every member's heartbeat goes
+  stale, the stall check orphans the thread and respawns the slots
+  together; the children the wedged thread still held calls on are
+  killed at once (not one close timeout after another), and the
+  orphaned thread unwinds without touching its successors."""
+  flag = tmp_path / 'hang'
+  flag.write_text('armed')
+  processes = []
+  buffer = ring_buffer.TrajectoryBuffer(64)
+  fleet = ActorFleet(
+      _hosted_factory(processes, lambda i: dict(
+          height=H, width=W, num_actions=A, seed=i,
+          crash_flag=str(flag) if i == 0 else None)),
+      buffer, num_actors=4)
+  fleet.start()
+  try:
+    wedged = fleet._slots[0].thread
+    assert _wait(lambda: not flag.exists())
+    time.sleep(0.6)
+    from scalable_agent_tpu.runtime import py_process
+    closes, close_all = [], py_process.close_all
+
+    def timed_close_all(processes, **kw):
+      t0 = time.monotonic()
+      close_all(processes, **kw)
+      closes.append((len(processes), time.monotonic() - t0))
+
+    monkeypatch.setattr(py_process, 'close_all', timed_close_all)
+    assert sorted(fleet.check_health(stall_timeout_secs=0.5)) == [
+        0, 1, 2, 3]
+    closed, took = closes[0]  # (the orphan closes its own again later)
+    assert closed == 4 and took < 3.0  # in turn: a second each, 4 s
+    # Charged to the slot the thread was waiting for, not to its mates.
+    assert [s.respawn_streak for s in fleet._slots] == [1, 0, 0, 0]
+    assert _wait(lambda: _none_running(processes[:4]), timeout=10)
+    wedged.join(timeout=15)  # its recv broke with the killed child
+    assert not wedged.is_alive()
+    assert fleet.errors() == []  # the orphan wrote nothing to the slots
+    seen, deadline = set(), time.monotonic() + 30
+    while len(seen) < 4 and time.monotonic() < deadline:
+      seen.add(int(buffer.get(timeout=10).level_name))
+    assert seen == {0, 1, 2, 3}
+    assert fleet.stats()['actor_threads'] == 1
+  finally:
+    fleet.stop()
+  assert _wait(lambda: _none_running(processes), timeout=10)
+
+
+def test_group_member_quarantine_spares_its_mates(monkeypatch, tmp_path):
+  """A member that crashes in every life climbs the respawn ladder
+  alone: it is quarantined, its mates are not, and they go on as a
+  smaller group."""
+  flag = tmp_path / 'crash'
+  flag.write_text('armed')
+  processes = []
+  buffer = ring_buffer.TrajectoryBuffer(64)
+  fleet = ActorFleet(
+      _hosted_factory(processes, lambda i: dict(
+          height=H, width=W, num_actions=A, seed=i, once=False,
+          crash_flag=str(flag) if i == 0 else None)),
+      buffer, num_actors=3, quarantine_after=1)
+  for slot in fleet._slots:
+    slot.backoff._rng = type('R', (), {'uniform':
+                                       staticmethod(lambda a, b: 0.0)})
+  fleet.start()
+  try:
+    assert _wait(_pumped(
+        fleet, lambda: fleet.stats()['slots_quarantined'] == 1, buffer),
+        timeout=60)
+    assert [s.quarantined for s in fleet._slots] == [True, False, False]
+    assert _wait(_pumped(
+        fleet, lambda: fleet.stats()['healthy'] == 2))
+    stats = fleet.stats()
+    assert stats['actor_threads'] == 1 and stats['envs_per_thread'] == 2.0
+    before = fleet.stats()['unrolls']
+    assert _wait(_pumped(
+        fleet, lambda: fleet.stats()['unrolls'] >= before + 4, buffer))
+  finally:
+    fleet.stop()
+  assert _wait(lambda: _none_running(processes), timeout=10)
+
+
+def test_stop_joins_a_group_parked_in_the_batcher(monkeypatch):
+  """A group parked in its ONE batcher request (a merge floor nobody
+  will fill) when the run stops: cancelled like a lone caller, joined,
+  every member's state slot released, no child left."""
+  import jax
+  from scalable_agent_tpu.config import Config
+  from scalable_agent_tpu.models import ImpalaAgent, init_params
+  from scalable_agent_tpu.models.instruction import MAX_INSTRUCTION_LEN
+  from scalable_agent_tpu.runtime.inference import InferenceServer
+  h, w = 24, 32
+  agent = ImpalaAgent(num_actions=A, torso='shallow',
+                      use_instruction=False)
+  params = init_params(agent, jax.random.PRNGKey(0), {
+      'frame': (h, w, 3), 'instr_len': MAX_INSTRUCTION_LEN})
+  cfg = Config(batch_size=2, unroll_length=4, num_action_repeats=1,
+               inference_state_cache=True, inference_min_batch=8,
+               inference_max_batch=8, inference_timeout_ms=60_000)
+  server = InferenceServer(agent, params, cfg, seed=3, fleet_size=2)
+  total = server.slots_free()
+  processes = []
+  buffer = ring_buffer.TrajectoryBuffer(8)
+  fleet = ActorFleet(
+      _hosted_factory(processes, lambda i: dict(
+          height=h, width=w, num_actions=A, seed=i),
+          policy=server.policy, state_fn=server.initial_core_state),
+      buffer, num_actors=2)
+  try:
+    fleet.start()
+    assert _wait(lambda: server.stats()['batcher_requests'] == 1)
+    time.sleep(0.2)  # parked in compute_wait
+    assert server.slots_free() == total - 2
+    assert fleet.stats()['actor_threads'] == 1
+    t0 = time.monotonic()
+    fleet.stop_event.set()  # stop first: the cancel is then clean
+    server.close()
+    report = fleet.stop(timeout=10)
+    assert report['unjoined_actors'] == []
+    assert time.monotonic() - t0 < 10
+    assert fleet.errors() == []
+    assert server.slots_free() == total
+  finally:
+    server.close()
+    fleet.stop(timeout=1)
+  assert _wait(lambda: _none_running(processes), timeout=10)
+
+
+def test_quiesce_joins_a_group_parked_on_a_full_buffer(monkeypatch):
+  """A group whose second unroll finds the buffer full and nobody
+  draining: quiesce() (buffer left open) joins it inside the put's
+  stop grace; its first unroll landed."""
+  from scalable_agent_tpu.runtime import actor as actor_lib
+  processes = []
+  buffer = ring_buffer.TrajectoryBuffer(1)
+  fleet = ActorFleet(
+      _hosted_factory(processes, lambda i: dict(
+          height=H, width=W, num_actions=A, seed=i)),
+      buffer, num_actors=2)
+  fleet.start()
+  try:
+    assert _wait(lambda: fleet.stats()['unrolls'] == 1)
+    time.sleep(0.2)  # the second member's put is parked
+    t0 = time.monotonic()
+    report = fleet.quiesce(
+        timeout=actor_lib._STOP_PUT_GRACE_SECS + 5.0)
+    assert report['unjoined_actors'] == []
+    assert (time.monotonic() - t0 <
+            actor_lib._STOP_PUT_GRACE_SECS + 3 * actor_lib._PUT_POLL_SECS)
+    assert int(buffer.get(timeout=1).level_name) == 0
+  finally:
+    fleet.stop()
+  assert _wait(lambda: _none_running(processes), timeout=10)
+
+
+def test_in_process_envs_keep_a_thread_each(monkeypatch):
+  """Envs hosted in the actor's own process are never grouped, however
+  few cores there are: `env.step` runs on the actor thread there."""
+  buffer = ring_buffer.TrajectoryBuffer(16)
+  fleet = ActorFleet(
+      _make_actor_factory(lambda i: FakeEnv(height=H, width=W,
+                                            num_actions=A, seed=i)),
+      buffer, num_actors=3)
+  fleet.start()
+  try:
+    stats = fleet.stats()
+    assert stats['actor_threads'] == 3
+    assert stats['envs_per_thread'] == 1.0
+    assert ([s.thread.name for s in fleet._slots] ==
+            ['actor-0', 'actor-1', 'actor-2'])
+  finally:
+    fleet.stop()
+
+
+def test_mixed_spec_fleet_groups_within_a_spec(monkeypatch):
+  """Envs of different observation specs never share a group (a k-row
+  request needs one trailing shape), wherever they sit in the fleet."""
+  processes = []
+  buffer = ring_buffer.TrajectoryBuffer(64)
+  sizes = [(8, 8), (16, 8), (8, 8), (16, 8), (8, 8)]
+  fleet = ActorFleet(
+      _hosted_factory(processes, lambda i: dict(
+          height=sizes[i][0], width=sizes[i][1], num_actions=A, seed=i),
+          env_class=FakeEnv),
+      buffer, num_actors=5)
+  fleet.start()
+  try:
+    threads = [s.thread for s in fleet._slots]
+    assert threads[0] is threads[2] is threads[4]
+    assert threads[1] is threads[3]
+    assert threads[0] is not threads[1]
+    stats = fleet.stats()
+    assert stats['actor_threads'] == 2
+    assert stats['envs_per_thread'] == 2.5
+    seen, deadline = set(), time.monotonic() + 30
+    while len(seen) < 5 and time.monotonic() < deadline:
+      unroll = buffer.get(timeout=10)
+      level = int(unroll.level_name)
+      assert unroll.env_outputs.observation[0].shape[1:3] == sizes[level]
+      seen.add(level)
+    assert seen == {0, 1, 2, 3, 4}
+  finally:
+    fleet.stop()
+  assert _wait(lambda: _none_running(processes), timeout=10)
+
+
+def test_set_target_size_moves_one_slot_of_a_group():
+  """The shipped rule puts a small hosted fleet on ONE thread: the
+  controller's step of one still parks one slot (the thread goes on
+  with the rest, no respawn, the child gone) and unparks one (it
+  joins the running group at its next unroll), and never the fleet."""
+  processes = []
+  buffer = ring_buffer.TrajectoryBuffer(64)
+  fleet = ActorFleet(
+      _hosted_factory(processes, lambda i: dict(
+          height=H, width=W, num_actions=A, seed=i), env_class=FakeEnv),
+      buffer, num_actors=4)
+  fleet.start()
+  try:
+    thread = fleet._slots[0].thread
+    assert all(s.thread is thread for s in fleet._slots)
+    report = fleet.set_target_size(3)
+    assert report['parked'] == [3] and fleet.target_size() == 3
+    assert _wait(_pumped(
+        fleet, lambda: fleet.stats()['alive'] == 3, buffer))
+    assert fleet._slots[3].thread is None
+    assert _wait(lambda: not processes[3]._process.is_alive())
+    stats = fleet.stats()
+    assert stats['actor_threads'] == 1 and stats['envs_per_thread'] == 3.0
+    assert stats['respawns'] == 0 and stats['parked'] == 1
+    before = [s.unrolls_done for s in fleet._slots]
+    assert _wait(_pumped(fleet, lambda: all(
+        s.unrolls_done >= n + 2
+        for s, n in zip(fleet._slots[:3], before)), buffer))
+    assert fleet._slots[3].unrolls_done == before[3]
+    assert thread.is_alive()
+
+    report = fleet.set_target_size(4)
+    assert report['unparked'] == [3] and fleet.target_size() == 4
+    assert _wait(_pumped(
+        fleet, lambda: fleet.stats()['healthy'] == 4, buffer))
+    assert _wait(_pumped(
+        fleet, lambda: fleet._slots[3].unrolls_done > before[3], buffer))
+    assert all(s.thread is thread for s in fleet._slots)
+    assert fleet.stats()['actor_threads'] == 1
+    assert [s.respawns for s in fleet._slots] == [0, 0, 0, 1]
+
+    # All the way down, one by one, and the last slot stays.
+    for n in (3, 2, 1):
+      assert len(fleet.set_target_size(n)['parked']) == 1
+    assert fleet.target_size() == 1
+    assert _wait(_pumped(
+        fleet, lambda: fleet.stats()['alive'] == 1, buffer))
+    assert fleet._slots[0].thread is thread and thread.is_alive()
+  finally:
+    fleet.stop()
+  assert _wait(lambda: _none_running(processes), timeout=10)
+
+
+def test_hanging_group_member_is_quarantined_alone(tmp_path):
+  """A member whose env hangs in every life, before its first unroll:
+  the stall check charges the member the thread is waiting for and
+  respawns its mates beside it off the ladder, so it is quarantined
+  alone and the rest go on feeding as a smaller group."""
+  flag = tmp_path / 'hang'
+  flag.write_text('armed')
+  processes = []
+  buffer = ring_buffer.TrajectoryBuffer(64)
+  fleet = ActorFleet(
+      _hosted_factory(processes, lambda i: dict(
+          height=H, width=W, num_actions=A, seed=i, once=False,
+          crash_flag=str(flag) if i == 1 else None)),
+      buffer, num_actors=3, quarantine_after=1)
+  for slot in fleet._slots:
+    slot.backoff._rng = type('R', (), {'uniform':
+                                       staticmethod(lambda a, b: 0.0)})
+  fleet.start()
+  try:
+    for life in (1, 2):
+      time.sleep(0.8)  # every heartbeat is stale, slot 1's call pending
+      assert sorted(fleet.check_health(stall_timeout_secs=0.5)) == [
+          0, 1, 2]
+      assert [s.respawn_streak for s in fleet._slots] == [0, life, 0]
+      assert [s.respawns for s in fleet._slots] == [life] * 3
+    assert [s.quarantined for s in fleet._slots] == [False, True, False]
+    assert fleet._slots[0].thread is fleet._slots[2].thread
+    before = fleet.stats()['unrolls']
+    assert _wait(_pumped(
+        fleet, lambda: fleet.stats()['unrolls'] >= before + 4, buffer))
+    stats = fleet.stats()
+    assert stats['slots_quarantined'] == 1 and stats['healthy'] == 2
+    assert stats['actor_threads'] == 1 and stats['envs_per_thread'] == 2.0
+    assert fleet.errors() == []
+  finally:
+    fleet.stop()
+  assert _wait(lambda: _none_running(processes), timeout=15)
+
+
+def test_group_size_is_bounded_by_the_policy_rows():
+  """A policy that takes at most two rows a call (the server's
+  `inference_max_batch`; the batcher never splits a request) gets
+  groups of two, not one request it would refuse."""
+  rows = []
+
+  def two_rows_at_most(prev_action, env_output, core_state):
+    rows.append(np.size(prev_action))
+    if rows[-1] > 2:
+      raise ValueError('rows exceeds maximum_batch_size')
+    return _group_policy(prev_action, env_output, core_state)
+
+  processes = []
+  buffer = ring_buffer.TrajectoryBuffer(64)
+  fleet = ActorFleet(
+      _hosted_factory(processes, lambda i: dict(
+          height=H, width=W, num_actions=A, seed=i), env_class=FakeEnv,
+          policy=two_rows_at_most),
+      buffer, num_actors=5, max_policy_rows=2)
+  fleet.start()
+  try:
+    seen, deadline = set(), time.monotonic() + 30
+    while len(seen) < 5 and time.monotonic() < deadline:
+      seen.add(int(buffer.get(timeout=10).level_name))
+    assert seen == {0, 1, 2, 3, 4}
+    stats = fleet.stats()
+    assert stats['actor_threads'] == 3
+    assert fleet._slots[0].thread is fleet._slots[1].thread
+    assert fleet._slots[2].thread is fleet._slots[3].thread
+    assert set(rows) == {1, 2}
+    assert fleet.errors() == [] and stats['respawns'] == 0
+  finally:
+    fleet.stop()
+  assert _wait(lambda: _none_running(processes), timeout=10)
+
+
+def test_make_fleet_bounds_groups_by_inference_max_batch():
+  from scalable_agent_tpu import driver
+  from scalable_agent_tpu.config import Config
+  for max_batch, want in ((16, 16), (1024, 32), (1, 1)):
+    fleet = driver.make_fleet(
+        Config(num_actors=32, inference_max_batch=max_batch), None,
+        _group_policy, ring_buffer.TrajectoryBuffer(4), ['fake'])
+    assert fleet._envs_per_thread == want
+
+
+def test_a_slowly_built_group_does_not_start_stalled():
+  """A group's envs are built in turn before their thread starts: the
+  time the later ones took must not read as the first ones' stall."""
+  processes = []
+  make_actor = _hosted_factory(processes, lambda i: dict(
+      height=H, width=W, num_actions=A, seed=i), env_class=FakeEnv)
+
+  def slow_make_actor(i):
+    time.sleep(0.3)
+    return make_actor(i)
+
+  buffer = ring_buffer.TrajectoryBuffer(64)
+  fleet = ActorFleet(slow_make_actor, buffer, num_actors=4)
+  fleet.start()
+  try:
+    assert fleet.check_health(stall_timeout_secs=0.5) == []
+    assert _wait(lambda: fleet.stats()['unrolls'] >= 4)
+    assert fleet.stats()['respawns'] == 0
+  finally:
+    fleet.stop()
+  assert _wait(lambda: _none_running(processes), timeout=10)

@@ -76,6 +76,18 @@ class FailsInMethod:
         f.write('closed')
 
 
+class Sleeper:
+  """Split-call fixture: a method that takes a while."""
+
+  def __init__(self, tag=0):
+    self._tag = tag
+
+  def nap(self, secs):
+    import time
+    time.sleep(secs)
+    return np.int32(self._tag)
+
+
 class PlatformProbeEnv(ProcgenEnv):
   """A real jittable host env that can also say where its JAX runs."""
 
@@ -163,6 +175,111 @@ def test_dead_process_raises_process_closed():
       p.proxy.ok()
   finally:
     p.close()
+
+
+# --- a call in two halves (PR 26): `_send` to k children, then
+# `_receive` from each, so one actor thread has k envs stepping at once.
+
+
+def test_split_call_steps_children_concurrently():
+  import time
+  procs = [PyProcess(Sleeper, dict(tag=i)).start() for i in range(3)]
+  try:
+    for p in procs:
+      p.proxy.nap(0.0)  # children constructed before the clock starts
+    t0 = time.monotonic()
+    for p in procs:
+      p._send('nap', (0.4,), {})
+    tags = [int(p._receive()) for p in procs]
+    took = time.monotonic() - t0
+    assert tags == [0, 1, 2]
+    assert took < 1.0, took  # three naps in turn would take 1.2 s
+    # The whole call is still the two halves in a row.
+    assert int(procs[1].proxy.nap(0.0)) == 1
+  finally:
+    py_process.close_all(procs)
+
+
+def test_split_call_refuses_a_second_send_and_a_lone_receive():
+  p = PyProcess(FailsInMethod).start()
+  try:
+    with pytest.raises(RuntimeError, match='receive without a send'):
+      p._receive()
+    p._send('ok', (), {})
+    with pytest.raises(RuntimeError, match='send before the receive'):
+      p._send('ok', (), {})
+    assert p._receive() == 7
+    # The refusal left the pipe in step: the next call is answered.
+    assert p.proxy.ok() == 7
+  finally:
+    p.close()
+
+
+def test_split_call_reports_ctor_failure_on_first_call():
+  """The "ctor failure reported on first proxy call" contract, whether
+  the child's pipe is found closed at the send or at the receive."""
+  p = PyProcess(FailsInCtor).start()
+  try:
+    p._process.join(30)  # the child has sent its error and gone
+    p._send('anything', (), {})
+    with pytest.raises(RemoteError, match='ctor boom'):
+      p._receive()
+  finally:
+    p.close()
+
+
+def test_split_call_closed_pipe_contract():
+  p = PyProcess(FailsInMethod).start()
+  try:
+    # The child dies between the halves: the receive says so, and
+    # gives the lock back (the next send fails fast, not parked).
+    p._send('die', (), {})
+    with pytest.raises(ProcessClosed):
+      p._receive()
+    with pytest.raises(ProcessClosed):
+      p._send('ok', (), {})
+      p._receive()
+    # A remote exception comes back at the receive, and the worker of
+    # a fresh process keeps serving.
+    q = PyProcess(FailsInMethod).start()
+    try:
+      q._send('boom', (), {})
+      with pytest.raises(RemoteError, match='method boom'):
+        q._receive()
+      assert q.proxy.ok() == 7
+    finally:
+      q.close()
+  finally:
+    p.close()
+  with pytest.raises(ProcessClosed):
+    p._send('ok', (), {})
+
+
+def test_split_call_send_failure_gives_the_lock_back():
+  p = PyProcess(Calculator).start()
+  try:
+    with pytest.raises(TypeError, match='could not serialize'):
+      p._send('add', (lambda: 0, 1), {})
+    assert p.proxy.add(1, 2) == 3
+  finally:
+    p.close()
+
+
+def test_proxy_env_split_step_matches_step():
+  kwargs = dict(height=8, width=8, episode_length=3, seed=4)
+  whole = ProxyEnv(PyProcess(FakeEnv, kwargs).start())
+  halves = ProxyEnv(PyProcess(FakeEnv, kwargs).start())
+  try:
+    whole.initial()
+    halves.initial()
+    for i in range(5):
+      halves.step_send(i % 2)
+      a, b = whole.step(i % 2), halves.step_receive()
+      assert a[0] == b[0] and a[1] == b[1]
+      np.testing.assert_array_equal(a[2][0], b[2][0])
+  finally:
+    whole.close()
+    halves.close()
 
 
 def test_fleet_lifecycle():

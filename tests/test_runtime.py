@@ -895,3 +895,377 @@ class TestFullPipeline:
       for t in threads:
         t.join(timeout=10)
       assert not any(t.is_alive() for t in threads)
+
+
+# --- An actor thread stepping k envs in lockstep (PR 26) ---
+
+
+class _ScriptedStatePolicy:
+  """A deterministic Actor-contract policy in both forms (scalar, and
+  k-row for an ActorGroup), computed row by row so that a row's result
+  cannot depend on the rows it travelled with. `cache=True` keeps the
+  carries behind opaque handles (snapshot / write / release), like the
+  InferenceServer's state-cache mode."""
+
+  class Handle:
+
+    def __init__(self, carries, slot):
+      self._carries, self.slot, self.released = carries, slot, False
+
+    def snapshot(self):
+      return tuple(x.copy() for x in self._carries[self.slot])
+
+    def write(self, carry):
+      self._carries[self.slot] = tuple(np.array(x) for x in carry)
+
+    def release(self):
+      self.released = True
+
+  def __init__(self, cache):
+    self._cache = cache
+    self._carries = {}
+    self.calls = []  # rows per call
+
+  def initial_core_state(self):
+    carry = (np.zeros((1, 4), np.float32), np.ones((1, 4), np.float32))
+    if not self._cache:
+      return carry
+    slot = len(self._carries)
+    self._carries[slot] = carry
+    return self.Handle(self._carries, slot)
+
+  @staticmethod
+  def _row(prev_action, reward, done, frame, carry):
+    from scalable_agent_tpu.structs import AgentOutput
+    c, h = carry
+    if done:
+      c, h = np.zeros_like(c), np.ones_like(h)
+    x = np.float32(frame.astype(np.float32).mean() / 255.0)
+    c = (np.float32(0.5) * c + x + np.float32(prev_action)).astype(
+        np.float32)
+    h = (h * np.float32(0.9) + np.float32(reward)).astype(np.float32)
+    logits = np.asarray([c[0, 0], h[0, 1], c[0, 2] - h[0, 3]],
+                        np.float32)
+    action = np.int32(int(np.abs(c).sum() * 7) % A)
+    return AgentOutput(action, logits, np.float32(h.sum())), (c, h)
+
+  def __call__(self, prev_action, env_output, core_state):
+    from scalable_agent_tpu.structs import AgentOutput
+    frame, _ = env_output.observation
+    if np.ndim(prev_action) == 0:
+      self.calls.append(1)
+      carry = core_state.snapshot() if self._cache else core_state
+      out, carry = self._row(prev_action, env_output.reward,
+                             env_output.done, frame, carry)
+      if self._cache:
+        core_state.write(carry)
+        return out, core_state
+      return out, carry
+    k = len(prev_action)
+    self.calls.append(k)
+    outs, carries = [], []
+    for j in range(k):
+      carry = (core_state[j].snapshot() if self._cache else
+               tuple(x[j:j + 1] for x in core_state))
+      out, carry = self._row(prev_action[j], env_output.reward[j],
+                             env_output.done[j], frame[j], carry)
+      outs.append(out)
+      carries.append(carry)
+      if self._cache:
+        core_state[j].write(carry)
+    out = AgentOutput(*[np.stack(xs) for xs in zip(*outs)])
+    if self._cache:
+      return out, core_state
+    return out, tuple(np.concatenate(xs, axis=0) for xs in zip(*carries))
+
+
+def _assert_unrolls_bitwise_equal(a, b):
+  la, ta = jax.tree_util.tree_flatten(a)
+  lb, tb = jax.tree_util.tree_flatten(b)
+  assert ta == tb
+  for x, y in zip(la, lb):
+    x, y = np.asarray(x), np.asarray(y)
+    assert x.dtype == y.dtype and x.shape == y.shape
+    np.testing.assert_array_equal(x, y)
+
+
+class TestActorGroup:
+
+  @pytest.mark.parametrize('cache', [False, True],
+                           ids=['carry_passing', 'state_cache'])
+  def test_group_unrolls_bitwise_equal_single_actors(self, cache):
+    """An env's unroll does not depend on the group it was stepped in:
+    three unrolls of T=7 over episodes of 5 steps, so episode
+    boundaries fall inside and across unrolls, the overlap frame, the
+    lazy priming call and the unroll-start carry included."""
+    from scalable_agent_tpu.runtime.actor import ActorGroup
+    k, T = 3, 7
+
+    def make(policy):
+      return [Actor(FakeEnv(height=H, width=W, num_actions=A, seed=i,
+                            episode_length=5),
+                    policy, policy.initial_core_state(), T,
+                    num_action_repeats=2, level_name_id=i)
+              for i in range(k)]
+
+    alone_policy = _ScriptedStatePolicy(cache)
+    alone = [[a.unroll() for _ in range(3)] for a in make(alone_policy)]
+    group_policy = _ScriptedStatePolicy(cache)
+    group = ActorGroup(make(group_policy))
+    grouped = [group.unroll() for _ in range(3)]
+    # One priming call and T steps an unroll, each carrying k rows.
+    assert group_policy.calls == [k] * (1 + 3 * T)
+    assert alone_policy.calls == [1] * (k * (1 + 3 * T))
+    for j in range(k):
+      for n in range(3):
+        _assert_unrolls_bitwise_equal(alone[j][n], grouped[n][j])
+      dones = np.concatenate(
+          [np.asarray(u.env_outputs.done[1:]) for u in alone[j]])
+      assert dones.sum() >= 3  # the episodes did end inside
+
+  def test_group_of_one_makes_the_scalar_call(self):
+    """`Actor.unroll()` is the k = 1 driver of the same loop: a policy
+    that only knows the scalar contract keeps working."""
+    seen = []
+
+    def scalar_only(prev_action, env_output, core_state):
+      from scalable_agent_tpu.structs import AgentOutput
+      seen.append(np.ndim(prev_action))
+      return AgentOutput(np.int32(1), np.zeros(A, np.float32),
+                         np.float32(0)), core_state
+
+    actor = Actor(FakeEnv(height=H, width=W, num_actions=A), scalar_only,
+                  (np.zeros((1, 4), np.float32),) * 2, 4)
+    unroll = actor.unroll()
+    assert unroll.agent_outputs.action.shape == (5,)
+    assert seen == [0] * 5
+
+  def test_failed_member_is_named_and_every_reply_collected(self):
+    """One env of a group raising: the group says which, and the
+    others' steps were still brought to an end."""
+    from scalable_agent_tpu.runtime.actor import ActorGroup
+
+    class Crashing(FakeEnv):
+      steps = 0
+
+      def step(self, action):
+        self.steps += 1
+        if self.steps == 3:
+          raise RuntimeError('env crashed mid-unroll')
+        return super().step(action)
+
+    policy = _ScriptedStatePolicy(cache=False)
+    envs = [FakeEnv(height=H, width=W, num_actions=A, seed=0),
+            Crashing(height=H, width=W, num_actions=A, seed=1),
+            FakeEnv(height=H, width=W, num_actions=A, seed=2)]
+    group = ActorGroup([Actor(env, policy, policy.initial_core_state(), 6)
+                        for env in envs])
+    with pytest.raises(RuntimeError, match='mid-unroll'):
+      group.unroll()
+    assert group.failed is group.actors[1]
+    assert group.waiting_on is None
+
+  @pytest.mark.parametrize('cache', [False, True],
+                           ids=['carry_passing', 'state_cache'])
+  def test_members_join_and_leave_between_unrolls(self, cache):
+    """A member that joins a running group (unprimed, among primed
+    mates) and one that leaves it: every env's unrolls stay bitwise
+    what it produces alone, and the one that left is closed."""
+    from scalable_agent_tpu.runtime.actor import ActorGroup
+    T = 7
+
+    def make(policy):
+      return [Actor(FakeEnv(height=H, width=W, num_actions=A, seed=i,
+                            episode_length=5),
+                    policy, policy.initial_core_state(), T,
+                    level_name_id=i) for i in range(3)]
+
+    alone = [[a.unroll() for _ in range(3)]
+             for a in make(_ScriptedStatePolicy(cache))]
+    policy = _ScriptedStatePolicy(cache)
+    a, b, c = make(policy)
+    group = ActorGroup([a, b], names=['a', 'b'])
+    first = group.unroll()
+    group.join(c, 'c')
+    assert group.actors == [a, b]  # not before the rolling thread says
+    group.admit()
+    second = group.unroll()
+    group.leave(a)
+    assert (group.actors, group.names) == ([b, c], ['b', 'c'])
+    third = group.unroll()
+    assert policy.calls == ([2] * (1 + T) + [1] + [3] * T + [2] * T)
+    for got, want in [(first[0], alone[0][0]), (second[0], alone[0][1]),
+                      (first[1], alone[1][0]), (second[1], alone[1][1]),
+                      (third[0], alone[1][2]),
+                      (second[2], alone[2][0]), (third[1], alone[2][1])]:
+      _assert_unrolls_bitwise_equal(want, got)
+    if cache:
+      assert a._core_state.released and not b._core_state.released
+    group.join(a, 'late')
+    group.close()  # a member no unroll took in is closed with the rest
+    if cache:
+      assert b._core_state.released and c._core_state.released
+
+
+class TestGroupedPolicyCall:
+
+  @pytest.mark.parametrize('cache', [False, True],
+                           ids=['carry_passing', 'state_cache'])
+  def test_k_row_call_equals_k_one_row_calls(self, cache):
+    """`InferenceServer.policy` with k rows in ONE request against k
+    one-row requests merged into one call in the same row order, from
+    the same key and the same carries: row for row the same action,
+    logits, baseline and new carry; `requests` counts rows either way
+    and `batcher_requests` the calls that carried them."""
+    from scalable_agent_tpu.structs import StepOutput
+    k = 3
+    agent, params, _ = _mk()
+    cfg = Config(**_cfg_variant(
+        inference_state_cache=cache, inference_min_batch=k,
+        inference_timeout_ms=60_000))
+    server = InferenceServer(agent, params, cfg, seed=3)
+    try:
+      key0 = jax.random.PRNGKey(17)
+      rng = np.random.RandomState(5)
+      env_outs = [_scripted_inputs(4, seed=j)(j + 1) for j in range(k)]
+      prev = rng.randint(0, A, (k,)).astype(np.int32)
+      carries = [tuple(rng.randn(1, 256).astype(np.float32)
+                       for _ in range(2)) for _ in range(k)]
+      handles = ([server.initial_core_state() for _ in range(k)]
+                 if cache else None)
+
+      def reset():
+        with server._key_lock:
+          server._key = key0
+        if cache:
+          for handle, carry in zip(handles, carries):
+            handle.write(carry)
+
+      def carry_of(j, new_state):
+        return handles[j].snapshot() if cache else new_state
+
+      # k requests of one row, enqueued in row order.
+      reset()
+      base = server.stats()['batcher_requests']
+      rows = [None] * k
+
+      def call(j):
+        out, new_state = server.policy(
+            prev[j], env_outs[j], handles[j] if cache else carries[j])
+        rows[j] = (out, new_state)
+
+      threads = [threading.Thread(target=call, args=(j,))
+                 for j in range(k)]
+      for j, t in enumerate(threads):
+        t.start()
+        deadline = time.monotonic() + 30
+        while (server.stats()['batcher_requests'] < base + j + 1 and
+               time.monotonic() < deadline):
+          time.sleep(0.002)
+        time.sleep(0.02)  # counted just before it enqueues
+      for t in threads:
+        t.join(timeout=60)
+      assert all(r is not None for r in rows)
+      one_row = [(r[0], carry_of(j, r[1])) for j, r in enumerate(rows)]
+      stats = server.stats()
+      assert stats['batcher_requests'] == base + k
+      calls, requests = stats['calls'], stats['requests']
+
+      # One request of k rows.
+      reset()
+      stacked = jax.tree_util.tree_map(lambda *xs: np.stack(xs),
+                                       *env_outs)
+      core = handles if cache else tuple(
+          np.concatenate(xs, axis=0) for xs in zip(*carries))
+      out, new_state = server.policy(prev, stacked, core)
+      stats = server.stats()
+      assert stats['batcher_requests'] == base + k + 1
+      assert stats['calls'] == calls + 1
+      assert stats['requests'] == requests + k
+      assert np.asarray(out.action).shape == (k,)
+      for j in range(k):
+        alone_out, alone_carry = one_row[j]
+        assert int(out.action[j]) == int(alone_out.action)
+        np.testing.assert_array_equal(out.policy_logits[j],
+                                      alone_out.policy_logits)
+        assert out.baseline[j] == alone_out.baseline
+        grouped_carry = (handles[j].snapshot() if cache else
+                         tuple(x[j:j + 1] for x in new_state))
+        for x, y in zip(grouped_carry, alone_carry):
+          np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    finally:
+      server.close()
+
+  def test_k_row_call_checks_every_handle(self):
+    agent, params, _ = _mk()
+    cfg = Config(**_cfg_variant(inference_state_cache=True))
+    server = InferenceServer(agent, params, cfg, seed=3)
+    try:
+      handles = [server.initial_core_state() for _ in range(2)]
+      env_out = _scripted_inputs(2)
+      stacked = jax.tree_util.tree_map(lambda *xs: np.stack(xs),
+                                       env_out(0), env_out(1))
+      handles[1].release()
+      with pytest.raises(RuntimeError, match='released'):
+        server.policy(np.zeros(2, np.int32), stacked, handles)
+      with pytest.raises(TypeError, match='slot handle'):
+        server.policy(np.zeros(2, np.int32), stacked,
+                      [handles[0], object()])
+    finally:
+      server.close()
+
+  def test_mixed_row_counts_under_contention(self):
+    """More callers than cores, scalar and k-row requests mixed, a
+    short switch interval: every caller gets ITS rows back (the row
+    count and the echo of its own frame's logits shape), and the two
+    counters add up: rows in `requests`, calls in `batcher_requests`."""
+    import sys
+    agent, params, _ = _mk()
+    cfg = Config(**_cfg_variant(inference_min_batch=1,
+                                inference_max_batch=16,
+                                inference_timeout_ms=2))
+    server = InferenceServer(agent, params, cfg, seed=3)
+    env_out = _scripted_inputs(4)
+    widths = [0, 1, 2, 3] * 3  # 0: the scalar form
+    calls_each, errors = 15, []
+
+    def caller(k):
+      try:
+        rows = max(k, 1)
+        carry = tuple(np.zeros((rows, 256), np.float32)
+                      for _ in range(2))
+        for t in range(calls_each):
+          if k == 0:
+            out, carry = server.policy(np.int32(0), env_out(t % 4), carry)
+            assert np.ndim(out.action) == 0
+          else:
+            stacked = jax.tree_util.tree_map(
+                lambda *xs: np.stack(xs), *[env_out(t % 4)] * k)
+            out, carry = server.policy(np.zeros(k, np.int32), stacked,
+                                       carry)
+            assert out.policy_logits.shape == (k, A)
+            # The same input in every row: the same logits back.
+            np.testing.assert_array_equal(out.policy_logits[0],
+                                          out.policy_logits[-1])
+          assert carry[0].shape == (rows, 256)
+      except BaseException as e:  # noqa: BLE001 — reported below
+        errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+      threads = [threading.Thread(target=caller, args=(k,))
+                 for k in widths]
+      for t in threads:
+        t.start()
+      for t in threads:
+        t.join(timeout=120)
+      assert not any(t.is_alive() for t in threads)
+      assert not errors, errors
+      stats = server.stats()
+      assert stats['batcher_requests'] == len(widths) * calls_each
+      assert stats['requests'] == calls_each * sum(
+          max(k, 1) for k in widths)
+    finally:
+      sys.setswitchinterval(interval)
+      server.close()
