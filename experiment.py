@@ -128,11 +128,13 @@ flags.DEFINE_float('epsilon', _DEFAULTS.epsilon, 'RMSProp epsilon.')
 # --- TPU-build additions (not in the reference). ---
 flags.DEFINE_enum('env_backend', _DEFAULTS.env_backend,
                   ['dmlab', 'atari', 'fake', 'bandit', 'cue_memory',
-                   'gridworld', 'procgen'],
+                   'gridworld', 'procgen', 'tokens'],
                   'Environment backend (fake/bandit/cue_memory are '
                   'simulator-free smoke tasks; gridworld/procgen are '
                   'the pure-JAX family of envs/jittable.py — the same '
-                  'task runs under both --runtime values).')
+                  'task runs under both --runtime values; tokens is '
+                  'the seeded token task of envs/tokens.py, for '
+                  '--agent=sequence).')
 flags.DEFINE_enum('runtime', _DEFAULTS.runtime, ['fleet', 'anakin'],
                   'Training runtime: fleet (host envs -> inference -> '
                   'buffer -> learner, the production pipeline) or '
@@ -175,6 +177,37 @@ flags.DEFINE_enum('torso', _DEFAULTS.torso,
                   "a real task), or the paper's shallow CNN.")
 flags.DEFINE_enum('compute_dtype', _DEFAULTS.compute_dtype,
                   ['float32', 'bfloat16'], 'On-device compute dtype.')
+flags.DEFINE_enum('agent', _DEFAULTS.agent, ['impala', 'sequence'],
+                  "The model: the paper's conv + LSTM agent over "
+                  'frames, or a sequence policy (token embedding -> '
+                  'power-retention blocks -> heads over the '
+                  'vocabulary --num_actions; widths: --seq_*), which '
+                  'goes with --env_backend=tokens.')
+flags.DEFINE_enum('param_dtype', _DEFAULTS.param_dtype,
+                  ['float32', 'bfloat16'],
+                  'Dtype the parameters are created in (bfloat16: '
+                  'serving a large sequence policy).')
+flags.DEFINE_integer('seq_num_layers', _DEFAULTS.seq_num_layers,
+                     'Sequence agent: retention blocks.', lower_bound=1)
+flags.DEFINE_integer('seq_hidden_size', _DEFAULTS.seq_hidden_size,
+                     'Sequence agent: hidden size.', lower_bound=1)
+flags.DEFINE_integer('seq_num_heads', _DEFAULTS.seq_num_heads,
+                     'Sequence agent: query heads.', lower_bound=1)
+flags.DEFINE_integer('seq_num_kv_heads', _DEFAULTS.seq_num_kv_heads,
+                     'Sequence agent: key-value heads (one retention '
+                     'state each).', lower_bound=1)
+flags.DEFINE_integer('seq_head_dim', _DEFAULTS.seq_head_dim,
+                     'Sequence agent: head size (even).', lower_bound=2)
+flags.DEFINE_integer('seq_mlp_size', _DEFAULTS.seq_mlp_size,
+                     'Sequence agent: SwiGLU width.', lower_bound=1)
+flags.DEFINE_float('seq_rope_theta', _DEFAULTS.seq_rope_theta,
+                   'Sequence agent: RoPE base.')
+flags.DEFINE_float('seq_norm_eps', _DEFAULTS.seq_norm_eps,
+                   'Sequence agent: RMSNorm epsilon.')
+flags.DEFINE_integer('token_prompt_length',
+                     _DEFAULTS.token_prompt_length,
+                     'tokens backend: seeded prompt tokens an episode.',
+                     lower_bound=1)
 flags.DEFINE_integer('model_parallelism', _DEFAULTS.model_parallelism,
                      'TP width of the device mesh.')
 flags.DEFINE_bool('use_py_process', _DEFAULTS.use_py_process,

@@ -73,7 +73,8 @@ class Config:
 
   # TPU-build additions (not in the reference).
   env_backend: str = 'dmlab'              # dmlab | atari | fake |
-                                          # bandit | cue_memory
+                                          # bandit | cue_memory |
+                                          # gridworld | procgen | tokens
   num_actions: Optional[int] = None       # backend default when None
   sticky_action_prob: float = 0.0         # Atari: per-frame previous-
                                           # action repeat prob (0.25 =
@@ -116,6 +117,27 @@ class Config:
   # level needs an explicit --use_instruction=true.
   use_instruction: Optional[bool] = None
   compute_dtype: str = 'float32'          # float32 | bfloat16
+  # The model (PR 27). 'impala': the paper's conv torso + LSTM core
+  # over frames. 'sequence': token embedding -> retention blocks ->
+  # heads over the vocabulary (models/sequence.py), for the 'tokens'
+  # env backend; its widths are the seq_* fields (defaults: the tiny
+  # size the CPU tests run), --num_actions is the vocabulary.
+  agent: str = 'impala'                   # impala | sequence
+  # Dtype the parameters are CREATED in. bfloat16 is for serving a
+  # large sequence policy (no float32 master copy ever exists); the
+  # learner's RMSProp wants float32.
+  param_dtype: str = 'float32'            # float32 | bfloat16
+  seq_num_layers: int = 2
+  seq_hidden_size: int = 64
+  seq_num_heads: int = 4
+  seq_num_kv_heads: int = 2
+  seq_head_dim: int = 16
+  seq_mlp_size: int = 128
+  seq_rope_theta: float = 1e6
+  seq_norm_eps: float = 1e-6
+  # 'tokens' backend: seeded prompt tokens at the start of each
+  # episode of --episode_length steps.
+  token_prompt_length: int = 4
   use_associative_scan: bool = False      # parallel V-trace recursion
   use_pallas_vtrace: bool = False         # fused Pallas V-trace kernel
   use_popart: bool = False                # PopArt value normalization
@@ -1139,6 +1161,30 @@ def validate_runtime(config: Config) -> List[str]:
   if config.filler_unroll_length < 0:
     raise ValueError(f'filler_unroll_length must be >= 0, got '
                      f'{config.filler_unroll_length}')
+  if config.agent not in ('impala', 'sequence'):
+    raise ValueError(f'agent must be impala|sequence, got '
+                     f'{config.agent!r}')
+  if config.param_dtype not in ('float32', 'bfloat16'):
+    raise ValueError(f'param_dtype must be float32|bfloat16, got '
+                     f'{config.param_dtype!r}')
+  if (config.agent == 'sequence') != (config.env_backend == 'tokens'):
+    raise ValueError(
+        'the sequence agent observes a token and the image agent a '
+        'frame: --agent=sequence goes with --env_backend=tokens and '
+        f'with no other (got agent={config.agent!r}, '
+        f'env_backend={config.env_backend!r})')
+  if config.agent == 'sequence':
+    if config.runtime != 'fleet' or config.fleet_tasks:
+      raise ValueError('--agent=sequence runs on the fleet runtime, '
+                       'on one task')
+    if (config.seq_num_heads % config.seq_num_kv_heads
+        or config.seq_head_dim % 2):
+      raise ValueError(
+          'seq_num_heads must be a multiple of seq_num_kv_heads and '
+          'seq_head_dim even')
+    if config.use_popart or config.pixel_control_cost > 0:
+      raise ValueError('--agent=sequence has neither PopArt value '
+                       'columns nor a pixel-control head')
   if config.runtime == 'anakin':
     if config.env_backend not in JITTABLE_BACKENDS:
       raise ValueError(

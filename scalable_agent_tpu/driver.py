@@ -58,7 +58,8 @@ from scalable_agent_tpu.config import (Config, validate_controller,
                                        validate_serving, validate_slo,
                                        validate_transport)
 from scalable_agent_tpu.envs import factory, suites
-from scalable_agent_tpu.models import ImpalaAgent, init_params
+from scalable_agent_tpu.models import (ImpalaAgent, SequenceAgent,
+                                      init_params)
 from scalable_agent_tpu.parallel import mesh as mesh_lib
 from scalable_agent_tpu.parallel import sharding as sharding_lib
 from scalable_agent_tpu.parallel import train_parallel
@@ -132,10 +133,20 @@ def _stats_only_view(level_name, info, done):
       agent_outputs=None)
 
 
-def build_agent(config: Config, num_actions: int,
-                num_tasks: int = 1) -> ImpalaAgent:
+def build_agent(config: Config, num_actions: int, num_tasks: int = 1):
   dtype = (jnp.bfloat16 if config.compute_dtype == 'bfloat16'
            else jnp.float32)
+  if config.agent == 'sequence':
+    return SequenceAgent(
+        num_actions=num_actions, num_layers=config.seq_num_layers,
+        hidden_size=config.seq_hidden_size,
+        num_heads=config.seq_num_heads,
+        num_kv_heads=config.seq_num_kv_heads,
+        head_dim=config.seq_head_dim, mlp_size=config.seq_mlp_size,
+        rope_theta=config.seq_rope_theta, norm_eps=config.seq_norm_eps,
+        scan_unroll=config.scan_unroll, dtype=dtype,
+        param_dtype=(jnp.bfloat16 if config.param_dtype == 'bfloat16'
+                     else jnp.float32))
   return ImpalaAgent(num_actions=num_actions, torso=config.torso,
                      use_instruction=config.resolved_use_instruction,
                      num_popart_tasks=(num_tasks if config.use_popart
@@ -750,6 +761,15 @@ def train(config: Config, max_steps: Optional[int] = None,
     # actors would run inference on deleted buffers (real on TPU;
     # invisible on CPU tests, where jit ignores donation).
     server.update_params(initial_pub, version=_initial_steps)
+    state_bytes = server.stats()['state_bytes_per_slot']
+    if state_bytes > inference_lib.MAX_HOST_STATE_BYTES:
+      server.close()
+      raise ValueError(
+          'the learner needs every unroll\'s starting state '
+          f'(ActorOutput.agent_state), and a state of {state_bytes} '
+          'bytes a session never leaves the inference server\'s '
+          'arena: such a policy '
+          'can be served (driver.play), not yet trained')
     # Pre-compile inference buckets up to the fleet size: a bucket's
     # first appearance otherwise stalls every parked actor for the TPU
     # compile (the reference's TF graph had dynamic batch dims). With
@@ -3449,6 +3469,116 @@ def train_population(config: Config, max_steps: Optional[int] = None,
     incidents.close()
 
 
+def play(config: Config, agent, params, obs_spec, levels,
+         num_actors: int, level_offset: int = 0, seed_base: int = 0,
+         fleet_factory=None,
+         stop_event: Optional[threading.Event] = None,
+         stall_timeout_secs: Optional[float] = 300.0,
+         drought_secs: float = 600.0) -> Dict[int, List[float]]:
+  """Serve `params` to `num_actors` actors and play: the serving path
+  with no learner behind it (inference server, batcher, actors, envs).
+
+  Actor i plays `levels[(level_offset + i) % len(levels)]` with env
+  seed `seed_base + i + 1`. Plays until every level id in play has
+  `config.test_num_episodes` finished episodes, or `stop_event` is set
+  (the preemption seam, as `train`'s `drain_event`). Returns {level
+  id: [episode returns]}.
+
+  Two callers, one path: `evaluate` passes the parameters it restored
+  from a checkpoint; a serving deployment (and the benchmark's serving
+  cells) pass the parameters they hold. `fleet_factory(config, agent,
+  policy, buffer, levels)` replaces `make_fleet` when given, as in
+  `train`: the seam a caller-side clock goes round `policy` at.
+
+  Inference compiles exactly ONE padded bucket (`pad_batch_to`): all
+  actors step concurrently, so merged batches converge to one size
+  anyway, and warming every power-of-two bucket cost 6 serial 20-40 s
+  compiles on dmlab30 before the first episode (VERDICT r3 W5).
+  """
+  ids = sorted({(level_offset + i) % len(levels)
+                for i in range(num_actors)})
+  returns: Dict[int, List[float]] = {level_id: [] for level_id in ids}
+
+  def stats_view(unroll):
+    """Single-unroll [T+1, 1] view — no frame stacking."""
+    expand = lambda x: np.asarray(x)[:, None]  # noqa: E731
+    return _stats_only_view(
+        np.asarray([unroll.level_name]),
+        jax.tree_util.tree_map(expand, unroll.env_outputs.info),
+        expand(unroll.env_outputs.done))
+
+  # Same setup-failure guard as train(): a make_fleet raise (env
+  # construction) must not leak the warmed inference server.
+  server = None
+  fleet = None
+  try:
+    # No fleet_size here: the auto merge FLOOR (inference_min_batch
+    # =0) must not apply to eval — levels retire as their episodes
+    # finish, so the caller count shrinks PERMANENTLY below the
+    # floor and the tail would step one timeout per batch
+    # (reintroducing the W5 tail stalls pad_batch_to eliminated).
+    # pad_batch_to keeps the single-compile property either way.
+    server = InferenceServer(agent, params, config,
+                             seed=config.seed + 2000,
+                             mesh=_choose_eval_mesh(),
+                             pad_batch_to=num_actors)
+    server.warmup(obs_spec, max_size=num_actors)
+    buffer = ring_buffer.TrajectoryBuffer(max(2 * num_actors, 2))
+    if fleet_factory is not None:
+      fleet = fleet_factory(config, agent, server.policy, buffer, levels)
+    else:
+      # Eval acquisitions carry the EVAL admission class: on a shared
+      # or constrained state arena, eval churn parks behind live
+      # traffic instead of starving it (the fleet's priority kwarg is
+      # accepted and overridden — every eval acquire is eval-class).
+      fleet = make_fleet(
+          config, agent, server.policy, buffer, levels,
+          seed_base=seed_base, level_offset=level_offset, is_test=True,
+          num_actors=num_actors,
+          initial_state_fn=lambda priority=None:
+              server.initial_core_state(
+                  priority=inference_lib.PRIORITY_EVAL))
+  except BaseException:
+    if server is not None:
+      server.close()
+    raise
+
+  try:
+    fleet.start()
+    last_unroll_time = time.monotonic()
+    errors: List[BaseException] = []
+    while (any(len(returns[i]) < config.test_num_episodes for i in ids)
+           and not (stop_event is not None and stop_event.is_set())):
+      try:
+        unroll = buffer.get(timeout=1 if stop_event is not None else 10)
+      except TimeoutError:
+        # Read errors BEFORE check_health — a respawn clears the
+        # slot's error, and a crash-looping actor's root cause must
+        # survive to the drought raise below.
+        errors = fleet.errors() or errors
+        # Detect dead AND stalled actors (a wedged env whose thread
+        # is alive would otherwise spin this loop forever while
+        # healthy levels keep producing).
+        fleet.check_health(stall_timeout_secs=stall_timeout_secs)
+        if time.monotonic() - last_unroll_time > drought_secs:
+          raise errors[0] if errors else TimeoutError(
+              f'play produced no unrolls for {drought_secs}s')
+        continue
+      except ring_buffer.Closed:
+        errors = fleet.errors() or errors
+        raise errors[0] if errors else ring_buffer.Closed()
+      last_unroll_time = time.monotonic()
+      errors = []  # recovered; see train()
+      for level_id, ep_return, _ in observability.extract_episodes(
+          stats_view(unroll)):
+        returns[level_id].append(ep_return)
+      fleet.check_health(stall_timeout_secs=stall_timeout_secs)
+  finally:
+    fleet.stop()
+    server.close()
+  return returns
+
+
 def evaluate(config: Config,
              stall_timeout_secs: Optional[float] = 300.0,
              eval_drought_secs: float = 600.0
@@ -3473,11 +3603,8 @@ def evaluate(config: Config,
   benchmark and wrote divergent score files). Every process returns
   the same combined dict.
 
-  Inference compiles exactly ONE padded bucket (`pad_batch_to`): all
-  of this host's levels step concurrently, so merged batches converge
-  to one size anyway, and warming every power-of-two bucket cost 6
-  serial 20–40 s compiles on dmlab30 before the first episode
-  (VERDICT r3 W5).
+  The play phase itself is `play`, which any holder of parameters can
+  call; this function restores them from the latest checkpoint.
   """
   from scalable_agent_tpu.parallel import distributed
   # Same contract as train(): validate the declared topology BEFORE
@@ -3550,89 +3677,20 @@ def evaluate(config: Config,
   level_returns: Dict[str, List[float]] = {
       name: [] for name in train_levels}
 
-  def stats_view(unroll):
-    """Single-unroll [T+1, 1] view — no frame stacking."""
-    expand = lambda x: np.asarray(x)[:, None]  # noqa: E731
-    return _stats_only_view(
-        np.asarray([unroll.level_name]),
-        jax.tree_util.tree_map(expand, unroll.env_outputs.info),
-        expand(unroll.env_outputs.done))
-
   # A process with no assigned levels (more hosts than test levels)
   # skips the play phase but still joins the allgather below.
   if my_count > 0:
-    # Same setup-failure guard as train(): a make_fleet raise (env
-    # construction) must not leak the warmed inference server.
-    server = None
-    fleet = None
-    try:
-      # No fleet_size here: the auto merge FLOOR (inference_min_batch
-      # =0) must not apply to eval — levels retire as their episodes
-      # finish, so the caller count shrinks PERMANENTLY below the
-      # floor and the tail would step one timeout per batch
-      # (reintroducing the W5 tail stalls pad_batch_to eliminated).
-      # pad_batch_to keeps the single-compile property either way.
-      server = InferenceServer(agent, params, config,
-                               seed=config.seed + 2000,
-                               mesh=_choose_eval_mesh(),
-                               pad_batch_to=my_count)
-      server.warmup(spec0.obs_spec, max_size=my_count)
-      buffer = ring_buffer.TrajectoryBuffer(max(2 * my_count, 2))
-      # level_offset keeps level ids GLOBAL (actor i plays
-      # test_levels[start + i] and stamps that id on its unrolls);
-      # seed_base offsets by start so env streams stay disjoint
-      # across processes.
-      # Eval acquisitions carry the EVAL admission class: on a shared
-      # or constrained state arena, eval churn parks behind live
-      # traffic instead of starving it (the fleet's priority kwarg is
-      # accepted and overridden — every eval acquire is eval-class).
-      fleet = make_fleet(
-          config, agent, server.policy, buffer,
-          test_levels,
-          seed_base=config.seed - 1 + start,
-          level_offset=start, is_test=True,
-          num_actors=my_count,
-          initial_state_fn=lambda priority=None:
-              server.initial_core_state(
-                  priority=inference_lib.PRIORITY_EVAL))
-    except BaseException:
-      if server is not None:
-        server.close()
-      raise
-
-    try:
-      fleet.start()
-      last_unroll_time = time.monotonic()
-      errors: List[BaseException] = []
-      while any(len(level_returns[train_levels[i]])
-                < config.test_num_episodes for i in my_ids):
-        try:
-          unroll = buffer.get(timeout=10)
-        except TimeoutError:
-          # Read errors BEFORE check_health — a respawn clears the
-          # slot's error, and a crash-looping actor's root cause must
-          # survive to the drought raise below.
-          errors = fleet.errors() or errors
-          # Detect dead AND stalled actors (a wedged env whose thread
-          # is alive would otherwise spin this loop forever while
-          # healthy levels keep producing).
-          fleet.check_health(stall_timeout_secs=stall_timeout_secs)
-          if time.monotonic() - last_unroll_time > eval_drought_secs:
-            raise errors[0] if errors else TimeoutError(
-                f'eval produced no unrolls for {eval_drought_secs}s')
-          continue
-        except ring_buffer.Closed:
-          errors = fleet.errors() or errors
-          raise errors[0] if errors else ring_buffer.Closed()
-        last_unroll_time = time.monotonic()
-        errors = []  # recovered; see train()
-        for level_id, ep_return, _ in observability.extract_episodes(
-            stats_view(unroll)):
-          level_returns[train_levels[level_id]].append(ep_return)
-        fleet.check_health(stall_timeout_secs=stall_timeout_secs)
-    finally:
-      fleet.stop()
-      server.close()
+    # level_offset keeps level ids GLOBAL (actor i plays
+    # test_levels[start + i] and stamps that id on its unrolls);
+    # seed_base offsets by start so env streams stay disjoint across
+    # processes.
+    played = play(config, agent, params, spec0.obs_spec, test_levels,
+                  num_actors=my_count, level_offset=start,
+                  seed_base=config.seed - 1 + start,
+                  stall_timeout_secs=stall_timeout_secs,
+                  drought_secs=eval_drought_secs)
+    for level_id, returns in played.items():
+      level_returns[train_levels[level_id]].extend(returns)
 
   if num_procs > 1:
     # Aggregate per-level returns: a dense [L, E] matrix (NaN = not

@@ -23,14 +23,20 @@ class EnvSpec(object):
   """What the driver needs to know about a backend before building it."""
 
   def __init__(self, env_class, constructor_kwargs, num_actions,
-               frame_shape):
+               frame_shape=None, observation_leaves=None):
     self.env_class = env_class
     self.constructor_kwargs = dict(constructor_kwargs)
     self.num_actions = num_actions
-    self.frame_shape = tuple(frame_shape)
+    # An image env states its frame's shape; any other states the
+    # ((shape, dtype), ...) of its observation's leaves.
+    self.frame_shape = None if frame_shape is None else tuple(frame_shape)
+    self.observation_leaves = observation_leaves
 
   @property
   def obs_spec(self):
+    """What `structs.observation_leaves` reads."""
+    if self.observation_leaves is not None:
+      return {'leaves': tuple(self.observation_leaves)}
     return {'frame': self.frame_shape, 'instr_len': MAX_INSTRUCTION_LEN}
 
   def build(self):
@@ -97,6 +103,23 @@ def make_env_spec(config: Config, level_name: str, seed: int,
       kwargs.update(num_levels=config.procgen_num_levels,
                     wall_density=config.procgen_wall_density)
     frame_shape = (config.height, config.width, 3)
+  elif backend == 'tokens':
+    from scalable_agent_tpu.envs import tokens
+    if not config.num_actions:
+      raise ValueError('--env_backend=tokens needs --num_actions (the '
+                       'vocabulary)')
+    # Consecutive seeds (make_fleet's) start a prompt's length apart
+    # in their first episode: session i is prompt_length * i steps
+    # further than session 0, modulo the episode.
+    kwargs = dict(vocab_size=config.num_actions,
+                  episode_length=config.episode_length,
+                  prompt_length=config.token_prompt_length,
+                  seed=seed, level_name=level_name,
+                  start_step=config.token_prompt_length * seed)
+    return EnvSpec(tokens.TokenEnv, kwargs, config.num_actions,
+                   observation_leaves=tuple(
+                       (spec.shape, spec.dtype)
+                       for spec in tokens.observation_specs()))
   elif backend == 'dmlab':
     from scalable_agent_tpu.envs import dmlab
     env_class = dmlab.DmLabEnv

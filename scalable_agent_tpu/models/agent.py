@@ -27,26 +27,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from scalable_agent_tpu.structs import AgentOutput
+from scalable_agent_tpu.structs import AgentOutput, observation_leaves
+from scalable_agent_tpu.models import core as core_lib
 from scalable_agent_tpu.models.torsos import TORSOS
 from scalable_agent_tpu.models.instruction import InstructionEncoder
 from scalable_agent_tpu.unreal import PixelControlHead
-
-
-class _ResetCore(nn.Module):
-  """LSTM core whose carry is zeroed wherever `done` is set (before the
-  step — `done[t]` marks the first observation of a new episode)."""
-  hidden_size: int
-  dtype: jnp.dtype = jnp.float32
-
-  @nn.compact
-  def __call__(self, carry, inputs):
-    x, done = inputs
-    carry = jax.tree_util.tree_map(
-        lambda s: jnp.where(done[:, None], jnp.zeros_like(s), s), carry)
-    cell = nn.OptimizedLSTMCell(self.hidden_size, dtype=self.dtype)
-    carry, out = cell(carry, x)
-    return carry, out
 
 
 class ImpalaAgent(nn.Module):
@@ -73,15 +58,30 @@ class ImpalaAgent(nn.Module):
   scan_unroll: int = 1
   dtype: jnp.dtype = jnp.float32
 
+  # The leaves of `StepOutput.observation`, in order.
+  observation_names = ('frame', 'instr')
+
+  def core(self, **placement):
+    """The recurrent core (models/core.py's protocol): detached from
+    any parameter tree, for its shapes; or, in `__call__`, under the
+    name it had when it was this file's `_ResetCore`, so checkpoints
+    keep loading."""
+    placement = placement or {'parent': None}
+    return core_lib.LSTMCore(self.hidden_size, dtype=self.dtype,
+                             **placement)
+
   def initial_state(self, batch_size):
     """Zeroed LSTM carry (c, h), each [B, hidden] (reference ≈L90)."""
-    shape = (batch_size, self.hidden_size)
-    return (jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32))
+    return self.core().initial_state(batch_size)
+
+  def state_arena(self, num_slots):
+    """The inference server's state arena for `num_slots` sessions."""
+    return self.core().arena(num_slots)
 
   @nn.compact
   def __call__(self, prev_actions, env_outputs, core_state,
                sample_rng=None, level_ids=None,
-               compute_pixel_control=False):
+               compute_pixel_control=False, state_slots=None):
     """Unroll over a [T, B] trajectory.
 
     Args:
@@ -99,6 +99,10 @@ class ImpalaAgent(nn.Module):
         and sow its output as intermediates['pixel_control_q']
         ([T, B, Hc, Wc, A]) — learner path only; actors skip the
         deconv cost. Params exist either way (created at init).
+      state_slots: i32 [B] slot ids (the inference server's state
+        cache; T must be 1): `core_state` is then the server's state
+        ARENA, of which the rows `state_slots` are advanced and which
+        is returned in place of the carry (models/core.py).
 
     Returns:
       (AgentOutput([T, B, ...]), final core_state).
@@ -129,17 +133,19 @@ class ImpalaAgent(nn.Module):
     core_input = jnp.concatenate(parts, axis=-1).reshape(t, b, -1)
 
     # --- Recurrent core: scan over time with done-reset on the carry. ---
-    scan = nn.scan(
-        lambda core, carry, x: core(carry, x),
-        variable_broadcast='params', split_rngs={'params': False},
-        in_axes=0, out_axes=0, unroll=self.scan_unroll)
-    core = _ResetCore(self.hidden_size, dtype=self.dtype)
-    core_state = jax.tree_util.tree_map(
-        lambda s: s.astype(self.dtype), core_state)
+    core = self.core(name='_ResetCore_0')
     with jax.named_scope('core'):
-      new_state, core_out = scan(core, core_state, (core_input, done))
-    new_state = jax.tree_util.tree_map(
-        lambda s: s.astype(jnp.float32), new_state)
+      if state_slots is None:
+        core_state = jax.tree_util.tree_map(
+            lambda s: s.astype(self.dtype), core_state)
+        new_state, core_out = core_lib.unroll(
+            core, core_state, core_input, done, self.scan_unroll)
+        new_state = jax.tree_util.tree_map(
+            lambda s: s.astype(jnp.float32), new_state)
+      else:
+        assert t == 1, 'the arena form is one step'
+        new_state, core_out = core.step(core_state, core_input[0],
+                                        done[0], slots=state_slots)
 
     # --- Heads over merged time+batch. ---
     flat_core = core_out.reshape(t * b, -1)
@@ -180,7 +186,7 @@ class ImpalaAgent(nn.Module):
     return AgentOutput(action, policy_logits, baseline), new_state
 
 
-def make_step_fn(agent: ImpalaAgent):
+def make_step_fn(agent):
   """Single-step (T=1) policy for actors: batch-shaped, no time axis.
 
   Returns f(params, rng, prev_action [B], env_output of [B, ...],
@@ -199,13 +205,12 @@ def make_step_fn(agent: ImpalaAgent):
   return step
 
 
-def init_params(agent: ImpalaAgent, rng, obs_spec, batch_size=1):
-  """Initialize parameters from an observation spec pytree.
+def init_params(agent, rng, obs_spec, batch_size=1):
+  """Initialize parameters from an observation spec.
 
-  obs_spec: dict with 'frame' (H, W, C) uint8 and 'instr_len' L.
+  obs_spec: dict with 'frame' (H, W, C) uint8 and 'instr_len' L, or
+  with the observation's 'leaves' (structs.observation_leaves).
   """
-  h, w, c = obs_spec['frame']
-  l = obs_spec['instr_len']
   t, b = 2, batch_size
   from scalable_agent_tpu.structs import StepOutput, StepOutputInfo
   dummy = StepOutput(
@@ -213,8 +218,8 @@ def init_params(agent: ImpalaAgent, rng, obs_spec, batch_size=1):
       info=StepOutputInfo(jnp.zeros((t, b), jnp.float32),
                           jnp.zeros((t, b), jnp.int32)),
       done=jnp.zeros((t, b), bool),
-      observation=(jnp.zeros((t, b, h, w, c), jnp.uint8),
-                   jnp.zeros((t, b, l), jnp.int32)))
+      observation=tuple(jnp.zeros((t, b) + tuple(shape), dtype)
+                        for shape, dtype in observation_leaves(obs_spec)))
   prev_actions = jnp.zeros((t, b), jnp.int32)
   return agent.init(rng, prev_actions, dummy,
                     agent.initial_state(b))

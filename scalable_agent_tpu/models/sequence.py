@@ -1,0 +1,113 @@
+"""A sequence policy: token embedding -> recurrent core of retention
+blocks -> norm -> policy head over the vocabulary and a value head.
+
+It answers the same call as `ImpalaAgent` (`prev_actions, env_outputs,
+core_state, sample_rng`), so the inference server, the actors and
+`learner.loss_fn` run it as they run the paper's agent. What differs is
+declared by the agent and read off its output, never switched by a
+flag:
+
+- the observation is one leaf, an int32 token id (`observation_names`);
+- acting (`sample_rng` given), `AgentOutput.policy_logits` is a SCALAR a
+  step: `log mu(action)`. Logits over a vocabulary of 151,936 are 19 MB
+  a merged call of 32; the actor needs the action, its log-probability
+  and the baseline, so the logits stay on the device and no unroll
+  stores them. The learner forms `log_rhos = log pi(a) - log mu(a)`
+  from it (`learner.loss_fn`);
+- in the learner's pass (`sample_rng` None) `policy_logits` is the full
+  `[T, B, vocabulary]`, inside the step's program only.
+
+The model has no value head of its own: `baseline` is this system's
+`w.x + b` on the final norm's output [assumed].
+"""
+
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from scalable_agent_tpu.models import core as core_lib
+from scalable_agent_tpu.models import retention
+from scalable_agent_tpu.structs import AgentOutput
+
+
+class SequenceAgent(nn.Module):
+  num_actions: int           # the vocabulary
+  num_layers: int = 2
+  hidden_size: int = 64
+  num_heads: int = 4
+  num_kv_heads: int = 2
+  head_dim: int = 16
+  mlp_size: int = 128
+  rope_theta: float = 1e6
+  norm_eps: float = 1e-6
+  scan_unroll: int = 1
+  dtype: Any = jnp.float32        # the projections' operands
+  param_dtype: Any = jnp.float32  # bfloat16 when served at full width
+
+  # The leaves of `StepOutput.observation`, in order.
+  observation_names = ('token',)
+
+  def core(self, **placement):
+    """The retention stack: detached (for its shapes), or named in
+    `__call__`."""
+    placement = placement or {'parent': None}
+    return retention.PowerRetentionStack(
+        self.num_layers, self.hidden_size, self.num_heads,
+        self.num_kv_heads, self.head_dim, self.mlp_size, self.rope_theta,
+        self.norm_eps, self.dtype, self.param_dtype, **placement)
+
+  def initial_state(self, batch_size):
+    return self.core().initial_state(batch_size)
+
+  def state_arena(self, num_slots):
+    return self.core().arena(num_slots)
+
+  @nn.compact
+  def __call__(self, prev_actions, env_outputs, core_state,
+               sample_rng=None, level_ids=None,
+               compute_pixel_control=False, state_slots=None):
+    """Unroll over a [T, B] trajectory of tokens; see `ImpalaAgent` for
+    the arguments. With `state_slots` (i32 [B]; T must be 1)
+    `core_state` is the server's state arena and is returned advanced
+    in the rows `state_slots`."""
+    del prev_actions, level_ids, compute_pixel_control  # the token says it
+    (token,) = env_outputs.observation
+    done = env_outputs.done
+    t, b = token.shape
+    with jax.named_scope('embed'):
+      table = self.param('embedding', nn.initializers.normal(1.0),
+                         (self.num_actions, self.hidden_size),
+                         self.param_dtype)
+      x = jnp.take(table, token, axis=0).astype(jnp.float32)
+    core = self.core(name='core')
+    if state_slots is None:
+      new_state, out = core_lib.unroll(core, core_state, x, done,
+                                       self.scan_unroll)
+    else:
+      assert t == 1, 'the arena form is one step'
+      new_state, out = core.step(core_state, x[0], done[0],
+                                 slots=state_slots)
+    flat = out.reshape(t * b, self.hidden_size)
+    with jax.named_scope('lm_head'):
+      n = retention._Scale(self.param_dtype, name='final_norm')(
+          flat, self.norm_eps)
+      logits = retention._Linear(
+          self.num_actions, self.dtype, self.param_dtype,
+          name='policy_logits')(n)
+      # float32 at full precision: on a TPU a float32 product is one
+      # bfloat16 pass unless told otherwise, and 5,120 terms are cheap.
+      baseline = nn.Dense(1, dtype=jnp.float32, name='baseline',
+                          precision=jax.lax.Precision.HIGHEST)(n)
+      baseline = baseline[:, 0].reshape(t, b)
+    with jax.named_scope('sample'):
+      if sample_rng is None:
+        action = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        policy = logits.reshape(t, b, self.num_actions)
+      else:
+        action = jax.random.categorical(
+            sample_rng, logits, axis=-1).astype(jnp.int32)
+        policy = (jnp.take_along_axis(logits, action[:, None], axis=-1)[
+            :, 0] - jax.nn.logsumexp(logits, axis=-1)).reshape(t, b)
+    return AgentOutput(action.reshape(t, b), policy, baseline), new_state

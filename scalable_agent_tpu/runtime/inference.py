@@ -21,11 +21,17 @@ natively; bucketing is the XLA-idiomatic trade).
 Round 7 overhaul (docs/INFERENCE.md) — three independent levers:
 
 1. Device-resident core-state cache (config.inference_state_cache):
-   instead of shipping the LSTM carry host→device and the new carry
-   device→host on EVERY env step, each actor owns a slot in an
-   on-device `[slots, hidden]` state arena; the jitted step gathers
-   carries by slot id, computes, and scatters the new carries back
-   in-graph (Podracer, arXiv:2104.06272). The per-step wire drops to
+   instead of shipping the recurrent carry host→device and the new
+   carry device→host on EVERY env step, each actor owns a slot in an
+   on-device state arena, a pytree with one `[rows, ...]` array per
+   leaf of the agent's state in the leaf's dtype (PR 27; the LSTM's
+   two `[slots, hidden]` float32 matrices, a retention stack's
+   per-layer float32 states and an int32 position); the jitted step
+   hands the arena and the slot ids to the agent's core, which gathers
+   the carries, computes and scatters them back in-graph (Podracer,
+   arXiv:2104.06272) or, for a state of megabytes a session, updates
+   the named rows in place (models/core.py; the arena is donated).
+   The per-step wire drops to
    (action, reward, done, frame, instr, slot_id); the carry crosses
    the host boundary only once per unroll (the learner needs the
    unroll-start state — `_SlotHandle.snapshot()`). Numerics-identical
@@ -120,7 +126,8 @@ from scalable_agent_tpu.ops import dynamic_batching
 from scalable_agent_tpu.runtime import codec as codec_lib
 from scalable_agent_tpu.runtime import faults as faults_lib
 from scalable_agent_tpu.runtime.remote import Backoff
-from scalable_agent_tpu.structs import AgentOutput, StepOutput
+from scalable_agent_tpu.structs import (AgentOutput, StepOutput,
+                                        observation_leaves)
 
 log = logging.getLogger('scalable_agent_tpu')
 
@@ -135,6 +142,10 @@ _EVICTIONS = telemetry.counter('serving/evictions')
 _VERSION_FLIPS = telemetry.counter('serving/version_flips')
 _RESIDENT_VERSIONS = telemetry.gauge('serving/resident_versions')
 _AOT_MISSES = telemetry.counter('serving/aot_misses')
+# The recurrent state the server holds for its sessions (PR 27).
+_STATE_BYTES_PER_SLOT = telemetry.gauge('serving/state_bytes_per_slot')
+_ARENA_BYTES = telemetry.gauge('serving/arena_bytes')
+_STATE_RESETS = telemetry.counter('serving/state_resets')
 
 # Admission priority classes (lower = served first): a released slot
 # is handed to the best-priority parked waiter, so background churn
@@ -150,6 +161,16 @@ ADMISSION_POLICIES = ('block', 'shed', 'grow')
 # the old `num_slots` stamp — still out of range after a 'grow'
 # admission doubles the arena between staging and dispatch.
 _PAD_SLOT_ID = np.int32(1 << 30)
+
+# The largest recurrent state of ONE session that may cross the host
+# boundary: with every call in carry-passing mode, with every unroll
+# as a slot's snapshot. At 1 MiB a merged call of 32 already moves
+# 32 MiB each way, more than ten times the 1.2 ms the whole readback
+# of such a call takes today (PERF.md section 5); a retention state is
+# 136 MB a session. Above it the state lives in the server's arena
+# and nowhere else: carry-passing mode is refused at construction,
+# and a slot's snapshot is None.
+MAX_HOST_STATE_BYTES = 1 << 20
 
 
 class SlotUnavailable(RuntimeError):
@@ -227,10 +248,13 @@ class _SlotHandle:
   Opaque under the `runtime.actor.Actor` core-state contract; the
   actor only touches the duck-typed surface:
 
-  - `snapshot()`: the slot's carry as host numpy `(c[1,H], h[1,H])` —
-    the once-per-unroll read the learner's `agent_state` needs.
+  - `snapshot()`: the slot's carry as host numpy, `[1, ...]` leaves
+    (the LSTM's `(c[1,H], h[1,H])`) — the once-per-unroll read the
+    learner's `agent_state` needs. None for a state above
+    MAX_HOST_STATE_BYTES, which never leaves the device.
   - `write(carry)`: overwrite the slot (the actor's priming-call
-    undo).
+    undo); `write(None)` zeroes it, which is what a slot held when
+    nothing could be snapshotted from it.
   - `release()`: return the slot to the free list (idempotent). The
     slot is zeroed again on the NEXT acquire, so a reclaimed slot can
     never serve a stale carry.
@@ -372,6 +396,7 @@ class InferenceServer:
   _unjoined_threads: guarded_by('_stats_lock')
   _latencies: guarded_by('_stats_lock')
   _chain_recoveries: guarded_by('_stats_lock')
+  _state_resets: guarded_by('_stats_lock')
   _version_flips: guarded_by('_stats_lock')
   _evictions: guarded_by('_stats_lock')
   _ab_calls: guarded_by('_stats_lock')
@@ -394,9 +419,24 @@ class InferenceServer:
       min_batch = max(fleet_size or 1, 1)
     self._min_batch = min(min_batch, config.inference_max_batch)
     self._agent = agent
-    self._core_sizes = (agent.hidden_size, agent.hidden_size)  # (c, h)
+    # One observation is `num_obs` arrays (the agent says which); one
+    # session's recurrent state is a pytree of `[1, ...]` leaves.
+    num_obs = len(agent.observation_names)
+    self._state_spec = jax.eval_shape(lambda: agent.initial_state(1))
+    state_leaves, self._state_treedef = jax.tree_util.tree_flatten(
+        self._state_spec)
+    self._state_dtypes = [l.dtype for l in state_leaves]
+    self._state_bytes = sum(
+        int(np.prod(l.shape)) * l.dtype.itemsize for l in state_leaves)
     self._mesh = mesh
     self._state_cache = bool(config.inference_state_cache)
+    if (not self._state_cache
+        and self._state_bytes > MAX_HOST_STATE_BYTES):
+      raise ValueError(
+          f'a recurrent state of {self._state_bytes} bytes a session '
+          'cannot ride with every policy call (carry-passing mode '
+          f'moves it both ways; the limit is {MAX_HOST_STATE_BYTES}): '
+          'keep it on the device with --inference_state_cache')
     self._depth = max(1, int(config.inference_pipeline_depth))
     # --- Slot admission policy (overload hardening; module docstring).
     self._admission = getattr(config, 'inference_admission', 'block')
@@ -498,6 +538,7 @@ class InferenceServer:
     self._key = jax.random.PRNGKey(seed)
     self._base_seed = seed
     self._chain_recoveries = 0
+    self._state_resets = 0
     self._max_batch = config.inference_max_batch
 
     # --- Device-resident state arena (state-cache mode). ---
@@ -518,11 +559,7 @@ class InferenceServer:
         num_slots = max(2 * max(fleet_size or 0, pad_batch_to or 0), 8)
       self._num_slots = num_slots
       self._free = list(range(num_slots))
-      arena = tuple(jnp.zeros((num_slots, s), jnp.float32)
-                    for s in self._core_sizes)
-      if mesh is not None:
-        arena = jax.device_put(arena, self._replicated)
-      self._arena = arena
+      self._arena = self._new_arena(num_slots)
     else:
       self._num_slots = 0
       self._free = []
@@ -530,8 +567,8 @@ class InferenceServer:
     if mesh is not None:
       self._key = jax.device_put(self._key, self._replicated)
 
-    def _apply(params, sub, prev_action, reward, done, frame, instr,
-               core_c, core_h):
+    def _apply(params, sub, prev_action, reward, done, obs, core_state,
+               slots=None):
       # Int8-resident versions (publish_codec=int8) dequantize HERE,
       # in-graph: XLA fuses the per-leaf multiply into the step, so
       # serving a quantized version costs no host round trip. Identity
@@ -539,79 +576,86 @@ class InferenceServer:
       params = codec_lib.dequantize_tree(params)
       env_output = StepOutput(
           reward=reward[None], info=None, done=done[None],
-          observation=(frame[None], instr[None]))
-      out, (new_c, new_h) = agent.apply(
-          params, prev_action[None], env_output, (core_c, core_h),
-          sample_rng=sub)
+          observation=tuple(o[None] for o in obs))
+      # With `slots` the agent's core advances those rows of the arena
+      # (`core_state`) and hands the arena back: models/core.py.
+      kwargs = {} if slots is None else {'state_slots': slots}
+      out, new_state = agent.apply(
+          params, prev_action[None], env_output, core_state,
+          sample_rng=sub, **kwargs)
       return (out.action[0], out.policy_logits[0], out.baseline[0],
-              new_c, new_h)
+              new_state)
 
-    def carry_step(params, key, prev_action, reward, done, frame,
-                   instr, core_c, core_h):
-      key, sub = jax.random.split(key)
-      action, logits, baseline, new_c, new_h = _apply(
-          params, sub, prev_action, reward, done, frame, instr,
-          core_c, core_h)
-      return key, action, logits, baseline, new_c, new_h
+    def unflatten(leaves):
+      return jax.tree_util.tree_unflatten(self._state_treedef, leaves)
 
-    def cache_step(params, key, arena_c, arena_h, slot_ids,
-                   prev_action, reward, done, frame, instr):
+    def carry_step(params, key, prev_action, reward, done, *rest):
       key, sub = jax.random.split(key)
-      # Gather each row's carry by slot id. Padded rows carry
-      # _PAD_SLOT_ID (out of range for any arena size, grown or not):
-      # the gather clamps (their compute is sliced away) and the
-      # scatter DROPS them — mode='drop' is what keeps a padded row
-      # from ever corrupting a live slot.
-      core_c = arena_c[slot_ids]
-      core_h = arena_h[slot_ids]
-      action, logits, baseline, new_c, new_h = _apply(
-          params, sub, prev_action, reward, done, frame, instr,
-          core_c, core_h)
-      arena_c = arena_c.at[slot_ids].set(new_c, mode='drop')
-      arena_h = arena_h.at[slot_ids].set(new_h, mode='drop')
-      return key, arena_c, arena_h, action, logits, baseline
+      action, logits, baseline, new_state = _apply(
+          params, sub, prev_action, reward, done, rest[:num_obs],
+          unflatten(rest[num_obs:]))
+      return (key, action, logits, baseline,
+              *jax.tree_util.tree_leaves(new_state))
+
+    def cache_step(params, key, arena, slot_ids, prev_action, reward,
+                   done, *obs):
+      key, sub = jax.random.split(key)
+      # Each row's state is the arena's row `slot_ids[row]`. Padded
+      # rows carry _PAD_SLOT_ID (out of range for any arena size,
+      # grown or not) and never touch a live slot: the default core
+      # gathers with a clamp (their compute is sliced away) and
+      # scatters with mode='drop'; a core that updates its arena in
+      # place sends them to a row of their own.
+      action, logits, baseline, arena = _apply(
+          params, sub, prev_action, reward, done, obs, arena,
+          slots=slot_ids)
+      return key, arena, action, logits, baseline
 
     step = cache_step if self._state_cache else carry_step
-    num_batch_args = 6 if self._state_cache else 7
+    num_batch_args = 3 + num_obs + (
+        1 if self._state_cache else len(state_leaves))
+    # The arena is DONATED: the step's output takes its buffers, so a
+    # core that updates rows in place costs no second arena (4.4 GB
+    # for 32 retention sessions). Whoever reads `self._arena`
+    # therefore dispatches its read under `_arena_lock`, before the
+    # next step can take the buffers (`_read_slot`).
+    donate = (2,) if self._state_cache else ()
     if mesh is None:
-      self._step = jax.jit(step)
+      self._step = jax.jit(step, donate_argnums=donate)
     else:
       # params keep their (replicated) placement; the key (and the
       # state arena) are replicated; batch args shard dim 0 over the
       # data axis.
       if self._state_cache:
-        in_shardings = (None, self._replicated, self._replicated,
-                        self._replicated) + \
+        in_shardings = (None, self._replicated, self._replicated) + \
             (self._batch_sharding,) * num_batch_args
-        out_shardings = (self._replicated,) * 3 + \
+        out_shardings = (self._replicated,) * 2 + \
             (self._batch_sharding,) * 3
       else:
         in_shardings = (None, self._replicated) + \
             (self._batch_sharding,) * num_batch_args
         out_shardings = (self._replicated,) + \
-            (self._batch_sharding,) * 5
+            (self._batch_sharding,) * (3 + len(state_leaves))
       self._step = jax.jit(step, in_shardings=in_shardings,
-                           out_shardings=out_shardings)
+                           out_shardings=out_shardings,
+                           donate_argnums=donate)
 
     # Shadow step (round 21): PURE — no key split chained back, no
     # arena scatter — so replaying a merged call against a shadow
     # version can never perturb the live fleet's RNG stream or
     # carries. Scored on GREEDY agreement downstream, so the fixed
     # sample key is irrelevant to the gauge.
-    def shadow_carry(params, prev_action, reward, done, frame, instr,
-                     core_c, core_h):
+    def shadow_carry(params, prev_action, reward, done, *rest):
       sub = jax.random.PRNGKey(0)
-      _, logits, _, _, _ = _apply(params, sub, prev_action, reward,
-                                  done, frame, instr, core_c, core_h)
+      _, logits, _, _ = _apply(params, sub, prev_action, reward, done,
+                               rest[:num_obs], unflatten(rest[num_obs:]))
       return logits
 
-    def shadow_cache(params, arena_c, arena_h, slot_ids, prev_action,
-                     reward, done, frame, instr):
+    def shadow_cache(params, arena, slot_ids, prev_action, reward, done,
+                     *obs):
       sub = jax.random.PRNGKey(0)
-      core_c = arena_c[slot_ids]
-      core_h = arena_h[slot_ids]
-      _, logits, _, _, _ = _apply(params, sub, prev_action, reward,
-                                  done, frame, instr, core_c, core_h)
+      _, logits, _, _ = _apply(params, sub, prev_action, reward, done,
+                               obs, arena, slots=slot_ids)
       return logits
 
     self._shadow_step = jax.jit(
@@ -663,8 +707,8 @@ class InferenceServer:
     (PRIORITY_LIVE / PRIORITY_RESPAWN / PRIORITY_EVAL — released
     slots go to the best-priority parked waiter first)."""
     if not self._state_cache:
-      return tuple(np.zeros((1, s), np.float32)
-                   for s in self._core_sizes)
+      return jax.tree_util.tree_map(
+          lambda l: np.zeros(l.shape, l.dtype), self._state_spec)
     return self._acquire_slot(priority=priority)
 
   @property
@@ -787,12 +831,9 @@ class InferenceServer:
     old = self._num_slots
     new = 2 * old if old else 8
     with self._arena_lock:
-      arena = tuple(
-          jnp.zeros((new, s), jnp.float32).at[:old].set(a)
-          for a, s in zip(self._arena, self._core_sizes))
-      if self._mesh is not None:
-        arena = jax.device_put(arena, self._replicated)
-      self._arena = arena
+      self._arena = jax.tree_util.tree_map(
+          lambda grown, a: grown.at[:old].set(a[:old]),
+          self._new_arena(new), self._arena)
       self._num_slots = new
     self._free.extend(range(old, new))
     # Cache-mode AOT executables bake the arena shape into their
@@ -821,23 +862,57 @@ class InferenceServer:
       else:
         self._free.append(slot)
 
+  def _new_arena(self, num_slots):
+    """The agent's zeroed state arena for `num_slots` sessions: per
+    state leaf one `[rows, ...]` array in the leaf's dtype."""
+    arena = self._agent.state_arena(num_slots)
+    if self._mesh is not None:
+      arena = jax.device_put(arena, self._replicated)
+    _STATE_BYTES_PER_SLOT.set(float(self._state_bytes))
+    _ARENA_BYTES.set(float(_tree_nbytes(arena)))
+    return arena
+
+  # One slot's row of every leaf, rewritten IN PLACE (the arena is
+  # donated to these as it is to the step): `a.at[slot].set(...)`
+  # outside a donating program would copy a whole arena of gigabytes
+  # to change one row.
+  _set_slot = staticmethod(jax.jit(
+      lambda arena, slot, rows: jax.tree_util.tree_map(
+          lambda a, r: a.at[slot].set(r[0]), arena, rows),
+      donate_argnums=0))
+  _clear_slot = staticmethod(jax.jit(
+      lambda arena, slot: jax.tree_util.tree_map(
+          lambda a: a.at[slot].set(jnp.zeros((), a.dtype)), arena),
+      donate_argnums=0))
+
   def _zero_slot(self, slot):
     with self._arena_lock:
-      self._arena = tuple(a.at[slot].set(0.0) for a in self._arena)
+      self._arena = self._clear_slot(self._arena, np.int32(slot))
+    with self._stats_lock:
+      self._state_resets += 1
+    _STATE_RESETS.inc()
 
   def _read_slot(self, slot):
+    if self._state_bytes > MAX_HOST_STATE_BYTES:
+      return None  # never leaves the device
     with self._arena_lock:
-      arena = self._arena
-    # The old arena array stays valid (never donated) even if the
-    # dispatch thread swaps in a successor while we read; only the
-    # owning actor writes this slot, and it is parked while reading.
-    return tuple(np.asarray(a[slot], np.float32)[None] for a in arena)
+      # Dispatched under the lock: the next step DONATES the arena,
+      # and a read enqueued before it still sees these buffers. Only
+      # the owning actor writes this slot, and it is parked here.
+      rows = jax.tree_util.tree_map(lambda a: a[slot], self._arena)
+    return jax.tree_util.tree_map(lambda r: np.asarray(r)[None], rows)
 
   def _write_slot(self, slot, carry):
-    vals = [jnp.asarray(np.asarray(c, np.float32)[0]) for c in carry]
+    if carry is None:
+      return self._zero_slot(slot)
+    rows = jax.tree_util.tree_map(
+        lambda c, l: np.asarray(c, l.dtype), carry, self._state_spec)
     with self._arena_lock:
-      self._arena = tuple(a.at[slot].set(v)
-                          for a, v in zip(self._arena, vals))
+      self._arena = self._set_slot(self._arena, np.int32(slot), rows)
+
+  def _arena_now(self):
+    with self._arena_lock:
+      return self._arena
 
   def slots_free(self):
     with self._slot_lock:
@@ -898,11 +973,11 @@ class InferenceServer:
           shadow_out = None
           if shadow_params is not None:
             shadow_out = self._shadow_step(
-                shadow_params, *self._arena, *inputs)
-          outs = fn(params, self._key, *self._arena, *inputs)
+                shadow_params, self._arena, *inputs)
+          outs = fn(params, self._key, self._arena, *inputs)
           self._key = outs[0]
-          self._arena = (outs[1], outs[2])
-          return outs[3:], shadow_out
+          self._arena = outs[1]
+          return outs[2:], shadow_out
       shadow_out = None
       if shadow_params is not None:
         shadow_out = self._shadow_step(shadow_params, *inputs)
@@ -943,9 +1018,15 @@ class InferenceServer:
           # a constant (not num_slots): a concurrent 'grow' admission
           # must not turn a just-stamped pad id into a live slot.
           bufs[0][n:] = _PAD_SLOT_ID
+        # `done` rows reset their session's state in-graph.
+        resets = int(np.count_nonzero(
+            bufs[3 if self._state_cache else 2][:n]))
         with self._stats_lock:
           self._calls += 1
           self._merged_requests += n
+          self._state_resets += resets
+        if resets:
+          _STATE_RESETS.inc(resets)
         with self._params_lock:
           params, _ = self._pick_live_locked()
           shadow_params = self._pick_shadow_locked()
@@ -1081,11 +1162,7 @@ class InferenceServer:
             jax.block_until_ready(self._arena)
           except Exception:
             recovered = True
-            arena = tuple(jnp.zeros((self._num_slots, s), jnp.float32)
-                          for s in self._core_sizes)
-            if self._mesh is not None:
-              arena = jax.device_put(arena, self._replicated)
-            self._arena = arena
+            self._arena = self._new_arena(self._num_slots)
       if recovered:
         # Still inside _key_lock: the count advance is part of the
         # recovery's critical section, not an afterthought a second
@@ -1117,15 +1194,15 @@ class InferenceServer:
     before starting the fleet.
 
     Args:
-      obs_spec: {'frame': (H, W, C), 'instr_len': L}.
+      obs_spec: the env's observation spec
+        (structs.observation_leaves).
       sizes: iterable of *unpadded* sizes to warm. Default: every
         power-of-two bucket up to `max_size` (capped at
         maximum_batch_size) — pass max_size=fleet size so only
         reachable buckets compile.
       max_size: see `sizes`; None means maximum_batch_size.
     """
-    h, w, c = obs_spec['frame']
-    l = obs_spec['instr_len']
+    obs_leaves = observation_leaves(obs_spec)
     if sizes is None:
       cap = self._max_batch if max_size is None else min(
           _next_power_of_two(max_size), self._max_batch)
@@ -1147,9 +1224,9 @@ class InferenceServer:
       inputs = (
           np.zeros((padded,), np.int32),
           np.zeros((padded,), np.float32),
-          np.zeros((padded,), bool),
-          np.zeros((padded, h, w, c), np.uint8),
-          np.zeros((padded, l), np.int32))
+          np.zeros((padded,), bool)) + tuple(
+              np.zeros((padded,) + shape, dtype)
+              for shape, dtype in obs_leaves)
       if self._state_cache:
         # Warmup must not touch live carries: out-of-range slot ids
         # make every scatter a drop (same compiled program — shapes
@@ -1158,7 +1235,8 @@ class InferenceServer:
         inputs = (ids,) + inputs
       else:
         inputs = inputs + tuple(
-            np.zeros((padded, s), np.float32) for s in self._core_sizes)
+            np.zeros((padded,) + l.shape[1:], l.dtype)
+            for l in jax.tree_util.tree_leaves(self._state_spec))
       # Record the input meta + warmed bucket for the AOT table —
       # _precompile_params re-derives argument specs from these when a
       # NEW params structure publishes later (the version-flip-
@@ -1201,6 +1279,7 @@ class InferenceServer:
       skipped = self._publishes_skipped
       peak = self._inflight_peak
       recoveries = self._chain_recoveries
+      state_resets = self._state_resets
       acquires = self._acquires
       admission_waits = self._admission_waits
       sheds = self._sheds
@@ -1244,6 +1323,14 @@ class InferenceServer:
         'inflight_peak': peak,
         'chain_recoveries': recoveries,
         'slots_free': self.slots_free() if self._state_cache else None,
+        # The recurrent state (PR 27): one session's bytes, the whole
+        # arena's (0 in carry-passing mode), and how often a state was
+        # zeroed: a `done` row of a merged call, or a slot cleared on
+        # acquire or by an actor's priming undo.
+        'state_bytes_per_slot': self._state_bytes,
+        'arena_bytes': (_tree_nbytes(self._arena_now())
+                        if self._state_cache else 0),
+        'state_resets': state_resets,
         # Admission/overload telemetry (round 9): the shed fraction is
         # sheds / acquires — the serving-plane overload SLO number.
         'admission': admission,
@@ -1390,9 +1477,9 @@ class InferenceServer:
         lambda l: jax.ShapeDtypeStruct(np.shape(l), l.dtype), params)
     arena_sds = ()
     if self._state_cache:
-      with self._arena_lock:
-        arena_sds = tuple(
-            jax.ShapeDtypeStruct(a.shape, a.dtype) for a in self._arena)
+      arena_sds = (jax.tree_util.tree_map(
+          lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+          self._arena_now()),)
     for padded in buckets:
       cache_key = (padded, fingerprint)
       with self._aot_lock:
@@ -1593,12 +1680,11 @@ class InferenceServer:
     park, one wake, one copy of the results), never split across
     merged calls. Row by row the two forms compute the same.
 
-    Carry-passing mode: core_state is the numeric (c, h) carry,
-    `[1, H]` each (`[k, H]` for k rows), and the new carry rides the
-    wire back. State-cache mode: core_state is a `_SlotHandle` (a list
+    Carry-passing mode: core_state is the numeric carry, a pytree of
+    `[1, ...]` leaves (`[k, ...]` for k rows; the LSTM's `(c, h)`),
+    and the new carry rides the wire back. State-cache mode: core_state is a `_SlotHandle` (a list
     of k for k rows) and only the slot ids ride the wire — the carries
     advance in-graph on the device."""
-    frame, instr = env_output.observation
     grouped = np.ndim(prev_action) > 0
 
     def rows(x, dtype=None):
@@ -1609,8 +1695,7 @@ class InferenceServer:
         rows(prev_action, np.int32),
         rows(env_output.reward, np.float32),
         rows(env_output.done, bool),
-        rows(frame),
-        rows(instr)]
+        *[rows(leaf) for leaf in env_output.observation]]
     if self._state_cache:
       handles = core_state if grouped else [core_state]
       for handle in handles:
@@ -1627,16 +1712,16 @@ class InferenceServer:
       inputs.insert(0, np.asarray([h.slot for h in handles], np.int32))
       new_state = core_state
     else:
-      core_c, core_h = core_state
-      inputs += [np.asarray(core_c, np.float32),
-                 np.asarray(core_h, np.float32)]
+      inputs += [np.asarray(leaf, dtype) for leaf, dtype in zip(
+          jax.tree_util.tree_leaves(core_state), self._state_dtypes)]
     # No lock for a counter on every caller's path: a count lost to
     # two callers' race is within what stats() promises of it.
     self._batcher_requests += 1
     with telemetry.span('batcher/compute'):
       outs = self._batcher.compute(inputs)
     if not self._state_cache:
-      new_state = tuple(outs[3:])
+      new_state = jax.tree_util.tree_unflatten(self._state_treedef,
+                                               outs[3:])
     action, logits, baseline = outs[:3]
     if not grouped:
       action, logits, baseline = action[0], logits[0], baseline[0]

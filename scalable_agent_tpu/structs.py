@@ -10,6 +10,7 @@ boundary and jit untouched.
 from typing import NamedTuple, Any
 
 import jax.numpy as jnp
+import numpy as np
 
 
 class StepOutputInfo(NamedTuple):
@@ -28,13 +29,19 @@ class StepOutput(NamedTuple):
   reward: Any       # f32 []
   info: Any         # StepOutputInfo
   done: Any         # bool []
-  observation: Any  # (frame uint8 [H, W, 3], instruction ids int32 [L])
+  observation: Any  # a tuple of arrays, as the agent's
+                    # `observation_names` has them: (frame uint8
+                    # [H, W, 3], instruction ids int32 [L]), or
+                    # (token int32 [],) for a sequence policy
 
 
 class AgentOutput(NamedTuple):
   """One agent step (reference: experiment.py ≈L55)."""
   action: Any         # i32 [] — sampled (actor) or argmax (learner unroll)
-  policy_logits: Any  # f32 [num_actions]
+  policy_logits: Any  # f32 [num_actions]; or f32 [], the action's
+                      # log-probability, from an agent that keeps
+                      # the logits of a large action space on the
+                      # device (models/sequence.py): told by its shape
   baseline: Any       # f32 []
 
 
@@ -56,3 +63,15 @@ def zeros_like_spec(spec):
   import jax
   return jax.tree_util.tree_map(
       lambda s: jnp.zeros(s.shape, s.dtype), spec)
+
+
+def observation_leaves(obs_spec):
+  """((shape, dtype), ...) of one observation, in the order of the
+  agent's `observation_names`, from an env's `obs_spec`: its 'leaves'
+  where it states them, else the image contract's 'frame' (H, W, C)
+  uint8 and 'instr_len' L int32."""
+  if 'leaves' in obs_spec:
+    return tuple((tuple(shape), np.dtype(dtype))
+                 for shape, dtype in obs_spec['leaves'])
+  return ((tuple(obs_spec['frame']), np.dtype(np.uint8)),
+          ((int(obs_spec['instr_len']),), np.dtype(np.int32)))

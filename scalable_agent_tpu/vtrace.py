@@ -70,9 +70,18 @@ def from_logits(behaviour_policy_logits, target_policy_logits, actions,
   the clipped surrogate's pi_theta/pi_target ratio is built from.
   Nothing differentiates through this function's outputs either way
   (vs/pg_advantages are stop-gradient'ed below).
+
+  `behaviour_policy_logits` of the ACTIONS' rank ([T, B]) are the
+  actions' log-probabilities under the behaviour policy, log mu(a):
+  what an agent records whose logits stay on the device
+  (structs.AgentOutput). The importance weights need nothing more.
   """
-  behaviour_action_log_probs = log_probs_from_logits_and_actions(
-      behaviour_policy_logits, actions)
+  if jnp.ndim(behaviour_policy_logits) == jnp.ndim(actions):
+    behaviour_action_log_probs = jnp.asarray(behaviour_policy_logits,
+                                             jnp.float32)
+  else:
+    behaviour_action_log_probs = log_probs_from_logits_and_actions(
+        behaviour_policy_logits, actions)
   target_action_log_probs = log_probs_from_logits_and_actions(
       target_policy_logits, actions)
   log_rhos = target_action_log_probs - behaviour_action_log_probs

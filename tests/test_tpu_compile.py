@@ -1,0 +1,117 @@
+"""The sequence policy's device programs compiled for the TPU v5e
+WITHOUT a chip (libtpu's compile-only topology): what the interpreter
+cannot show. The retention kernel at the published widths passes
+Mosaic (tiling, fast memory); the server's whole `cache_step` at the
+benchmark cell's sizes keeps ONE copy of the 4.5 GB state arena (the
+kernel's in-place update holds through the donated step) and fits the
+chip. Nothing runs: no result, no time.
+
+All compile-only tests live in this one file, the topology is described
+inside a fixture (never at import), and every compile happens in the
+test's own process (the guide's rules: one process holds libtpu)."""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from scalable_agent_tpu.models import SequenceAgent, init_params
+from scalable_agent_tpu.ops import retention_pallas
+from scalable_agent_tpu.structs import StepOutput
+
+WIDTHS = dict(num_actions=151936, num_layers=4, hidden_size=5120,
+              num_heads=40, num_kv_heads=8, head_dim=128, mlp_size=17408,
+              dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+SESSIONS = 32
+
+
+@pytest.fixture(scope='module')
+def one_chip():
+  os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+  from jax.experimental import topologies
+  try:
+    topo = topologies.get_topology_desc(platform='tpu',
+                                        topology_name='v5e:2x2')
+  except Exception as e:  # noqa: BLE001 — whatever libtpu raises
+    pytest.skip(f'no v5e:2x2 topology can be described here: {e}')
+  return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compiled_kernel(monkeypatch):
+  """`update_rows` asks jax.default_backend(), which is the CPU here,
+  and would trace the interpreter's form: steer it in the test."""
+  monkeypatch.setattr(retention_pallas, '_interpret_on',
+                      lambda platform: False)
+  # The persistent cache cannot read back what a compile-only
+  # topology wrote; keep these compiles out of it.
+  jax.config.update('jax_enable_compilation_cache', False)
+  yield
+  jax.config.update('jax_enable_compilation_cache', True)
+
+
+def _on(sharding, tree):
+  return jax.tree_util.tree_map(
+      lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+      tree)
+
+
+def test_retention_kernel_compiles_at_the_published_widths(
+    one_chip, compiled_kernel):
+  rows, kv, dv, dk, groups, b = SESSIONS + 1, 8, 128, 128, 5, SESSIONS
+  f32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+      shape, jnp.float32, sharding=one_chip)
+  step = jax.jit(retention_pallas.update_rows.__wrapped__,
+                 donate_argnums=(0,))
+  compiled = step.lower(
+      f32(rows, kv, dv, (dk // 2 + 1) * dk),
+      jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip),
+      f32(b, kv), f32(b, kv, groups, dk), f32(b, kv, dk),
+      f32(b, kv, dv)).compile()
+  memory = compiled.memory_analysis()
+  state_bytes = rows * kv * dv * (dk // 2 + 1) * dk * 4
+  # In place: the state is aliased to the output, nothing is copied.
+  assert memory.alias_size_in_bytes >= state_bytes
+  assert memory.temp_size_in_bytes < state_bytes // 100
+
+
+def test_cache_step_holds_one_arena_and_fits_the_chip(
+    one_chip, compiled_kernel):
+  agent = SequenceAgent(**WIDTHS)
+  params = jax.eval_shape(lambda: init_params(
+      agent, jax.random.PRNGKey(0), {'leaves': (((), 'int32'),)}))
+  arena = jax.eval_shape(lambda: agent.state_arena(SESSIONS))
+  key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+
+  def cache_step(params, key, arena, slot_ids, prev_action, reward, done,
+                 token):  # runtime/inference.py's, for this agent
+    key, sub = jax.random.split(key)
+    env_output = StepOutput(reward=reward[None], info=None,
+                            done=done[None], observation=(token[None],))
+    out, arena = agent.apply(params, prev_action[None], env_output, arena,
+                             sample_rng=sub, state_slots=slot_ids)
+    return key, arena, out.action[0], out.policy_logits[0], out.baseline[0]
+
+  row = lambda dtype: jax.ShapeDtypeStruct(  # noqa: E731
+      (SESSIONS,), dtype, sharding=one_chip)
+  compiled = jax.jit(cache_step, donate_argnums=(2,)).lower(
+      _on(one_chip, params), _on(one_chip, key), _on(one_chip, arena),
+      row(jnp.int32), row(jnp.int32), row(jnp.float32), row(jnp.bool_),
+      row(jnp.int32)).compile()
+  memory = compiled.memory_analysis()
+  arena_bytes = sum(l.size * l.dtype.itemsize
+                    for l in jax.tree_util.tree_leaves(arena))
+  param_bytes = sum(l.size * l.dtype.itemsize
+                    for l in jax.tree_util.tree_leaves(params))
+  assert arena_bytes > 4.3e9 and param_bytes > 5.7e9
+  # ONE copy of the arena: all of it aliased, and temporaries far
+  # under one layer's state (a gathered copy would be 1.1 GB a layer).
+  assert memory.alias_size_in_bytes >= arena_bytes
+  assert memory.temp_size_in_bytes < 0.3e9
+  total = (memory.argument_size_in_bytes + memory.temp_size_in_bytes +
+           memory.output_size_in_bytes - memory.alias_size_in_bytes)
+  assert total < 12e9  # of the chip's 16.9e9
+  assert compiled.as_text().count(retention_pallas.KERNEL_NAME) >= 4
