@@ -34,6 +34,7 @@ from scalable_agent_tpu import telemetry
 from scalable_agent_tpu import unreal
 from scalable_agent_tpu import vtrace
 from scalable_agent_tpu.config import Config
+from scalable_agent_tpu.parallel import sharding as sharding_lib
 from scalable_agent_tpu.structs import ActorOutput
 
 # Unified-registry telemetry (round 13): registered once at import —
@@ -115,9 +116,11 @@ def loss_fn(params, agent, batch: ActorOutput, config: Config,
   """Total IMPALA loss for one batch; returns (loss, (metrics, aux)).
 
   `mesh` is the sharded step's mesh (train_parallel passes it; None on
-  the single-device path). It only matters to the Pallas V-trace form,
-  which runs under shard_map over the mesh's data axis — pallas_call
-  has no SPMD partitioning rule of its own (vtrace.py).
+  the single-device path). The agent is told into how many shards the
+  mesh cuts the batch (`sharding.batch_shards`), so that its merged
+  [T*B] rows keep that sharding; and the Pallas V-trace form runs under
+  shard_map over the mesh's data axis — pallas_call has no SPMD
+  partitioning rule of its own (vtrace.py).
 
   With PopArt (popart_state not None): the agent's baseline is the
   NORMALIZED per-task value; V-trace runs on the unnormalized σ·n + μ,
@@ -145,17 +148,20 @@ def loss_fn(params, agent, batch: ActorOutput, config: Config,
   constants. None (every non-population caller) keeps the config's
   compile-time constant, bit-identical to before."""
   task_ids = jnp.asarray(batch.level_name).astype(jnp.int32)
+  batch_shards = sharding_lib.batch_shards(config, mesh)
   use_pc = config.pixel_control_cost > 0
   if use_pc:
     ((learner_outputs, _), mutables) = agent.apply(
         params, batch.agent_outputs.action, batch.env_outputs,
         batch.agent_state, level_ids=task_ids,
-        compute_pixel_control=True, mutable=['intermediates'])
+        compute_pixel_control=True, batch_shards=batch_shards,
+        mutable=['intermediates'])
     pc_q = mutables['intermediates']['pixel_control_q'][0]
   else:
     learner_outputs, _ = agent.apply(
         params, batch.agent_outputs.action, batch.env_outputs,
-        batch.agent_state, level_ids=task_ids)
+        batch.agent_state, level_ids=task_ids,
+        batch_shards=batch_shards)
 
   if popart_state is not None:
     normalized = learner_outputs.baseline  # [T+1, B]
@@ -177,7 +183,8 @@ def loss_fn(params, agent, batch: ActorOutput, config: Config,
     # any jvp reaching the anchor subtree.
     target_outputs, _ = agent.apply(
         target_params, batch.agent_outputs.action, batch.env_outputs,
-        batch.agent_state, level_ids=task_ids)
+        batch.agent_state, level_ids=task_ids,
+        batch_shards=batch_shards)
     target_outputs = jax.lax.stop_gradient(target_outputs)
     if popart_state is not None:
       # Unnormalize with the stats snapshotted AT the anchor's refresh

@@ -13,6 +13,12 @@ experiment.py ≈L85–210) for XLA:
   + `tf.where` (experiment.py ≈L195–205), which it comments precludes
   fused RNN kernels; the scan form compiles to a single fused XLA loop.
 - Heads (policy logits, baseline) again run over the merged [T*B] batch.
+- The merged rows lie shard-major (`parallel/sharding.merge_time_batch`):
+  with the batch cut into D shards on a mesh, shard d's T × B/D rows
+  are one contiguous block, time-major inside it, so the merged axis
+  keeps the batch's sharding and each device runs torso and heads over
+  its own rows. With D = 1 (one device, T = 1) that is the plain
+  time-major reshape. The core's scan and every output see [T, B].
 
 Inputs each step, matching the reference contract: `(last_action,
 StepOutput(reward, info, done, (frame, instruction_ids)))`. Rewards are
@@ -31,6 +37,8 @@ from scalable_agent_tpu.structs import AgentOutput, observation_leaves
 from scalable_agent_tpu.models import core as core_lib
 from scalable_agent_tpu.models.torsos import TORSOS
 from scalable_agent_tpu.models.instruction import InstructionEncoder
+from scalable_agent_tpu.parallel.sharding import (
+    merge_time_batch, split_time_batch)
 from scalable_agent_tpu.unreal import PixelControlHead
 
 
@@ -81,8 +89,14 @@ class ImpalaAgent(nn.Module):
   @nn.compact
   def __call__(self, prev_actions, env_outputs, core_state,
                sample_rng=None, level_ids=None,
-               compute_pixel_control=False, state_slots=None):
+               compute_pixel_control=False, state_slots=None,
+               batch_shards=1):
     """Unroll over a [T, B] trajectory.
+
+    Torso, instruction encoder and heads run over the merged [T*B]
+    axis, whose rows lie shard-major: row (d*T + t) * B/D + j is
+    [t, d*B/D + j] (D = `batch_shards`; D = 1: row t*B + b). Inputs and
+    outputs are [T, B, ...] in the caller's order whatever D is.
 
     Args:
       prev_actions: i32 [T, B] — action taken *before* each timestep.
@@ -103,6 +117,10 @@ class ImpalaAgent(nn.Module):
         cache; T must be 1): `core_state` is then the server's state
         ARENA, of which the rows `state_slots` are advanced and which
         is returned in place of the carry (models/core.py).
+      batch_shards: static int D, the number of shards the batch dim
+        is cut into on the mesh the caller's step was built with
+        (`parallel/sharding.batch_shards`); it changes where rows lie
+        in the merged axis and no value.
 
     Returns:
       (AgentOutput([T, B, ...]), final core_state).
@@ -118,19 +136,22 @@ class ImpalaAgent(nn.Module):
     # operation names, which the device trace's per-scope shares read:
     # Flax names its modules by class inside them, and not the scan's
     # own slicing or the reshapes around the heads.)
-    flat_frame = frame.reshape((t * b,) + frame.shape[2:])
+    merge = functools.partial(merge_time_batch, shards=batch_shards)
+    split = functools.partial(split_time_batch, t=t, b=b,
+                              shards=batch_shards)
+    flat_frame = merge(frame)
     with jax.named_scope('torso'):
       torso_out = TORSOS[self.torso](dtype=self.dtype)(flat_frame)
 
-    clipped_reward = jnp.clip(reward, -1.0, 1.0).reshape(t * b, 1)
+    clipped_reward = merge(jnp.clip(reward, -1.0, 1.0), trailing=(1,))
     one_hot_action = jax.nn.one_hot(
-        prev_actions.reshape(t * b), self.num_actions, dtype=torso_out.dtype)
+        merge(prev_actions), self.num_actions, dtype=torso_out.dtype)
     parts = [torso_out, clipped_reward.astype(torso_out.dtype),
              one_hot_action]
     if self.use_instruction:
-      flat_ids = instr_ids.reshape((t * b,) + instr_ids.shape[2:])
+      flat_ids = merge(instr_ids)
       parts.append(InstructionEncoder(dtype=self.dtype)(flat_ids))
-    core_input = jnp.concatenate(parts, axis=-1).reshape(t, b, -1)
+    core_input = split(jnp.concatenate(parts, axis=-1))
 
     # --- Recurrent core: scan over time with done-reset on the carry. ---
     core = self.core(name='_ResetCore_0')
@@ -148,7 +169,8 @@ class ImpalaAgent(nn.Module):
                                         done[0], slots=state_slots)
 
     # --- Heads over merged time+batch. ---
-    flat_core = core_out.reshape(t * b, -1)
+    # (The arena step is one step: its output is [B, hidden] already.)
+    flat_core = core_out if state_slots is not None else merge(core_out)
     if self.use_pixel_control and (compute_pixel_control or
                                    self.is_initializing()):
       cell = self.pixel_control_cell_size
@@ -158,17 +180,15 @@ class ImpalaAgent(nn.Module):
                               head_impl=self.pixel_control_head_impl,
                               out_f32=self.pixel_control_q_f32,
                               name='pixel_control')(flat_core)
-      self.sow('intermediates', 'pixel_control_q',
-               pc_q.reshape(t, b, hc, wc, self.num_actions))
+      self.sow('intermediates', 'pixel_control_q', split(pc_q))
     with jax.named_scope('heads'):
       policy_logits = nn.Dense(self.num_actions, dtype=self.dtype,
                                name='policy_logits')(flat_core)
       num_values = max(self.num_popart_tasks, 1)
       baseline = nn.Dense(num_values, dtype=self.dtype,
                           name='baseline')(flat_core)
-      policy_logits = policy_logits.astype(jnp.float32).reshape(
-          t, b, self.num_actions)
-      baseline = baseline.astype(jnp.float32).reshape(t, b, num_values)
+      policy_logits = split(policy_logits.astype(jnp.float32))
+      baseline = split(baseline.astype(jnp.float32))
       if self.num_popart_tasks:
         if level_ids is None:
           level_ids = jnp.zeros((b,), jnp.int32)

@@ -21,6 +21,7 @@ The model has no value head of its own: `baseline` is this system's
 `w.x + b` on the final norm's output [assumed].
 """
 
+import functools
 from typing import Any
 
 import flax.linen as nn
@@ -29,6 +30,8 @@ import jax.numpy as jnp
 
 from scalable_agent_tpu.models import core as core_lib
 from scalable_agent_tpu.models import retention
+from scalable_agent_tpu.parallel.sharding import (
+    merge_time_batch, split_time_batch)
 from scalable_agent_tpu.structs import AgentOutput
 
 
@@ -67,9 +70,11 @@ class SequenceAgent(nn.Module):
   @nn.compact
   def __call__(self, prev_actions, env_outputs, core_state,
                sample_rng=None, level_ids=None,
-               compute_pixel_control=False, state_slots=None):
+               compute_pixel_control=False, state_slots=None,
+               batch_shards=1):
     """Unroll over a [T, B] trajectory of tokens; see `ImpalaAgent` for
-    the arguments. With `state_slots` (i32 [B]; T must be 1)
+    the arguments (`batch_shards`: the heads' merged rows lie
+    shard-major, as there). With `state_slots` (i32 [B]; T must be 1)
     `core_state` is the server's state arena and is returned advanced
     in the rows `state_slots`."""
     del prev_actions, level_ids, compute_pixel_control  # the token says it
@@ -89,7 +94,11 @@ class SequenceAgent(nn.Module):
       assert t == 1, 'the arena form is one step'
       new_state, out = core.step(core_state, x[0], done[0],
                                  slots=state_slots)
-    flat = out.reshape(t * b, self.hidden_size)
+    split = functools.partial(split_time_batch, t=t, b=b,
+                              shards=batch_shards)
+    # (The arena step is one step: its output is [B, hidden] already.)
+    flat = (out if state_slots is not None
+            else merge_time_batch(out, batch_shards))
     with jax.named_scope('lm_head'):
       n = retention._Scale(self.param_dtype, name='final_norm')(
           flat, self.norm_eps)
@@ -100,14 +109,15 @@ class SequenceAgent(nn.Module):
       # bfloat16 pass unless told otherwise, and 5,120 terms are cheap.
       baseline = nn.Dense(1, dtype=jnp.float32, name='baseline',
                           precision=jax.lax.Precision.HIGHEST)(n)
-      baseline = baseline[:, 0].reshape(t, b)
+      baseline = split(baseline[:, 0])
     with jax.named_scope('sample'):
       if sample_rng is None:
         action = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        policy = logits.reshape(t, b, self.num_actions)
+        policy = split(logits)
       else:
         action = jax.random.categorical(
             sample_rng, logits, axis=-1).astype(jnp.int32)
         policy = (jnp.take_along_axis(logits, action[:, None], axis=-1)[
-            :, 0] - jax.nn.logsumexp(logits, axis=-1)).reshape(t, b)
-    return AgentOutput(action.reshape(t, b), policy, baseline), new_state
+            :, 0] - jax.nn.logsumexp(logits, axis=-1))
+        policy = split(policy)
+    return AgentOutput(split(action), policy, baseline), new_state

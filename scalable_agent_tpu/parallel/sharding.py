@@ -55,6 +55,7 @@ import re
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -351,7 +352,7 @@ class ShardingRegistry:
     the TP matmuls need the full data shard."""
     from scalable_agent_tpu.structs import ActorOutput
 
-    axes = (DATA_AXIS, MODEL_AXIS) if shard_over_model else DATA_AXIS
+    axes = _batch_axes(shard_over_model)
     traj = lambda _: P(None, axes)  # noqa: E731
     lead = lambda _: P(axes)        # noqa: E731
     return ActorOutput(
@@ -370,6 +371,23 @@ class ShardingRegistry:
         lambda s: NamedSharding(mesh, s),
         self.batch_specs(batch_pytree,
                          shard_over_model=shard_over_model))
+
+
+def _batch_axes(shard_over_model: bool):
+  """The mesh axes `batch_specs` puts on the batch dim."""
+  return (DATA_AXIS, MODEL_AXIS) if shard_over_model else (DATA_AXIS,)
+
+
+def batch_shards(config, mesh: Optional[Mesh]) -> int:
+  """D: into how many shards `batch_specs` cuts the batch dim on this
+  mesh: the data width, times the model width where
+  `shard_batch_over_model` holds; 1 with no mesh. The learner hands it
+  to the agent's `[T, B] -> [T*B]` merge (`merge_time_batch`)."""
+  if mesh is None:
+    return 1
+  return int(np.prod([
+      mesh.shape[axis]
+      for axis in _batch_axes(shard_batch_over_model(config))]))
 
 
 def _path_str(kp) -> str:
@@ -436,6 +454,50 @@ def data_sharding(mesh: Mesh) -> NamedSharding:
   """Leading-dim data-axis placement (inference batch rows, SDC probe
   vectors)."""
   return NamedSharding(mesh, P(DATA_AXIS))
+
+
+# --- row order of the merged [T*B] axis --------------------------------
+#
+# The agent runs its torso and heads over time merged into the batch.
+# In a plain `[T, B] -> [T*B]` reshape B is the MINOR factor of the
+# merged axis, so the rows of one batch shard lie strided through it
+# and no tiled sharding of `[T*B]` can name them: the partitioner
+# all-gathers, and every device computes every row. With the shards of
+# B outermost (`[T, D, B/D] -> [D, T, B/D] -> [D*T*B/D]`) a shard's
+# rows are one contiguous block, the merged axis stays sharded over the
+# batch axes, and nothing moves. Rows are independent in everything
+# that runs on the merged axis, so their order changes no value.
+
+
+def _rows_per_shard(b: int, shards: int) -> int:
+  if b % shards:
+    raise ValueError(f'batch {b} does not divide into {shards} shards')
+  return b // shards
+
+
+def merge_time_batch(x, shards: int = 1, trailing=None):
+  """`[T, B, ...] -> [T*B, ...]`, the `shards` shards of B outermost:
+  row `(d*T + t) * B/D + j` holds `x[t, d*B/D + j]`. `shards` = 1 and
+  T = 1 are the plain reshape. `trailing`: the result's shape after
+  its first axis where that is not `x.shape[2:]` (a [T, B] array
+  merged into a column)."""
+  t, b = x.shape[:2]
+  merged = (t * b,) + tuple(x.shape[2:] if trailing is None
+                            else trailing)
+  if shards == 1 or t == 1:
+    return x.reshape(merged)
+  x = x.reshape((t, shards, _rows_per_shard(b, shards)) + x.shape[2:])
+  return jnp.swapaxes(x, 0, 1).reshape(merged)
+
+
+def split_time_batch(y, t: int, b: int, shards: int = 1):
+  """The inverse of `merge_time_batch`: `[T*B, ...] -> [T, B, ...]` in
+  the caller's order."""
+  split = (t, b) + y.shape[1:]
+  if shards == 1 or t == 1:
+    return y.reshape(split)
+  y = y.reshape((shards, t, _rows_per_shard(b, shards)) + y.shape[1:])
+  return jnp.swapaxes(y, 0, 1).reshape(split)
 
 
 def quantized_specs(quantized_tree, plain_specs):

@@ -79,6 +79,15 @@ def make_sharded_train_step(agent, config: Config, mesh: Mesh,
   tests/test_parallel.py); __graft_entry__'s dryrun falls back to it
   so the parity gate still runs there.
 
+  The step carries how the batch is split as attributes:
+  `batch_shardings` (the shardings it is jitted with), `batch_shards`
+  (D, the number of shards of the batch dim: `sharding.batch_shards`)
+  and `rows_per_device` ((T+1)·B/D, the rows of the agent's merged
+  axis a device computes: the agent lays them shard-major so that the
+  merge keeps the sharding, `sharding.merge_time_batch`). That no
+  device computes more is in the compiled program, not in D
+  (tests/test_parallel.py reads it there).
+
   The mesh rides into the step fn (round 8): the Pallas V-trace has
   no SPMD partitioning rule, so under this jit it runs shard_map'ped
   over the data axis — the fused kernel is no longer single-device
@@ -182,10 +191,20 @@ def make_sharded_train_step(agent, config: Config, mesh: Mesh,
       step.donation_fallback = True
       return run_step(state, batch)
 
+  # The step's program for these arguments, not run (`.compile()`
+  # gives its text and memory: the tests and the compile-only fit).
+  step.lower = lambda state, batch: compiled['fn'].lower(state, batch)
   step.donation_fallback = False
   step.tp_gathered = bool(gathered_tp)
   # The shardings the step is jitted with: how the batch is split.
   step.batch_shardings = batch_shard
+  step.batch_shards = sharding_lib.batch_shards(config, mesh)
+  t1, b = example_batch.env_outputs.reward.shape
+  step.rows_per_device = t1 * b // step.batch_shards
+  log.info('sharded train step on mesh %s: batch %d in %d shards, '
+           '%d of the %d merged [T*B] rows on a device',
+           dict(mesh.shape), b, step.batch_shards,
+           step.rows_per_device, t1 * b)
 
   def _log_gathered():
     log.info(
