@@ -31,7 +31,8 @@ COPY scalable_agent_tpu/ scalable_agent_tpu/
 COPY tests/ tests/
 COPY scripts/ scripts/
 COPY docs/ docs/
-COPY experiment.py bench.py __graft_entry__.py README.md LICENSE ./
+COPY benchmark/ benchmark/
+COPY experiment.py chip_smoke.py BENCHMARK.json __graft_entry__.py README.md LICENSE ./
 
 # Native host batcher (ctypes; no TF/pybind dependency).
 RUN make -C scalable_agent_tpu/ops/batcher

@@ -25,12 +25,9 @@ from scalable_agent_tpu.config import Config
 _DEFAULTS = Config()
 
 flags.DEFINE_string('logdir', _DEFAULTS.logdir, 'Experiment directory.')
-flags.DEFINE_enum('mode', 'train', ['train', 'test', 'anakin'],
-                  'Run mode. mode=anakin is the LEGACY research loop '
-                  '(parallel/anakin.train: summaries + checkpoint '
-                  'only); production Anakin runs are '
-                  '--mode=train --runtime=anakin, which adds the full '
-                  'lifecycle (health ladder, SLO verdict, incidents).')
+flags.DEFINE_enum('mode', 'train', ['train', 'test'],
+                  'Run mode (the fused on-device loop is '
+                  '--mode=train --runtime=anakin).')
 flags.DEFINE_integer('test_num_episodes', _DEFAULTS.test_num_episodes,
                      'Episodes per level in test mode.')
 flags.DEFINE_integer('task', _DEFAULTS.task,
@@ -243,9 +240,8 @@ flags.DEFINE_bool('inference_state_cache',
                   'Keep each actor\'s LSTM carry in a device-resident '
                   'state arena (gather/scatter by slot id in-graph) '
                   'instead of shipping it host<->device every step. '
-                  'Numerics-identical (parity-gated); measured per '
-                  'round by bench.py inference_plane '
-                  '(docs/INFERENCE.md).')
+                  'Numerics-identical (parity-gated; '
+                  'docs/INFERENCE.md).')
 flags.DEFINE_integer('inference_pipeline_depth',
                      _DEFAULTS.inference_pipeline_depth,
                      'Merged inference batches in flight on device: '
@@ -314,7 +310,7 @@ flags.DEFINE_enum('pixel_control_head_impl',
                   'deconv (nn.ConvTranspose reference form, default) '
                   'or d2s (depth-to-space recast — parameter-'
                   'identical, checkpoint-interchangeable, parity-'
-                  'gated; measured per round by bench.py pc_levers).')
+                  'gated).')
 flags.DEFINE_bool('pixel_control_q_f32', _DEFAULTS.pixel_control_q_f32,
                   'Cast the pixel-control Q-map to float32 at the '
                   'head (default). False keeps it in the compute '
@@ -358,8 +354,7 @@ flags.DEFINE_enum('staging_mode', _DEFAULTS.staging_mode,
                   'device_put burst per step (default); unroll = '
                   'per-unroll eager H2D + on-device batch assembly '
                   '(the step-boundary burst becomes a trickle '
-                  'overlapped with compute — parity-gated, measured '
-                  'per round by bench.py learner_plane; docs/PERF.md '
+                  'overlapped with compute — parity-gated; docs/PERF.md '
                   'r8).')
 # --- Sample reuse (round 10; IMPACT arXiv 1912.00167 — docs/PERF.md
 # r9, RUNBOOK §5 knob guidance). ---
@@ -386,8 +381,7 @@ flags.DEFINE_integer('replay_k', _DEFAULTS.replay_k,
                      'Times each staged device batch is served to the '
                      'learner before release (no re-stage, no added '
                      'H2D). Default 1 = no reuse, per the measured '
-                     'accept/reject discipline — bench.py\'s replay '
-                     'stage carries the flip call.')
+                     'accept/reject discipline (docs/PERF.md r9).')
 flags.DEFINE_float('replay_ratio', _DEFAULTS.replay_ratio,
                    'Fraction of each batch\'s unroll slots drawn from '
                    'the circular replay tier ([0, 1); 0 = off). '
@@ -734,8 +728,8 @@ def main(argv):
   # platform's kill escalation arriving before the drain finished)
   # falls back to the old raise-through-finally path; a third is
   # ignored so it cannot abort the final save. Only the train loop
-  # consumes the drain event — every other mode (actor host, anakin,
-  # eval) keeps the old first-SIGTERM-raises behavior, or its one
+  # consumes the drain event — every other mode (actor host, eval)
+  # keeps the old first-SIGTERM-raises behavior, or its one
   # graceful shot would be absorbed by an event nobody reads.
   import signal
   import threading
@@ -762,15 +756,6 @@ def main(argv):
         '--job_name=actor does not join jax.distributed (actor hosts '
         'feed over --learner_address TCP ingest); drop '
         '--coordinator_address on actor hosts')
-  if cfg.coordinator_address and cfg.mode == 'anakin':
-    # The legacy research loop never calls driver.train, so the
-    # coordinator flags would be silently ignored and every host
-    # would train an independent replica — the process_count guard
-    # below can't catch it because nothing ever joins.
-    raise app.UsageError(
-        '--mode=anakin is the single-host legacy loop and does not '
-        'join jax.distributed; drop the coordinator flags (multi-host '
-        'runs use --mode=train)')
   if cfg.job_name == 'actor':
     # Actor-only host: no TPU, no learner — stream unrolls to the
     # learner's ingest server (reference ≈L625 actor loop).
@@ -793,29 +778,6 @@ def main(argv):
     drain_supported.set()
     run = driver.train(cfg, drain_event=drain_event)
     logging.info('training done at %d frames', run.frames)
-  elif cfg.mode == 'anakin':
-    import jax
-    from scalable_agent_tpu.parallel import anakin
-    if jax.process_count() > 1:
-      # Anakin is single-host by design: there is no cross-host batch
-      # transport in the fused loop, so each process would train an
-      # independent, never-synchronized replica (the failure
-      # driver.choose_mesh refuses for multi-host too).
-      raise app.UsageError('--mode=anakin is single-host; use '
-                           '--mode=train for the multi-host pipeline')
-    if cfg.model_parallelism > 1:
-      # Anakin shards only the data axis (init_carry); a TP mesh would
-      # silently replicate identical compute across the model axis.
-      raise app.UsageError('--mode=anakin is data-parallel only; drop '
-                           '--model_parallelism')
-    # Same mesh policy as driver.train (ADVICE r4: a v5e-8 pod slice
-    # must not silently train on one chip): all local devices,
-    # model_parallelism honored, warn-and-fallback to single-device
-    # when the batch cannot shard.
-    carry = anakin.train(cfg, mesh=driver.choose_mesh(cfg))
-    logging.info('anakin training done at %d frames',
-                 int(carry.train_state.update_steps) *
-                 cfg.frames_per_step)
   else:
     driver.evaluate(cfg)
 

@@ -509,22 +509,29 @@ SUMMARY_BLOCK_BEGIN = '<!-- lint:summary-scalars:begin -->'
 SUMMARY_BLOCK_END = '<!-- lint:summary-scalars:end -->'
 
 
+# The files whose `.scalar(...)` calls are the driver's summary block:
+# driver.py and the run lifecycle its loops share.
+_SUMMARY_SOURCES = ('scalable_agent_tpu/driver.py',
+                    'scalable_agent_tpu/lifecycle.py')
+
+
 def driver_summary_tags(ctx: CheckContext) -> Dict[str, int]:
   """Literal summary-scalar tags the driver writes: first args of
-  `.scalar(tag, value, step)` calls in driver.py — direct literals
-  plus names bound by a `for tag in (<literal tuple>)` loop (the
-  replay-stats export shape). Fully dynamic tags (per-level episode
-  tags, tracer percentile dicts, stacked step metrics) are outside
-  the static contract and documented in prose instead."""
-  tree = ctx.tree('scalable_agent_tpu/driver.py')
+  `.scalar(tag, value, step)` calls in driver.py and lifecycle.py —
+  direct literals plus names bound by a `for tag in (<literal tuple>)`
+  loop (the replay-stats export shape). Fully dynamic tags (per-level
+  episode tags, tracer percentile dicts, stacked step metrics) are
+  outside the static contract and documented in prose instead."""
+  nodes = [node for rel in _SUMMARY_SOURCES
+           for node in ast.walk(ctx.tree(rel))]
   loop_names: Dict[str, List[str]] = {}
-  for node in ast.walk(tree):
+  for node in nodes:
     if (isinstance(node, ast.For) and isinstance(node.target, ast.Name)):
       vals = _str_tuple(node.iter)
       if vals:
         loop_names.setdefault(node.target.id, []).extend(vals)
   tags: Dict[str, int] = {}
-  for node in ast.walk(tree):
+  for node in nodes:
     if (isinstance(node, ast.Call)
         and isinstance(node.func, ast.Attribute)
         and node.func.attr == 'scalar' and node.args):
@@ -549,8 +556,8 @@ def documented_summary_tags(ctx: CheckContext) -> Set[str]:
 
 
 @checker('summary-scalars',
-         'every literal summary-scalar tag driver.py writes appears '
-         'in the generated docs/OBSERVABILITY.md inventory block '
+         'every literal summary-scalar tag driver.py or lifecycle.py '
+         'writes appears in the generated docs/OBSERVABILITY.md block '
          '(scripts/lint.py --fix-docs regenerates it), and no '
          'documented tag is orphaned')
 def check_summary_scalars(ctx: CheckContext) -> List[Finding]:
@@ -671,12 +678,11 @@ def check_sharding_registry(ctx: CheckContext) -> List[Finding]:
   deliberately out of scope (they construct expected specs to assert
   the registry against)."""
   sources = ctx.package_sources()
-  for extra in ('experiment.py', 'bench.py'):
-    try:
-      ctx.text(extra)
-      sources.append(extra)
-    except (FileNotFoundError, OSError):
-      pass
+  try:
+    ctx.text('experiment.py')
+    sources.append('experiment.py')
+  except (FileNotFoundError, OSError):
+    pass
   try:
     sources.extend(ctx.package_sources('scripts'))
   except (FileNotFoundError, OSError):

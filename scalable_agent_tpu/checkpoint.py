@@ -16,6 +16,9 @@ and restores to the same placements when given the live state as the
 abstract target.
 """
 
+import contextlib
+import functools
+import importlib.metadata
 import json
 import logging
 import os
@@ -24,7 +27,27 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 
-import orbax.checkpoint as ocp
+
+@contextlib.contextmanager
+def _one_scan_of_installed_files():
+  """Orbax imports google.cloud.logging, and each google.* package it
+  pulls in runs api_core's `check_python_version` as it is imported:
+  every call asks `importlib.metadata.packages_distributions()`, which
+  stats every file of every installed distribution. On the chip
+  machine's file system that is about 10 s a call, twice, before a run
+  has built anything (root PERF.md section 6, PR 30: found in the
+  main thread's stack samples of set-up). The answer cannot change
+  between two imports of one statement, so it is computed once."""
+  real = importlib.metadata.packages_distributions
+  importlib.metadata.packages_distributions = functools.cache(real)
+  try:
+    yield
+  finally:
+    importlib.metadata.packages_distributions = real
+
+
+with _one_scan_of_installed_files():
+  import orbax.checkpoint as ocp
 
 from scalable_agent_tpu import integrity
 from scalable_agent_tpu.learner import TrainState

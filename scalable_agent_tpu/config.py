@@ -147,8 +147,9 @@ class Config:
   pixel_control_cell_size: int = 4
   # --- Pixel-control fast path (round 6, docs/PERF.md itemization).
   # Three candidate levers, each parity-gated (tests/test_unreal.py)
-  # and measured head-to-head by bench.py's `pc_levers` stage every
-  # round. DEFAULTS STAY AT THE r5 REFERENCE FORMS: per the repo's
+  # and never measured on a chip (docs/PERF.md, "Defaults did NOT
+  # flip this round").
+  # DEFAULTS STAY AT THE r5 REFERENCE FORMS: per the repo's
   # measured accept/reject discipline a default only flips on CHIP
   # numbers, and the round-6 build host had no chip — the CPU-backend
   # compile evidence (scripts/attribute_bytes.py) actually favors the
@@ -210,9 +211,8 @@ class Config:
   # (golden parity gate, tests/test_runtime.py — done edges, respawn
   # slot reuse, sharded eval). DEFAULT OFF pending chip rows: per the
   # repo's measured accept/reject discipline a default only flips on
-  # chip numbers, and bench.py's inference_plane stage measures
-  # cache×depth head-to-head every round so BENCH_rN carries the
-  # call (this build host's CPU rows are recorded in docs/PERF.md r7).
+  # chip numbers (the build host's CPU rows of cache×depth are
+  # recorded in docs/PERF.md r7).
   inference_state_cache: bool = False
   # Dispatched-but-uncompleted merged inference batches allowed in
   # flight (the actor-plane mirror of staging_depth): 2 lets merged
@@ -280,9 +280,9 @@ class Config:
   #   placement cannot serve (model-axis batch sharding, indivisible
   #   local batch — parallel/train_parallel.supports_unroll_staging).
   # DEFAULT STAYS 'batch' per the repo's measured accept/reject
-  # discipline: bench.py's `learner_plane` stage measures both modes
-  # × staging_depth head-to-head every round (exposed H2D ms/step,
-  # stack_ms, step gap), so BENCH_r08's chip rows carry the flip call.
+  # discipline: both modes × staging_depth were measured head-to-head
+  # (exposed H2D ms/step, stack_ms, step gap: docs/PERF.md r8); the
+  # flip call waits for chip rows.
   staging_mode: str = 'batch'            # batch | unroll
   # --- Sample reuse (round 10; IMPACT, arXiv 1912.00167 —
   # docs/PERF.md r9). The e2e bench shows the actor/env plane bounding
@@ -315,9 +315,8 @@ class Config:
   # AS IS — no re-stage, no additional H2D traffic — so K updates ride
   # one transfer; episode stats/frame counters only count the first
   # serve. DEFAULT 1 (no reuse) per the measured accept/reject
-  # discipline: bench.py's `replay` stage measures step_ms and
-  # learner-updates/env-frame across replay_k x replay_ratio every
-  # round, and the cue_memory return-vs-wallclock artifact carries
+  # discipline: step_ms and learner-updates/env-frame were measured
+  # across replay_k x replay_ratio (docs/PERF.md r9), and the cue_memory return-vs-wallclock artifact carries
   # the flip call.
   replay_k: int = 1
   # Fraction of each batch's unroll slots drawn from the circular
@@ -411,9 +410,8 @@ class Config:
   # negotiated per connection at hello (v5/v6 peers: off). A corrupt
   # unroll is refused BEFORE the buffer put ('corrupt' reply — the
   # client re-sends once, then quarantines itself); param blobs are
-  # trailer-checked by the fetching client. Overhead is measured by
-  # bench.py's transport stage (CRC on/off rows; <5% frames/s on the
-  # build host, docs/PERF.md r10).
+  # trailer-checked by the fetching client. Overhead was measured with
+  # CRC on and off (<5% frames/s on the build host, docs/PERF.md r10).
   wire_crc: bool = True
   # Verified checkpoint saves record a per-file content digest
   # (DIGEST_<step>.json + the LAST_GOOD manifest); the restore ladder
@@ -450,8 +448,8 @@ class Config:
   # policy-lag vector) and scripts/trace_report.py reconstructs
   # per-hop latency + the lag distribution. Negotiated on the wire
   # (protocol v8) — older peers simply don't stamp. Default ON: the
-  # bench.py `telemetry` stage measured the overhead below run-to-run
-  # noise (docs/PERF.md r11 records the accept call); False turns off
+  # overhead measured below run-to-run noise with tracing on and off
+  # (docs/PERF.md r11 records the accept call); False turns off
   # stamping, the tracer, and the traces.jsonl stream.
   telemetry_trace: bool = True
   # Flight-recorder depth: the most recent N trace records (batches /
@@ -465,8 +463,7 @@ class Config:
   # over the metrics registry, evaluated continuously on fast/slow
   # burn windows, with the per-run SLO_VERDICT.json go/no-go artifact
   # and triggered deep diagnostics on page-severity burns. Default ON:
-  # the bench.py `slo` stage measured the evaluator tick sub-
-  # millisecond, paid once per cadence interval off the hot loop
+  # the evaluator tick measured sub-millisecond, paid once per cadence interval off the hot loop
   # (docs/PERF.md r12 records the accept call); False removes the
   # thread, the verdict, and the captures entirely. ---
   slo_engine: bool = True
@@ -507,7 +504,7 @@ class Config:
   # and nothing is touched; 'act' applies them (replay_k, admission
   # mode, remote publish cadence, fleet size); 'off' removes the
   # thread and the log. The acceptance drill is
-  # CHAOS_STORM=controller; cost is bench.py's `controller` stage. ---
+  # CHAOS_STORM=controller (scripts/chaos.py). ---
   controller: str = 'observe'             # off | observe | act
   # Policy table: '' = controller.DEFAULT_RULES (the table in
   # docs/OBSERVABILITY.md); a path loads a JSON rule list. A rule
@@ -559,9 +556,8 @@ class Config:
   # on the fleet's fresh-frame clock (filler work is accounted
   # separately: filler_updates/filler_frames summaries + the
   # driver/filler_updates registry counter). DEFAULT OFF per the
-  # measured accept/reject discipline: bench.py's `anakin` stage
-  # measures the hybrid row every round and docs/PERF.md r13 records
-  # the call.
+  # measured accept/reject discipline: docs/PERF.md r13 records the
+  # hybrid row and the call.
   anakin_filler: bool = False
   # Filler env core: '' = auto (env_backend itself when jittable,
   # else 'bandit' — which accepts the main task's action-space width).
@@ -632,8 +628,8 @@ class Config:
   # publish/warmup time (the jit lower/compile AOT seam) so a version
   # flip or warmed bucket never pays first-call compile on the serve
   # path. DEFAULT OFF pending chip rows per the docs/PERF.md
-  # accept/reject discipline (bench.py serving stage measures the
-  # flip-blackout delta every round).
+  # accept/reject discipline (the flip-blackout delta is the number
+  # to measure).
   serving_aot: bool = False
   # Comma-separated learner replica addresses ('host:port,...') an
   # actor host routes inference over (runtime/routing.py: health-
@@ -649,9 +645,8 @@ class Config:
   # arXiv 2010.03934); 'td' EMAs |TD error|. Sampler and score update
   # both live INSIDE the fused device step — zero host round trips
   # per level decision. DEFAULT stays 'uniform' per the measured
-  # accept/reject discipline: bench.py's population stage measures
-  # the curriculum fps delta every round, and the regret default flip
-  # is parked in ROADMAP housekeeping (b) pending chip rows.
+  # accept/reject discipline: the curriculum's fps delta is the
+  # number to measure, and the regret default flip is parked in ROADMAP housekeeping (b) pending chip rows.
   curriculum: str = 'uniform'             # uniform | regret | td
   curriculum_temperature: float = 1.0     # score-softmax temperature
   curriculum_eps: float = 0.1             # uniform mixing floor — every
@@ -732,8 +727,8 @@ class Config:
     """The ingest server's wire_dtype from the codec knobs: the
     legacy `remote_params_dtype` (non-empty) wins, else
     `publish_codec` ('bf16' → 'bfloat16', 'f32' → exact float32).
-    Resolved here so the driver, the remote-actor role, and bench.py
-    can never disagree on the production default."""
+    Resolved here so the driver and the remote-actor role can never
+    disagree on the production default."""
     if self.remote_params_dtype:
       return self.remote_params_dtype
     if self.publish_codec == 'bf16':
@@ -842,9 +837,9 @@ class Config:
 def validate_replay(config: Config) -> List[str]:
   """Validate the sample-reuse knob group (round 10); raises
   ValueError on hard errors, returns human-readable warnings for the
-  caller to log (config.py has no logger; driver.train and bench.py
-  both call this before spin-up so a bad knob combination fails
-  before any env/checkpoint cost).
+  caller to log (config.py has no logger; driver.train calls this
+  before spin-up so a bad knob combination fails before any
+  env/checkpoint cost).
 
   The staleness cross-link (the round-10 unit unification): both
   `max_unroll_staleness` (ingest admission) and `replay_max_staleness`

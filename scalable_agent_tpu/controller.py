@@ -59,8 +59,8 @@ The acceptance drill is `scripts/chaos.py run_controller_storm`:
 offered load doubles mid-run, the actuated run's SLO_VERDICT.json
 stays green with the escalation and the later revert in the action
 log, and the same storm under `observe` records the violation the
-actuated run avoided. Cost: bench.py's `controller` stage prices the
-tick.
+actuated run avoided. What a tick costs is not measured on the chip:
+every benchmark cell runs with `controller: off`.
 
 No jax imports here (the slo.py rule): the controller must be
 importable by scripts and tests without accelerator initialization.

@@ -29,7 +29,6 @@ import json
 import logging
 import os
 import shutil
-import sys
 import threading
 import time
 from typing import Dict, List, Optional
@@ -42,11 +41,10 @@ from jax.experimental import multihost_utils
 
 from scalable_agent_tpu import checkpoint as checkpoint_lib
 from scalable_agent_tpu import controller as controller_lib
-from scalable_agent_tpu import health as health_lib
 from scalable_agent_tpu import learner as learner_lib
+from scalable_agent_tpu import lifecycle
 from scalable_agent_tpu import observability
 from scalable_agent_tpu import population as population_lib
-from scalable_agent_tpu import slo as slo_lib
 from scalable_agent_tpu import telemetry
 from scalable_agent_tpu.analysis import runtime as lock_check
 from scalable_agent_tpu.config import (Config, validate_controller,
@@ -96,28 +94,6 @@ def _write_resume_manifest(logdir: str, manifest: Dict) -> str:
     json.dump(manifest, f, indent=2, sort_keys=True)
   os.replace(tmp, path)
   return path
-
-
-def _record_run(config: Config, write: bool = True) -> None:
-  """Reproducibility: the exact config of every run and the device it
-  ran on live next to its checkpoints/summaries (the reference leaves
-  flags only in shell history). The device is also one log line, so a
-  run that came up on another platform than intended says so at
-  start-up. `write=False` logs only (multi-host: process 0 owns the
-  files)."""
-  devices = jax.devices()
-  device = {'platform': devices[0].platform,
-            'device_kind': devices[0].device_kind,
-            'device_count': len(devices)}
-  log.info('running on platform=%s device_kind=%s device_count=%d',
-           device['platform'], device['device_kind'],
-           device['device_count'])
-  if not write:
-    return
-  for name, payload in (('config.json', dataclasses.asdict(config)),
-                        ('device.json', device)):
-    with open(os.path.join(config.logdir, name), 'w') as f:
-      json.dump(payload, f, indent=2, sort_keys=True)
 
 
 def _stats_only_view(level_name, info, done):
@@ -351,11 +327,11 @@ def train(config: Config, max_steps: Optional[int] = None,
   / max_seconds — timed smoke and bench runs).
 
   `fleet_factory(config, agent, policy, buffer, levels)` replaces
-  make_fleet when given — bench.py's fed-learner stage injects a
-  synthetic producer fleet here so THIS loop (stats extraction,
-  publish cadence, summaries, health checks) can be measured at full
-  feed rate without env/inference cost (VERDICT r4 #3). Production
-  always uses the default.
+  make_fleet when given: tests inject a synthetic producer fleet so
+  THIS loop (stats extraction, publish cadence, summaries, health
+  checks) runs at full feed rate without env/inference cost (VERDICT
+  r4 #3); benchmark/drivers/train_loop.py wraps make_fleet to watch
+  the children. Production always uses the default.
 
   `drain_event` is the preemption seam (experiment.py sets it from
   SIGTERM; the 'preempt_signal' fault site fires it deterministically
@@ -546,11 +522,6 @@ def train(config: Config, max_steps: Optional[int] = None,
 
   # --- Checkpoint restore (reference: MonitoredTrainingSession auto-
   # restore from --logdir, ≈L570). ---
-  checkpointer = checkpoint_lib.Checkpointer(
-      config.logdir + '/checkpoints',
-      save_interval_secs=config.checkpoint_secs,
-      verify_digests=config.ckpt_digests,
-      registry=registry, mesh=mesh)
   # Elastic restore gate (round 20, elastic membership): when the
   # newest step's sharding manifest records a DIFFERENT mesh than this
   # run's (a 2-process checkpoint under a 4-process restart, or vice
@@ -560,33 +531,28 @@ def train(config: Config, max_steps: Optional[int] = None,
   # implicit same-topology pinning. Fixed-topology restores take the
   # unchanged restore_latest path (docs/MIGRATION.md).
   elastic_restore = None
-  try:
+
+  def _restore(checkpointer, state):
+    nonlocal elastic_restore
     topo_delta = (distributed.topology_delta(
         checkpointer.saved_mesh_shape(), mesh)
                   if mesh is not None else None)
-    if topo_delta is not None:
-      log.warning(
-          'cross-topology restore: checkpoint saved on mesh %s, this '
-          'run is mesh %s (%d process(es)) — resharding onto registry '
-          'targets for the live topology', topo_delta['saved_mesh'],
-          topo_delta['live_mesh'], topo_delta['processes'])
-      abstract = jax.tree_util.tree_map(
-          lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state)
-      restored = checkpointer.restore_resharded(abstract, registry,
-                                                mesh)
-      if restored is not None:
-        elastic_restore = topo_delta
-    else:
-      restored = checkpointer.restore_latest(state)
-  except BaseException:
-    # A structure-mismatch raise must not leak the manager (its
-    # background threads survive a same-process retry).
-    checkpointer.close()
-    raise
-  if restored is not None:
-    state = restored
-    log.info('restored checkpoint at step %d',
-             int(jax.device_get(state.update_steps)))
+    if topo_delta is None:
+      return checkpointer.restore_latest(state)
+    log.warning(
+        'cross-topology restore: checkpoint saved on mesh %s, this '
+        'run is mesh %s (%d process(es)) — resharding onto registry '
+        'targets for the live topology', topo_delta['saved_mesh'],
+        topo_delta['live_mesh'], topo_delta['processes'])
+    abstract = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state)
+    restored = checkpointer.restore_resharded(abstract, registry, mesh)
+    if restored is not None:
+      elastic_restore = topo_delta
+    return restored
+
+  checkpointer, state = lifecycle.restore_at_start(
+      config, state, mesh=mesh, registry=registry, restore=_restore)
   # Host-side step/frame mirror: the loop must not device_get the
   # on-device counter every iteration (that would sync the async
   # dispatch pipeline each step).
@@ -669,10 +635,8 @@ def train(config: Config, max_steps: Optional[int] = None,
   server = None
   fleet = None
   prefetcher = None
-  writer = None
-  incidents = None
+  life = None
   tracer = None
-  slo_engine = None
   ctrl = None
   filler = None
   # The remote-publish cadence as a mutable cell (round 15): the loop
@@ -899,32 +863,6 @@ def train(config: Config, max_steps: Optional[int] = None,
                 prefetcher.fresh_slots_served() *
                 frames_per_unroll * hosts_scale)
 
-    # Multi-host: every host logs its OWN fleet's stream; process 0
-    # keeps the canonical filename (shared logdirs must not interleave
-    # writers).
-    summary_name = ('summaries.jsonl' if process_index == 0
-                    else f'summaries_p{process_index}.jsonl')
-    writer = observability.SummaryWriter(config.logdir,
-                                         filename=summary_name)
-    # Structured incident stream (observability.EventLog): bad-step
-    # bursts, rollbacks, halts, fault injections — what the scalar
-    # summaries can't narrate. chaos.py reads this for its SLOs.
-    incidents = observability.EventLog(
-        config.logdir,
-        filename=('incidents.jsonl' if process_index == 0
-                  else f'incidents_p{process_index}.jsonl'))
-    # Lock-order detections land as DURABLE lock_order_inversion
-    # incidents (round 18): a latent ABBA deadlock found by a storm
-    # must survive whatever crash follows it. Armed or not, wiring
-    # the sink is free; the finally clears it (the bound method keeps
-    # this run's incident stream referenced).
-    lock_check.set_incident_sink(incidents.event)
-    # The elastic restore above predates this stream — announce it
-    # here so the topology change is on the incident record, not just
-    # in the log (round 20).
-    if elastic_restore is not None:
-      incidents.event('topology_resharded', step=_initial_steps,
-                      **elastic_restore)
     # Telemetry plane (round 13, telemetry.py): the pipeline tracer
     # completes per-unroll trace spans (actor → wire → ingest →
     # staging → serve → step) into traces.jsonl and keeps the flight
@@ -939,54 +877,85 @@ def train(config: Config, max_steps: Optional[int] = None,
           flight_capacity=config.telemetry_flight_len,
           epoch=(ingest.session_epoch if ingest is not None else None))
       telemetry.set_tracer(tracer)
-    _record_run(config, write=process_index == 0)
+
+    def _sdc_dispatch(step_now, state):
+      # SDC fingerprints ride the ladder's delayed-read cadence: the
+      # [replicas] uint32 array is dispatched NOW (before the next
+      # step donates the state) and read one check later. The
+      # 'replica_divergence' fault site fires here — one event per
+      # health check — perturbing one replica's probe lane so the
+      # real detection→rollback path executes.
+      probe = np.zeros((sdc_replicas,), np.uint32)
+      div = faults_lib.fire('replica_divergence')
+      if div is not None:
+        victim = div.index % sdc_replicas
+        probe[victim] = np.uint32(1 + (div.index % 1000))
+        incidents.event('fault_replica_divergence', step=step_now,
+                        replica=victim)
+      return sdc_fp_fn(state.params, probe)
+
+    def _sdc_read(obs_step, fp_handle):
+      fps = np.asarray(jax.device_get(fp_handle))
+      sdc_mismatch = bool((fps != fps[0]).any())
+      if sdc_mismatch:
+        incidents.event('sdc_replica_mismatch', step=obs_step,
+                        fingerprints=[int(x) for x in fps])
+        log.error(
+            'SDC sentinel: per-replica param fingerprints DISAGREE at '
+            'step %d: %s — deterministic compute violated (suspect '
+            'chip/HBM; docs/RUNBOOK.md §9)', obs_step,
+            [f'{int(x):08x}' for x in fps])
+      return {'sdc_replica_mismatch': 1.0 if sdc_mismatch else 0.0}
+
+    def _rollback_restore_all_hosts(state):
+      # Hosts must enter the (collective) restore with the SAME step:
+      # the per-host ladder could diverge on host-local I/O errors.
+      # Process 0 chooses; everyone follows — the broadcast is safe
+      # here because verdicts are a deterministic function of the
+      # replicated metrics, so every host reaches this in lockstep.
+      choice = int(multihost_utils.broadcast_one_to_all(
+          jnp.asarray(checkpointer.rollback_step_choice(), jnp.int32)))
+      return (checkpointer.restore_step(choice, state)
+              if choice >= 0 else None)
+
+    def _republish_rolled_back(step_now, state):
+      published = actor_params(state.params)
+      server.update_params(published)
+      rolled_remote_version = None
+      if ingest is not None:
+        rolled_remote_version = ingest.publish_params(
+            jax.device_get(published))
+      if tracer is not None:
+        # The rollback republish is a real publish: the local lag
+        # clock and the install join both see it.
+        tracer.on_publish(step_now,
+                          remote_version=rolled_remote_version)
+
+    # Summaries, incidents (bad-step bursts, rollbacks, halts, fault
+    # injections: what the scalars can't narrate; chaos.py reads them
+    # for its SLOs), health watchdog, SLO engine: lifecycle.open_run.
+    life = lifecycle.open_run(
+        config, checkpointer,
+        flight=(tracer.flight if tracer is not None else None),
+        rollback_restore=(None if num_processes == 1
+                          else _rollback_restore_all_hosts),
+        on_rollback=_republish_rolled_back,
+        extra_sentinels=((_sdc_dispatch, _sdc_read)
+                         if sdc_fp_fn is not None else None))
+    writer, incidents, fps_meter = (life.writer, life.incidents,
+                                    life.fps_meter)
+    health, slo_engine = life.health, life.slo_engine
+    # The elastic restore above predates the incident stream: announce
+    # it here so the topology change is on the record, not just in the
+    # log (round 20).
+    if elastic_restore is not None:
+      incidents.event('topology_resharded', step=_initial_steps,
+                      **elastic_restore)
     stats = observability.EpisodeStats(
         levels,
         benchmark=(config.level_name
                    if config.level_name in suites.SUITES else None),
         writer=writer)
-    fps_meter = observability.FpsMeter()
-    # Training-health watchdog (health.py): the device-side guard in
-    # the train step already skips non-finite updates; this host
-    # monitor escalates — skip-and-count → rollback → halt. Verdicts
-    # are a deterministic function of the (replicated) step metrics,
-    # so multi-host processes reach rollback/halt decisions in
-    # lockstep — the rollback restore stays a valid collective.
-    health = (health_lib.monitor_from_config(config)
-              if config.health_watchdog else None)
-    # SLO engine (round 14, slo.py): the declarative-objective judge
-    # over the metrics registry. Its thread snapshots the registry on
-    # a cadence (the summary block also evaluates, so detection is
-    # step-synchronous whenever summaries are frequent), emits
-    # structured slo_violation incidents + the slo_violations summary
-    # scalar, feeds burns into health's external-incident ledger, and
-    # on the first page-severity burn captures its own explanation
-    # (flight dump + trace slice now; a bounded jax.profiler capture
-    # via the loop below). The finally writes SLO_VERDICT.json —
-    # the per-run go/no-go artifact chaos/soak/slo_report consume.
-    if config.slo_engine:
-      slo_objectives = slo_lib.load_objectives(
-          config.slo_spec,
-          fast_window_secs=config.slo_fast_window_secs,
-          slow_window_secs=config.slo_slow_window_secs)
-      # Derived cadence: summary-paced, but ALWAYS at least ~4
-      # samples inside the fast burn window — value objectives need
-      # min_samples (3) fast-window samples before they can burn, so
-      # an interval as long as the window would leave the page
-      # objectives structurally unable to fire (validate_slo warns
-      # when an EXPLICIT interval does this).
-      slo_interval = (config.slo_interval_secs
-                      if config.slo_interval_secs > 0 else
-                      min(max(float(config.summary_secs), 1.0), 30.0,
-                          config.slo_fast_window_secs / 4.0))
-      slo_engine = slo_lib.SloEngine(
-          slo_objectives, config.logdir, writer=writer,
-          incidents=incidents,
-          flight=(tracer.flight if tracer is not None else None),
-          health=health, capture=config.slo_capture,
-          interval_secs=slo_interval,
-          baseline=slo_lib.load_baseline(config.slo_fps_baseline))
-      slo_engine.start()
     run = TrainRun(config, agent, state, fleet, prefetcher, server,
                    checkpointer, writer, stats, fps_meter,
                    ingest=ingest, health=health)
@@ -1070,7 +1039,7 @@ def train(config: Config, max_steps: Optional[int] = None,
             minimum=1, maximum=config.pod_max_hosts))
       ctrl_interval = (config.controller_interval_secs
                        if config.controller_interval_secs > 0
-                       else slo_interval)
+                       else life.slo_interval)
       ctrl = controller_lib.Controller(
           slo_engine, ctrl_rules, actuators, config.logdir,
           mode=config.controller, interval_secs=ctrl_interval,
@@ -1137,53 +1106,30 @@ def train(config: Config, max_steps: Optional[int] = None,
       _try(server.close)
     if fleet is not None:
       _try(lambda: fleet.stop(timeout=2.0))
-    if writer is not None:
-      _try(writer.close)
-    if incidents is not None:
-      _try(lambda: lock_check.set_incident_sink(None))
-      _try(incidents.close)
+    if ctrl is not None:
+      _try(ctrl.stop)  # no log finalize: the run never started
+    if life is not None:
+      _try(life.abort)  # no verdict: the run never started
     if tracer is not None:
       _try(lambda: telemetry.set_tracer(None))
       _try(tracer.close)
-    if ctrl is not None:
-      _try(ctrl.stop)  # no log finalize: the run never started
-    if slo_engine is not None:
-      _try(slo_engine.stop)  # no verdict: the run never started
     if filler is not None:
       _try(filler.close)
     _try(checkpointer.close)
     raise
 
   steps_done = 0
-  profiling = False
-  # Operator-requested profile window state: `pending` until the
-  # window actually starts (DEFERRED past any in-flight SLO capture,
-  # never silently skipped), then the captured stop step.
-  profile_dir_pending = bool(config.profile_dir)
-  profile_stop_step = None
-  # SLO-triggered profiler capture in flight: (objective name, the
-  # steps_done value at which the bounded trace stops). jax.profiler
-  # supports one trace at a time, so this and the config.profile_dir
-  # window are mutually exclusive in the loop below.
-  slo_profile = None
-  # The capture under way, either kind (observability.ProfilerCapture:
-  # device-only profiler + the span recorder armed beside it).
-  capture = None
   errors: List[BaseException] = []
-  # Unified-registry view of the loop itself (round 13): the step and
-  # frame clocks every other counter is read against. Lazy closures
-  # over the loop locals — the registry reads the live values; the
-  # finally unregisters them (the env-frames closure reaches the
-  # prefetcher, which must not stay registry-pinned after the run).
-  _loop_gauges = [
-      telemetry.gauge('driver/update_steps',
-                      fn=lambda: steps_done + _initial_steps),
-      telemetry.gauge(
-          'driver/env_frames',
-          fn=lambda: (env_frames_fn() if env_frames_fn is not None
-                      else (_initial_steps + steps_done) *
-                      config.frames_per_step)),
-  ]
+
+  def env_frames():
+    if env_frames_fn is not None:
+      return env_frames_fn()
+    return (_initial_steps + steps_done) * config.frames_per_step
+
+  # The step and frame clocks in the registry: closures over the loop
+  # locals (the env-frames one reaches the prefetcher).
+  life.loop_gauges(update_steps=lambda: steps_done + _initial_steps,
+                   env_frames=env_frames)
   # Plane-state gauges (round 14): the summary block's utilization
   # split and fleet quorum, registered into the unified registry so
   # the SLO engine (and the flight recorder / drain manifest) judge
@@ -1204,7 +1150,7 @@ def train(config: Config, max_steps: Optional[int] = None,
         gauge = telemetry.gauge('driver/remote_live_hosts')
       else:
         gauge = telemetry.gauge('driver/fleet_healthy_fraction')
-      _plane_gauges[name] = gauge
+      _plane_gauges[name] = life.track(gauge)
     gauge.set(value)
   # Preemption-drain state: set once the drain is requested (SIGTERM
   # via drain_event, or the deterministic 'preempt_signal' fault);
@@ -1215,18 +1161,6 @@ def train(config: Config, max_steps: Optional[int] = None,
   drain_t0 = None
   drain_deadline = None
   drain_source = None
-  # Watchdog loop state: the stashed (step, SentinelHandle) awaiting
-  # its delayed read, and the bad-step count of the current burst
-  # (driver-side: the monitor's consecutive counter resets on
-  # rollback, so it cannot bracket bursts).
-  pending_sentinel = None
-  bad_count_in_burst = 0
-  # Deferred metrics readback (round 8): (step, stacked-handle) pairs.
-  # `pending_metrics` is the step just dispatched; `prev_metrics` is
-  # one step older — its values are computed by now, so the summary
-  # read is a single non-syncing transfer.
-  pending_metrics = None
-  prev_metrics = None
   action_counts_acc = np.zeros((num_actions,), np.int64)
   last_publish_step = _initial_steps   # resume-manifest param version
   last_quarantined_slots = 0
@@ -1295,8 +1229,7 @@ def train(config: Config, max_steps: Optional[int] = None,
       if draining and time.monotonic() > drain_deadline:
         log.warning('preemption drain budget exhausted; finalizing')
         break
-      frames = (env_frames_fn() if env_frames_fn is not None else
-                (_initial_steps + steps_done) * config.frames_per_step)
+      frames = env_frames()
       if frames >= config.total_environment_frames:
         break
       if max_steps is not None and steps_done >= max_steps:
@@ -1382,58 +1315,10 @@ def train(config: Config, max_steps: Optional[int] = None,
       # Data is flowing again: captured errors are from a recovered
       # incident; keeping them would misattribute a much later stall.
       errors = []
-      # jax.profiler capture window (SURVEY §5.1 — the reference has
-      # no tracing at all): [start, start+num) learner steps, placed
-      # after warmup so compiles don't drown the timeline.
-      if config.profile_dir:
-        # The operator window DEFERS past an in-flight SLO capture
-        # (>= start step + the pending flag) instead of silently
-        # skipping it when the two collide on the one profiler.
-        if (profile_dir_pending and not profiling
-            and slo_profile is None
-            and steps_done >= config.profile_start_step):
-          capture = observability.ProfilerCapture(config.profile_dir)
-          profiling = True
-          profile_dir_pending = False
-          profile_stop_step = steps_done + config.profile_num_steps
-        elif profiling and steps_done >= profile_stop_step:
-          capture.stop()
-          profiling = False
-          log.info('profiler trace and spans.json written to %s',
-                   config.profile_dir)
-      # SLO-triggered deep diagnostics (round 14): a page-severity
-      # burn queued a bounded profiler capture — the next
-      # slo_capture_steps learner steps trace into
-      # diagnostics/slo_profile_<objective>/ (the flight dump and the
-      # trace slice already landed from the engine thread). One
-      # capture at a time; the operator-requested profile_dir window
-      # wins when both want the profiler.
-      if slo_engine is not None and not profiling:
-        if slo_profile is not None:
-          name, end_step = slo_profile
-          if steps_done >= end_step:
-            capture.stop()
-            slo_profile = None
-            log.info('SLO diagnostic profile for %r complete', name)
-        else:
-          req = slo_engine.take_profile_request()
-          if req is not None:
-            slo_prof_dir = os.path.join(config.logdir, 'diagnostics',
-                                        f'slo_profile_{req}')
-            os.makedirs(slo_prof_dir, exist_ok=True)
-            try:
-              capture = observability.ProfilerCapture(slo_prof_dir)
-            except Exception:
-              log.exception('SLO profiler capture failed to start')
-              slo_engine.note_profile(req, None)
-            else:
-              slo_profile = (req,
-                             steps_done + config.slo_capture_steps)
-              slo_engine.note_profile(req, slo_prof_dir)
-              log.warning(
-                  'SLO page (%s): capturing a %d-step profiler trace '
-                  'into %s', req, config.slo_capture_steps,
-                  slo_prof_dir)
+      # The profiler (SURVEY §5.1 — the reference has no tracing at
+      # all): the operator's window, placed after warmup so compiles
+      # don't drown the timeline, or an SLO page's bounded capture.
+      life.profiler.tick(steps_done)
       # Fault-injection seam (runtime/faults.py 'nan_burst'): rewards
       # become NaN on the staged device batch, driving a non-finite
       # loss through the REAL loss/grad path — what organic divergence
@@ -1479,8 +1364,7 @@ def train(config: Config, max_steps: Optional[int] = None,
       # pattern applied to the whole metrics dict — round 8; the old
       # path device_get each key separately against just-dispatched
       # values).
-      prev_metrics = pending_metrics
-      pending_metrics = (step_now, observability.stack_metrics(metrics))
+      life.metrics.push(step_now, metrics)
       # A re-served batch (replay_k > 1) carries no env-plane view —
       # its episodes/actions were recorded on the first serve.
       if stats_view is not None:
@@ -1489,146 +1373,10 @@ def train(config: Config, max_steps: Optional[int] = None,
           log.info('episode %s return=%.2f frames=%d', name, ep_return,
                    ep_frames)
 
-      # --- Escalation ladder (health.py): skip-and-count (the device
-      # guard already withheld a non-finite update) → roll back to the
-      # last-known-good checkpoint after K consecutive bad steps →
-      # halt with a diagnostic bundle instead of training through
-      # divergence. The sentinel read is ONE-STEP DELAYED: step N's
-      # stacked scalars are fetched after step N+1 was dispatched, so
-      # the device_get reads already-computed values instead of
-      # syncing the dispatch pipeline every step (per-step coverage at
-      # zero sync cost; the in-graph skip protects params with no
-      # latency either way). ---
-      if health is not None:
-        prev_sentinel = pending_sentinel
-        pending_sentinel = None
-        if steps_done % config.health_check_every_steps == 0:
-          # SDC fingerprints ride the same delayed-read cadence: the
-          # [replicas] uint32 array is dispatched NOW (before the
-          # next step donates the state) and read one check later.
-          # The 'replica_divergence' fault site fires here — one
-          # event per health check — perturbing one replica's probe
-          # lane so the real detection→rollback path executes.
-          fp_handle = None
-          if sdc_fp_fn is not None:
-            probe = np.zeros((sdc_replicas,), np.uint32)
-            div = faults_lib.fire('replica_divergence')
-            if div is not None:
-              victim = div.index % sdc_replicas
-              probe[victim] = np.uint32(1 + (div.index % 1000))
-              incidents.event('fault_replica_divergence',
-                              step=step_now, replica=victim)
-            fp_handle = sdc_fp_fn(state.params, probe)
-          pending_sentinel = (step_now,
-                              health_lib.stack_sentinels(metrics),
-                              fp_handle)
-      if health is not None and prev_sentinel is not None:
-        obs_step, handle, fp_handle_prev = prev_sentinel
-        values = health_lib.read_handle(handle)
-        if fp_handle_prev is not None:
-          fps = np.asarray(jax.device_get(fp_handle_prev))
-          sdc_mismatch = bool((fps != fps[0]).any())
-          values['sdc_replica_mismatch'] = (1.0 if sdc_mismatch
-                                            else 0.0)
-          if sdc_mismatch:
-            incidents.event('sdc_replica_mismatch', step=obs_step,
-                            fingerprints=[int(x) for x in fps])
-            log.error(
-                'SDC sentinel: per-replica param fingerprints '
-                'DISAGREE at step %d: %s — deterministic compute '
-                'violated (suspect chip/HBM; docs/RUNBOOK.md §9)',
-                obs_step, [f'{int(x):08x}' for x in fps])
-        verdict = health.observe_values(obs_step, values)
-        # Burst bracketing is driver-side state: the monitor resets
-        # its consecutive count on a ROLLBACK verdict, so 'burst
-        # ended' must be judged by verdicts, not that counter (a
-        # burst whose length is an exact multiple of K would
-        # otherwise never emit health_recovered).
-        bad_count_in_burst += (verdict != health_lib.OK)
-        if verdict != health_lib.OK and bad_count_in_burst == 1:
-          incidents.event('health_bad_burst_start', step=obs_step,
-                          reason=health.last_reason)
-          log.warning('unhealthy training step %d: %s', obs_step,
-                      health.last_reason)
-        elif verdict == health_lib.OK and bad_count_in_burst > 0:
-          incidents.event('health_recovered', step=obs_step,
-                          bad_steps=bad_count_in_burst)
-          bad_count_in_burst = 0
-        if verdict == health_lib.ROLLBACK:
-          if num_processes == 1:
-            rolled = checkpointer.restore_last_good(state)
-          else:
-            # Hosts must enter the (collective) restore with the SAME
-            # step: the per-host ladder could diverge on host-local
-            # I/O errors. Process 0 chooses; everyone follows — the
-            # broadcast is safe here because verdicts are a
-            # deterministic function of the replicated metrics, so
-            # every host reaches this branch in lockstep.
-            choice = int(multihost_utils.broadcast_one_to_all(
-                jnp.asarray(checkpointer.rollback_step_choice(),
-                            jnp.int32)))
-            rolled = (checkpointer.restore_step(choice, state)
-                      if choice >= 0 else None)
-          if rolled is None:
-            verdict = health_lib.HALT
-            health.rollbacks -= 1  # granted but could not be honored
-            health.last_reason = (f'{health.last_reason}; rollback '
-                                  'requested but no restorable '
-                                  'checkpoint exists')
-          else:
-            # Keep the CURRENT update counter: frames/steps count
-            # consumed env data and must stay monotone through a
-            # rollback (checkpoint step numbers and the LR schedule
-            # never move backwards; only params/opt/popart revert).
-            restored_step = int(jax.device_get(rolled.update_steps))
-            state = rolled._replace(update_steps=state.update_steps)
-            run.state = state
-            published = actor_params(state.params)
-            server.update_params(published)
-            rolled_remote_version = None
-            if ingest is not None:
-              rolled_remote_version = ingest.publish_params(
-                  jax.device_get(published))
-            if tracer is not None:
-              # The rollback republish is a real publish: the local
-              # lag clock and the install join both see it.
-              tracer.on_publish(step_now,
-                                remote_version=rolled_remote_version)
-            # Flight-recorder dump (round 13): the last N seconds of
-            # pipeline history (trace records + registry snapshots)
-            # next to the rollback incident — a rollback postmortem
-            # starts from what the pipeline was DOING, not just a
-            # counter total.
-            flight_path = None
-            if tracer is not None:
-              try:
-                out_dir = os.path.join(config.logdir, 'diagnostics')
-                os.makedirs(out_dir, exist_ok=True)
-                flight_path = tracer.flight.write(os.path.join(
-                    out_dir, f'flight_rollback_step{step_now}.json'))
-              except OSError:
-                log.exception('flight-recorder dump failed')
-            incidents.event('rollback', step=step_now,
-                            restored_checkpoint_step=restored_step,
-                            reason=health.last_reason,
-                            flight=flight_path)
-            log.warning(
-                'health rollback at step %d: restored checkpoint '
-                'step %d (params/optimizer/popart revert; step '
-                'counter keeps running)', step_now, restored_step)
-        if verdict == health_lib.HALT:
-          bundle = health.write_halt_bundle(
-              config.logdir, config, step_now,
-              reason=health.last_reason,
-              flight=(tracer.flight.dump() if tracer is not None
-                      else None))
-          incidents.event('health_halt', step=step_now,
-                          reason=health.last_reason, bundle=bundle)
-          raise health_lib.TrainingDivergence(
-              f'training halted at step {step_now} after '
-              f'{health.rollbacks} rollback escalation(s): '
-              f'{health.last_reason}. Diagnostic bundle: {bundle}',
-              bundle_path=bundle)
+      # Escalation ladder (health.py): skip → rollback → halt, on a
+      # one-step-delayed read.
+      state = life.ladder.step(step_now, metrics, state)
+      run.state = state
 
       if steps_done % config.publish_params_every == 0:
         publish = telemetry.span('learner/publish')
@@ -1681,10 +1429,9 @@ def train(config: Config, max_steps: Optional[int] = None,
         # downstream readers assert non-decreasing steps). Only the
         # very first step has no predecessor — that one read blocks on
         # the fresh dispatch, like the old path always did.
-        _, handle = (prev_metrics if prev_metrics is not None
-                     else pending_metrics)
-        writer.scalars(observability.read_stacked_metrics(handle),
-                       step_now)
+        writer.scalars(
+            observability.read_stacked_metrics(life.metrics.older()[1]),
+            step_now)
         writer.scalar('env_frames_per_sec', fps_meter.fps(), step_now)
         # Telemetry plane (round 13): the live policy-lag and
         # end-to-end span percentiles (the trace stream's headline
@@ -1723,25 +1470,14 @@ def train(config: Config, max_steps: Optional[int] = None,
         writer.scalar('envs_per_thread',
                       fleet_stats.get('envs_per_thread', 0.0), step_now)
         # Learner failure-domain counters (health.py / checkpoint.py).
+        life.write_health_scalars(step_now)
         if health is not None:
-          hs = health.stats()
-          writer.scalar('skipped_steps', hs['skipped_steps'], step_now)
-          writer.scalar('flagged_steps', hs['flagged_steps'], step_now)
-          writer.scalar('rollbacks', hs['rollbacks'], step_now)
           # SDC sentinel (round 12): replica fingerprint mismatches,
           # counted separately from non-finite skips — hardware lying
           # vs math diverging are different operator responses.
           writer.scalar('sdc_replica_mismatches',
-                        hs.get('sdc_mismatches', 0), step_now)
-        writer.scalar('checkpoint_save_errors',
-                      checkpointer.save_errors, step_now)
-        writer.scalar('checkpoint_restore_fallbacks',
-                      checkpointer.restore_fallbacks, step_now)
-        # Restore rungs refused for CONTENT-digest mismatch (bit rot
-        # on a committed step) — a strict subset of the fallbacks
-        # above, split out so disk rot alarms on its own curve.
-        writer.scalar('ckpt_digest_fallbacks',
-                      checkpointer.digest_fallbacks, step_now)
+                        health.stats().get('sdc_mismatches', 0),
+                        step_now)
         # Buffer occupancy: ~0 means the learner is starved (env/
         # inference bound); ~capacity means actors are throttled by
         # backpressure (learner bound).
@@ -1904,8 +1640,8 @@ def train(config: Config, max_steps: Optional[int] = None,
         # EXPOSED staging wait over this interval (round 8): ms/step
         # the learner actually blocked on the feed — the part of
         # H2D+stacking NOT hidden behind compute. The overlap fraction
-        # says how often a step waited; this says how much. bench.py's
-        # learner_plane / e2e_fed itemization reads it back out.
+        # says how often a step waited; this says how much (the
+        # benchmark's `staging.exposed_ms_per_step`).
         d_gets = pf['gets'] - last_pf_snap['gets']
         d_wait = pf['wait_secs'] - last_pf_snap['wait_secs']
         writer.scalar('staging_exposed_ms_per_step',
@@ -2102,14 +1838,7 @@ def train(config: Config, max_steps: Optional[int] = None,
                         step_now)
           writer.scalar('controller_publish_secs',
                         publish_cadence['secs'], step_now)
-        # Step-synchronous SLO evaluation (round 14): the engine's
-        # thread covers long summary gaps; this call makes detection
-        # deterministic wherever summaries are frequent (chaos runs
-        # at summary_secs=0 — the storm's violation is judged the
-        # step it happens, and the triggered capture still has loop
-        # steps left to profile).
-        if slo_engine is not None:
-          slo_engine.observe()
+        life.observe_slo()
         summaries.end()
       # Checkpoint cadence: Orbax saves are collective across hosts;
       # clocks differ, so all hosts act on PROCESS 0's decision (a
@@ -2118,13 +1847,10 @@ def train(config: Config, max_steps: Optional[int] = None,
       # checkpoint_check_every_steps — the cadence check itself must
       # not tax the hot loop (at worst the save lands that many steps
       # late, noise against checkpoint_secs=600).
-      # Saves are WITHHELD mid-burst: finite divergence (loss
-      # explosion) mutates params every step, and saving them would
-      # both advance LAST_GOOD onto the diverged state (making the
-      # rollback a no-op) and evict the healthy retained steps the
-      # rollback needs. The gate is lockstep across hosts (verdicts
-      # are a function of the replicated metrics).
-      healthy_now = health is None or (bad_count_in_burst == 0)
+      # Saves are WITHHELD mid-burst (HealthLadder.healthy_now); the
+      # gate is lockstep across hosts (verdicts are a function of the
+      # replicated metrics).
+      healthy_now = life.ladder.healthy_now
       if num_processes == 1:
         if healthy_now:
           checkpointer.maybe_save(state)
@@ -2152,8 +1878,7 @@ def train(config: Config, max_steps: Optional[int] = None,
       # periodic and final saves: checkpointing diverged params would
       # advance LAST_GOOD onto the poison. The manifest then names
       # the retained last-good step as the resume point.
-      healthy_now = health is None or bad_count_in_burst == 0
-      if healthy_now:
+      if life.ladder.healthy_now:
         checkpointer.save(run.state, force=True)
       else:
         log.warning('drain checkpoint withheld: training was '
@@ -2163,8 +1888,7 @@ def train(config: Config, max_steps: Optional[int] = None,
       drain_latency = time.monotonic() - drain_t0
       manifest = {
           'update_steps': step_final,
-          'frames': (env_frames_fn() if env_frames_fn is not None
-                     else step_final * config.frames_per_step),
+          'frames': env_frames(),
           'params_version_step': last_publish_step,
           'params_publishes': server.stats()['params_version'],
           'checkpoint_step': ckpt_step,
@@ -2221,7 +1945,6 @@ def train(config: Config, max_steps: Optional[int] = None,
       writer.scalar('drain_latency_secs', round(drain_latency, 3),
                     step_final)
   finally:
-    exiting_clean = sys.exc_info()[0] is None
     if iteration is not None:
       iteration.end()  # the loop was left mid-pass
     # One robustness roll-up while the fleet still runs (stats after
@@ -2258,95 +1981,38 @@ def train(config: Config, max_steps: Optional[int] = None,
                  ctrl_counts['applied'])
       except Exception:
         log.exception('controller finalize failed')
-    # SLO verdict (round 14): stop the evaluator thread and write the
-    # per-run SLO_VERDICT.json — BEFORE component teardown, so the
-    # final observation still sees every fn-gauge its objectives
-    # judge. Written on every exit path (a crashed run's verdict is
-    # exactly what the postmortem wants); chaos/soak/slo_report read
-    # the file.
-    if slo_engine is not None:
-      try:
-        slo_engine.stop()
-        verdict_name = ('SLO_VERDICT.json' if process_index == 0
-                        else f'SLO_VERDICT_p{process_index}.json')
-        verdict = slo_engine.finalize(
-            os.path.join(config.logdir, verdict_name),
-            extra={'clean_exit': exiting_clean,
-                   'update_steps': _initial_steps + steps_done})
-        (log.info if verdict['pass'] else log.warning)(
-            'SLO verdict: %s (%d objective(s), violations: %s) -> %s',
-            'PASS' if verdict['pass'] else 'FAIL',
-            len(verdict['objectives']),
-            verdict['violations'] or 'none', verdict_name)
-      except Exception:
-        log.exception('SLO verdict write failed')
-    if profiling or slo_profile is not None:
-      capture.stop()
-    elif config.profile_dir and profile_dir_pending:
-      log.warning(
-          'profile_dir set but the run ended at step %d before the '
-          'window could start (profile_start_step=%d, or an SLO '
-          'capture held the profiler) — no operator trace was '
-          'captured', steps_done, config.profile_start_step)
-    if ingest is not None:
-      # v10 routed serving: flip the draining notice FIRST — every
-      # infer reply from here on tells routers to shift traffic away
-      # while the rest of the teardown runs.
-      try:
-        ingest.set_draining()
-      except Exception:
-        log.exception('set_draining failed')
-    fleet.stop()
-    prefetcher.close()
-    server.close()
-    if filler is not None:
-      # Unregister the filler's per-run counter (identity-checked, so
-      # this can never evict a newer run's registration).
-      try:
-        filler.close()
-      except Exception:
-        log.exception('filler close failed')
-    if ingest is not None:
-      # Clean end → 'bye' frame (remote actors exit immediately);
-      # exception unwind → crash semantics (actors keep their
-      # reconnect window for the supervisor's restart).
-      ingest.close(graceful=exiting_clean)
+    def _stop_planes():
+      if ingest is not None:
+        # v10 routed serving: flip the draining notice FIRST — every
+        # infer reply from here on tells routers to shift traffic away
+        # while the rest of the teardown runs.
+        try:
+          ingest.set_draining()
+        except Exception:
+          log.exception('set_draining failed')
+      fleet.stop()
+      prefetcher.close()
+      server.close()
+      if filler is not None:
+        # Unregister the filler's per-run counter (identity-checked,
+        # so this can never evict a newer run's registration).
+        try:
+          filler.close()
+        except Exception:
+          log.exception('filler close failed')
+      if ingest is not None:
+        # Clean end → 'bye' frame (remote actors exit immediately);
+        # exception unwind → crash semantics (actors keep their
+        # reconnect window for the supervisor's restart).
+        ingest.close(graceful=life.clean_exit)
+
+    # The verdict is written on every exit path (a crashed run's is
+    # exactly what the postmortem wants; chaos/soak/slo_report read
+    # the file), then the planes above stop, then the tail checkpoint.
     try:
-      # The final save is a COLLECTIVE. On a clean exit every host
-      # reaches it in lockstep (termination is a deterministic
-      # function of the shared step count). When unwinding from a
-      # host-local exception, other hosts are still inside the
-      # collective train step — entering the Orbax barrier here would
-      # deadlock the job instead of surfacing the error; periodic
-      # checkpoints cover the tail. An UNHEALTHY exit (divergence
-      # halt, or any unwind mid-bad-burst) must not save either:
-      # finite divergence mutates params, and checkpointing them here
-      # would advance LAST_GOOD onto the diverged state and evict the
-      # healthy steps — the restarted run would restore the poison
-      # and halt again, a crash loop with no way back.
-      unhealthy_exit = health is not None and bad_count_in_burst > 0
-      if unhealthy_exit:
-        log.warning('skipping final checkpoint: training was '
-                    'unhealthy at exit (the retained last-known-good '
-                    'checkpoint covers the resume)')
-      elif num_processes == 1 or exiting_clean:
-        checkpointer.save(run.state, force=True)
-      else:
-        log.warning('skipping final collective checkpoint on '
-                    'exception unwind (multi-host)')
+      life.close(run.state, _initial_steps + steps_done,
+                 teardown=_stop_planes)
     finally:
-      checkpointer.close()
-      writer.close()
-      # The lock-order sink closes over THIS run's incident stream —
-      # clear it before the stream closes (a later detection in a
-      # leaked daemon thread becomes a counted log line, not a write
-      # into a closed file).
-      lock_check.set_incident_sink(None)
-      incidents.close()
-      for gauge in _loop_gauges:
-        telemetry.registry().unregister(gauge.name, gauge)
-      for gauge in _plane_gauges.values():
-        telemetry.registry().unregister(gauge.name, gauge)
       if tracer is not None:
         telemetry.set_tracer(None)
         tracer.close()
@@ -2427,141 +2093,102 @@ def train_anakin(config: Config, max_steps: Optional[int] = None,
   del env_core
   os.makedirs(config.logdir, exist_ok=True)
 
-  checkpointer = checkpoint_lib.Checkpointer(
-      config.logdir + '/checkpoints',
-      save_interval_secs=config.checkpoint_secs,
-      verify_digests=config.ckpt_digests,
-      registry=sharding_lib.from_config(config), mesh=mesh)
-  restore_ok = False
-  if initial_state is not None:
-    # On-device inheritance: the caller hands the starting state
-    # directly (already the right structure — it came from a sibling
-    # member of the same population). No disk round trip; the ladder
-    # below saves it durably at the normal cadence.
-    carry = carry._replace(train_state=initial_state)
-    restore_ok = True
-    log.info('starting from caller-provided state at step %d',
-             int(jax.device_get(initial_state.update_steps)))
-  else:
-    try:
-      restored = checkpointer.restore_latest(carry.train_state)
-      restore_ok = True
-    except BaseException:
-      # A structure-mismatch raise must not leak the manager (its
-      # background threads survive a same-process retry) — and the
-      # finally below must NOT tail-save a fresh state into a logdir
-      # holding an incompatible checkpoint (restore_ok gates it).
-      checkpointer.close()
-      raise
-    if restored is not None:
-      carry = carry._replace(train_state=restored)
-      log.info('restored checkpoint at step %d',
-               int(jax.device_get(restored.update_steps)))
-  _initial_steps = int(jax.device_get(carry.train_state.update_steps))
-
-  writer = None
-  incidents = None
-  slo_engine = None
-  health = None
+  checkpointer, train_state = lifecycle.restore_at_start(
+      config, carry.train_state, mesh=mesh, initial_state=initial_state)
+  carry = carry._replace(train_state=train_state)
+  _initial_steps = int(jax.device_get(train_state.update_steps))
   try:
-    writer = observability.SummaryWriter(config.logdir)
-    incidents = observability.EventLog(config.logdir)
-    # Same contract as the fleet loop (round 18): a lock-order
-    # detection among the anakin checkpoint/SLO/health locks must
-    # land as a DURABLE lock_order_inversion incident, not just a
-    # counted log line. Cleared in both teardown paths.
-    lock_check.set_incident_sink(incidents.event)
-    _record_run(config)
-    fps_meter = observability.FpsMeter()
-    health = (health_lib.monitor_from_config(config)
-              if config.health_watchdog else None)
-    if config.slo_engine:
-      slo_objectives = slo_lib.load_objectives(
-          config.slo_spec,
-          fast_window_secs=config.slo_fast_window_secs,
-          slow_window_secs=config.slo_slow_window_secs)
-      slo_interval = (config.slo_interval_secs
-                      if config.slo_interval_secs > 0 else
-                      min(max(float(config.summary_secs), 1.0), 30.0,
-                          config.slo_fast_window_secs / 4.0))
-      slo_engine = slo_lib.SloEngine(
-          slo_objectives, config.logdir, writer=writer,
-          incidents=incidents, flight=None, health=health,
-          capture=config.slo_capture, interval_secs=slo_interval,
-          baseline=slo_lib.load_baseline(config.slo_fps_baseline))
-      slo_engine.start()
+    life = lifecycle.open_run(config, checkpointer)
   except BaseException:
-    if writer is not None:
-      writer.close()
-    if incidents is not None:
-      lock_check.set_incident_sink(None)
-      incidents.close()
-    if slo_engine is not None:
-      slo_engine.stop()
     checkpointer.close()
     raise
+  writer, fps_meter = life.writer, life.fps_meter
 
   run = TrainRun(config, agent, carry.train_state, None, None, None,
-                 checkpointer, writer, None, fps_meter, health=health)
+                 checkpointer, writer, None, fps_meter,
+                 health=life.health)
   run.mesh = mesh
   steps_done = 0
-  # Registry view of the loop (the same literal names train()
-  # registers — the SLO engine and the name lint see ONE inventory).
   # The plane split is a fleet concept; in the fused runtime env and
   # learner are the same XLA program, busy whenever the loop is, so
-  # both gauges pin 1.0 — fps_floor is the objective that catches a
-  # wedged loop. fleet_healthy_fraction stays unregistered (no fleet:
-  # no_data, never a violation).
-  _loop_gauges = [
-      telemetry.gauge('driver/update_steps',
-                      fn=lambda: steps_done + _initial_steps),
-      telemetry.gauge('driver/env_frames',
-                      fn=lambda: (steps_done + _initial_steps) *
-                      config.frames_per_step),
-      telemetry.gauge('driver/env_plane_utilization', fn=lambda: 1.0),
-      telemetry.gauge('driver/learner_plane_utilization',
-                      fn=lambda: 1.0),
-  ]
+  # both utilization gauges pin 1.0: fps_floor is the objective that
+  # catches a wedged loop. fleet_healthy_fraction stays unregistered
+  # (no fleet: no_data, never a violation).
+  life.loop_gauges(
+      update_steps=lambda: steps_done + _initial_steps,
+      env_frames=lambda: ((steps_done + _initial_steps) *
+                          config.frames_per_step),
+      utilization=lambda: 1.0)
   # Curriculum telemetry (round 22): the fused step already folds the
   # per-level score/visit tables and their scalar digests into the
   # stacked metrics; these registry gauges re-export the latest
   # summary-read values so the SLO engine and scripts see them under
-  # registry names without an extra device sync (zero host round
-  # trips stays true — the dict updates at the summary cadence from
-  # the one-step-delayed read the loop does anyway).
+  # registry names without an extra device sync (the dict updates at
+  # the summary cadence from the one-step-delayed read the loop does
+  # anyway).
   curriculum_latest: Dict[str, float] = {}
   if config.curriculum != 'uniform':
-    _loop_gauges += [
-        telemetry.gauge(
-            'curriculum/entropy',
-            fn=lambda: curriculum_latest.get('curriculum_entropy',
-                                             0.0)),
-        telemetry.gauge(
-            'curriculum/levels_visited',
-            fn=lambda: curriculum_latest.get(
-                'curriculum_levels_visited', 0.0)),
-        telemetry.gauge(
-            'curriculum/score_max',
-            fn=lambda: curriculum_latest.get('curriculum_score_max',
-                                             0.0)),
-    ]
+    life.track(telemetry.gauge(
+        'curriculum/entropy',
+        fn=lambda: curriculum_latest.get('curriculum_entropy', 0.0)))
+    life.track(telemetry.gauge(
+        'curriculum/levels_visited',
+        fn=lambda: curriculum_latest.get('curriculum_levels_visited',
+                                         0.0)))
+    life.track(telemetry.gauge(
+        'curriculum/score_max',
+        fn=lambda: curriculum_latest.get('curriculum_score_max', 0.0)))
   sync_every = anakin_lib._cpu_mesh_sync_every(mesh)
-  pending_metrics = None
-  prev_metrics = None
-  pending_sentinel = None
-  bad_count_in_burst = 0
-  slo_profile = None
-  capture = None  # the ProfilerCapture under way
   loop_start = time.monotonic()
   last_summary = loop_start
+
+  def _final_artifacts():
+    # Final summary flush: short runs end inside one window and would
+    # otherwise ship empty curves.
+    if steps_done and life.metrics.pending is not None:
+      step_final, handle = life.metrics.pending
+      try:
+        writer.scalars(observability.read_stacked_metrics(handle),
+                       step_final)
+        writer.scalar('env_frames_per_sec', fps_meter.fps(),
+                      step_final)
+      except Exception:
+        log.exception('final summary flush failed')
+    # Per-level curriculum artifact (round 22): the final score /
+    # visit tables plus the live sampling distribution — the
+    # machine-readable answer to "which levels got the frames"
+    # (scripts and the CI population lane read this, not summaries).
+    if (config.curriculum != 'uniform' and
+        hasattr(carry.env_state, 'level_scores')):
+      try:
+        scores = np.asarray(
+            jax.device_get(carry.env_state.level_scores))
+        visits = np.asarray(
+            jax.device_get(carry.env_state.level_visits))
+        probs = np.asarray(population_lib.level_probs(
+            scores, config.curriculum_temperature,
+            config.curriculum_eps))
+        curriculum_path = os.path.join(config.logdir,
+                                       'CURRICULUM_LEVELS.json')
+        with open(curriculum_path, 'w') as f:
+          json.dump({'curriculum': config.curriculum,
+                     'temperature': config.curriculum_temperature,
+                     'eps': config.curriculum_eps,
+                     'scores': [float(s) for s in scores],
+                     'visits': [float(v) for v in visits],
+                     'probs': [float(p) for p in probs]},
+                    f, indent=2)
+      except Exception:
+        log.exception('curriculum artifact write failed')
+
   try:
     while True:
       if drain_event is not None and drain_event.is_set():
         # SIGTERM: the fused loop quiesces at a step boundary — the
-        # finally's tail checkpoint + SLO verdict ARE the drain (no
+        # close's tail checkpoint + SLO verdict ARE the drain (no
         # buffers to flush, no fleet to join).
-        incidents.event('anakin_stop_requested',
-                        step=_initial_steps + steps_done)
+        life.incidents.event('anakin_stop_requested',
+                             step=_initial_steps + steps_done)
         log.warning('stop requested (SIGTERM): finalizing at step %d',
                     _initial_steps + steps_done)
         break
@@ -2580,199 +2207,32 @@ def train_anakin(config: Config, max_steps: Optional[int] = None,
       fps_meter.update(config.frames_per_step)
       if sync_every is not None and steps_done % sync_every == 0:
         jax.block_until_ready(metrics['total_loss'])
-      # One-step-delayed stacked metrics (the train() discipline): the
-      # summary read transfers already-computed values, never syncing
-      # the async dispatch chain.
-      prev_metrics = pending_metrics
-      pending_metrics = (step_now, observability.stack_metrics(metrics))
-
-      # SLO-triggered profiler capture (round 14): the engine thread
-      # already dumped what it could; the bounded jax.profiler window
-      # must ride the loop that dispatches device work.
-      if slo_engine is not None:
-        if slo_profile is not None:
-          name, end_step = slo_profile
-          if steps_done >= end_step:
-            capture.stop()
-            slo_profile = None
-            log.info('SLO diagnostic profile for %r complete', name)
-        else:
-          req = slo_engine.take_profile_request()
-          if req is not None:
-            slo_prof_dir = os.path.join(config.logdir, 'diagnostics',
-                                        f'slo_profile_{req}')
-            os.makedirs(slo_prof_dir, exist_ok=True)
-            try:
-              capture = observability.ProfilerCapture(slo_prof_dir)
-            except Exception:
-              log.exception('SLO profiler capture failed to start')
-              slo_engine.note_profile(req, None)
-            else:
-              slo_profile = (req,
-                             steps_done + config.slo_capture_steps)
-              slo_engine.note_profile(req, slo_prof_dir)
-
-      # --- Health ladder (PR 2), one-step delayed exactly like
-      # train(): skip-and-count → rollback to LAST_GOOD after K
-      # consecutive bad steps → halt with the diagnostic bundle. The
-      # fused step's in-graph guard already withheld any non-finite
-      # update on device. ---
-      if health is not None:
-        prev_sentinel = pending_sentinel
-        pending_sentinel = None
-        if steps_done % config.health_check_every_steps == 0:
-          pending_sentinel = (step_now,
-                              health_lib.stack_sentinels(metrics))
-        if prev_sentinel is not None:
-          obs_step, handle = prev_sentinel
-          verdict = health.observe_values(
-              obs_step, health_lib.read_handle(handle))
-          bad_count_in_burst += (verdict != health_lib.OK)
-          if verdict != health_lib.OK and bad_count_in_burst == 1:
-            incidents.event('health_bad_burst_start', step=obs_step,
-                            reason=health.last_reason)
-            log.warning('unhealthy training step %d: %s', obs_step,
-                        health.last_reason)
-          elif verdict == health_lib.OK and bad_count_in_burst > 0:
-            incidents.event('health_recovered', step=obs_step,
-                            bad_steps=bad_count_in_burst)
-            bad_count_in_burst = 0
-          if verdict == health_lib.ROLLBACK:
-            rolled = checkpointer.restore_last_good(carry.train_state)
-            if rolled is None:
-              verdict = health_lib.HALT
-              health.rollbacks -= 1  # granted but not honorable
-              health.last_reason = (f'{health.last_reason}; rollback '
-                                    'requested but no restorable '
-                                    'checkpoint exists')
-            else:
-              restored_step = int(jax.device_get(rolled.update_steps))
-              # Step counter stays monotone through a rollback (only
-              # params/opt/popart revert) — the train() contract.
-              carry = carry._replace(train_state=rolled._replace(
-                  update_steps=carry.train_state.update_steps))
-              run.state = carry.train_state
-              incidents.event('rollback', step=step_now,
-                              restored_checkpoint_step=restored_step,
-                              reason=health.last_reason, flight=None)
-              log.warning(
-                  'health rollback at step %d: restored checkpoint '
-                  'step %d', step_now, restored_step)
-          if verdict == health_lib.HALT:
-            bundle = health.write_halt_bundle(
-                config.logdir, config, step_now,
-                reason=health.last_reason, flight=None)
-            incidents.event('health_halt', step=step_now,
-                            reason=health.last_reason, bundle=bundle)
-            raise health_lib.TrainingDivergence(
-                f'training halted at step {step_now} after '
-                f'{health.rollbacks} rollback escalation(s): '
-                f'{health.last_reason}. Diagnostic bundle: {bundle}',
-                bundle_path=bundle)
+      life.metrics.push(step_now, metrics)
+      life.profiler.tick(steps_done)
+      # The fused step's in-graph guard already withheld any
+      # non-finite update on device; the ladder escalates.
+      carry = carry._replace(train_state=life.ladder.step(
+          step_now, metrics, carry.train_state))
+      run.state = carry.train_state
 
       now = time.monotonic()
       if now - last_summary >= config.summary_secs:
         last_summary = now
-        _, handle = (prev_metrics if prev_metrics is not None
-                     else pending_metrics)
-        vals = observability.read_stacked_metrics(handle)
+        vals = observability.read_stacked_metrics(
+            life.metrics.older()[1])
         writer.scalars(vals, step_now)
         if config.curriculum != 'uniform':
           curriculum_latest.update(
               {k: v for k, v in vals.items()
                if k.startswith('curriculum_')})
         writer.scalar('env_frames_per_sec', fps_meter.fps(), step_now)
-        if health is not None:
-          hs = health.stats()
-          writer.scalar('skipped_steps', hs['skipped_steps'],
-                        step_now)
-          writer.scalar('flagged_steps', hs['flagged_steps'],
-                        step_now)
-          writer.scalar('rollbacks', hs['rollbacks'], step_now)
-        writer.scalar('checkpoint_save_errors',
-                      checkpointer.save_errors, step_now)
-        writer.scalar('checkpoint_restore_fallbacks',
-                      checkpointer.restore_fallbacks, step_now)
-        writer.scalar('ckpt_digest_fallbacks',
-                      checkpointer.digest_fallbacks, step_now)
-        # Step-synchronous SLO evaluation (the chaos/summary_secs=0
-        # determinism contract, same as train()).
-        if slo_engine is not None:
-          slo_engine.observe()
-      healthy_now = health is None or bad_count_in_burst == 0
-      if healthy_now:
+        life.write_health_scalars(step_now)
+        life.observe_slo()
+      if life.ladder.healthy_now:
         checkpointer.maybe_save(carry.train_state)
   finally:
-    exiting_clean = sys.exc_info()[0] is None
-    if slo_engine is not None:
-      try:
-        slo_engine.stop()
-        verdict = slo_engine.finalize(
-            os.path.join(config.logdir, 'SLO_VERDICT.json'),
-            extra={'clean_exit': exiting_clean,
-                   'update_steps': _initial_steps + steps_done,
-                   'runtime': 'anakin'})
-        (log.info if verdict['pass'] else log.warning)(
-            'SLO verdict: %s (%d objective(s), violations: %s)',
-            'PASS' if verdict['pass'] else 'FAIL',
-            len(verdict['objectives']),
-            verdict['violations'] or 'none')
-      except Exception:
-        log.exception('SLO verdict write failed')
-    if slo_profile is not None:
-      capture.stop()
-    try:
-      # Final summary flush: short runs end inside one window and
-      # would otherwise ship empty curves (anakin.train's contract).
-      if steps_done and pending_metrics is not None:
-        step_final, handle = pending_metrics
-        try:
-          writer.scalars(observability.read_stacked_metrics(handle),
-                         step_final)
-          writer.scalar('env_frames_per_sec', fps_meter.fps(),
-                        step_final)
-        except Exception:
-          log.exception('final summary flush failed')
-      # Per-level curriculum artifact (round 22): the final score /
-      # visit tables plus the live sampling distribution — the
-      # machine-readable answer to "which levels got the frames"
-      # (scripts and the CI population lane read this, not summaries).
-      if (config.curriculum != 'uniform' and
-          hasattr(carry.env_state, 'level_scores')):
-        try:
-          scores = np.asarray(
-              jax.device_get(carry.env_state.level_scores))
-          visits = np.asarray(
-              jax.device_get(carry.env_state.level_visits))
-          probs = np.asarray(population_lib.level_probs(
-              scores, config.curriculum_temperature,
-              config.curriculum_eps))
-          curriculum_path = os.path.join(config.logdir,
-                                         'CURRICULUM_LEVELS.json')
-          with open(curriculum_path, 'w') as f:
-            json.dump({'curriculum': config.curriculum,
-                       'temperature': config.curriculum_temperature,
-                       'eps': config.curriculum_eps,
-                       'scores': [float(s) for s in scores],
-                       'visits': [float(v) for v in visits],
-                       'probs': [float(p) for p in probs]},
-                      f, indent=2)
-        except Exception:
-          log.exception('curriculum artifact write failed')
-      unhealthy_exit = health is not None and bad_count_in_burst > 0
-      if unhealthy_exit:
-        log.warning('skipping final checkpoint: training was '
-                    'unhealthy at exit (the retained last-known-good '
-                    'checkpoint covers the resume)')
-      elif restore_ok:
-        checkpointer.save(run.state, force=True)
-    finally:
-      checkpointer.close()
-      writer.close()
-      lock_check.set_incident_sink(None)
-      incidents.close()
-      for gauge in _loop_gauges:
-        telemetry.registry().unregister(gauge.name, gauge)
+    life.close(run.state, _initial_steps + steps_done,
+               extra={'runtime': 'anakin'}, teardown=_final_artifacts)
   return run
 
 
@@ -2828,6 +2288,27 @@ def _inherit_member_dir(donor_dir: str, loser_dir: str) -> None:
     os.rename(loser_dir, old)
   os.rename(tmp, loser_dir)
   shutil.rmtree(old, ignore_errors=True)
+
+
+def _population_gauges(pop_stats: Dict[str, float]) -> List:
+  """The population's registry gauges over the loop's `pop_stats`.
+  Registered lazily AFTER the first scoring pass: an objective over an
+  absent gauge evaluates no_data (never violates), while a gauge
+  registered before any member has a return would judge a
+  placeholder. Member SLO engines from round 1 on DO see these (same
+  process, same registry): the per-task floor is judged while the
+  population still trains. The caller unregisters them."""
+  return [
+      telemetry.gauge(
+          'population/task_return_min',
+          fn=lambda: pop_stats.get('task_return_min', 0.0)),
+      telemetry.gauge(
+          'population/best_return',
+          fn=lambda: pop_stats.get('best_return', 0.0)),
+      telemetry.gauge(
+          'population/exploits_total',
+          fn=lambda: pop_stats.get('exploits', 0.0)),
+  ]
 
 
 def _train_population_fused(config: Config,
@@ -2897,10 +2378,11 @@ def _train_population_fused(config: Config,
   member_configs = []
   checkpointers = []
   member_writers = []
-  writer = None
-  incidents = None
-  slo_engine = None
   try:
+    # Per-member init (each member's own PRNG stream — same seed
+    # recipe as the serial member spin-up), per-member restore
+    # through its own ladder, then ONE stacked carry.
+    carries = []
     for k in range(n):
       member_dir = os.path.join(config.logdir, f'member_{k:02d}')
       os.makedirs(member_dir, exist_ok=True)
@@ -2914,83 +2396,30 @@ def _train_population_fused(config: Config,
                   sort_keys=True)
       member_dirs.append(member_dir)
       member_configs.append(member_config)
-      checkpointers.append(checkpoint_lib.Checkpointer(
-          os.path.join(member_dir, 'checkpoints'),
-          save_interval_secs=config.checkpoint_secs,
-          verify_digests=config.ckpt_digests,
-          registry=sharding_lib.from_config(member_config)))
-      member_writers.append(observability.SummaryWriter(member_dir))
-
-    # Per-member init (each member's own PRNG stream — same seed
-    # recipe as the serial member spin-up), per-member restore
-    # through its own ladder, then ONE stacked carry.
-    carries = []
-    for k in range(n):
       carry_k = anakin_lib.init_carry(
           agent, env_core, base_config,
-          jax.random.PRNGKey(member_configs[k].seed))
-      restored = checkpointers[k].restore_latest(carry_k.train_state)
-      if restored is not None:
-        carry_k = carry_k._replace(train_state=restored)
-        log.info('member %d: restored checkpoint at step %d', k,
-                 int(jax.device_get(restored.update_steps)))
-      carries.append(carry_k)
+          jax.random.PRNGKey(member_config.seed))
+      checkpointer, train_state = lifecycle.restore_at_start(
+          member_config, carry_k.train_state)
+      checkpointers.append(checkpointer)
+      member_writers.append(observability.SummaryWriter(member_dir))
+      carries.append(carry_k._replace(train_state=train_state))
     stacked = jax.tree_util.tree_map(
         lambda *xs: jnp.stack(xs), *carries)
     del carries
-
-    writer = observability.SummaryWriter(config.logdir)
-    incidents = observability.EventLog(config.logdir)
-    lock_check.set_incident_sink(incidents.event)
-    _record_run(config)
-    fps_meter = observability.FpsMeter()
-    if config.slo_engine:
-      slo_objectives = slo_lib.load_objectives(
-          config.slo_spec,
-          fast_window_secs=config.slo_fast_window_secs,
-          slow_window_secs=config.slo_slow_window_secs)
-      slo_interval = (config.slo_interval_secs
-                      if config.slo_interval_secs > 0 else
-                      min(max(float(config.summary_secs), 1.0), 30.0,
-                          config.slo_fast_window_secs / 4.0))
-      slo_engine = slo_lib.SloEngine(
-          slo_objectives, config.logdir, writer=writer,
-          incidents=incidents, flight=None, health=None,
-          capture=config.slo_capture, interval_secs=slo_interval,
-          baseline=slo_lib.load_baseline(config.slo_fps_baseline))
-      slo_engine.start()
+    life = lifecycle.open_run(config)
   except BaseException:
     for w in member_writers:
       w.close()
     for c in checkpointers:
       c.close()
-    if slo_engine is not None:
-      slo_engine.stop()
-    if writer is not None:
-      writer.close()
-    if incidents is not None:
-      lock_check.set_incident_sink(None)
-      incidents.close()
     raise
+  writer, incidents, fps_meter = (life.writer, life.incidents,
+                                  life.fps_meter)
 
   pop_path = os.path.join(config.logdir, 'population_summaries.jsonl')
   pop_stats: Dict[str, float] = {'exploits': 0.0}
   pop_gauges: List = []
-
-  def _ensure_gauges():
-    if pop_gauges:
-      return
-    pop_gauges.extend([
-        telemetry.gauge(
-            'population/task_return_min',
-            fn=lambda: pop_stats.get('task_return_min', 0.0)),
-        telemetry.gauge(
-            'population/best_return',
-            fn=lambda: pop_stats.get('best_return', 0.0)),
-        telemetry.gauge(
-            'population/exploits_total',
-            fn=lambda: pop_stats.get('exploits', 0.0)),
-    ])
 
   pbt_log = {'population': n, 'suites': suite_list,
              'round_frames': round_frames, 'num_rounds': num_rounds,
@@ -3009,16 +2438,11 @@ def _train_population_fused(config: Config,
       jax.device_get(stacked.train_state.update_steps))))
   steps_done = 0
   frames_per_step = config.frames_per_step
-  _loop_gauges = [
-      telemetry.gauge('driver/update_steps',
-                      fn=lambda: steps_done + _initial_steps),
-      telemetry.gauge('driver/env_frames',
-                      fn=lambda: (steps_done + _initial_steps) *
-                      frames_per_step * n),
-      telemetry.gauge('driver/env_plane_utilization', fn=lambda: 1.0),
-      telemetry.gauge('driver/learner_plane_utilization',
-                      fn=lambda: 1.0),
-  ]
+  life.loop_gauges(
+      update_steps=lambda: steps_done + _initial_steps,
+      env_frames=lambda: ((steps_done + _initial_steps) *
+                          frames_per_step * n),
+      utilization=lambda: 1.0)
 
   def _hyp_arrays():
     return {
@@ -3040,8 +2464,6 @@ def _train_population_fused(config: Config,
 
   returns = [0.0] * n
   scored = False
-  pending_metrics = None
-  prev_metrics = None
   loop_start = time.monotonic()
   last_summary = loop_start
   try:
@@ -3069,22 +2491,18 @@ def _train_population_fused(config: Config,
         round_steps += 1
         step_now = _initial_steps + steps_done
         fps_meter.update(frames_per_step * n)
-        prev_metrics = pending_metrics
-        pending_metrics = (step_now,
-                           observability.stack_metrics(metrics))
+        life.metrics.push(step_now, metrics)
         now = time.monotonic()
         if now - last_summary >= config.summary_secs:
           last_summary = now
-          _flush_members(prev_metrics if prev_metrics is not None
-                         else pending_metrics)
+          _flush_members(life.metrics.older())
           writer.scalar('env_frames_per_sec', fps_meter.fps(),
                         step_now)
-          if slo_engine is not None:
-            slo_engine.observe()
+          life.observe_slo()
       # Round boundary: flush the freshest metrics so the scoring
       # pass below reads THIS round's tail, then score/decide.
-      if pending_metrics is not None:
-        _flush_members(pending_metrics)
+      if life.metrics.pending is not None:
+        _flush_members(life.metrics.pending)
       for k in range(n):
         returns[k] = _member_return(member_dirs[k])
         row = {'wall_time': round(time.time(), 3), 'round': r,
@@ -3097,7 +2515,9 @@ def _train_population_fused(config: Config,
       scored = True
       pop_stats['task_return_min'] = min(returns)
       pop_stats['best_return'] = max(returns)
-      _ensure_gauges()
+      if not pop_gauges:
+        pop_gauges.extend(life.track(gauge) for gauge in
+                          _population_gauges(pop_stats))
       writer.scalar('population/task_return_min',
                     pop_stats['task_return_min'], target)
       writer.scalar('population/best_return',
@@ -3180,32 +2600,18 @@ def _train_population_fused(config: Config,
     raise RuntimeError('population run trained no member (drained '
                        'before the first round scored?)')
   finally:
-    exiting_clean = sys.exc_info()[0] is None
-    if slo_engine is not None:
-      try:
-        slo_engine.stop()
-        verdict = slo_engine.finalize(
-            os.path.join(config.logdir, 'SLO_VERDICT.json'),
-            extra={'clean_exit': exiting_clean,
-                   'update_steps': _initial_steps + steps_done,
-                   'runtime': 'anakin', 'vectorized': True,
-                   'population': n})
-        (log.info if verdict['pass'] else log.warning)(
-            'SLO verdict: %s (%d objective(s), violations: %s)',
-            'PASS' if verdict['pass'] else 'FAIL',
-            len(verdict['objectives']),
-            verdict['violations'] or 'none')
-      except Exception:
-        log.exception('SLO verdict write failed')
-    for gauge in _loop_gauges + pop_gauges:
-      telemetry.registry().unregister(gauge.name, gauge)
-    for c in checkpointers:
-      c.close()
-    for w in member_writers:
-      w.close()
-    writer.close()
-    lock_check.set_incident_sink(None)
-    incidents.close()
+    def _close_members():
+      for c in checkpointers:
+        c.close()
+      for w in member_writers:
+        w.close()
+
+    # No tail save here: every member's slice was force-saved at its
+    # round boundary, after the exploits landed.
+    life.close(None, _initial_steps + steps_done,
+               extra={'runtime': 'anakin', 'vectorized': True,
+                      'population': n},
+               teardown=_close_members)
 
 
 def train_population(config: Config, max_steps: Optional[int] = None,
@@ -3289,27 +2695,6 @@ def train_population(config: Config, max_steps: Optional[int] = None,
   pop_stats: Dict[str, float] = {'exploits': 0.0}
   pop_gauges: List = []
 
-  def _ensure_gauges():
-    # Registered lazily AFTER the first scoring pass: an objective
-    # over an absent gauge evaluates no_data (never violates), while
-    # a gauge registered before any member has a return would judge a
-    # placeholder. Member SLO engines from round 1 on DO see these
-    # (same process, same registry) — that is the point: the
-    # per-task floor is judged while the population still trains.
-    if pop_gauges:
-      return
-    pop_gauges.extend([
-        telemetry.gauge(
-            'population/task_return_min',
-            fn=lambda: pop_stats.get('task_return_min', 0.0)),
-        telemetry.gauge(
-            'population/best_return',
-            fn=lambda: pop_stats.get('best_return', 0.0)),
-        telemetry.gauge(
-            'population/exploits_total',
-            fn=lambda: pop_stats.get('exploits', 0.0)),
-    ])
-
   pbt_log = {'population': n, 'suites': suite_list,
              'round_frames': round_frames, 'num_rounds': num_rounds,
              'quantile': config.pbt_quantile,
@@ -3375,7 +2760,8 @@ def train_population(config: Config, max_steps: Optional[int] = None,
           for s in suite_list}
       pop_stats['task_return_min'] = min(per_suite_best.values())
       pop_stats['best_return'] = max(returns)
-      _ensure_gauges()
+      if not pop_gauges:
+        pop_gauges.extend(_population_gauges(pop_stats))
       writer.scalar('population/task_return_min',
                     pop_stats['task_return_min'], target)
       writer.scalar('population/best_return',

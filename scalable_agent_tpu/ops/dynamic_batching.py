@@ -415,8 +415,7 @@ class FamilyBatcher:
 
   `padding_stats()` carries the measured perf claim: useful bytes
   served per family vs the counterfactual naive max-shape cost over
-  the SAME request stream (every row padded to the widest family seen)
-  — the bench.py population stage's mixed-suite row."""
+  the SAME request stream (every row padded to the widest family seen)."""
 
   _families: guarded_by('_lock')
   _rows: guarded_by('_lock')

@@ -596,94 +596,6 @@ def _cpu_mesh_sync_every(mesh) -> Optional[int]:
                and jax.default_backend() == 'cpu') else None
 
 
-def train(config: Config, max_steps: Optional[int] = None, mesh=None):
-  """Operator-facing Anakin training (`experiment.py --mode=anakin`):
-  chunked fused steps with the framework's standard run artifacts —
-  JSONL summaries (total_loss, mean_reward, env_frames_per_sec,
-  learning_rate), checkpoint/resume in the same TrainState layout as
-  driver.train, config.json dump, total_environment_frames
-  termination. Returns the final AnakinCarry.
-
-  The carry's env/agent state is NOT checkpointed — matching the
-  production path, where actor-local state is intentionally excluded
-  (reference: local variables are not saved; SURVEY §5.4)."""
-  import dataclasses
-  import json as json_lib
-  import os
-  import time
-  from scalable_agent_tpu import checkpoint as checkpoint_lib
-  from scalable_agent_tpu import observability
-
-  _, _, step, carry = build_run(config, mesh=mesh)
-  os.makedirs(config.logdir, exist_ok=True)
-  with open(os.path.join(config.logdir, 'config.json'), 'w') as f:
-    json_lib.dump(dataclasses.asdict(config), f, indent=2,
-                  sort_keys=True)
-  checkpointer = checkpoint_lib.Checkpointer(
-      os.path.join(config.logdir, 'checkpoints'),
-      save_interval_secs=config.checkpoint_secs)
-  writer = observability.SummaryWriter(config.logdir)
-  fps_meter = observability.FpsMeter()
-  sync_every = _cpu_mesh_sync_every(mesh)
-
-  steps_done = 0
-  metrics = None
-
-  def flush(step_num):
-    m = jax.device_get(metrics)  # readback = pipeline barrier
-    writer.scalars(
-        {'total_loss': float(m['total_loss']),
-         'mean_reward': float(m['mean_reward']),
-         'learning_rate': float(m['learning_rate']),
-         'env_frames_per_sec': fps_meter.fps()}, step=step_num)
-
-  restore_ok = False
-  try:
-    # A structure-mismatch raise must not leak the manager/writer
-    # (same discipline as driver.train's restore path).
-    restored = checkpointer.restore_latest(carry.train_state)
-    restore_ok = True
-    if restored is not None:
-      carry = carry._replace(train_state=restored)
-    # Step count tracked host-side: reading the device counter in the
-    # loop condition would be a per-step sync, serializing the async
-    # dispatch chain.
-    base_steps = int(carry.train_state.update_steps)
-    last_summary = time.monotonic()
-    while True:
-      steps = base_steps + steps_done
-      frames = steps * config.frames_per_step
-      if frames >= config.total_environment_frames:
-        break
-      if max_steps is not None and steps_done >= max_steps:
-        break
-      carry, metrics = step(carry)
-      steps_done += 1
-      fps_meter.update(config.frames_per_step)
-      if sync_every is not None and steps_done % sync_every == 0:
-        jax.block_until_ready(metrics['total_loss'])
-      now = time.monotonic()
-      if now - last_summary >= config.summary_secs:
-        flush(base_steps + steps_done)
-        last_summary = now
-      checkpointer.maybe_save(carry.train_state)
-    if steps_done:
-      # Final flush: a short run can finish inside one summary window
-      # and would otherwise end with only the post-compile sample.
-      flush(base_steps + steps_done)
-  finally:
-    try:
-      if restore_ok:
-        # Tail-save (preemption/interrupt safety); skipped when the
-        # restore itself failed — a fresh state must not be written
-        # into a logdir holding an incompatible checkpoint.
-        checkpointer.save(carry.train_state)
-    finally:
-      checkpointer.close()
-      writer.close()
-  return carry
-
-
 def run(config: Config, num_steps: int, rng_seed: Optional[int] = None,
         env_backend: Optional[str] = None, mesh=None):
   """Convenience runner: build agent + env core, run `num_steps` fused

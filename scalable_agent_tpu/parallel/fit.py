@@ -39,7 +39,7 @@ HBM_BUDGET_FRACTION = 0.85
 def full_feature_config(batch_size: int = 32, unroll_length: int = 100,
                         height: int = 72, width: int = 96):
   """The flagship full-feature learner config (the BASELINE.json
-  DMLab-30 operating point bench.py's `full_feature` row measures)."""
+  DMLab-30 operating point)."""
   from scalable_agent_tpu.config import Config
   return Config(batch_size=batch_size, unroll_length=unroll_length,
                 num_action_repeats=4, torso='deep',

@@ -46,8 +46,8 @@ driver calls between rounds:
 The driver wires these into `train_anakin` (curriculum telemetry +
 CURRICULUM_LEVELS.json), `train_population` (the one-invocation
 population run), and `make_fleet` (mixed-suite actor assignment);
-bench.py's population stage carries the fps-parity and padding-waste
-measurements; docs/PARALLELISM.md carries the operator story.
+docs/PARALLELISM.md carries the operator story. No benchmark cell
+runs a curriculum or a population yet.
 """
 
 from typing import Any, Dict, List, Optional, Sequence, Tuple

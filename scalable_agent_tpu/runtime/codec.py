@@ -28,9 +28,8 @@ trip.
 
 Quantization is LOSSY (max per-leaf error = scale/2). It therefore
 ships parity-GATED: `greedy_agreement` scores argmax-action agreement
-of the quantized policy against fp32 on the same inputs, and the
-serving bench (BENCH_ONLY=serving) + the CI serving lane hold the
-gate. docs/PERF.md records the wire-bytes/blackout rows per the
+of the quantized policy against fp32 on the same inputs, and
+tests/test_serving.py holds the gate. docs/PERF.md records the wire-bytes/blackout rows per the
 accept/reject discipline.
 """
 

@@ -1269,7 +1269,7 @@ class InferenceServer:
     number being high — paper Table 1); watch it when tuning
     inference_{min_batch,timeout_ms}. The latency percentiles cover
     the last ≤512 merged calls, assembly start → callers unparked
-    (the per-call number bench.py's inference_plane stage itemizes).
+    (the benchmark's `inference.call_host_ms_p50` reads it).
     """
     with self._stats_lock:
       calls, reqs = self._calls, self._merged_requests

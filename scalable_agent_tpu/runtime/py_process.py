@@ -62,8 +62,8 @@ def warm_forkserver():
   for each actor's first observation in turn: start-up linear in the
   fleet size (about 5 s per actor measured on the chip machine).
   `python experiment.py` got this by accident — the default preload is
-  `__main__`, and experiment.py imports the package — while bench.py,
-  the tests and chip_smoke.py did not."""
+  `__main__`, and experiment.py imports the package — while the
+  tests and chip_smoke.py did not."""
   from multiprocessing import forkserver
   multiprocessing.set_forkserver_preload(
       ['__main__', 'scalable_agent_tpu.envs.factory'])

@@ -880,7 +880,7 @@ class BatchPrefetcher:
           # fresh-vs-serve accounting is attributed at consumption
           # time (a batch staged ahead by the prefetcher but never
           # served counts nothing — the lookahead-free invariant
-          # bench.py's composition rows rely on). staged_k pins the K
+          # the composition accounting relies on). staged_k pins the K
           # this entry was staged under: set_replay_k (round 15, the
           # controller's actuator) changes only FUTURE entries, and
           # first-serve detection compares against the entry's own K,

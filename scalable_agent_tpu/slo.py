@@ -47,9 +47,9 @@ first-class, arXiv 2104.06272):
    scripts/slo_report.py (the CI/chip go-no-go gate, which also diffs
    bench headline numbers against docs/BENCH_HISTORY.md baselines).
 
-Cost is measured, not assumed: bench.py's `slo` stage times the
-evaluator tick and the profiler-capture overhead; the default-ON call
-is recorded in docs/PERF.md (r12).
+Cost is measured, not assumed: the evaluator tick and the
+profiler-capture overhead were timed, and the default-ON call is
+recorded in docs/PERF.md (r12).
 
 No jax imports here — the engine must be importable by actor hosts,
 scripts, and tests without accelerator initialization (the telemetry

@@ -66,9 +66,10 @@ telemetry behind it. This module is that layer, in three pieces:
    stamps above, a landmark program joins them to the device trace.
    Span names are listed in docs/OBSERVABILITY.md ("Spans").
 
-Costs are measured, not assumed: bench.py's `telemetry` stage runs
-the feed pipeline with tracing on vs off and the always-on default is
-an accept/reject call recorded in docs/PERF.md.
+Costs are measured, not assumed: the feed pipeline was run with
+tracing on and off, and the always-on default is an accept/reject call
+recorded in docs/PERF.md (r11); what the span recorder costs on the
+chip is in the root PERF.md.
 
 No jax imports here — actor hosts and test helpers use this module
 before (or without) jax initialization.

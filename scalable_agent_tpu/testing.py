@@ -1,8 +1,8 @@
 """Shared test/bench fixtures: synthetic trajectory batches.
 
 One canonical constructor for a random learner batch so tests, the
-driver entry points, and bench.py can't drift apart when the trajectory
-structs change.
+driver entry points, and the benchmark can't drift apart when the
+trajectory structs change.
 """
 
 import numpy as np

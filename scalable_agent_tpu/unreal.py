@@ -21,8 +21,8 @@ itself lives in models/agent.py (it needs the LSTM features).
 Round 6 (the full-feature 20%, docs/PERF.md): the pixel-control path
 got the step-cost treatment. Two numerics-preserving fast paths ship
 behind config (defaults stay at the reference forms until the chip
-rows land — see config.py), each parity-gated in tests/test_unreal.py
-and individually measured by bench.py's `pc_levers` stage:
+rows land — see config.py), each parity-gated in tests/test_unreal.py,
+neither measured on a chip yet:
 
 - `pixel_control_rewards` has an INTEGER-DOMAIN form (uint8 frames
   only): |Δ| in int16, per-cell sum in int32, one float32 scale at

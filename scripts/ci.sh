@@ -17,9 +17,6 @@ python -m pytest tests/ -q
 echo '== multi-chip sharding dry-run =='
 python __graft_entry__.py
 
-echo '== bench smoke (mechanics only, tiny shapes) =='
-BENCH_SMOKE=1 python bench.py
-
 echo '== soak smoke (mechanics only: popart/pc stack runs, tiny shapes;'
 echo '   the real flagship soak is scripts/soak.py on the chip) =='
 SOAK_SMOKE=1 python scripts/soak.py
@@ -37,12 +34,11 @@ echo '== overload-chaos smoke (fleet at 2x inference slots under shed'
 echo '   admission + slow-learner backpressure + REAL mid-storm'
 echo '   SIGTERM -> drain -> verified checkpoint + resume manifest ->'
 echo '   resume parity; plus the drain/resume + admission selector'
-echo '   and the tiny 1x/2x/4x shed-rate bench rows — <60 s CPU) =='
+echo '   — <60 s CPU) =='
 CHAOS_SMOKE=1 CHAOS_STORM=overload python scripts/chaos.py
 JAX_PLATFORMS=cpu python -m pytest tests/test_overload.py -q \
   -k 'drain or admission or shed or waitlist or staleness' \
   -p no:cacheprovider
-BENCH_SMOKE=1 BENCH_ONLY=overload python bench.py
 
 echo '== partition-chaos smoke (remote feed under conn partition +'
 echo '   delay faults, learner hard-killed (-9) mid-storm, restarted'
@@ -89,8 +85,7 @@ echo '   burn-rate evaluation, triggered deep diagnostics, the'
 echo '   SLO_VERDICT.json go/no-go artifact + slo_report regression'
 echo '   gate; then a tiny driver run asserting the verdict lands with'
 echo '   every default objective evaluated and zero captures on a'
-echo '   clean run, and the tiny evaluator/capture bench rows — <90 s'
-echo '   CPU) =='
+echo '   clean run — <90 s CPU) =='
 JAX_PLATFORMS=cpu python -m pytest tests/test_slo.py -q \
   -p no:cacheprovider
 JAX_PLATFORMS=cpu python - <<'SLO_EOF'
@@ -127,7 +122,6 @@ assert rc == 0, f'slo_report exited {rc} on a passing verdict'
 print(f'slo lane OK: {len(got)} objectives evaluated, verdict PASS, '
       'zero captures, slo_report gate green')
 SLO_EOF
-BENCH_SMOKE=1 BENCH_ONLY=slo python bench.py
 
 echo '== controller lane (round 15: the self-healing control plane —'
 echo '   policy-table determinism, bounded escalate/revert with'
@@ -135,8 +129,7 @@ echo '   hysteresis, fleet elasticity + quarantine rehabilitation,'
 echo '   then the load-surge storm: offered load doubles mid-run, the'
 echo '   actuated run keeps SLO_VERDICT.json green with the'
 echo '   escalation+revert in CONTROLLER_LOG.json while the observe'
-echo '   run records the violation it avoided; plus the tiny'
-echo '   tick-cost bench rows — <90 s CPU) =='
+echo '   run records the violation it avoided — <90 s CPU) =='
 JAX_PLATFORMS=cpu python -m pytest tests/test_controller.py -q \
   -p no:cacheprovider
 JAX_PLATFORMS=cpu python -m pytest tests/test_fleet.py \
@@ -146,16 +139,14 @@ JAX_PLATFORMS=cpu python -m pytest tests/test_fleet.py \
 'set_admission or control_snapshot' \
   -p no:cacheprovider
 CHAOS_SMOKE=1 CHAOS_STORM=controller python scripts/chaos.py
-BENCH_SMOKE=1 BENCH_ONLY=controller python bench.py
 
 echo '== anakin-runtime lane (round 16: the --runtime={fleet,anakin}'
 echo '   axis — jittable env family semantics + mesh sharding, the'
 echo '   hybrid filler (yield determinism, fresh-vs-filler frame'
 echo '   accounting), then a tiny --runtime=anakin driver run'
 echo '   asserting the full lifecycle artifacts land (SLO_VERDICT'
-echo '   green, summaries/incidents JSONL, checkpoint restore), and'
-echo '   the BENCH_ONLY=anakin smoke with the fed-reference + hybrid'
-echo '   rows — <120 s CPU) =='
+echo '   green, summaries/incidents JSONL, checkpoint restore)'
+echo '   — <120 s CPU) =='
 JAX_PLATFORMS=cpu python -m pytest tests/test_anakin.py \
   tests/test_filler.py -q -p no:cacheprovider
 JAX_PLATFORMS=cpu python - <<'ANAKIN_EOF'
@@ -183,8 +174,6 @@ run2 = driver.train(cfg)
 assert run2.frames == 120, run2.frames
 print('anakin lane OK: 6 fused steps, verdict PASS, restore green')
 ANAKIN_EOF
-XLA_FLAGS='--xla_force_host_platform_device_count=8' \
-  BENCH_SMOKE=1 BENCH_ONLY=anakin python bench.py
 
 echo '== multihost lane (round 17: the real multi-process runtime —'
 echo '   2 OS processes join jax.distributed over gloo CPU collectives'
@@ -192,8 +181,8 @@ echo '   and run the FULL driver over one mesh: per-host fleets'
 echo '   feeding process-local shards, the cross-process gradient'
 echo '   psum, broadcast-gated collective checkpoints + the SIGKILL'
 echo '   drill, the SDC all-gather rollback drill, cross-host trace'
-echo '   joins, and the BENCH_ONLY=multihost scaling row; the'
-echo '   validate_distributed/slot-placement unit half runs first.'
+echo '   joins; the validate_distributed/slot-placement unit half'
+echo '   runs first.'
 echo '   Round 19: the heavy drills (mixed topology, kill drills,'
 echo '   cross-process TP) are slow-marked OUT of tier-1 and run HERE'
 echo '   — the whole file, no -m filter — <600 s CPU) =='
@@ -205,7 +194,6 @@ python -m pytest \
   tests/test_multihost.py \
   tests/test_multihost_extra.py \
   -q -p no:cacheprovider
-BENCH_SMOKE=1 BENCH_ONLY=multihost python bench.py
 
 echo '== elastic lane (round 20: elastic pod membership — the'
 echo '   resharding edge-case unit tests + v9 membership-ledger units,'
@@ -226,43 +214,32 @@ CHAOS_SMOKE=1 CHAOS_STORM=elastic python scripts/chaos.py
 
 echo '== telemetry smoke (trace spans end to end: registry semantics,'
 echo '   tracer pipeline, v8 negotiation + remote stamping,'
-echo '   trace_report reconstruction; then the tiny tracing-on/off'
-echo '   overhead rows via BENCH_ONLY=telemetry — <60 s CPU) =='
+echo '   trace_report reconstruction — <60 s CPU) =='
 JAX_PLATFORMS=cpu python -m pytest tests/test_telemetry.py \
   tests/test_observability.py -q -p no:cacheprovider
-BENCH_SMOKE=1 BENCH_ONLY=telemetry python bench.py
 
 echo '== inference-plane smoke (state-cache golden parity + slot'
-echo '   lifecycle selector, then the tiny cache×depth bench rows'
-echo '   via BENCH_ONLY=inference_plane — <60 s CPU) =='
+echo '   lifecycle selector — <60 s CPU) =='
 JAX_PLATFORMS=cpu python -m pytest tests/test_runtime.py \
   tests/test_parallel.py -q \
   -k 'state_cache or slot or inflight or version_gate or arena' \
   -p no:cacheprovider
-BENCH_SMOKE=1 BENCH_ONLY=inference_plane python bench.py
 
 echo '== learner-plane smoke (on-device assembly golden parity +'
-echo '   failure paths + sharded Pallas V-trace parity selector, then'
-echo '   the tiny {batch,unroll}×depth bench rows via'
-echo '   BENCH_ONLY=learner_plane — <60 s CPU) =='
+echo '   failure paths + sharded Pallas V-trace parity selector'
+echo '   — <60 s CPU) =='
 JAX_PLATFORMS=cpu python -m pytest tests/test_learner_plane.py \
   "tests/test_parallel.py::test_pallas_vtrace_sharded_step_matches_single_device" \
   -q -p no:cacheprovider
-# 8 virtual devices: the vtrace_sharded row must exercise the
-# multi-shard shard_map path here (the bench chip has 1 device).
-XLA_FLAGS='--xla_force_host_platform_device_count=8' \
-  BENCH_SMOKE=1 BENCH_ONLY=learner_plane python bench.py
 
 echo '== sample-reuse smoke (circular replay tier + staged-arena'
-echo '   re-serve lifecycle + IMPACT clipped-target parity selector,'
-echo '   then the tiny replay_k x ratio rows + cue_memory curve run'
-echo '   via BENCH_ONLY=replay — <60 s CPU) =='
+echo '   re-serve lifecycle + IMPACT clipped-target parity selector'
+echo '   — <60 s CPU) =='
 JAX_PLATFORMS=cpu python -m pytest tests/test_replay.py \
   -q -k 'parity or tier or compos or validation or cadence' \
   -p no:cacheprovider
 JAX_PLATFORMS=cpu python -m pytest tests/test_learner_plane.py \
   -q -k 'reserve or reuse' -p no:cacheprovider
-BENCH_SMOKE=1 BENCH_ONLY=replay python bench.py
 
 echo '== pixel-control fast-path parity (integer rewards + d2s head'
 echo '   + bf16-Q levers vs the r5 reference forms — <60 s CPU) =='
@@ -293,27 +270,22 @@ echo '== sharding lane (round 19: the declarative registry as the one'
 echo '   source of sharding truth — rule/guard/opt-clone semantics,'
 echo '   the consumers-agree contract, the checkpoint manifest +'
 echo '   cross-mesh resharded restore, and the 2D {data,model} deep-'
-echo '   agent parity gate; then the DP vs DP+TP per-device bytes'
-echo '   rows via BENCH_ONLY=mesh2d and the sharding-registry lint'
+echo '   agent parity gate; then the sharding-registry lint'
 echo '   (no inline PartitionSpec outside parallel/sharding.py)'
 echo '   — <2 min CPU) =='
 JAX_PLATFORMS=cpu python -m pytest tests/test_sharding.py -q \
   -p no:cacheprovider
-XLA_FLAGS='--xla_force_host_platform_device_count=8' \
-  BENCH_SMOKE=1 BENCH_ONLY=mesh2d python bench.py
 python scripts/lint.py --check sharding-registry
 
 echo '== serving lane (round 21: the multi-tenant serving plane — the'
 echo '   version-table/codec/AOT/routing/wire-v10 unit suite + the'
-echo '   slow-marked 3-process routed drill, then the serving bench'
-echo '   rows (int8 parity gate + wire bytes + publish/flip blackout'
-echo '   + resident split) and the routed chaos storm: SIGKILL a'
+echo '   slow-marked 3-process routed drill, then the routed chaos'
+echo '   storm: SIGKILL a'
 echo '   serving replica under judged traffic, the router fails over'
 echo '   with zero starvation and a green routed-latency verdict'
 echo '   — <120 s CPU) =='
 JAX_PLATFORMS=cpu python -m pytest tests/test_serving.py -q \
   -p no:cacheprovider
-BENCH_SMOKE=1 BENCH_ONLY=serving python bench.py
 CHAOS_SMOKE=1 CHAOS_STORM=routed python scripts/chaos.py
 
 echo '== population lane (round 22: the population engine — in-graph'
@@ -323,8 +295,7 @@ echo '   one-invocation two-suite population drills (no -m filter:'
 echo '   the slow-marked curves run HERE), then a tiny real'
 echo '   --runtime=anakin --curriculum=regret driver run asserting'
 echo '   verdict PASS + per-level telemetry in summaries +'
-echo '   CURRICULUM_LEVELS.json, and the BENCH_ONLY=population smoke'
-echo '   (curriculum fps gate + padding-waste row) — <600 s CPU) =='
+echo '   CURRICULUM_LEVELS.json — <600 s CPU) =='
 JAX_PLATFORMS=cpu python -m pytest tests/test_population.py -q \
   -p no:cacheprovider
 JAX_PLATFORMS=cpu python - <<'POP_EOF'
@@ -356,7 +327,6 @@ assert len(levels['visits']) == 4 and sum(levels['visits']) > 0, levels
 print('population lane OK: regret curriculum in-graph, verdict PASS, '
       'per-level telemetry landed')
 POP_EOF
-BENCH_SMOKE=1 BENCH_ONLY=population python bench.py
 
 echo '== fused population + compile cache lane (round 23: vmapped PBT'
 echo '   members in ONE Anakin program, on-device weight inheritance,'

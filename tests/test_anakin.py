@@ -155,17 +155,18 @@ def test_anakin_shards_over_the_mesh():
 
 
 def test_anakin_train_artifacts_and_resume(tmp_path):
-  """The operator-facing loop (experiment.py --mode=anakin) produces
-  the standard run artifacts: config dump, JSONL summaries, a
-  checkpoint that a second invocation resumes from, and
-  total_environment_frames termination."""
-  import glob
-  import json
+  """The operator-facing loop (experiment.py --mode=train
+  --runtime=anakin) produces the standard run artifacts: config dump,
+  JSONL summaries, a checkpoint that a second invocation resumes
+  from, and total_environment_frames termination."""
+  from scalable_agent_tpu import driver
+  from scalable_agent_tpu.config import apply_overrides
   cfg = _anakin_config(
-      logdir=str(tmp_path), summary_secs=0, checkpoint_secs=0,
+      logdir=str(tmp_path), runtime='anakin', summary_secs=0,
+      checkpoint_secs=0,
       total_environment_frames=10 * 4 * 5)  # exactly 10 steps (B=4,T=5)
-  carry = anakin.train(cfg)
-  assert int(carry.train_state.update_steps) == 10
+  run = driver.train(cfg)
+  assert int(run.state.update_steps) == 10
 
   events = [json.loads(line) for line in
             open(str(tmp_path / 'summaries.jsonl'))]
@@ -176,13 +177,20 @@ def test_anakin_train_artifacts_and_resume(tmp_path):
       'env_backend'] == 'bandit'
 
   # Resume: frames target already met -> restores and stops at 10.
-  carry2 = anakin.train(cfg)
-  assert int(carry2.train_state.update_steps) == 10
+  run2 = driver.train(cfg)
+  assert int(run2.state.update_steps) == 10
   # And a raised target continues from the checkpoint, not from 0.
-  from scalable_agent_tpu.config import apply_overrides
-  carry3 = anakin.train(
+  run3 = driver.train(
       apply_overrides(cfg, total_environment_frames=12 * 4 * 5))
-  assert int(carry3.train_state.update_steps) == 12
+  assert int(run3.state.update_steps) == 12
+  # A structure mismatch on resume is refused, and the refusal leaves
+  # the ladder as it was (no tail save of a fresh state over it).
+  import glob
+  from scalable_agent_tpu.checkpoint import CheckpointStructureError
+  before = sorted(glob.glob(str(tmp_path / 'checkpoints' / '*')))
+  with pytest.raises(CheckpointStructureError):
+    driver.train(apply_overrides(cfg, use_instruction=True))
+  assert sorted(glob.glob(str(tmp_path / 'checkpoints' / '*'))) == before
 
 
 @pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
@@ -190,15 +198,16 @@ def test_anakin_train_restore_mismatch_does_not_overwrite(tmp_path):
   """A structure-mismatch on resume must raise (with the flag
   guidance), not tail-save a fresh incompatible state into the logdir."""
   import glob
-  import pytest
+  from scalable_agent_tpu import driver
   from scalable_agent_tpu.checkpoint import CheckpointStructureError
   from scalable_agent_tpu.config import apply_overrides
-  cfg = _anakin_config(logdir=str(tmp_path), checkpoint_secs=0,
+  cfg = _anakin_config(logdir=str(tmp_path), runtime='anakin',
+                       checkpoint_secs=0,
                        total_environment_frames=2 * 4 * 5)
-  anakin.train(cfg)
+  driver.train(cfg)
   before = sorted(glob.glob(str(tmp_path / 'checkpoints' / '*')))
   with pytest.raises(CheckpointStructureError):
-    anakin.train(apply_overrides(cfg, use_instruction=True))
+    driver.train(apply_overrides(cfg, use_instruction=True))
   assert sorted(glob.glob(str(tmp_path / 'checkpoints' / '*'))) == before
 
 

@@ -190,21 +190,26 @@ def test_summary_scalars_fires(tmp_path):
       'scalable_agent_tpu/driver.py':
           "def train(w):\n"
           "  w.scalar('mystery_tag', 1.0, 0)\n"
-          "  w.scalar('known_tag', 1.0, 0)\n"
           "  for key in ('loop_tag_a', 'known_tag'):\n"
           "    w.scalar(key, 2.0, 0)\n",
+      # The loops' shared lifecycle writes summary scalars too.
+      'scalable_agent_tpu/lifecycle.py':
+          "def write_health_scalars(w):\n"
+          "  w.scalar('known_tag', 1.0, 0)\n"
+          "  w.scalar('lifecycle_tag', 1.0, 0)\n",
       'docs/OBSERVABILITY.md': OBS_DOC,
   })
   symbols = {f.symbol for f in run_only(root, 'summary-scalars')}
   # Literal + loop-resolved tags missing from the doc block; the
   # documented known_tag is written, so it is NOT orphaned.
-  assert symbols == {'mystery_tag', 'loop_tag_a'}
+  assert symbols == {'mystery_tag', 'loop_tag_a', 'lifecycle_tag'}
 
 
 def test_summary_scalars_fix_docs_round_trip(tmp_path):
   files = {
       'scalable_agent_tpu/driver.py':
           "def train(w):\n  w.scalar('fresh_tag', 1.0, 0)\n",
+      'scalable_agent_tpu/lifecycle.py': '',
       'docs/OBSERVABILITY.md': OBS_DOC,
   }
   root = mini_repo(tmp_path, files)

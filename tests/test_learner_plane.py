@@ -447,30 +447,3 @@ class TestDriverIntegration:
     pf = run.prefetcher.stats()
     assert pf['mode'] == 'unroll'
     assert pf['unrolls_staged'] >= 16
-
-
-class TestBenchStage:
-
-  @pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
-  def test_learner_plane_smoke_rows(self, monkeypatch):
-    """Bench mechanics gate (CI): the stage produces every cell of the
-    {batch, unroll} × depth grid plus the sharded-vtrace and
-    metrics-readback rows."""
-    import bench
-    monkeypatch.setenv('BENCH_SMOKE', '1')
-    plane = bench.bench_learner_plane(smoke=True)
-    for mode in ('batch', 'unroll'):
-      for depth in (1, 2):
-        row = plane[f'{mode}_d{depth}']
-        assert row['mode'] == mode and row['depth'] == depth
-        assert 'exposed_feed_ms_per_step' in row
-        assert 'step_gap_ms' in row
-        assert 0.0 <= row['h2d_overlap_fraction'] <= 1.0
-        if mode == 'unroll':
-          assert row['stack_ms'] == 0.0
-    assert plane['bare_step_ms'] > 0
-    assert plane['vtrace_sharded']['pallas_ms'] > 0
-    assert plane['vtrace_sharded']['scan_ms'] > 0
-    assert plane['metrics_readback']['per_leaf_ms'] > 0
-    assert plane['metrics_readback']['stacked_read_ms'] > 0
-    assert plane['metrics_readback']['stack_dispatch_ms'] > 0

@@ -626,32 +626,6 @@ class TestDriverIntegration:
     assert len(episode_events) <= fresh_unroll_count
 
 
-class TestBenchStage:
-
-  @pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
-  def test_replay_smoke_rows(self, monkeypatch):
-    """Bench mechanics gate (CI): every replay_k x ratio cell lands
-    with its reuse/H2D accounting; the k2_r0 cell carries the >=1.8x
-    acceptance scaling with FEWER transfers per update than k1. The
-    cue_memory curve runs are stubbed out — BENCH_ONLY=replay
-    exercises them end to end in the CI lane."""
-    import bench
-    monkeypatch.setenv('BENCH_SMOKE', '1')
-    monkeypatch.setattr(bench, '_bench_replay_return_curves',
-                        lambda smoke: {'task': 'cue_memory'})
-    replay = bench.bench_replay(smoke=True)
-    for k in (1, 2, 4):
-      for r in (0, 50, 75):
-        row = replay[f'k{k}_r{r}']
-        assert row['replay_k'] == k
-        assert row['reuse_factor'] >= 1.0
-        assert row['fed_step_ms'] > 0
-    assert replay['k1_r0']['reuse_factor'] == pytest.approx(1.0)
-    assert replay['k2_r0']['reuse_factor'] >= 1.8
-    assert (replay['k2_r0']['h2d_unrolls_per_update'] <=
-            replay['k1_r0']['h2d_unrolls_per_update'] / 1.8)
-
-
 def test_replay_tier_crc_evicts_rotted_entry():
   """Round 12: a retained unroll mutated in host memory AFTER insert
   (the tier holds by reference — rot is exactly this shape) must be
