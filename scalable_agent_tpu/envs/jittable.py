@@ -33,6 +33,17 @@ registered in envs/factory.py — so one task definition runs under both
 runtimes, which is the substrate of the anakin-vs-fleet parity gate
 (tests/test_anakin.py). Dynamics parity is by construction, not by a
 twin implementation.
+
+HOW THE FRAME IS PAINTED (PR 31): every plane comes from maps that are
+static in (height, width, grid) — which cell a pixel lies in never
+changes — and none from a per-pixel lookup. Planes 0 and 1 (agent,
+goal: one lit cell an env) compare the two [H, W] maps of
+`_cell_masks` with the cell's [B] coordinates (`_cell_plane`). Plane
+2 of procgen (walls: a whole [B, G, G] layout an env) expands the
+layout along the two one-hot maps of `_cell_onehots`
+(`ProcgenCore._wall_plane`), elementwise in B, so it shards over the
+data axis like everything else here. The only gathers left are [B]
+lookups of one element each (`_moves()[action]`, `_blocked`).
 """
 
 import functools
@@ -51,13 +62,33 @@ def _zero_instr(batch):
   return jnp.zeros((batch, MAX_INSTRUCTION_LEN), jnp.int32)
 
 
+def _cell_of_pixel(size, grid):
+  """Static [size] map pixel → cell along one axis of the frame: the
+  same for every environment and every step (`size` need not divide
+  by `grid`)."""
+  return (np.arange(size) * grid) // max(size, 1)
+
+
 def _cell_masks(height, width, grid):
-  """Static [H, W] int32 maps pixel → cell row/col (rendering grid
-  cells into the frame without gathers)."""
-  rows = (np.arange(height) * grid) // max(height, 1)
-  cols = (np.arange(width) * grid) // max(width, 1)
+  """Static [H, W] int32 maps pixel → cell row/col: a plane with ONE
+  lit cell an env (agent, goal) is these two maps compared with the
+  cell's [B] coordinates (`_cell_plane`), without a gather. A plane
+  with a whole layout an env (procgen's walls) is painted from
+  `_cell_onehots` instead, without one either."""
+  rows = _cell_of_pixel(height, grid)
+  cols = _cell_of_pixel(width, grid)
   return (jnp.asarray(rows[:, None].repeat(width, 1), jnp.int32),
           jnp.asarray(cols[None, :].repeat(height, 0), jnp.int32))
+
+
+def _cell_onehots(height, width, grid):
+  """Static one-hot maps [H, G] pixel row → cell row and [W, G] pixel
+  column → cell column, as 0/1 in bfloat16 (NumPy: constants of
+  whatever program uses them)."""
+  cells = np.arange(grid)
+  return tuple(
+      (_cell_of_pixel(size, grid)[:, None] == cells).astype(jnp.bfloat16)
+      for size in (height, width))
 
 
 class GridworldState(NamedTuple):
@@ -241,6 +272,8 @@ class ProcgenCore(GridworldCore):
     self.curriculum = curriculum
     self.curriculum_temperature = curriculum_temperature
     self.curriculum_eps = curriculum_eps
+    self._row_onehot, self._col_onehot = _cell_onehots(
+        height, width, grid_size)
 
   def _walls(self, level_id):
     """[B, G, G] bool wall mask, a pure function of the level id."""
@@ -260,12 +293,22 @@ class ProcgenCore(GridworldCore):
     corner = jnp.asarray([self.grid - 1, self.grid - 1], jnp.int32)
     return jnp.broadcast_to(corner[None], (batch, 2))
 
+  def _wall_plane(self, walls):
+    """[B, G, G] layout → [B, H, W] bool: pixel (h, w) shows cell
+    (rows[h], cols[w]). The map from pixel to cell is static, so the
+    layout is EXPANDED along the two one-hot maps, elementwise in B,
+    and never looked up per pixel: on a TPU v5e an advanced-index
+    gather of this plane takes 24 ms at B=512, 64x64, this 0.05
+    (PERF.md §6, PR 31). Exact in bfloat16: every factor is 0 or 1 and
+    each sum has exactly one non-zero term."""
+    rows, cols = self._row_onehot, self._col_onehot
+    return jnp.einsum('bij,hi,wj->bhw', walls.astype(rows.dtype),
+                      rows, cols) > 0
+
   def _observation(self, state):
     agent = self._cell_plane(state.agent_yx)
     goal = self._cell_plane(state.goal_yx)
-    walls = self._walls(state.level_id)  # [B, G, G]
-    wall_plane = walls[jnp.arange(walls.shape[0])[:, None, None],
-                       self._row_cell[None], self._col_cell[None]]
+    wall_plane = self._wall_plane(self._walls(state.level_id))
     frame = jnp.stack(
         [agent.astype(jnp.uint8) * 255, goal.astype(jnp.uint8) * 255,
          wall_plane.astype(jnp.uint8) * 255], axis=-1)

@@ -8,6 +8,7 @@ return parity gate on cue_memory.
 
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -340,6 +341,125 @@ def test_procgen_levels_deterministic_and_walls_block():
   # step neither can fire — so blocked <-> stayed exactly.
   np.testing.assert_array_equal(moved, ~right_blocked)
   np.testing.assert_array_equal(stayed, right_blocked)
+
+
+@pytest.mark.parametrize('height,width,grid', [
+    (64, 64, 5), (72, 96, 5), (10, 10, 4), (24, 32, 7)])
+def test_procgen_frame_matches_a_numpy_oracle(height, width, grid):
+  """The whole frame against a per-pixel NumPy loop that shares no
+  code with the painter: plane 2 is 255 where the pixel's cell
+  (h*G//H, w*G//W) is a wall of the env's level, planes 0 and 1 are
+  the agent's and the goal's cells. H and W need not divide by G."""
+  from scalable_agent_tpu.envs.jittable import ProcgenCore
+  batch = 6
+  core = ProcgenCore(height=height, width=width, grid_size=grid,
+                     num_levels=6, wall_density=0.5)
+  state, _ = core.init(jax.random.PRNGKey(3), batch)
+  # Agents off the origin, a different cell each env.
+  cells = np.arange(batch) * 3 % (grid * grid)
+  state = state._replace(agent_yx=jnp.asarray(
+      np.stack([cells // grid, cells % grid], -1), jnp.int32))
+  frame = np.asarray(core._observation(state)[0])
+  assert frame.dtype == np.uint8
+  assert frame.shape == (batch, height, width, 3)
+
+  walls = np.asarray(core._walls(state.level_id))
+  agent = np.asarray(state.agent_yx)
+  goal = np.asarray(state.goal_yx)
+  assert walls.any() and not walls.all()
+  want = np.zeros((batch, height, width, 3), np.uint8)
+  for b in range(batch):
+    for h in range(height):
+      for w in range(width):
+        cell = ((h * grid) // height, (w * grid) // width)
+        want[b, h, w, 0] = 255 * (cell == tuple(agent[b]))
+        want[b, h, w, 1] = 255 * (cell == tuple(goal[b]))
+        want[b, h, w, 2] = 255 * walls[b, cell[0], cell[1]]
+  np.testing.assert_array_equal(frame, want)
+
+
+def _gather_result_sizes(hlo_text):
+  """Element counts of every `gather` instruction's result in an
+  optimised HLO module's text."""
+  return [int(np.prod([int(d) for d in dims.split(',') if d] or [1]))
+          for dims in re.findall(
+              r'= \w+\[([\d,]*)\]\S* gather\(', hlo_text)]
+
+
+# A seeded init + 5 steps of ProcgenCore(5x6, G=3, 5 levels, density
+# 0.3, episode 4) at B=4, taken from the tree BEFORE the wall plane
+# stopped being gathered (PR 31's parent, 82f8bf7): env 0 reaches the
+# goal at step 4, env 1 walks into walls and stays, the others time
+# out; every env draws a fresh level after it. Frames are six
+# [4, 5, 6, 3] uint8 frames of 0/255, bit-packed.
+_PROCGEN_FIXTURE_ACTIONS = [[3, 1, 0, 1], [3, 0, 3, 1], [1, 1, 2, 1],
+                            [1, 3, 3, 1], [2, 1, 3, 2]]
+_PROCGEN_FIXTURE_REWARD = [[0, 0, 0, 0], [0, 0, 0, 0], [0, 0, 0, 0],
+                           [1, 0, 0, 0], [0, 0, 0, 0]]
+_PROCGEN_FIXTURE_DONE = [[0, 0, 0, 0], [0, 0, 0, 0], [0, 0, 0, 0],
+                         [1, 1, 1, 1], [0, 0, 0, 0]]
+_PROCGEN_FIXTURE_LEVELS = [[3, 1, 2, 3], [3, 1, 2, 3], [3, 1, 2, 3],
+                           [3, 1, 2, 3], [0, 3, 3, 2], [0, 3, 3, 2]]
+_PROCGEN_FIXTURE_FRAMES = (
+    '9000240000090002400004a4249909249000240000252900264009000000'
+    '0002494a40009000002400090000120240009000090002400004a4249909'
+    '249000240000252900264009000000000249480000000024240909000012'
+    '0009000240090002400004a4249909249000240000252024240909000000'
+    '0002494800000000002400090240120000000000099002640004a4249909'
+    '249000240000252900264009000000000249480000000000240009024012'
+    '9000240002490092400004a4000900000240009000012900024000009000'
+    '2400004a4009900240000000009252900024000249009240000480000000'
+    '0242409090000120240009000090002400004a4009900240000000009252')
+
+
+def test_procgen_paints_walls_without_a_per_pixel_gather():
+  """The wall plane is an expansion of the [B, G, G] layout along
+  static pixel->cell maps, not a lookup at each of B*H*W pixels: the
+  compiled `_observation` and `step` hold no gather of that size
+  (`_blocked`'s [B] lookup of one cell each stays). And the expansion
+  is the same work: a seeded trajectory equals the parent's, frame
+  for frame."""
+  from scalable_agent_tpu.envs.jittable import ProcgenCore
+  batch, height, width = 8, 24, 32
+  core = ProcgenCore(height=height, width=width, grid_size=5)
+  state, _ = core.init(jax.random.PRNGKey(0), batch)
+  action = jnp.zeros((batch,), jnp.int32)
+  for fn, args in ((core._observation, (state,)),
+                   (core.step, (state, action))):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    sizes = _gather_result_sizes(text)
+    assert not [n for n in sizes if n >= batch * height * width], sizes
+  # The detector sees a per-pixel gather when there is one.
+  rows = jnp.asarray((np.arange(height) * 5) // height)
+  gathered = jax.jit(lambda w: w[:, rows]).lower(
+      jnp.zeros((batch, 5, width), bool)).compile().as_text()
+  assert max(_gather_result_sizes(gathered)) >= batch * height
+
+  core = ProcgenCore(height=5, width=6, grid_size=3, episode_length=4,
+                     num_levels=5, wall_density=0.3)
+  state, out = core.init(jax.random.PRNGKey(20), 4)
+  frames, levels = [out.observation[0]], [state.level_id]
+  rewards, dones = [], []
+  step = jax.jit(core.step)
+  for action in _PROCGEN_FIXTURE_ACTIONS:
+    state, out = step(state, jnp.asarray(action, jnp.int32))
+    frames.append(out.observation[0])
+    levels.append(state.level_id)
+    rewards.append(out.reward)
+    dones.append(out.done)
+  frames = np.stack(frames)
+  assert frames.dtype == np.uint8 and frames.shape == (6, 4, 5, 6, 3)
+  want = np.unpackbits(np.frombuffer(
+      bytes.fromhex(_PROCGEN_FIXTURE_FRAMES), np.uint8))
+  np.testing.assert_array_equal(frames,
+                                255 * want.reshape(frames.shape))
+  np.testing.assert_array_equal(np.stack(rewards),
+                                np.asarray(_PROCGEN_FIXTURE_REWARD,
+                                           np.float32))
+  np.testing.assert_array_equal(np.stack(dones),
+                                np.asarray(_PROCGEN_FIXTURE_DONE, bool))
+  np.testing.assert_array_equal(np.stack(levels),
+                                _PROCGEN_FIXTURE_LEVELS)
 
 
 def test_jittable_host_envs_run_the_same_cores():
