@@ -201,10 +201,89 @@ flags.DEFINE_float('seq_rope_theta', _DEFAULTS.seq_rope_theta,
                    'Sequence agent: RoPE base.')
 flags.DEFINE_float('seq_norm_eps', _DEFAULTS.seq_norm_eps,
                    'Sequence agent: RMSNorm epsilon.')
+flags.DEFINE_integer('seq_kv_lora_rank', _DEFAULTS.seq_kv_lora_rank,
+                     'Sequence agent: 0 for the power-retention core; '
+                     'above 0 the core is latent attention (MLA) with '
+                     'this latent width, over a per-session latent cache, '
+                     'with dense and routed-expert feed-forward layers '
+                     '(the flags below; --seq_num_kv_heads and '
+                     '--seq_head_dim are then unused).', lower_bound=0)
+flags.DEFINE_integer('seq_q_lora_rank', _DEFAULTS.seq_q_lora_rank,
+                     'Latent core: the query latent\'s width.',
+                     lower_bound=1)
+flags.DEFINE_integer('seq_qk_nope_head_dim', _DEFAULTS.seq_qk_nope_head_dim,
+                     'Latent core: a head\'s query/key width without '
+                     'position.', lower_bound=1)
+flags.DEFINE_integer('seq_qk_rope_head_dim', _DEFAULTS.seq_qk_rope_head_dim,
+                     'Latent core: a head\'s rotary width (even); the '
+                     'rotary key is one for all heads.', lower_bound=2)
+flags.DEFINE_integer('seq_v_head_dim', _DEFAULTS.seq_v_head_dim,
+                     'Latent core: a head\'s value width.', lower_bound=1)
+flags.DEFINE_integer('seq_first_dense_layers',
+                     _DEFAULTS.seq_first_dense_layers,
+                     'Latent core: leading layers whose feed-forward is '
+                     'the dense MLP of --seq_mlp_size; the others route.',
+                     lower_bound=1)
+flags.DEFINE_integer('seq_moe_size', _DEFAULTS.seq_moe_size,
+                     'Latent core: an expert\'s SwiGLU width.',
+                     lower_bound=1)
+flags.DEFINE_integer('seq_routed_experts', _DEFAULTS.seq_routed_experts,
+                     'Latent core: routed experts, the router\'s outputs.',
+                     lower_bound=2)
+flags.DEFINE_integer('seq_experts_held', _DEFAULTS.seq_experts_held,
+                     'Latent core: how many of the routed experts this '
+                     'process holds and computes (its share of an '
+                     'expert-parallel deployment).', lower_bound=1)
+flags.DEFINE_integer('seq_expert_offset', _DEFAULTS.seq_expert_offset,
+                     'Latent core: the first expert held.', lower_bound=0)
+flags.DEFINE_integer('seq_experts_per_token',
+                     _DEFAULTS.seq_experts_per_token,
+                     'Latent core: experts chosen a token.', lower_bound=1)
+flags.DEFINE_integer('seq_expert_groups', _DEFAULTS.seq_expert_groups,
+                     'Latent core: groups the routed experts lie in.',
+                     lower_bound=1)
+flags.DEFINE_integer('seq_expert_groups_kept',
+                     _DEFAULTS.seq_expert_groups_kept,
+                     'Latent core: groups a token may choose from.',
+                     lower_bound=1)
+flags.DEFINE_float('seq_routed_scale', _DEFAULTS.seq_routed_scale,
+                   'Latent core: scale on the normalised expert weights.')
+flags.DEFINE_integer('seq_shared_experts', _DEFAULTS.seq_shared_experts,
+                     'Latent core: shared experts (one SwiGLU of that '
+                     'many times --seq_moe_size).', lower_bound=1)
+flags.DEFINE_float('seq_rope_factor', _DEFAULTS.seq_rope_factor,
+                   'Latent core: YaRN context-extension factor (1: plain '
+                   'rotary).')
+flags.DEFINE_integer('seq_rope_original_max',
+                     _DEFAULTS.seq_rope_original_max,
+                     'Latent core: YaRN original_max_position_embeddings.',
+                     lower_bound=1)
+flags.DEFINE_float('seq_rope_beta_fast', _DEFAULTS.seq_rope_beta_fast,
+                   'Latent core: YaRN beta_fast.')
+flags.DEFINE_float('seq_rope_beta_slow', _DEFAULTS.seq_rope_beta_slow,
+                   'Latent core: YaRN beta_slow.')
+flags.DEFINE_float('seq_rope_mscale', _DEFAULTS.seq_rope_mscale,
+                   'Latent core: YaRN mscale.')
+flags.DEFINE_float('seq_rope_mscale_all_dim',
+                   _DEFAULTS.seq_rope_mscale_all_dim,
+                   'Latent core: YaRN mscale_all_dim.')
+flags.DEFINE_integer('seq_cache_capacity', _DEFAULTS.seq_cache_capacity,
+                     'Latent core: tokens of an episode a session\'s '
+                     'latent cache holds (at least --episode_length).',
+                     lower_bound=1)
+flags.DEFINE_integer('seq_prefill_chunk', _DEFAULTS.seq_prefill_chunk,
+                     'Latent core: tokens one prefill call takes; an '
+                     'episode\'s prompt reaches the server in such '
+                     'blocks.', lower_bound=1)
 flags.DEFINE_integer('token_prompt_length',
                      _DEFAULTS.token_prompt_length,
                      'tokens backend: seeded prompt tokens an episode.',
                      lower_bound=1)
+flags.DEFINE_integer('token_prompt_stride',
+                     _DEFAULTS.token_prompt_stride,
+                     'tokens backend: session i\'s prompt is this many '
+                     'tokens times (i mod --num_actors) longer.',
+                     lower_bound=0)
 flags.DEFINE_integer('model_parallelism', _DEFAULTS.model_parallelism,
                      'TP width of the device mesh.')
 flags.DEFINE_bool('use_py_process', _DEFAULTS.use_py_process,

@@ -135,9 +135,42 @@ class Config:
   seq_mlp_size: int = 128
   seq_rope_theta: float = 1e6
   seq_norm_eps: float = 1e-6
+  # The sequence agent's core. seq_kv_lora_rank 0: power-retention
+  # blocks (the fields above). Above 0: latent attention (MLA) over a
+  # per-session latent cache, dense and routed-expert feed-forward
+  # layers (models/latent_moe.py); seq_num_kv_heads and seq_head_dim
+  # are then unused. Defaults: the tiny size the CPU tests run.
+  seq_kv_lora_rank: int = 0
+  seq_q_lora_rank: int = 24
+  seq_qk_nope_head_dim: int = 8
+  seq_qk_rope_head_dim: int = 4
+  seq_v_head_dim: int = 8
+  seq_first_dense_layers: int = 1         # leading layers with the dense MLP
+  seq_moe_size: int = 32                  # an expert's width
+  seq_routed_experts: int = 16            # the router's outputs
+  seq_experts_held: int = 4               # of them computed here ...
+  seq_expert_offset: int = 0              # ... from this one on
+  seq_experts_per_token: int = 4
+  seq_expert_groups: int = 4
+  seq_expert_groups_kept: int = 2
+  seq_routed_scale: float = 2.5
+  seq_shared_experts: int = 1
+  seq_rope_factor: float = 40.0           # YaRN; 1: plain rotary
+  seq_rope_original_max: int = 4096
+  seq_rope_beta_fast: float = 32.0
+  seq_rope_beta_slow: float = 1.0
+  seq_rope_mscale: float = 1.0
+  seq_rope_mscale_all_dim: float = 1.0
+  # Tokens of an episode a session's cache holds (>= --episode_length),
+  # and the tokens one prefill call takes: where the core computes a
+  # chunk at once, an episode's prompt reaches the server as a block.
+  seq_cache_capacity: int = 64
+  seq_prefill_chunk: int = 8
   # 'tokens' backend: seeded prompt tokens at the start of each
-  # episode of --episode_length steps.
+  # episode of --episode_length steps; session i's prompt is
+  # token_prompt_stride * (i mod --num_actors) tokens longer.
   token_prompt_length: int = 4
+  token_prompt_stride: int = 0
   use_associative_scan: bool = False      # parallel V-trace recursion
   use_pallas_vtrace: bool = False         # fused Pallas V-trace kernel
   use_popart: bool = False                # PopArt value normalization
@@ -1180,6 +1213,22 @@ def validate_runtime(config: Config) -> List[str]:
     if config.use_popart or config.pixel_control_cost > 0:
       raise ValueError('--agent=sequence has neither PopArt value '
                        'columns nor a pixel-control head')
+    if config.seq_kv_lora_rank > 0:
+      if config.episode_length > config.seq_cache_capacity:
+        raise ValueError(
+            f'an episode of {config.episode_length} tokens does not fit '
+            f'a cache of --seq_cache_capacity={config.seq_cache_capacity}')
+      if not 0 < config.seq_first_dense_layers <= config.seq_num_layers:
+        raise ValueError('--seq_first_dense_layers lies in 1..'
+                         '--seq_num_layers')
+  longest_prompt = (config.token_prompt_length + config.token_prompt_stride
+                    * max(config.num_actors - 1, 0))
+  if (config.env_backend == 'tokens' and config.token_prompt_stride and
+      longest_prompt >= config.episode_length):
+    raise ValueError(
+        f'the longest prompt ({longest_prompt} tokens: '
+        '--token_prompt_length + --token_prompt_stride * (--num_actors '
+        f'- 1)) must lie inside the episode of {config.episode_length}')
   if config.runtime == 'anakin':
     if config.env_backend not in JITTABLE_BACKENDS:
       raise ValueError(

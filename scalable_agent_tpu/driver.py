@@ -58,6 +58,7 @@ from scalable_agent_tpu.config import (Config, validate_controller,
 from scalable_agent_tpu.envs import factory, suites
 from scalable_agent_tpu.models import (ImpalaAgent, SequenceAgent,
                                       init_params)
+from scalable_agent_tpu.models import latent_moe
 from scalable_agent_tpu.parallel import mesh as mesh_lib
 from scalable_agent_tpu.parallel import sharding as sharding_lib
 from scalable_agent_tpu.parallel import train_parallel
@@ -113,7 +114,15 @@ def build_agent(config: Config, num_actions: int, num_tasks: int = 1):
   dtype = (jnp.bfloat16 if config.compute_dtype == 'bfloat16'
            else jnp.float32)
   if config.agent == 'sequence':
+    latent = None
+    if config.seq_kv_lora_rank > 0:
+      # Every field of the latent core's widths is the flag of its name.
+      latent = latent_moe.LatentMoEDims(**{
+          field.name: getattr(config, f'seq_{field.name}')
+          for field in dataclasses.fields(latent_moe.LatentMoEDims)})
+      latent.check()
     return SequenceAgent(
+        latent=latent,
         num_actions=num_actions, num_layers=config.seq_num_layers,
         hidden_size=config.seq_hidden_size,
         num_heads=config.seq_num_heads,
@@ -725,6 +734,14 @@ def train(config: Config, max_steps: Optional[int] = None,
     # actors would run inference on deleted buffers (real on TPU;
     # invisible on CPU tests, where jit ignores donation).
     server.update_params(initial_pub, version=_initial_steps)
+    if getattr(agent, 'prefill_chunk', 0):
+      server.close()
+      raise ValueError(
+          'this agent\'s core takes an episode\'s prompt as a block '
+          'through the inference server (prefill), which no unroll '
+          'records, so the learner\'s pass over the unroll would not be '
+          'the actor\'s: such a policy can be served (driver.play), not '
+          'yet trained')
     state_bytes = server.stats()['state_bytes_per_slot']
     if state_bytes > inference_lib.MAX_HOST_STATE_BYTES:
       server.close()

@@ -111,11 +111,21 @@ def make_env_spec(config: Config, level_name: str, seed: int,
     # Consecutive seeds (make_fleet's) start a prompt's length apart
     # in their first episode: session i is prompt_length * i steps
     # further than session 0, modulo the episode.
+    # With a stride, session i's prompt is stride * (i mod fleet)
+    # tokens longer. Where the agent's core computes a chunk at once
+    # and its state lives in the server's arena, the prompt goes over
+    # as a block (envs/tokens.py), sized for the fleet's longest.
+    fleet = max(config.num_actors, 1)
+    chunked = config.seq_kv_lora_rank > 0 and config.inference_state_cache
     kwargs = dict(vocab_size=config.num_actions,
                   episode_length=config.episode_length,
-                  prompt_length=config.token_prompt_length,
+                  prompt_length=(config.token_prompt_length +
+                                 config.token_prompt_stride * (seed % fleet)),
                   seed=seed, level_name=level_name,
-                  start_step=config.token_prompt_length * seed)
+                  start_step=config.token_prompt_length * seed,
+                  prompt_block=(config.token_prompt_length +
+                                config.token_prompt_stride * (fleet - 1)
+                                if chunked else 0))
     return EnvSpec(tokens.TokenEnv, kwargs, config.num_actions,
                    observation_leaves=tuple(
                        (spec.shape, spec.dtype)

@@ -15,7 +15,15 @@ A core is a flax module with
 - `unroll(core, carry, xs, dones)`: the scan of that step over time.
   A function of this file and not a method, so every core's unroll IS
   the scan of its step (the learner and the actors then compute the
-  same thing by construction).
+  same thing by construction);
+- `chunk(carry, xs, n_valid, reset, slot=None)`: ONE session advanced
+  by the first `n_valid` of the `C` inputs `xs [C, ...]` (the rest are
+  padding: they change nothing), its state zeroed first where `reset`
+  says the chunk begins an episode. The default IS the scan of `step`,
+  so every core has it; a core whose step over `C` tokens at once is
+  cheaper than `C` steps (attention over a cache) overrides it and
+  says so in `chunk_size`, which is what makes the serving path hand a
+  session's prompt over as blocks (runtime/inference.py).
 
 With `slots` (i32 `[B]` slot ids) the carry handed to `step` is the
 inference server's ARENA instead: per state leaf one `[rows, ...]`
@@ -78,6 +86,31 @@ class RecurrentCore(nn.Module):
     `slots` of `arena`: gather, step, scatter."""
     carry, out = step(gather_rows(arena, slots), x, done)
     return scatter_rows(arena, slots, carry), out
+
+  # Tokens a `chunk` call takes where the core computes a chunk at
+  # once; 0: the core has no form of its own, and nobody chunks for it.
+  chunk_size = 0
+
+  def chunk(self, carry, xs, n_valid, reset, slot=None):
+    """The chunk form by the scan of `step`: `carry` is the session's
+    own (`[1, ...]` leaves) or, with `slot` (i32 scalar), the arena, of
+    which row `slot` is advanced -> (carry, outs [C, ...])."""
+    rows = carry if slot is None else gather_rows(carry, slot[None])
+
+    def one(core, rows, inputs):
+      x, t = inputs
+      new, out = core.step(rows, x[None], (reset & (t == 0))[None])
+      keep = t < n_valid
+      rows = jax.tree_util.tree_map(
+          lambda n, o: jnp.where(keep, n, o), new, rows)
+      return rows, out[0]
+
+    scan = nn.scan(one, variable_broadcast='params',
+                   split_rngs={'params': False})
+    rows, outs = scan(self, rows, (xs, jnp.arange(xs.shape[0])))
+    if slot is None:
+      return rows, outs
+    return scatter_rows(carry, slot[None], rows), outs
 
 
 def unroll(core, carry, xs, dones, scan_unroll=1):

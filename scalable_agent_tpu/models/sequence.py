@@ -1,5 +1,7 @@
-"""A sequence policy: token embedding -> recurrent core of retention
-blocks -> norm -> policy head over the vocabulary and a value head.
+"""A sequence policy: token embedding -> recurrent core -> norm ->
+policy head over the vocabulary and a value head. The core is the
+stack of power-retention blocks, or with `latent` given (the widths of
+latent attention and routed experts: models/latent_moe.py) that stack.
 
 It answers the same call as `ImpalaAgent` (`prev_actions, env_outputs,
 core_state, sample_rng`), so the inference server, the actors and
@@ -17,18 +19,26 @@ flag:
 - in the learner's pass (`sample_rng` None) `policy_logits` is the full
   `[T, B, vocabulary]`, inside the step's program only.
 
+- a core that computes a chunk of tokens at once says so
+  (`prefill_chunk`, from `RecurrentCore.chunk_size`), and the serving
+  path then hands a session's prompt over in blocks through
+  `prefill`: embedding and core, no head;
+- a core that counts what a call did (`call_counters`) sows the
+  counts, and the inference server reads them with the call's outputs.
+
 The model has no value head of its own: `baseline` is this system's
 `w.x + b` on the final norm's output [assumed].
 """
 
 import functools
-from typing import Any
+from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from scalable_agent_tpu.models import core as core_lib
+from scalable_agent_tpu.models import latent_moe
 from scalable_agent_tpu.models import retention
 from scalable_agent_tpu.parallel.sharding import (
     merge_time_batch, split_time_batch)
@@ -48,14 +58,22 @@ class SequenceAgent(nn.Module):
   scan_unroll: int = 1
   dtype: Any = jnp.float32        # the projections' operands
   param_dtype: Any = jnp.float32  # bfloat16 when served at full width
+  # Given: the core is the latent-attention stack (`num_kv_heads` and
+  # `head_dim` are then the retention stack's and unused).
+  latent: Optional[latent_moe.LatentMoEDims] = None
 
   # The leaves of `StepOutput.observation`, in order.
   observation_names = ('token',)
 
   def core(self, **placement):
-    """The retention stack: detached (for its shapes), or named in
-    `__call__`."""
+    """The core the widths name: detached (for its shapes), or named
+    in `__call__`."""
     placement = placement or {'parent': None}
+    if self.latent is not None:
+      return latent_moe.LatentMoEStack(
+          self.num_layers, self.hidden_size, self.num_heads,
+          self.mlp_size, self.rope_theta, self.norm_eps, self.latent,
+          self.dtype, self.param_dtype, **placement)
     return retention.PowerRetentionStack(
         self.num_layers, self.hidden_size, self.num_heads,
         self.num_kv_heads, self.head_dim, self.mlp_size, self.rope_theta,
@@ -67,26 +85,54 @@ class SequenceAgent(nn.Module):
   def state_arena(self, num_slots):
     return self.core().arena(num_slots)
 
+  @property
+  def prefill_chunk(self):
+    """Tokens a `prefill` call takes; 0: the prompt comes a token a
+    policy call, as every other observation."""
+    return self.core().chunk_size
+
+  @property
+  def cache_capacity(self):
+    """Tokens of an episode a session's state holds; 0: a state of
+    fixed size, whatever the episode's length."""
+    return self.latent.cache_capacity if self.latent is not None else 0
+
+  @property
+  def call_counters(self):
+    return latent_moe.COUNTERS if self.latent is not None else ()
+
+  def prefill(self, tokens, core_state, slot, n_valid, reset):
+    """The chunk call without the head: session `slot` of the arena
+    `core_state` advanced by the first `n_valid` of `tokens` i32 [C],
+    from an empty state where `reset` -> the arena."""
+    return self(None, tokens, core_state, chunk=(slot, n_valid, reset))
+
   @nn.compact
   def __call__(self, prev_actions, env_outputs, core_state,
                sample_rng=None, level_ids=None,
                compute_pixel_control=False, state_slots=None,
-               batch_shards=1):
+               batch_shards=1, chunk=None):
     """Unroll over a [T, B] trajectory of tokens; see `ImpalaAgent` for
     the arguments (`batch_shards`: the heads' merged rows lie
     shard-major, as there). With `state_slots` (i32 [B]; T must be 1)
     `core_state` is the server's state arena and is returned advanced
-    in the rows `state_slots`."""
+    in the rows `state_slots`. With `chunk` the call is `prefill`'s:
+    `env_outputs` is the token block, and the arena is all it
+    returns."""
     del prev_actions, level_ids, compute_pixel_control  # the token says it
-    (token,) = env_outputs.observation
-    done = env_outputs.done
-    t, b = token.shape
+    token = (env_outputs if chunk is not None
+             else env_outputs.observation[0])
     with jax.named_scope('embed'):
       table = self.param('embedding', nn.initializers.normal(1.0),
                          (self.num_actions, self.hidden_size),
                          self.param_dtype)
       x = jnp.take(table, token, axis=0).astype(jnp.float32)
     core = self.core(name='core')
+    if chunk is not None:  # `prefill`
+      slot, n_valid, reset = chunk
+      return core.chunk(core_state, x, n_valid, reset, slot)[0]
+    done = env_outputs.done
+    t, b = token.shape
     if state_slots is None:
       new_state, out = core_lib.unroll(core, core_state, x, done,
                                        self.scan_unroll)

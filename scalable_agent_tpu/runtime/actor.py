@@ -134,6 +134,26 @@ class Actor:
         policy_logits=np.zeros_like(np.asarray(out.policy_logits)),
         baseline=np.float32(0.0))
 
+  def _hand_over_prompt(self):
+    """The env output the next policy call sees. Where an episode
+    begins, the policy state lives with a server whose core computes
+    a chunk of tokens at once (`prefill_chunk`) and the env offers the
+    episode's prompt as a block (`prompt_block`: all but its last
+    token, which is the observation at hand), the block goes to the
+    server first. The call that follows must not reset what the block
+    built: the policy sees this step's `done` cleared, the unroll
+    keeps it."""
+    out, state = self._env_output, self._core_state
+    if not (out.done and getattr(state, 'prefill_chunk', 0)):
+      return out
+    fetch = getattr(self._env, 'prompt_block', None)
+    block = fetch() if fetch is not None else None
+    if block is None:
+      return out
+    tokens, n = block
+    state.prefill(np.asarray(tokens)[:int(n)])
+    return out._replace(done=np.bool_(False))
+
   def _record_step(self, agent_output, core_state, reward, done,
                    observation):
     # Flow-style episode accounting (output carries final stats at
@@ -261,8 +281,8 @@ class ActorGroup:
         actor._begin_unroll()
       unprimed = [a for a in actors if a._agent_output is None]
       if unprimed:
-        outs, _ = self._policy_call(unprimed,
-                                    [np.int32(0)] * len(unprimed))
+        outs, _ = self._policy_call(
+            unprimed, [np.int32(0)] * len(unprimed), priming=True)
         for actor, out in zip(unprimed, outs):
           actor._primed(out)
       for actor in actors:
@@ -284,12 +304,15 @@ class ActorGroup:
               for actor, span_id in zip(actors, span_ids)]
 
   @staticmethod
-  def _policy_call(actors, prev_actions):
+  def _policy_call(actors, prev_actions, priming=False):
     """One policy call for `actors` -> (their AgentOutputs of numpy
-    scalars, their new core states)."""
+    scalars, their new core states). The priming call's state is put
+    back afterwards, so no prompt is handed over for it."""
     policy = actors[0]._policy
+    env_outputs = [a._env_output if priming else a._hand_over_prompt()
+                   for a in actors]
     if len(actors) == 1:
-      out, core_state = policy(prev_actions[0], actors[0]._env_output,
+      out, core_state = policy(prev_actions[0], env_outputs[0],
                                actors[0]._core_state)
       return [AgentOutput(*[np.asarray(x) for x in out])], [core_state]
     import jax
@@ -297,7 +320,7 @@ class ActorGroup:
     handles = hasattr(states[0], 'snapshot')
     out, new_states = policy(
         np.asarray(prev_actions, np.int32),
-        _tree_stack([a._env_output for a in actors]),
+        _tree_stack(env_outputs),
         states if handles else jax.tree_util.tree_map(
             lambda *xs: np.concatenate(xs, axis=0), *states))
     out = [np.asarray(x) for x in out]

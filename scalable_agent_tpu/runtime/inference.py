@@ -106,6 +106,21 @@ the serve path (misses fall back to the jit cache and count
 stats()['aot_misses']). `serve_remote` serves carry-passing batches
 from the same table for the wire-v10 routed inference service
 (runtime/routing.py).
+
+PR 32 (a state that grows with the episode): an agent whose core
+computes a chunk of tokens at once (`agent.prefill_chunk`; a cache of
+latents read by attention, models/latent_moe.py) gets a second compiled
+program beside the step, `prefill_chunk`: ONE session's slot advanced
+by up to that many tokens, on the same donated arena. `prefill(handle,
+tokens)` (an actor calls it through its slot handle where an episode
+begins) dispatches ceil(n / chunk) of them under `_arena_lock`, so
+they take their place in the arena's chain among the merged calls in
+flight; `warmup` compiles it. Such a state is written AT A POSITION:
+`done` resets the position in-graph, not the row, and the server
+follows every slot's position on the host (it sees each call's `done`
+rows and each block's length) to count the cached tokens the calls
+read. Counters an agent's layers sow a call (`agent.call_counters`)
+come back with the call's own readback.
 """
 
 import collections
@@ -116,6 +131,7 @@ import time
 
 import numpy as np
 
+import flax.traverse_util
 import jax
 import jax.numpy as jnp
 
@@ -146,6 +162,18 @@ _AOT_MISSES = telemetry.counter('serving/aot_misses')
 _STATE_BYTES_PER_SLOT = telemetry.gauge('serving/state_bytes_per_slot')
 _ARENA_BYTES = telemetry.gauge('serving/arena_bytes')
 _STATE_RESETS = telemetry.counter('serving/state_resets')
+# A state written at a position (PR 32): the prompt tokens handed over
+# in blocks and the chunk programs they took, the cached tokens the
+# merged calls' live rows read, what a slot's cache holds at most, and
+# the sums of the per-call counters an agent's layers may sow
+# (`agent.call_counters` names some of these).
+_CALL_COUNTERS = {
+    'routed_rows_held': telemetry.counter('serving/routed_rows_held'),
+    'experts_hit': telemetry.counter('serving/experts_hit')}
+_PREFILL_TOKENS = telemetry.counter('serving/prefill_tokens')
+_PREFILL_CHUNKS = telemetry.counter('serving/prefill_chunks')
+_CACHE_TOKENS_READ = telemetry.counter('serving/cache_tokens_read')
+_CACHE_CAPACITY = telemetry.gauge('serving/cache_capacity')
 
 # Admission priority classes (lower = served first): a released slot
 # is handed to the best-priority parked waiter, so background churn
@@ -258,6 +286,9 @@ class _SlotHandle:
   - `release()`: return the slot to the free list (idempotent). The
     slot is zeroed again on the NEXT acquire, so a reclaimed slot can
     never serve a stale carry.
+  - `prefill_chunk`, `prefill(tokens)`: where the agent's core
+    computes a chunk at once, an episode's prompt goes in as a block
+    before the session's next policy call.
   """
 
   __slots__ = ('_server', 'slot', 'released')
@@ -284,6 +315,19 @@ class _SlotHandle:
     if not self.released:
       self.released = True
       self._server._release_slot(self.slot)
+
+  @property
+  def prefill_chunk(self):
+    """Tokens one chunk program takes; 0: the agent's core has no
+    chunk form of its own, and a prompt comes a token a policy call."""
+    return self._server.prefill_chunk
+
+  def prefill(self, tokens):
+    """Begin an episode in this slot with `tokens` i32 [n] behind it
+    (`InferenceServer.prefill`)."""
+    if self.released:
+      raise RuntimeError('prefill() on a released state slot')
+    self._server.prefill(self, tokens)
 
   def __repr__(self):
     return (f'_SlotHandle(slot={self.slot}, '
@@ -403,6 +447,11 @@ class InferenceServer:
   _shadow_calls: guarded_by('_stats_lock')
   _shadow_divergence: guarded_by('_stats_lock')
   _aot_misses: guarded_by('_stats_lock')
+  _slot_pos: guarded_by('_stats_lock')
+  _prefill_tokens: guarded_by('_stats_lock')
+  _prefill_chunks: guarded_by('_stats_lock')
+  _cache_tokens_read: guarded_by('_stats_lock')
+  _call_counts: guarded_by('_stats_lock')
 
   def __init__(self, agent, params, config, seed=0, mesh=None,
                pad_batch_to=None, fleet_size=None):
@@ -540,6 +589,24 @@ class InferenceServer:
     self._chain_recoveries = 0
     self._state_resets = 0
     self._max_batch = config.inference_max_batch
+    # A state written at a position (module docstring, PR 32).
+    self.prefill_chunk = int(getattr(agent, 'prefill_chunk', 0))
+    self._cache_capacity = int(getattr(agent, 'cache_capacity', 0))
+    self._counter_names = tuple(getattr(agent, 'call_counters', ()))
+    self._call_counts = {name: 0 for name in self._counter_names
+                         if name in _CALL_COUNTERS}
+    if len(self._call_counts) != len(self._counter_names):
+      raise ValueError(f'call counters {self._counter_names}: the '
+                       f'server knows {sorted(_CALL_COUNTERS)}')
+    self._prefill_tokens = 0
+    self._prefill_chunks = 0
+    self._cache_tokens_read = 0
+    self._slot_pos = np.zeros((0,), np.int64)
+    if (self.prefill_chunk or self._counter_names) and not (
+        self._state_cache):
+      raise ValueError(
+          'an agent whose core computes chunks or counts its calls '
+          'keeps its state in the arena: --inference_state_cache')
 
     # --- Device-resident state arena (state-cache mode). ---
     # Lock order where nested: _slot_lock -> _arena_lock (the grow
@@ -560,6 +627,7 @@ class InferenceServer:
       self._num_slots = num_slots
       self._free = list(range(num_slots))
       self._arena = self._new_arena(num_slots)
+      self._slot_pos = np.zeros((num_slots,), np.int64)
     else:
       self._num_slots = 0
       self._free = []
@@ -567,8 +635,10 @@ class InferenceServer:
     if mesh is not None:
       self._key = jax.device_put(self._key, self._replicated)
 
+    counted = bool(self._counter_names)
+
     def _apply(params, sub, prev_action, reward, done, obs, core_state,
-               slots=None):
+               slots=None, counters=False):
       # Int8-resident versions (publish_codec=int8) dequantize HERE,
       # in-graph: XLA fuses the per-leaf multiply into the step, so
       # serving a quantized version costs no host round trip. Identity
@@ -580,6 +650,19 @@ class InferenceServer:
       # With `slots` the agent's core advances those rows of the arena
       # (`core_state`) and hands the arena back: models/core.py.
       kwargs = {} if slots is None else {'state_slots': slots}
+      if counters:
+        # What the agent's layers sowed this call, summed by name over
+        # the layers, goes out with the call's outputs.
+        (out, new_state), sown = agent.apply(
+            params, prev_action[None], env_output, core_state,
+            sample_rng=sub, mutable=['counters'], **kwargs)
+        totals = dict.fromkeys(self._counter_names, 0)
+        for path, value in flax.traverse_util.flatten_dict(
+            sown.get('counters', {})).items():
+          totals[path[-1]] += value
+        return (out.action[0], out.policy_logits[0], out.baseline[0],
+                new_state, *[jnp.asarray(totals[name], jnp.int32)
+                             for name in self._counter_names])
       out, new_state = agent.apply(
           params, prev_action[None], env_output, core_state,
           sample_rng=sub, **kwargs)
@@ -606,10 +689,22 @@ class InferenceServer:
       # gathers with a clamp (their compute is sliced away) and
       # scatters with mode='drop'; a core that updates its arena in
       # place sends them to a row of their own.
-      action, logits, baseline, arena = _apply(
+      action, logits, baseline, arena, *counts = _apply(
           params, sub, prev_action, reward, done, obs, arena,
-          slots=slot_ids)
-      return key, arena, action, logits, baseline
+          slots=slot_ids, counters=counted)
+      return (key, arena, action, logits, baseline, *counts)
+
+    def prefill_chunk(params, arena, slot, tokens, n_valid, reset):
+      # One session's slot advanced by the first `n_valid` of `tokens`
+      # (from an empty state where `reset`): embedding and core, no
+      # head. The arena is donated, as to the step.
+      return agent.apply(
+          codec_lib.dequantize_tree(params), tokens, arena, slot,
+          n_valid, reset, method=agent.prefill)
+
+    self._prefill_step = (
+        jax.jit(prefill_chunk, donate_argnums=(1,))
+        if self.prefill_chunk else None)
 
     step = cache_step if self._state_cache else carry_step
     num_batch_args = 3 + num_obs + (
@@ -630,7 +725,8 @@ class InferenceServer:
         in_shardings = (None, self._replicated, self._replicated) + \
             (self._batch_sharding,) * num_batch_args
         out_shardings = (self._replicated,) * 2 + \
-            (self._batch_sharding,) * 3
+            (self._batch_sharding,) * 3 + \
+            (self._replicated,) * len(self._counter_names)
       else:
         in_shardings = (None, self._replicated) + \
             (self._batch_sharding,) * num_batch_args
@@ -835,6 +931,9 @@ class InferenceServer:
           lambda grown, a: grown.at[:old].set(a[:old]),
           self._new_arena(new), self._arena)
       self._num_slots = new
+    with self._stats_lock:
+      self._slot_pos = np.concatenate(
+          [self._slot_pos, np.zeros((new - old,), np.int64)])
     self._free.extend(range(old, new))
     # Cache-mode AOT executables bake the arena shape into their
     # compiled programs — all stale after a grow. Drop them; the next
@@ -870,6 +969,7 @@ class InferenceServer:
       arena = jax.device_put(arena, self._replicated)
     _STATE_BYTES_PER_SLOT.set(float(self._state_bytes))
     _ARENA_BYTES.set(float(_tree_nbytes(arena)))
+    _CACHE_CAPACITY.set(float(self._cache_capacity))
     return arena
 
   # One slot's row of every leaf, rewritten IN PLACE (the arena is
@@ -890,7 +990,39 @@ class InferenceServer:
       self._arena = self._clear_slot(self._arena, np.int32(slot))
     with self._stats_lock:
       self._state_resets += 1
+      self._slot_pos[slot] = 0
     _STATE_RESETS.inc()
+
+  def prefill(self, handle, tokens):
+    """Begin an episode in `handle`'s slot with `tokens` i32 [n] behind
+    it: ceil(n / prefill_chunk) chunk programs on the donated arena,
+    each dispatched under `_arena_lock` like every other writer of the
+    arena, so they run in order among the merged calls in flight (the
+    caller is the slot's one actor, between two of its policy calls).
+    The first chunk resets the slot's position; an empty block is that
+    reset alone. Nothing is read back."""
+    if not self.prefill_chunk:
+      raise RuntimeError('this agent\'s core has no chunk form')
+    tokens = np.asarray(tokens, np.int32)
+    size, slot = self.prefill_chunk, np.int32(handle.slot)
+    params = self.live_params()
+    chunks = 0
+    for lo in range(0, max(len(tokens), 1), size):
+      block = np.zeros((size,), np.int32)
+      valid = min(size, len(tokens) - lo)
+      block[:valid] = tokens[lo:lo + valid]
+      with telemetry.span('inference/prefill', id=int(slot)):
+        with self._arena_lock:
+          self._arena = self._prefill_step(
+              params, self._arena, slot, block, np.int32(valid),
+              np.bool_(lo == 0))
+      chunks += 1
+    with self._stats_lock:
+      self._slot_pos[handle.slot] = len(tokens)
+      self._prefill_tokens += len(tokens)
+      self._prefill_chunks += chunks
+    _PREFILL_TOKENS.inc(len(tokens))
+    _PREFILL_CHUNKS.inc(chunks)
 
   def _read_slot(self, slot):
     if self._state_bytes > MAX_HOST_STATE_BYTES:
@@ -913,6 +1045,13 @@ class InferenceServer:
   def _arena_now(self):
     with self._arena_lock:
       return self._arena
+
+  def release_state(self):
+    """Give the arena's device memory back; for a server that has been
+    closed and is kept for its stats (gigabytes, where the state is a
+    cache: a benchmark's reference needs the room)."""
+    with self._arena_lock:
+      self._arena = None
 
   def slots_free(self):
     with self._slot_lock:
@@ -1025,6 +1164,17 @@ class InferenceServer:
           self._calls += 1
           self._merged_requests += n
           self._state_resets += resets
+          if self._cache_capacity:
+            # Each live row reads its cache up to the token this call
+            # writes: its position (0 again where `done`) and one.
+            slots = bufs[0][:n]
+            reads = np.where(bufs[3][:n], 0, self._slot_pos[slots]) + 1
+            self._slot_pos[slots] = reads
+            cache_reads = int(np.sum(np.minimum(
+                reads, self._cache_capacity)))
+            self._cache_tokens_read += cache_reads
+        if self._cache_capacity:
+          _CACHE_TOKENS_READ.inc(cache_reads)
         if resets:
           _STATE_RESETS.inc(resets)
         with self._params_lock:
@@ -1073,9 +1223,20 @@ class InferenceServer:
         # strictly better.
         with telemetry.span('inference/readback', id=batch_id):
           host = jax.device_get(payload)
+        counts = ()
+        if self._counter_names:
+          # The call's counters rode its readback, behind its outputs.
+          split = len(host) - len(self._counter_names)
+          host, counts = host[:split], host[split:]
         with telemetry.span('inference/unpark', id=batch_id):
           self._batcher.set_outputs(
               batch_id, [np.asarray(o)[:n] for o in host])
+        if counts:
+          with self._stats_lock:
+            for name, count in zip(self._counter_names, counts):
+              self._call_counts[name] += int(count)
+          for name, count in zip(self._counter_names, counts):
+            _CALL_COUNTERS[name].inc(int(count))
         if shadow_out is not None:
           # Shadow scoring AFTER the callers are answered: the gauge
           # must never add device_get latency to the live path. Logits
@@ -1163,6 +1324,10 @@ class InferenceServer:
           except Exception:
             recovered = True
             self._arena = self._new_arena(self._num_slots)
+            # Every session begins again: so do the positions the
+            # host follows (_key_lock -> _arena_lock -> _stats_lock).
+            with self._stats_lock:
+              self._slot_pos[:] = 0
       if recovered:
         # Still inside _key_lock: the count advance is part of the
         # recovery's critical section, not an afterthought a second
@@ -1214,6 +1379,15 @@ class InferenceServer:
         # A non-power-of-two max_batch cap is itself a reachable
         # padded size (merged batches pad to min(pow2, max_batch)).
         sizes.append(cap)
+    if self._prefill_step is not None:
+      # The chunk program too, on a slot out of range: it writes
+      # nowhere (same program: values are not what XLA specializes on).
+      with self._arena_lock:
+        self._arena = self._prefill_step(
+            self.live_params(), self._arena, _PAD_SLOT_ID,
+            np.zeros((self.prefill_chunk,), np.int32),
+            np.int32(self.prefill_chunk), np.bool_(True))
+        jax.block_until_ready(self._arena)
     padded_done = set()
     for size in sizes:
       padded = self._padded_size(size)
@@ -1292,6 +1466,10 @@ class InferenceServer:
       shadow_calls = self._shadow_calls
       shadow_divergence = self._shadow_divergence
       aot_misses = self._aot_misses
+      prefill_tokens = self._prefill_tokens
+      prefill_chunks = self._prefill_chunks
+      cache_tokens_read = self._cache_tokens_read
+      call_counts = dict(self._call_counts)
     with self._params_lock:
       resident = len(self._versions)
       live_label = self._versions[self._live_key].label()
@@ -1331,6 +1509,17 @@ class InferenceServer:
         'arena_bytes': (_tree_nbytes(self._arena_now())
                         if self._state_cache else 0),
         'state_resets': state_resets,
+        # A state written at a position (PR 32): prompt tokens handed
+        # over in blocks and the chunk programs they took; the cached
+        # tokens the merged calls' live rows read (host arithmetic on
+        # each call's `done` rows and each block's length); what a
+        # slot's cache holds at most (0: a state of fixed size); and
+        # the per-call counters the agent's layers sow, summed.
+        'prefill_tokens': prefill_tokens,
+        'prefill_chunks': prefill_chunks,
+        'cache_tokens_read': cache_tokens_read,
+        'cache_capacity': self._cache_capacity,
+        **call_counts,
         # Admission/overload telemetry (round 9): the shed fraction is
         # sheds / acquires — the serving-plane overload SLO number.
         'admission': admission,

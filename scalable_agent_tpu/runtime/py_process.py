@@ -460,6 +460,10 @@ class ProxyEnv:
   def __init__(self, process: PyProcess):
     self._process = process
     self._proxy = process.proxy
+    # What an env offers beyond the Environment surface, and an actor
+    # asks for by name (envs/tokens.py), is offered here too.
+    if hasattr(process._type, 'prompt_block'):
+      self.prompt_block = self._proxy.prompt_block
 
   def initial(self):
     return self._proxy.initial()

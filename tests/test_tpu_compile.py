@@ -1,4 +1,4 @@
-"""The sequence policy's device programs compiled for the TPU v5e
+"""The sequence policies' device programs compiled for the TPU v5e
 WITHOUT a chip (libtpu's compile-only topology): what the interpreter
 cannot show. The retention kernel at the published widths passes
 Mosaic (tiling, fast memory); the server's whole `cache_step` at the
@@ -18,8 +18,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from scalable_agent_tpu.models import SequenceAgent, init_params
-from scalable_agent_tpu.ops import retention_pallas
+from scalable_agent_tpu.models import (LatentMoEDims, SequenceAgent,
+                                      init_params)
+from scalable_agent_tpu.ops import mla_pallas, retention_pallas
 from scalable_agent_tpu.structs import StepOutput
 
 WIDTHS = dict(num_actions=151936, num_layers=4, hidden_size=5120,
@@ -46,6 +47,7 @@ def compiled_kernel(monkeypatch):
   and would trace the interpreter's form: steer it in the test."""
   monkeypatch.setattr(retention_pallas, '_interpret_on',
                       lambda platform: False)
+  monkeypatch.setattr(mla_pallas, '_interpret_on', lambda platform: False)
   # The persistent cache cannot read back what a compile-only
   # topology wrote; keep these compiles out of it.
   jax.config.update('jax_enable_compilation_cache', False)
@@ -115,3 +117,83 @@ def test_cache_step_holds_one_arena_and_fits_the_chip(
            memory.output_size_in_bytes - memory.alias_size_in_bytes)
   assert total < 12e9  # of the chip's 16.9e9
   assert compiled.as_text().count(retention_pallas.KERNEL_NAME) >= 4
+
+
+# The latent-attention policy at the published widths of its benchmark
+# cell (PR 32): 5 layers, 16 of 256 experts, an eighth of the vocabulary.
+LATENT = dict(
+    num_actions=16160, num_layers=5, hidden_size=7168, num_heads=128,
+    mlp_size=18432, rope_theta=1e4, dtype=jnp.bfloat16,
+    param_dtype=jnp.bfloat16, latent=LatentMoEDims(
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, first_dense_layers=1,
+        moe_size=2048, routed_experts=256, experts_held=16,
+        experts_per_token=8, expert_groups=8, expert_groups_kept=4,
+        cache_capacity=16384, prefill_chunk=512))
+
+
+def test_latent_programs_never_copy_a_cache_leaf_and_fit_the_chip(
+    one_chip, compiled_kernel):
+  """Both programs of the latent core, `cache_step` and the prefill
+  chunk: the two kernels pass Mosaic at the published widths; the 3.1 GB
+  arena is aliased to the output and NO copy of a cache leaf is in
+  either program (a TPU lays `[slots, capacity, 576]` out with the
+  positions along the lanes, and a program that indexes it the other
+  way round copies the leaf there and back, 1.2 GB a layer and call:
+  the leaf is `[slots, 576, capacity]` and written by a kernel for that
+  reason); temporaries stay small; weights, arena and temporaries fit."""
+  agent = SequenceAgent(**LATENT)
+  params = jax.eval_shape(lambda: init_params(
+      agent, jax.random.PRNGKey(0), {'leaves': (((), 'int32'),)}))
+  arena = jax.eval_shape(lambda: agent.state_arena(SESSIONS))
+  key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+  nbytes = lambda tree: sum(  # noqa: E731
+      l.size * l.dtype.itemsize for l in jax.tree_util.tree_leaves(tree))
+  arena_bytes = nbytes(arena)
+  assert arena_bytes > 3.1e9 and nbytes(params) > 9.1e9
+  leaf = 'bf16[33,576,16384]'
+
+  def cache_step(params, key, arena, slot_ids, prev_action, reward, done,
+                 token):  # runtime/inference.py's, for this agent
+    key, sub = jax.random.split(key)
+    env_output = StepOutput(reward=reward[None], info=None,
+                            done=done[None], observation=(token[None],))
+    (out, arena), counters = agent.apply(
+        params, prev_action[None], env_output, arena, sample_rng=sub,
+        state_slots=slot_ids, mutable=['counters'])
+    return (key, arena, out.action[0], out.policy_logits[0],
+            out.baseline[0], counters)
+
+  def prefill_chunk(params, arena, slot, tokens, n_valid, reset):
+    return agent.apply(params, tokens, arena, slot, n_valid, reset,
+                       method=agent.prefill)
+
+  spec = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+      shape, dtype, sharding=one_chip)
+  row = lambda dtype: spec((SESSIONS,), dtype)  # noqa: E731
+  programs = {
+      'cache_step': jax.jit(cache_step, donate_argnums=(2,)).lower(
+          _on(one_chip, params), _on(one_chip, key), _on(one_chip, arena),
+          row(jnp.int32), row(jnp.int32), row(jnp.float32),
+          row(jnp.bool_), row(jnp.int32)),
+      'prefill_chunk': jax.jit(prefill_chunk, donate_argnums=(1,)).lower(
+          _on(one_chip, params), _on(one_chip, arena), spec((), jnp.int32),
+          spec((512,), jnp.int32), spec((), jnp.int32),
+          spec((), jnp.bool_))}
+  for name, lowered in programs.items():
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert memory.alias_size_in_bytes >= arena_bytes, name
+    copies = [line for line in text.splitlines()
+              if leaf in line and (' copy(' in line or 'copy-start(' in line)]
+    assert not copies, (name, copies[:2])
+    # The largest temporary is far under one cache leaf (629 MB).
+    assert memory.temp_size_in_bytes < 0.55e9, name
+    total = (memory.argument_size_in_bytes + memory.temp_size_in_bytes +
+             memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert total < 13e9, name  # of the chip's 16.9e9
+    if name == 'cache_step':
+      assert text.count(mla_pallas.KERNEL_NAME) >= 5
+      assert text.count(mla_pallas.WRITE_KERNEL_NAME) >= 5
+      assert memory.temp_size_in_bytes < 0.1e9
