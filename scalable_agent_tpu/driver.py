@@ -240,6 +240,18 @@ def make_fleet(config: Config, agent, policy, buffer, levels,
                     max_policy_rows=config.inference_max_batch)
 
 
+def _log_env_transport(fleet_stats):
+  """The closing line on how the process-hosted envs were stepped:
+  through their group's shared block, or by pickled calls."""
+  steps = fleet_stats.get('block_steps', 0)
+  calls = fleet_stats.get('pipe_calls', 0)
+  if steps or calls:
+    log.info(
+        'env transport: block_steps=%d pipe_calls=%d (%.1f%% of the '
+        'traffic to the env processes went through a shared block)',
+        steps, calls, 100.0 * steps / (steps + calls))
+
+
 def _choose_eval_mesh():
   """Inference mesh for evaluate(): LOCAL devices only (each host's
   dynamic batcher fires independently — a cross-process mesh would
@@ -1980,6 +1992,7 @@ def train(config: Config, max_steps: Optional[int] = None,
           hs.get('skipped_steps', 0), hs.get('rollbacks', 0), ing_q,
           fleet_stats['respawns'], fleet_stats['healthy_fraction'],
           checkpointer.save_errors, checkpointer.restore_fallbacks)
+      _log_env_transport(fleet_stats)
     except Exception:
       log.exception('robustness summary failed')
     # Controller (round 15): stop the actuation thread FIRST (it
@@ -2977,6 +2990,10 @@ def play(config: Config, agent, params, obs_spec, levels,
         returns[level_id].append(ep_return)
       fleet.check_health(stall_timeout_secs=stall_timeout_secs)
   finally:
+    try:
+      _log_env_transport(fleet.stats())
+    except Exception:
+      log.exception('env transport summary failed')
     fleet.stop()
     server.close()
   return returns

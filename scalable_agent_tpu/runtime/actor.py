@@ -27,6 +27,7 @@ from typing import Callable, Optional
 import numpy as np
 
 from scalable_agent_tpu import telemetry
+from scalable_agent_tpu.runtime import py_process
 from scalable_agent_tpu.structs import (
     ActorOutput, AgentOutput, StepOutput, StepOutputInfo)
 
@@ -40,12 +41,6 @@ _PUT_POLL_SECS = 0.5
 # this bound only fires when nothing is draining, where the old
 # behavior was an unjoinable thread).
 _STOP_PUT_GRACE_SECS = 5.0
-
-
-def _tree_stack(items):
-  """Stack a list of identically-structured pytrees of np arrays."""
-  import jax
-  return jax.tree_util.tree_map(lambda *xs: np.stack(xs), *items)
 
 
 class Actor:
@@ -87,6 +82,7 @@ class Actor:
     self._agent_output: Optional[AgentOutput] = None
     self._episode_return = np.float32(0.0)
     self._episode_step = np.int32(0)
+    self._solo: Optional['ActorGroup'] = None  # `unroll`'s group of one
 
   def group_key(self):
     """What two Actors must agree on to be stepped as one ActorGroup:
@@ -102,11 +98,12 @@ class Actor:
     group of one (`ActorGroup.unroll` is THE loop). `span_id` is the
     `id` its recorder spans carry (telemetry.span): the actor loop
     passes the unroll's `(actor, seq)`."""
-    return ActorGroup([self]).unroll([span_id])[0]
+    if self._solo is None:
+      self._solo = ActorGroup([self])
+    return self._solo.unroll([span_id])[0]
 
   def _begin_unroll(self):
-    """The numeric carry at the unroll start, and the lists the
-    unroll's T+1 steps collect in (the overlap frame first)."""
+    """Note the numeric carry at the unroll start."""
     # Device-resident policy state (InferenceServer state-cache mode)
     # is an opaque handle: the learner still needs the NUMERIC carry
     # at the unroll start, so snapshot it here — the once-per-unroll
@@ -116,10 +113,8 @@ class Actor:
       self._initial_core_state = core0.snapshot()
     else:
       self._initial_core_state = core0
-    self._env_outputs = [self._env_output]
-    self._agent_outputs = []
 
-  def _primed(self, out):
+  def _primed(self, logits):
     """After the priming policy call (made lazily, so num_actions is
     known from its logits)."""
     core0 = self._core_state
@@ -131,59 +126,8 @@ class Actor:
       core0.write(self._initial_core_state)
     self._agent_output = AgentOutput(
         action=np.int32(0),
-        policy_logits=np.zeros_like(np.asarray(out.policy_logits)),
+        policy_logits=np.zeros_like(np.asarray(logits)),
         baseline=np.float32(0.0))
-
-  def _hand_over_prompt(self):
-    """The env output the next policy call sees. Where an episode
-    begins, the policy state lives with a server whose core computes
-    a chunk of tokens at once (`prefill_chunk`) and the env offers the
-    episode's prompt as a block (`prompt_block`: all but its last
-    token, which is the observation at hand), the block goes to the
-    server first. The call that follows must not reset what the block
-    built: the policy sees this step's `done` cleared, the unroll
-    keeps it."""
-    out, state = self._env_output, self._core_state
-    if not (out.done and getattr(state, 'prefill_chunk', 0)):
-      return out
-    fetch = getattr(self._env, 'prompt_block', None)
-    block = fetch() if fetch is not None else None
-    if block is None:
-      return out
-    tokens, n = block
-    state.prefill(np.asarray(tokens)[:int(n)])
-    return out._replace(done=np.bool_(False))
-
-  def _record_step(self, agent_output, core_state, reward, done,
-                   observation):
-    # Flow-style episode accounting (output carries final stats at
-    # done; carried state resets).
-    self._episode_return = np.float32(self._episode_return + reward)
-    self._episode_step = np.int32(
-        self._episode_step + self._num_action_repeats)
-    info = StepOutputInfo(self._episode_return, self._episode_step)
-    if done:
-      self._episode_return = np.float32(0.0)
-      self._episode_step = np.int32(0)
-
-    env_output = StepOutput(np.float32(reward), info, np.bool_(done),
-                            observation)
-    self._env_outputs.append(env_output)
-    self._agent_outputs.append(agent_output)
-    self._env_output = env_output
-    self._agent_output = agent_output
-    self._core_state = core_state
-
-  def _assemble(self, span_id=None) -> ActorOutput:
-    with telemetry.span('actor/assemble', id=span_id):
-      env_outputs = _tree_stack(self._env_outputs)
-      agent_outputs = _tree_stack(self._agent_outputs)
-    self._env_outputs = self._agent_outputs = None
-    return ActorOutput(
-        level_name=self._level_name_id,
-        agent_state=self._initial_core_state,
-        env_outputs=env_outputs,
-        agent_outputs=agent_outputs)
 
   def release_policy_state(self):
     """Return device-resident policy state (a state-arena slot) to its
@@ -200,6 +144,200 @@ class Actor:
   def close(self):
     self.release_policy_state()
     self._env.close()
+
+
+class _Rollout:
+  """A group's unroll as it is made: the T+1 steps of its k envs in
+  [T+1, k, ...] arrays, so that step t is the policy's request as it
+  stands (the contiguous row `x[t]`, no stacking) and a member's
+  unroll is the column `x[:, j]`. The env's side (reward, done, the
+  observation's leaves) is a `py_process.StepBlock`: in shared memory
+  where every member is a hosted env that can map it, and then each
+  child writes its own column and a step costs its pipe a byte each
+  way; otherwise in this process's memory, written here from what
+  each `step` returns. Which it is follows from what the members
+  offer (`step_block_specs`, `attach_block`), never from a setting.
+
+  The members keep their own state BETWEEN unrolls (`begin` reads it,
+  `finish` writes it back), so the arrays are reused from unroll to
+  unroll and a change of membership just builds another Rollout.
+  """
+
+  def __init__(self, group):
+    import jax
+    self.actors = actors = list(group.actors)
+    self.rows = actors[0]._unroll_length + 1
+    k = len(actors)
+    leaves, self.treedef = jax.tree_util.tree_flatten(
+        actors[0]._env_output.observation)
+    leaf_specs = [(np.shape(x), np.asarray(x).dtype.str) for x in leaves]
+    self.block = group._shared_block(leaf_specs, self.rows)
+    self.shared = self.block is not None
+    if not self.shared:
+      self.block = py_process.StepBlock.private(leaf_specs, self.rows, k)
+    self.episode_return = np.zeros((self.rows, k), np.float32)
+    self.episode_step = np.zeros((self.rows, k), np.int32)
+    self.repeats = np.asarray([a._num_action_repeats for a in actors],
+                              np.int32)
+    self.handles = hasattr(actors[0]._core_state, 'snapshot')
+    # Hosted envs step in two halves, the others in one piece.
+    envs = [a._env for a in actors]
+    self.sends = [getattr(env, 'step_send', None) for env in envs]
+    self.receives = [getattr(env, 'step_receive', None) for env in envs]
+    self.agent = None  # AgentOutput of [T+1, k, ...]: see `begin`
+
+  def begin(self):
+    """Row 0 is each member's last step of the unroll before."""
+    actors = self.actors
+    for j, actor in enumerate(actors):
+      reward, info, done, observation = actor._env_output
+      self._write(0, j, reward, done, observation)
+      self.episode_return[0, j], self.episode_step[0, j] = info
+    self._return = np.asarray([a._episode_return for a in actors],
+                              np.float32)
+    self._step = np.asarray([a._episode_step for a in actors], np.int32)
+    first = [a._agent_output for a in actors]
+    if self.agent is None:
+      self.agent = AgentOutput(*[
+          np.empty((self.rows, len(actors)) + np.shape(y),
+                   np.asarray(y).dtype) for y in first[0]])
+    for j, out in enumerate(first):
+      for x, y in zip(self.agent, out):
+        x[0, j] = y
+    self.states = _states_of(actors)
+
+  def _write(self, t, j, reward, done, observation):
+    import jax
+    block = self.block
+    block.reward[t, j] = reward
+    block.done[t, j] = done
+    for leaf, x in zip(block.leaves,
+                       jax.tree_util.tree_leaves(observation)):
+      leaf[t, j] = x
+
+  def env_output(self, t, done):
+    """Step t of every member as the k-row policy call takes it, its
+    `done` as `hand_over_prompts` left it."""
+    block = self.block
+    return StepOutput(
+        block.reward[t],
+        StepOutputInfo(self.episode_return[t], self.episode_step[t]),
+        done, self.treedef.unflatten([leaf[t] for leaf in block.leaves]))
+
+  def hand_over_prompts(self, t):
+    """`done` of step t as the policy is to see it. Where an episode
+    begins, the policy state lives with a server whose core computes
+    a chunk of tokens at once (`prefill_chunk`) and the env offers the
+    episode's prompt as a block (`prompt_block`: all but its last
+    token, which is the observation at hand), the block goes to the
+    server first. The call that follows must not reset what the block
+    built: the policy sees that member's `done` cleared, the unroll
+    keeps it."""
+    done = self.block.done[t]
+    if not (self.handles and done.any()):
+      return done
+    for j in np.flatnonzero(done):
+      state = self.states[j]
+      if not getattr(state, 'prefill_chunk', 0):
+        continue
+      fetch = getattr(self.actors[j]._env, 'prompt_block', None)
+      prompt = fetch() if fetch is not None else None
+      if prompt is None:
+        continue
+      tokens, n = prompt
+      state.prefill(np.asarray(tokens)[:int(n)])
+      if done.base is not None:
+        done = done.copy()
+      done[j] = False
+    return done
+
+  def act(self, t):
+    """The policy call of step t -> the k actions; its outputs are row
+    t + 1 of `agent`."""
+    out, self.states = _call_policy(
+        self.actors[0]._policy, self.agent.action[t],
+        self.env_output(t, self.hand_over_prompts(t)), self.states)
+    if any(np.asarray(y).dtype != x.dtype for x, y in zip(self.agent, out)):
+      # A policy whose outputs are wider than the priming zeros: widen
+      # as np.stack would have.
+      self.agent = AgentOutput(*[
+          x.astype(np.result_type(x, np.asarray(y)))
+          for x, y in zip(self.agent, out)])
+    for x, y in zip(self.agent, out):
+      x[t + 1] = y
+    return self.agent.action[t + 1].tolist()
+
+  def record(self, t):
+    """Flow-style episode accounting of the step now in row t (the
+    output carries the final stats at done; the carried state
+    resets)."""
+    done = self.block.done[t]
+    self._return += self.block.reward[t]
+    self._step += self.repeats
+    self.episode_return[t] = self._return
+    self.episode_step[t] = self._step
+    if done.any():
+      self._return[done] = 0
+      self._step[done] = 0
+
+  def finish(self, span_ids):
+    """The members' ActorOutputs, each a copy of its column (the
+    arrays go on to the next unroll), and their state for it."""
+    import jax
+    block, outputs = self.block, []
+    for j, (actor, span_id) in enumerate(zip(self.actors, span_ids)):
+      with telemetry.span('actor/assemble', id=span_id):
+        env_outputs = StepOutput(
+            block.reward[:, j].copy(),
+            StepOutputInfo(self.episode_return[:, j].copy(),
+                           self.episode_step[:, j].copy()),
+            block.done[:, j].copy(),
+            self.treedef.unflatten(
+                [leaf[:, j].copy() for leaf in block.leaves]))
+        agent_outputs = AgentOutput(*[x[:, j].copy() for x in self.agent])
+      last = lambda x: x[-1]  # noqa: E731
+      actor._env_output = jax.tree_util.tree_map(last, env_outputs)
+      actor._agent_output = AgentOutput(*map(last, agent_outputs))
+      if not self.handles:
+        actor._core_state = jax.tree_util.tree_map(
+            lambda x, j=j: x[j:j + 1], self.states)
+      actor._episode_return = self._return[j]
+      actor._episode_step = self._step[j]
+      outputs.append(ActorOutput(
+          level_name=actor._level_name_id,
+          agent_state=actor._initial_core_state,
+          env_outputs=env_outputs,
+          agent_outputs=agent_outputs))
+    return outputs
+
+
+def _states_of(actors):
+  """The members' policy states as one k-row call takes them: opaque
+  handles (`snapshot`) as a list, numeric carries concatenated on
+  axis 0."""
+  states = [a._core_state for a in actors]
+  if hasattr(states[0], 'snapshot'):
+    return states
+  import jax
+  return jax.tree_util.tree_map(
+      lambda *xs: np.concatenate(xs, axis=0), *states)
+
+
+def _call_policy(policy, prev_actions, env_output, states):
+  """One policy call for k rows -> (AgentOutput of [k, ...] arrays,
+  the new states). A single row makes the scalar call of the Actor
+  contract; `states` is a list of k opaque handles or a numeric carry
+  of [k, ...] leaves."""
+  if len(prev_actions) > 1:
+    out, states = policy(prev_actions, env_output, states)
+    return AgentOutput(*[np.asarray(x) for x in out]), states
+  import jax
+  handles = isinstance(states, list)
+  out, state = policy(
+      prev_actions[0], jax.tree_util.tree_map(lambda x: x[0], env_output),
+      states[0] if handles else states)
+  return (AgentOutput(*[np.asarray(x)[None] for x in out]),
+          [state] if handles else state)
 
 
 class ActorGroup:
@@ -220,9 +358,12 @@ class ActorGroup:
   leaves, core_state for k) -> (AgentOutput with [k, ...] leaves,
   core_state for k)`, where a numeric core state is the members'
   concatenated on axis 0 and opaque handles (`snapshot`) go as a list
-  (`InferenceServer.policy` honours both). Members whose env has
+  (`InferenceServer.policy` honours both). The request's leaves are
+  rows of the group's `_Rollout` (views: the policy must have done
+  with them when it returns). Members whose env has
   `step_send`/`step_receive` (process-hosted: `py_process.ProxyEnv`)
-  step concurrently; any other env steps in turn on this thread.
+  step concurrently, and through a shared block where every member
+  can map one (PR 33); any other env steps in turn on this thread.
 
   Membership moves only between unrolls, on the rolling thread: a
   member `leave`s (and is closed) there, and an Actor that any thread
@@ -242,6 +383,7 @@ class ActorGroup:
     # is: of a group that stalls, the one that hangs.
     self.waiting_on: Optional[Actor] = None
     self._joining = collections.deque()  # (actor, name), any thread
+    self._rollout: Optional[_Rollout] = None  # of the members as they are
 
   def join(self, actor, name):
     """Hand the group (one that has `names`) one more member, from
@@ -256,12 +398,14 @@ class ActorGroup:
       actor, name = self._joining.popleft()
       self.actors.append(actor)
       self.names.append(name)
+      self._rollout = None
 
   def leave(self, actor):
     """Drop a member and close it (the rolling thread, between
     unrolls); the others go on."""
     j = self.actors.index(actor)
     del self.actors[j], self.names[j]
+    self._rollout = None
     _close_quietly(actor)
 
   def unroll(self, span_ids=None):
@@ -279,91 +423,101 @@ class ActorGroup:
         spans.enter_context(telemetry.span('actor/unroll', id=span_id))
       for actor in actors:
         actor._begin_unroll()
-      unprimed = [a for a in actors if a._agent_output is None]
-      if unprimed:
-        outs, _ = self._policy_call(
-            unprimed, [np.int32(0)] * len(unprimed), priming=True)
-        for actor, out in zip(unprimed, outs):
-          actor._primed(out)
-      for actor in actors:
-        actor._agent_outputs.append(actor._agent_output)
-
-      for _ in range(actors[0]._unroll_length):
+      self._prime([a for a in actors if a._agent_output is None])
+      rollout = self._rollout
+      if rollout is None or rollout.actors != actors:
+        rollout = self._rollout = _Rollout(self)
+      rollout.begin()
+      for t in range(rollout.rows - 1):
         with telemetry.span('actor/step'):
           with telemetry.span('actor/policy_call'):
-            agent_outputs, core_states = self._policy_call(
-                actors, [a._agent_output.action for a in actors])
+            actions = rollout.act(t)
           with telemetry.span('actor/env_step'):
-            steps = self._env_step(
-                [int(out.action) for out in agent_outputs])
-          for actor, out, core_state, step in zip(
-              actors, agent_outputs, core_states, steps):
-            actor._record_step(out, core_state, *step)
-
-      return [actor._assemble(span_id)
-              for actor, span_id in zip(actors, span_ids)]
+            self._env_step(rollout, t + 1, actions)
+          rollout.record(t + 1)
+      return rollout.finish(span_ids)
 
   @staticmethod
-  def _policy_call(actors, prev_actions, priming=False):
-    """One policy call for `actors` -> (their AgentOutputs of numpy
-    scalars, their new core states). The priming call's state is put
-    back afterwards, so no prompt is handed over for it."""
-    policy = actors[0]._policy
-    env_outputs = [a._env_output if priming else a._hand_over_prompt()
-                   for a in actors]
-    if len(actors) == 1:
-      out, core_state = policy(prev_actions[0], env_outputs[0],
-                               actors[0]._core_state)
-      return [AgentOutput(*[np.asarray(x) for x in out])], [core_state]
+  def _prime(actors):
+    """The priming call of members that have made no policy call yet
+    (its state is put back afterwards, so no prompt is handed over for
+    it): their last step stacked, which happens once a member."""
+    if not actors:
+      return
     import jax
-    states = [a._core_state for a in actors]
-    handles = hasattr(states[0], 'snapshot')
-    out, new_states = policy(
-        np.asarray(prev_actions, np.int32),
-        _tree_stack(env_outputs),
-        states if handles else jax.tree_util.tree_map(
-            lambda *xs: np.concatenate(xs, axis=0), *states))
-    out = [np.asarray(x) for x in out]
-    rows = range(len(actors))
-    if not handles:
-      new_states = [jax.tree_util.tree_map(lambda x, j=j: x[j:j + 1],
-                                           new_states) for j in rows]
-    return ([AgentOutput(*[np.asarray(x[j]) for x in out]) for j in rows],
-            new_states)
+    out, _ = _call_policy(
+        actors[0]._policy, np.zeros(len(actors), np.int32),
+        jax.tree_util.tree_map(lambda *xs: np.stack(xs),
+                               *[a._env_output for a in actors]),
+        _states_of(actors))
+    for j, actor in enumerate(actors):
+      actor._primed(out.policy_logits[j])
 
-  def _env_step(self, actions):
-    """Step every member's env -> [(reward, done, observation)]. Every
-    send goes out before the first reply is waited for, so hosted envs
-    step at once; every reply sent for is collected, whatever failed,
-    so no child is left mid-call. The first failure is raised."""
-    results, sent, failure = [None] * len(actions), [], None
-    for j, (actor, action) in enumerate(zip(self.actors, actions)):
+  def _shared_block(self, leaf_specs, rows):
+    """A StepBlock in shared memory that every member's env has
+    mapped, if every member is a hosted env whose `step` reply is
+    declared as what the members' observations are, and the machine
+    has the memory to share; else None. A member that fails here
+    fails the unroll, as it would in a step."""
+    envs = [a._env for a in self.actors]
+    if not all(hasattr(env, 'attach_block') and
+               env.step_block_specs() == leaf_specs for env in envs):
+      return None
+    try:
+      block = py_process.StepBlock.create(leaf_specs, rows, len(envs))
+    except OSError:
+      return None
+    try:
+      for j, (actor, env) in enumerate(zip(self.actors, envs)):
+        self.waiting_on = actor
+        try:
+          env.attach_block(block, j)
+        except BaseException:
+          self.failed = actor
+          raise
+    finally:
+      self.waiting_on = None
+      block.unlink()  # mapped by all, or of no more use
+    return block
+
+  def _env_step(self, rollout, t, actions):
+    """Step every member's env into row `t`. Every send goes out
+    before the first reply is waited for, so hosted envs step at once;
+    every reply sent for is collected, whatever failed, so no child is
+    left mid-call. The first failure is raised."""
+    actors, sent, failure = rollout.actors, [], None
+    if rollout.shared:
+      rollout.block.begin_step(t)
+    for j, (actor, send) in enumerate(zip(actors, rollout.sends)):
       self.waiting_on = actor
       try:
-        send = getattr(actor._env, 'step_send', None)
         if send is None:
-          results[j] = actor._env.step(action)
+          rollout._write(t, j, *actor._env.step(actions[j]))
         else:
-          send(action)
+          send(actions[j])
           sent.append(j)
       except BaseException as e:
         failure = (actor, e)
         break
     for j in sent:
-      self.waiting_on = self.actors[j]
+      self.waiting_on = actors[j]
       try:
-        results[j] = self.actors[j]._env.step_receive()
+        step = rollout.receives[j]()
+        if not rollout.shared:
+          rollout._write(t, j, *step)
       except BaseException as e:
-        failure = failure or (self.actors[j], e)
+        failure = failure or (actors[j], e)
     self.waiting_on = None
     if failure is not None:
       self.failed, exc = failure
       raise exc
-    return results
+    if rollout.shared:
+      py_process.BLOCK_STEPS.inc(len(sent))
 
   def close(self):
     """Close every member, those `join` brought and no unroll took in
     among them."""
+    self._rollout = None
     for actor in self.actors + [actor for actor, _ in self._joining]:
       _close_quietly(actor)
 

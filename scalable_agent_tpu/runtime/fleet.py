@@ -113,6 +113,11 @@ class _Slot:
     self.last_heartbeat: float = time.monotonic()
     self.unrolls_done: int = 0
     self.respawns: int = 0
+    # What the env processes this slot had before counted (`process`
+    # counts its own): env steps through a shared block, and calls
+    # down the pickled pipe.
+    self.block_steps: int = 0
+    self.pipe_calls: int = 0
     self.error: Optional[BaseException] = None
     # The group's thread ended or hung on ANOTHER member's env: this
     # slot respawns with it, off the respawn ladder (no streak, no
@@ -227,6 +232,8 @@ class ActorFleet:
         with self._lock:
           slot.generation += 1
           generation = slot.generation
+          slot.block_steps += getattr(slot.process, 'block_steps', 0)
+          slot.pipe_calls += getattr(slot.process, 'pipe_calls', 0)
           slot.env, slot.process, slot.actor = env, process, actor
           slot.error = None
           slot.collateral = False
@@ -642,6 +649,16 @@ class ActorFleet:
           'actor_threads': threads,
           'envs_per_thread': len(alive) / threads if threads else 0.0,
           'unrolls': sum(s.unrolls_done for s in self._slots),
+          # How the process-hosted envs were reached (PR 33): env
+          # steps through a group's shared block, and calls down the
+          # pickled pipe (`initial`, `prompt_block`, `close`, and
+          # `step` where no block could be had).
+          'block_steps': sum(
+              s.block_steps + getattr(s.process, 'block_steps', 0)
+              for s in self._slots),
+          'pipe_calls': sum(
+              s.pipe_calls + getattr(s.process, 'pipe_calls', 0)
+              for s in self._slots),
           'respawns': sum(s.respawns for s in self._slots),
           'alive': len(alive),
           'healthy': len(healthy),

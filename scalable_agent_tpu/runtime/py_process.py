@@ -23,6 +23,11 @@ to wrap because on the TPU build env stepping is host Python already
   signal, the reference's `IOError → StopIteration` convention (≈L72).
 - `start_all` / `close_all` start/stop fleets via a thread pool — the
   reference's `PyProcessHook.begin/end` (≈L190–230) without the session.
+- `step` of an env whose declared reply has a fixed spec can go through
+  a `StepBlock` in shared memory instead (PR 33): the action and the
+  reply live in a block that the caller's group owns and the child has
+  mapped (`attach_block`), and the pipe carries one byte each way.
+  Every other call stays a pickled message on the same pipe.
 
 Start method: `forkserver` by default. The driver builds env processes
 AFTER JAX's inference warmup, i.e. from a parent already running JAX
@@ -38,12 +43,15 @@ unpicklable fixtures; `spawn` for classes needing a pristine
 interpreter.
 """
 
+import mmap
 import multiprocessing
 import os
 import sys
+import tempfile
 import threading
 import traceback
 from multiprocessing.pool import ThreadPool
+from multiprocessing.reduction import ForkingPickler
 
 import numpy as np
 
@@ -103,6 +111,21 @@ class SpecMismatchError(Exception):
 
 
 _CLOSE = '__process_close__'
+_ATTACH = '__process_attach_block__'
+# One byte goes ahead of everything the parent asks for, so that the
+# child knows what to read next: a pickled (method, args, kwargs), or
+# nothing more, for a step through its block. A step's answer is one
+# byte too: the row is written, or a pickled failure follows. Raw
+# bytes and pickled messages never interleave: the call lock keeps a
+# call's two halves together.
+_CALL, _STEP = b'C', b'S'
+_STEPPED, _FAILED = b'K', b'X'
+# A step through a block, where a call's method name goes.
+_BLOCK_STEP = object()
+_BLOCK_DIR = '/dev/shm'
+
+BLOCK_STEPS = telemetry.counter('actors/block_steps')
+_PIPE_CALLS = telemetry.counter('actors/pipe_calls')
 
 
 def pin_process_to_cpu():
@@ -123,8 +146,163 @@ def pin_process_to_cpu():
     jax.config.update('jax_platforms', 'cpu')
 
 
+def _leaves(tree):
+  """The leaves of a pytree of tuples and lists, in order (an
+  ArraySpec is a tuple and a leaf)."""
+  if isinstance(tree, (tuple, list)) and not hasattr(tree, 'dtype'):
+    for node in tree:
+      yield from _leaves(node)
+  else:
+    yield tree
+
+
+def step_block_specs(specs):
+  """[(shape, dtype str)] of the observation's leaves, if a `step`
+  spec is one a StepBlock can hold: (reward, done, observation) with
+  scalar reward and done and every observation leaf of a declared
+  shape and dtype. None otherwise."""
+  if (not isinstance(specs, (tuple, list)) or hasattr(specs, 'dtype')
+      or len(specs) != 3):
+    return None
+  leaves = list(_leaves(specs))
+  if len(leaves) < 3 or not all(
+      hasattr(leaf, 'shape') and hasattr(leaf, 'dtype') and
+      all(isinstance(d, int) for d in leaf.shape) for leaf in leaves):
+    return None
+  if any(not hasattr(spec, 'dtype') or tuple(spec.shape)
+         for spec in specs[:2]):
+    return None
+  return [(tuple(leaf.shape), np.dtype(leaf.dtype).str)
+          for leaf in _leaves(specs[2])]
+
+
+class StepBlock:
+  """The steps of `columns` envs over `rows` steps, in one buffer:
+  `reward` f32 and `done` bool [rows, columns], and `leaves`, the
+  observation's leaves as [rows, columns, ...] arrays, so that step
+  t of all the envs is the contiguous `leaf[t]` and one env's steps
+  are `leaf[:, j]`.
+
+  `create` puts the buffer in shared memory: a hosted env that has
+  mapped it (`PyProcess.attach_block`) writes its own column, and a
+  step costs the pipe one byte each way. Per column the parent says
+  what it wants (`action`, the `row` to write, and `want`, a number
+  no earlier step of this block had) and the child says what it did
+  (`seq` = `want`, written after the row), so that a row left from an
+  earlier step can never be taken for the one asked for. The file is
+  named only until every child has mapped it (`unlink`): nothing is
+  left to clean up, however the processes end. `private` is the same
+  layout in this process's own memory, for envs that cannot map it.
+  """
+
+  def __init__(self, leaf_specs, rows, columns, buffer, path=None):
+    self.leaf_specs = [(tuple(shape), dtype) for shape, dtype in leaf_specs]
+    self.rows, self.columns, self.path = rows, columns, path
+    self.step_seq = 0
+    fields, _ = self._layout(self.leaf_specs, rows, columns)
+    arrays = [
+        np.frombuffer(buffer, dtype, int(np.prod(shape, dtype=np.int64)),
+                      offset).reshape(shape)
+        for shape, dtype, offset in fields]
+    (self.action, self.row, self.want, self.seq, self.reward,
+     self.done) = arrays[:6]
+    self.leaves = arrays[6:]
+
+  @staticmethod
+  def _layout(leaf_specs, rows, columns):
+    """[(shape, dtype, offset)] of the fields, and the bytes in all."""
+    shapes = [((columns,), 'i4'), ((columns,), 'i4'), ((columns,), 'i8'),
+              ((columns,), 'i8'), ((rows, columns), 'f4'),
+              ((rows, columns), '?')]
+    shapes += [((rows, columns) + shape, dtype)
+               for shape, dtype in leaf_specs]
+    fields, offset = [], 0
+    for shape, dtype in shapes:
+      fields.append((shape, dtype, offset))
+      size = int(np.prod(shape, dtype=np.int64)) * np.dtype(dtype).itemsize
+      offset += -(-size // 64) * 64
+    return fields, max(offset, 64)
+
+  @classmethod
+  def private(cls, leaf_specs, rows, columns):
+    _, size = cls._layout(leaf_specs, rows, columns)
+    return cls(leaf_specs, rows, columns, np.empty(size, np.uint8))
+
+  @classmethod
+  def create(cls, leaf_specs, rows, columns):
+    """A block in shared memory. OSError where there is none to be
+    had (no /dev/shm, or no room in it)."""
+    _, size = cls._layout(leaf_specs, rows, columns)
+    fd, path = tempfile.mkstemp(prefix=f'step_block_{os.getpid()}_',
+                                dir=_BLOCK_DIR)
+    try:
+      os.posix_fallocate(fd, 0, size)  # room now, not a SIGBUS later
+      return cls(leaf_specs, rows, columns, mmap.mmap(fd, size), path)
+    except BaseException:
+      os.unlink(path)
+      raise
+    finally:
+      os.close(fd)
+
+  @classmethod
+  def open(cls, path, leaf_specs, rows, columns):
+    """The block another process created, by its name."""
+    _, size = cls._layout(leaf_specs, rows, columns)
+    fd = os.open(path, os.O_RDWR)
+    try:
+      return cls(leaf_specs, rows, columns, mmap.mmap(fd, size))
+    finally:
+      os.close(fd)
+
+  def unlink(self):
+    """Take the name away (idempotent); the mappings stay."""
+    path, self.path = self.path, None
+    if path is not None:
+      try:
+        os.unlink(path)
+      except OSError:
+        pass
+
+  def begin_step(self, row):
+    """The parent, before it wakes the children: every column's next
+    step goes to `row`, under a sequence number of its own."""
+    self.step_seq += 1
+    self.row[:] = row
+    self.want[:] = self.step_seq
+
+
+class _BlockStepper:
+  """The child's side of a block: steps the env into its column."""
+
+  def __init__(self, obj, specs, name, path, leaf_specs, rows, columns,
+               column):
+    self._obj, self._specs, self._name = obj, specs, name
+    block = StepBlock.open(path, leaf_specs, rows, columns)
+    self._action = block.action[column:column + 1]
+    self._row = block.row[column:column + 1]
+    self._want = block.want[column:column + 1]
+    self._seq = block.seq[column:column + 1]
+    self._reward = block.reward[:, column]
+    self._done = block.done[:, column]
+    self._columns = [leaf[:, column] for leaf in block.leaves]
+
+  def step(self):
+    result = self._obj.step(int(self._action[0]))
+    # The one check of the reply: here, beside the env, and not on the
+    # thread that steps the whole group.
+    _validate_specs(result, self._specs, self._name)
+    reward, done, observation = result
+    row = int(self._row[0])
+    self._reward[row] = reward
+    self._done[row] = done
+    for column, leaf in zip(self._columns, _leaves(observation)):
+      column[row] = leaf
+    self._seq[0] = self._want[0]  # last: the row is whole
+
+
 def _worker(conn, type_, constructor_kwargs):
-  """Worker loop: construct, then serve (method, args, kwargs) requests."""
+  """Worker loop: construct, then serve requests: a pickled (method,
+  args, kwargs), or a step through the block it was attached to."""
   pin_process_to_cpu()
   try:
     obj = type_(**constructor_kwargs)
@@ -132,11 +310,26 @@ def _worker(conn, type_, constructor_kwargs):
     conn.send(('exception', _serialize_error(e)))
     conn.close()
     return
+  fd, stepper = conn.fileno(), None
   while True:
     try:
+      tag = os.read(fd, 1)
+      if tag == _STEP:
+        try:
+          stepper.step()
+          os.write(fd, _STEPPED)
+        except SpecMismatchError as e:
+          os.write(fd, _FAILED)
+          conn.send(('mismatch', str(e)))
+        except Exception as e:  # keep serving, as after any method
+          os.write(fd, _FAILED)
+          conn.send(('exception', _serialize_error(e)))
+        continue
+      if not tag:
+        break  # parent died/closed: fall through to close the object
       request = conn.recv()
     except (EOFError, OSError):
-      break  # parent died/closed: fall through to close the object
+      break
     method, args, kwargs = request
     if method == _CLOSE:
       try:
@@ -147,7 +340,15 @@ def _worker(conn, type_, constructor_kwargs):
         conn.send(('exception', _serialize_error(e)))
       break
     try:
-      result = getattr(obj, method)(*args, **kwargs)
+      if method == _ATTACH:
+        validate, *where = args
+        specs = (type_._tensor_specs('step', {}, constructor_kwargs)
+                 if validate else None)
+        stepper = _BlockStepper(obj, specs, f'{type_.__name__}.step',
+                                *where)
+        result = None
+      else:
+        result = getattr(obj, method)(*args, **kwargs)
       conn.send(('ok', result))
     except Exception as e:  # keep serving — reference semantics
       conn.send(('exception', _serialize_error(e)))
@@ -220,15 +421,19 @@ class PyProcess:
     context: multiprocessing start method (None = the module default,
       'forkserver'; 'fork'/'spawn' as explicit opt-ins).
     validate_specs: disable to skip reply validation (hot-path opt-out).
+    step_block: False keeps `step` on the pickled pipe even where its
+      declared spec would fit a StepBlock (for a test that holds the
+      two paths side by side; nothing else sets it).
   """
 
   def __init__(self, type_, constructor_kwargs=None, context=None,
-               validate_specs=True):
+               validate_specs=True, step_block=True):
     self._type = type_
     self._constructor_kwargs = dict(constructor_kwargs or {})
     self._ctx = multiprocessing.get_context(
         context or DEFAULT_START_METHOD)
     self._validate = validate_specs and hasattr(type_, '_tensor_specs')
+    self._step_block = step_block
     self._conn = None
     self._process = None
     self._lock = threading.Lock()  # pipes are not thread-safe
@@ -237,6 +442,14 @@ class PyProcess:
     # hand, the env/pipe span).
     self._pending = None
     self._closed = False
+    # The StepBlock this env's `step` goes through and its column
+    # there (`attach_block`); None: `step` is a pickled call.
+    self.block = None
+    self.column = None
+    # Env steps that went through a block, and calls that went down
+    # the pickled pipe (read by `ActorFleet.stats`).
+    self.block_steps = 0
+    self.pipe_calls = 0
 
   @property
   def proxy(self):
@@ -254,6 +467,24 @@ class PyProcess:
     child_conn.close()  # parent keeps one end only
     return self
 
+  def step_block_specs(self):
+    """The observation leaves of this env's declared `step` reply, if
+    a StepBlock can hold it (`step_block_specs`); None: `step` stays
+    on the pipe."""
+    if not (self._step_block and hasattr(self._type, '_tensor_specs')):
+      return None
+    return step_block_specs(self._type._tensor_specs(
+        'step', {}, self._constructor_kwargs))
+
+  def attach_block(self, block, column):
+    """From here on `step_send` / `step_receive` step the env into
+    `column` of `block` (one made by `StepBlock.create`, not yet
+    unlinked), which the child maps now. Every other call, `step`
+    in one piece among them, stays a pickled call."""
+    self._call(_ATTACH, (self._validate, block.path, block.leaf_specs,
+                         block.rows, block.columns, column), {})
+    self.block, self.column = block, column
+
   def _call(self, method, args, kwargs):
     self._send(method, args, kwargs)
     return self._receive()
@@ -265,14 +496,18 @@ class PyProcess:
     failure here gives it back), so calls still never interleave on
     the pipe and `close()` still finds a call in flight. Between the
     halves the caller may send to OTHER processes: that is how one
-    actor thread has k children stepping at once."""
+    actor thread has k children stepping at once.
+
+    `method` `_BLOCK_STEP` steps the env through its block: `args` is
+    the action, written to the block's column, and one byte wakes the
+    child."""
     me = threading.get_ident()
     pending = self._pending
     if pending is not None and pending[0] == me:
       # The lock is not reentrant: this would park the thread for ever.
       raise RuntimeError(
-          f'{self._type.__name__}.{method}: send before the receive of '
-          f'{pending[1]!r}')
+          f'{self._type.__name__}.{self._name(method)}: send before '
+          f'the receive of {self._name(pending[1])!r}')
     self._lock.acquire()
     try:
       if self._closed or self._conn is None:
@@ -280,11 +515,22 @@ class PyProcess:
       reply = None
       pipe = telemetry.span('env/pipe')  # send -> reply in hand
       try:
-        self._conn.send((method, args, kwargs))
+        if method is _BLOCK_STEP:
+          self.block.action[self.column] = args
+          self.block_steps += 1
+          os.write(self._conn.fileno(), _STEP)
+        else:
+          # Pickled before the first byte goes: a request that cannot
+          # be must leave the child expecting nothing.
+          request = ForkingPickler.dumps((method, args, kwargs))
+          self.pipe_calls += 1
+          _PIPE_CALLS.inc()
+          os.write(self._conn.fileno(), _CALL)
+          self._conn.send_bytes(request)
       except (EOFError, OSError, BrokenPipeError) as e:
         reply = self._buffered_reply_or_closed(e)
       except Exception as e:
-        # send() failed locally (e.g. unpicklable argument) — nothing
+        # Failed locally (e.g. unpicklable argument) — nothing
         # reached the child; blame the caller, not the remote side.
         raise TypeError(
             f'could not serialize request for '
@@ -294,10 +540,15 @@ class PyProcess:
       raise
     self._pending = (me, method, kwargs, reply, pipe)
 
+  @staticmethod
+  def _name(method):
+    return 'step' if method is _BLOCK_STEP else method
+
   def _receive(self):
     """Second half of a call: block for the reply to this thread's
     `_send`, give the lock back, and turn the reply into the result
-    or the remote exception."""
+    or the remote exception. Of a step through the block the result
+    is None: it is in the block's row."""
     pending = self._pending
     if pending is None or pending[0] != threading.get_ident():
       raise RuntimeError(
@@ -307,7 +558,16 @@ class PyProcess:
     try:
       if reply is None:
         try:
-          reply = self._conn.recv()
+          if method is _BLOCK_STEP:
+            # One byte: the row is written, or a pickled failure
+            # follows.
+            answer = os.read(self._conn.fileno(), 1)
+            if answer == _STEPPED:
+              reply = 'ok', None
+            elif answer != _FAILED:
+              raise EOFError('the child closed its end')
+          if reply is None:
+            reply = self._conn.recv()
         except (EOFError, OSError, BrokenPipeError) as e:
           reply = self._buffered_reply_or_closed(e)
         except Exception as e:
@@ -317,24 +577,36 @@ class PyProcess:
           # report it as a remote failure instead of leaking a bare
           # unpickling error with no context.
           raise RemoteError(
-              f'in hosted {self._type.__name__}.{method}: reply could '
-              f'not be deserialized ({e!r})') from e
+              f'in hosted {self._type.__name__}.{self._name(method)}: '
+              f'reply could not be deserialized ({e!r})') from e
       pipe.end()
     finally:
       self._pending = None
       self._lock.release()
     status, payload = reply
+    block = self.block if method is _BLOCK_STEP else None
+    if (block is not None and status == 'ok'
+        and block.seq[self.column] == block.step_seq):
+      return None  # the row asked for is there
+    name = f'{self._type.__name__}.{self._name(method)}'
     if status == 'exception':
       exc, tb = payload
-      err = RemoteError(
-          f'in hosted {self._type.__name__}.{method}:\n{tb}')
+      err = RemoteError(f'in hosted {name}:\n{tb}')
       if exc is not None:
         raise err from exc
       raise err
+    if status == 'mismatch':  # found in the child, against the block
+      raise SpecMismatchError(payload)
+    if block is not None:
+      # Never seen: what the number is there to catch.
+      raise RemoteError(
+          f'in hosted {name}: column {self.column} of the block holds '
+          f'step {int(block.seq[self.column])}, not step '
+          f'{block.step_seq}: a row from before')
     if self._validate:
       specs = self._type._tensor_specs(method, kwargs,
                                        self._constructor_kwargs)
-      _validate_specs(payload, specs, f'{self._type.__name__}.{method}')
+      _validate_specs(payload, specs, name)
     return payload
 
   def _buffered_reply_or_closed(self, e):
@@ -382,7 +654,10 @@ class PyProcess:
       self._lock.release()
     if conn is not None:
       try:
+        os.write(conn.fileno(), _CALL)
         conn.send((_CLOSE, (), {}))
+        self.pipe_calls += 1
+        _PIPE_CALLS.inc()
         if conn.poll(timeout):
           conn.recv()
       except (EOFError, OSError, BrokenPipeError):
@@ -473,11 +748,23 @@ class ProxyEnv:
 
   def step_send(self, action):
     """`step` in two halves, for an actor thread that steps several
-    hosted envs at once: send to each, then `step_receive` from each."""
-    self._process._send('step', (action,), {})
+    hosted envs at once: send to each, then `step_receive` from each.
+    Through the block once the env is attached to one."""
+    if self._process.block is not None:
+      self._process._send(_BLOCK_STEP, action, None)
+    else:
+      self._process._send('step', (action,), {})
 
   def step_receive(self):
+    """(reward, done, observation); None of a step through the block,
+    whose row holds them."""
     return self._process._receive()
+
+  def step_block_specs(self):
+    return self._process.step_block_specs()
+
+  def attach_block(self, block, column):
+    self._process.attach_block(block, column)
 
   def close(self):
     self._process.close()

@@ -165,9 +165,12 @@ def test_train_with_popart_and_pixel_control(tmp_path):
                              mu[0], rtol=1e-6)
 
 
-def test_train_with_process_hosted_envs(tmp_path):
+def test_train_with_process_hosted_envs(tmp_path, caplog):
   """The production env-hosting path (use_py_process=True): each env in
   its own OS process behind the spec protocol, through the full driver.
+  Every `step` goes through the group's shared block (PR 33), only
+  `initial` and the attaching down the pickled pipe, and the run's
+  closing lines say so.
 
   Also the fork-hazard regression (VERDICT r2 W1): the driver builds
   env processes AFTER inference warmup, i.e. from a JAX-multithreaded
@@ -175,7 +178,8 @@ def test_train_with_process_hosted_envs(tmp_path):
   multi-threaded-fork warnings (py 3.12's deadlock deprecation)."""
   import warnings
   cfg = _config(tmp_path, use_py_process=True, num_actors=2)
-  with warnings.catch_warnings(record=True) as caught:
+  with warnings.catch_warnings(record=True) as caught, caplog.at_level(
+      'INFO', logger='scalable_agent_tpu'):
     warnings.simplefilter('always')
     run = driver.train(cfg, max_steps=2, stall_timeout_secs=120)
   fork_warnings = [w for w in caught
@@ -184,6 +188,11 @@ def test_train_with_process_hosted_envs(tmp_path):
   assert int(run.state.update_steps) == 2
   stats = run.fleet.stats()
   assert stats['unrolls'] >= 2
+  assert stats['block_steps'] >= stats['unrolls'] * cfg.unroll_length
+  # `initial`, the attaching and `close`, an env each.
+  assert stats['pipe_calls'] == 3 * cfg.num_actors
+  assert any(r.getMessage().startswith('env transport: block_steps=')
+             for r in caplog.records)
 
 
 def test_evaluate_multitask_parallel(tmp_path):

@@ -4,7 +4,10 @@ the reference has no equivalent: a dead actor silently stops feeding)."""
 import threading
 import time
 
+import os
+
 import numpy as np
+import pytest
 
 from scalable_agent_tpu.envs.fake import FakeEnv
 from scalable_agent_tpu.runtime import ring_buffer
@@ -58,7 +61,10 @@ def test_fleet_produces_and_stops():
   got = [buffer.get(timeout=10) for _ in range(3)]
   assert len(got) == 3
   fleet.stop()
-  assert fleet.stats()['unrolls'] >= 3
+  stats = fleet.stats()
+  assert stats['unrolls'] >= 3
+  # Envs in this process: no env process was reached either way.
+  assert stats['block_steps'] == 0 and stats['pipe_calls'] == 0
   assert not fleet.errors()
 
 
@@ -282,7 +288,15 @@ def test_stop_reports_unjoined_and_buffer_refuses_writes():
   fleet.start()
   buffer.get(timeout=10)  # healthy first
   stall.set()
-  time.sleep(0.3)         # actor 0 wedges mid-step
+  # Actor 0 wedges in its next step. Drained meanwhile: an actor
+  # parked on a full buffer is not in a step (and unrolls are quick
+  # enough to fill these 8 slots before this thread gets here).
+  deadline = time.monotonic() + 0.5
+  while time.monotonic() < deadline:
+    try:
+      buffer.get(timeout=0.05)
+    except TimeoutError:
+      pass
   report = fleet.stop(timeout=1.0)
   assert report['unjoined_actors'] == [0]
   # After stop() returns, a straggler's put cannot land a stale
@@ -527,13 +541,16 @@ def _group_policy(prev_action, env_output, core_state):
 
 
 def _hosted_factory(processes, env_kwargs, policy=_group_policy,
-                    state_fn=None, env_class=FlagCrashEnv):
+                    state_fn=None, env_class=FlagCrashEnv,
+                    step_block=True):
   """make_actor for process-hosted envs; every PyProcess it starts is
-  appended to `processes`."""
+  appended to `processes`. `step_block=False` holds their steps to
+  the pickled pipe."""
   from scalable_agent_tpu.runtime import py_process
 
   def make_actor(i):
-    process = py_process.PyProcess(env_class, env_kwargs(i)).start()
+    process = py_process.PyProcess(env_class, env_kwargs(i),
+                                   step_block=step_block).start()
     processes.append(process)
     env = py_process.ProxyEnv(process)
     state = (state_fn() if state_fn else
@@ -547,11 +564,38 @@ def _none_running(processes):
   return not any(p._process.is_alive() for p in processes)
 
 
-def test_group_member_failure_respawns_the_group(monkeypatch, tmp_path):
+def _no_segment_left():
+  from scalable_agent_tpu.runtime import py_process
+  return not [name for name in os.listdir(py_process._BLOCK_DIR)
+              if name.startswith(f'step_block_{os.getpid()}_')]
+
+
+def _assert_stepped_by(stats, step_block, envs, spawns):
+  """The fleet's counters of how its env processes were reached:
+  every `step` through the block, or none; an `initial` (on the
+  block, an attach too) a spawned process either way, and a `close`
+  where one could still be sent."""
+  if step_block:
+    assert stats['block_steps'] >= 4 * envs  # an unroll each, at least
+    assert 2 * spawns <= stats['pipe_calls'] <= 3 * spawns
+  else:
+    assert stats['block_steps'] == 0
+    assert stats['pipe_calls'] >= spawns + 4 * envs
+
+
+_BOTH_PATHS = pytest.mark.parametrize(
+    'step_block', [True, False], ids=['block', 'pipe'])
+
+
+@_BOTH_PATHS
+def test_group_member_failure_respawns_the_group(monkeypatch, tmp_path,
+                                                 step_block):
   """One env of a group raises: the group's thread ends through
   run_actor_loop's one failure path, the error lands on that env's
-  slot alone, the slots respawn together and share a thread again,
-  the buffer stays open, and no child outlives the fleet."""
+  slot alone, the slots respawn together and share a thread again
+  (on a block of their own, mapped anew: the counters go on across
+  the respawn), the buffer stays open, and no child and no shared
+  segment outlives the fleet."""
   flag = tmp_path / 'crash'
   flag.write_text('armed')
   processes = []
@@ -559,7 +603,8 @@ def test_group_member_failure_respawns_the_group(monkeypatch, tmp_path):
   fleet = ActorFleet(
       _hosted_factory(processes, lambda i: dict(
           height=H, width=W, num_actions=A, seed=i,
-          crash_flag=str(flag) if i == 1 else None)),
+          crash_flag=str(flag) if i == 1 else None),
+          step_block=step_block),
       buffer, num_actors=3)
   fleet.start()
   try:
@@ -589,13 +634,18 @@ def test_group_member_failure_respawns_the_group(monkeypatch, tmp_path):
     assert fleet.errors() == []
     assert fleet.stats()['slots_quarantined'] == 0
     assert len(processes) == 6
+    assert _no_segment_left()
+    _assert_stepped_by(fleet.stats(), step_block, envs=3, spawns=6)
   finally:
     report = fleet.stop()
   assert report['unjoined_actors'] == []
   assert _wait(lambda: _none_running(processes), timeout=10)
+  assert _no_segment_left()
 
 
-def test_wedged_group_member_respawns_the_group(monkeypatch, tmp_path):
+@_BOTH_PATHS
+def test_wedged_group_member_respawns_the_group(monkeypatch, tmp_path,
+                                                step_block):
   """One env of a group hangs mid-step: every member's heartbeat goes
   stale, the stall check orphans the thread and respawns the slots
   together; the children the wedged thread still held calls on are
@@ -608,7 +658,8 @@ def test_wedged_group_member_respawns_the_group(monkeypatch, tmp_path):
   fleet = ActorFleet(
       _hosted_factory(processes, lambda i: dict(
           height=H, width=W, num_actions=A, seed=i,
-          crash_flag=str(flag) if i == 0 else None)),
+          crash_flag=str(flag) if i == 0 else None),
+          step_block=step_block),
       buffer, num_actors=4)
   fleet.start()
   try:
@@ -639,9 +690,11 @@ def test_wedged_group_member_respawns_the_group(monkeypatch, tmp_path):
       seen.add(int(buffer.get(timeout=10).level_name))
     assert seen == {0, 1, 2, 3}
     assert fleet.stats()['actor_threads'] == 1
+    _assert_stepped_by(fleet.stats(), step_block, envs=4, spawns=8)
   finally:
     fleet.stop()
   assert _wait(lambda: _none_running(processes), timeout=10)
+  assert _no_segment_left()
 
 
 def test_group_member_quarantine_spares_its_mates(monkeypatch, tmp_path):

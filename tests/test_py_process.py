@@ -282,6 +282,297 @@ def test_proxy_env_split_step_matches_step():
     halves.close()
 
 
+# --- `step` through a shared block (PR 33) ---
+
+
+class WrongStepEnv(FakeEnv):
+  """Block fixture: its third step breaks its declared spec (`wrong`:
+  'shape', 'dtype' or 'structure'), raises ('raise'), takes a while
+  ('sleep') or ends the process ('die'). The other steps are FakeEnv's."""
+
+  def __init__(self, wrong=None, **kw):
+    super().__init__(**kw)
+    self._wrong, self._steps = wrong, 0
+
+  def step(self, action):
+    import time
+    reward, done, (frame, instr) = super().step(action)
+    self._steps += 1
+    if self._steps == 3:
+      if self._wrong == 'shape':
+        frame = frame[:-1]
+      elif self._wrong == 'dtype':
+        reward = float(reward)
+      elif self._wrong == 'structure':
+        return reward, done
+      elif self._wrong == 'raise':
+        raise KeyError('step boom')
+      elif self._wrong == 'sleep':
+        time.sleep(60)
+      elif self._wrong == 'die':
+        os._exit(1)
+    return reward, done, (frame, instr)
+
+
+def _token_env_kwargs(**kw):
+  return dict(vocab_size=11, episode_length=6, prompt_length=3, **kw)
+
+
+def _attached(env_class, kwargs_list, rows=4, **process_kwargs):
+  """Hosted envs attached to one shared block, as an ActorGroup does
+  it -> (envs, block); the block's name is gone again."""
+  envs = [ProxyEnv(PyProcess(env_class, kw, **process_kwargs).start())
+          for kw in kwargs_list]
+  block = py_process.StepBlock.create(
+      envs[0].step_block_specs(), rows, len(envs))
+  try:
+    for j, env in enumerate(envs):
+      env.attach_block(block, j)
+  finally:
+    block.unlink()
+  return envs, block
+
+
+def _block_step(envs, block, row, actions):
+  block.begin_step(row)
+  for env, action in zip(envs, actions):
+    env.step_send(action)
+  return [env.step_receive() for env in envs]
+
+
+def _segments_of(pid):
+  return [name for name in os.listdir(py_process._BLOCK_DIR)
+          if name.startswith(f'step_block_{pid}_')]
+
+
+def _block_cases():
+  from scalable_agent_tpu.envs.tokens import TokenEnv
+  return [
+      pytest.param(FakeEnv, dict(height=8, width=8, episode_length=3),
+                   id='image'),
+      pytest.param(TokenEnv, _token_env_kwargs(), id='tokens')]
+
+
+@pytest.mark.parametrize('env_class,kwargs', _block_cases())
+def test_block_step_is_bitwise_the_pickled_step(env_class, kwargs):
+  """Frame for frame, reward for reward, done for done, in their own
+  dtypes: k children stepping into a block against the same envs
+  stepped by pickled calls. Rows are reused as an unroll reuses them."""
+  k, rows = 3, 4
+  kwargs_list = [dict(kwargs, seed=i) for i in range(k)]
+  piped = [ProxyEnv(PyProcess(env_class, kw, step_block=False).start())
+           for kw in kwargs_list]
+  envs, block = _attached(env_class, kwargs_list, rows)
+  try:
+    assert piped[0].step_block_specs() is None  # the argument's doing
+    assert not _segments_of(os.getpid())
+    for env in piped + envs:
+      env.initial()
+    for t in range(9):
+      row = 1 + t % (rows - 1)
+      actions = [(t + j) % 3 for j in range(k)]
+      assert _block_step(envs, block, row, actions) == [None] * k
+      for j, env in enumerate(piped):
+        reward, done, observation = env.step(actions[j])
+        assert block.reward[row, j] == reward
+        assert block.done[row, j] == done
+        leaves = list(py_process._leaves(observation))
+        assert len(leaves) == len(block.leaves)
+        for leaf, column in zip(leaves, block.leaves):
+          np.testing.assert_array_equal(column[row, j], leaf)
+          assert column.dtype == np.asarray(leaf).dtype
+    assert [e._process.block_steps for e in envs] == [9] * k
+    assert [e._process.block_steps for e in piped] == [0] * k
+    # `initial` and attaching went down the pipe, like every `step`
+    # of the others.
+    assert [e._process.pipe_calls for e in envs] == [2] * k
+    assert [e._process.pipe_calls for e in piped] == [10] * k
+  finally:
+    py_process.close_all([e._process for e in piped + envs])
+
+
+def test_other_calls_ride_the_pipe_between_block_steps():
+  """A pickled call (`prompt_block`: a block of tokens; `initial`; the
+  whole `step`) between two steps through the block: raw bytes and
+  pickled messages never meet on the pipe, and `close` still reaches
+  the child."""
+  from scalable_agent_tpu.envs.tokens import TokenEnv
+  kwargs = _token_env_kwargs(prompt_block=8, seed=5)
+  (env,), block = _attached(TokenEnv, [kwargs])
+  alone = TokenEnv(**kwargs)
+  try:
+    assert env.initial() == alone.initial()
+    begins, prompts, whole = True, 0, 0
+    for t in range(14):  # over three episode ends
+      if begins:
+        tokens, n = env.prompt_block()
+        want_tokens, want_n = alone.prompt_block()
+        np.testing.assert_array_equal(tokens, want_tokens)
+        assert n == want_n
+        prompts += 1
+      reward, done, (token,) = alone.step(t % 11)
+      if t % 5 == 4:  # `step` in one piece stays a pickled call
+        assert env.step(t % 11) == (reward, done, (token,))
+        whole += 1
+      else:
+        row = 1 + t % 3
+        _block_step([env], block, row, [t % 11])
+        assert (block.reward[row, 0], block.done[row, 0],
+                block.leaves[0][row, 0]) == (reward, done, token)
+      begins = bool(done)
+    process = env._process
+    assert (prompts, whole) == (4, 2)
+    assert process.block_steps == 12
+    # attach, initial, the prompt blocks, the whole steps
+    assert process.pipe_calls == 2 + prompts + whole
+  finally:
+    env.close()
+  assert not env._process.running
+  assert env._process.pipe_calls == 9  # `close` went down the pipe too
+
+
+@pytest.mark.parametrize('wrong', ['shape', 'dtype', 'structure'])
+def test_block_step_checks_the_spec_in_the_child(wrong):
+  """A reply that breaks the declared spec never reaches the block:
+  the parent raises SpecMismatchError naming the method, as it does
+  for a pickled reply, and the child serves on."""
+  kwargs = dict(height=8, width=8, wrong=wrong)
+  envs, block = _attached(WrongStepEnv, [kwargs, dict(height=8, width=8)])
+  try:
+    for t in range(2):
+      _block_step(envs, block, 1, [0, 0])
+    block.begin_step(2)
+    for env in envs:
+      env.step_send(1)
+    with pytest.raises(SpecMismatchError, match='WrongStepEnv.step'):
+      envs[0].step_receive()
+    assert envs[1].step_receive() is None  # its mate's step is whole
+    assert block.seq[0] != block.step_seq  # nothing was written
+    _block_step(envs, block, 3, [1, 1])
+    assert block.seq[0] == block.seq[1] == block.step_seq
+  finally:
+    py_process.close_all([e._process for e in envs])
+
+
+def test_block_step_failures_keep_the_pipes_contract():
+  """An exception of the env's comes back as RemoteError and the
+  worker serves on; a child that dies between wake-up and answer is
+  ProcessClosed at the receive and at every send after; one that
+  hangs is killed by `close`, which breaks the parked receive."""
+  import threading
+  import time
+  envs, block = _attached(WrongStepEnv, [
+      dict(height=8, width=8, wrong=w) for w in ('raise', 'die', 'sleep')])
+  raising, dying, hanging = envs
+  try:
+    for t in range(2):
+      _block_step(envs, block, 1, [0, 0, 0])
+    block.begin_step(2)
+    for env in envs:
+      env.step_send(1)
+    with pytest.raises(RuntimeError, match='send before the receive'):
+      raising.step_send(1)
+    with pytest.raises(RemoteError, match='step boom'):
+      raising.step_receive()
+    with pytest.raises(ProcessClosed):
+      dying.step_receive()
+    with pytest.raises(ProcessClosed):
+      dying.step_send(1)
+    # Parked on a child that hangs: `close` from another thread waits
+    # its timeout out for the call lock, then kills the child, which
+    # breaks the receive.
+    closer = threading.Timer(
+        0.2, lambda: hanging._process.close(timeout=0.5))
+    closer.start()
+    t0 = time.monotonic()
+    with pytest.raises(ProcessClosed):
+      hanging.step_receive()
+    assert time.monotonic() - t0 < 10
+    closer.join(timeout=10)
+    # The one that raised serves on, through the block.
+    _block_step([raising], block, 3, [1])
+    assert block.seq[0] == block.step_seq
+  finally:
+    py_process.close_all([e._process for e in envs], timeout=1.0)
+
+
+def test_a_row_from_before_is_never_taken_for_the_step():
+  """The sequence number: an acknowledgement over a row that an
+  earlier step wrote (here: the parent moved on without telling the
+  child) is refused."""
+  (env,), block = _attached(FakeEnv, [dict(height=8, width=8)])
+  try:
+    _block_step([env], block, 1, [0])
+    block.step_seq += 1  # `want` still says the step before
+    env.step_send(0)
+    with pytest.raises(RemoteError, match='a row from before'):
+      env.step_receive()
+  finally:
+    env.close()
+
+
+def test_a_type_without_a_fixed_step_spec_stays_on_the_pipe():
+  """No `_tensor_specs`, or a `step` spec that is no (reward, done,
+  observation) of declared shapes: there is nothing to lay a block
+  out from."""
+  class Spec(base.ArraySpec):
+    pass
+
+  assert PyProcess(Calculator).step_block_specs() is None
+  assert PyProcess(SpeccedZeros).step_block_specs() is None
+  assert py_process.step_block_specs(None) is None
+  assert py_process.step_block_specs(
+      (Spec((), np.float32), Spec((), bool))) is None
+  assert py_process.step_block_specs(
+      (Spec((2,), np.float32), Spec((), bool), (Spec((3,), np.int32),))
+  ) is None  # a reward that is no scalar
+  assert py_process.step_block_specs(
+      (Spec((), np.float32), Spec((), bool),
+       (Spec((None, 3), np.int32),))) is None  # a shape left open
+  assert py_process.step_block_specs(
+      (Spec((), np.float32), Spec((), bool),
+       [Spec((4, 3), np.uint8), (Spec((), np.int32),)])) == [
+           ((4, 3), '|u1'), ((), '<i4')]
+  assert PyProcess(FakeEnv, dict(height=8, width=8)).step_block_specs() == [
+      ((8, 8, 3), '|u1'),
+      ((FakeEnv(height=8, width=8).initial()[1].shape[0],), '<i4')]
+
+
+def _killed_parent_main():
+  """Body of the killed-parent test's PARENT process (this file run as
+  a script): a group of hosted envs on a block, then its own SIGKILL
+  mid-unroll, the children parked on their pipes."""
+  import signal
+  py_process.warm_forkserver()
+  envs, block = _attached(FakeEnv, [dict(height=8, width=8)] * 3)
+  _block_step(envs, block, 1, [0, 0, 0])
+  print('PIDS', os.getpid(), *[e._process._process.pid for e in envs],
+        flush=True)
+  os.kill(os.getpid(), signal.SIGKILL)
+
+
+def test_a_killed_parent_leaves_no_segment_and_no_process():
+  """The block's name is gone as soon as every child has mapped it, so
+  there is nothing to clean up however the parent ends; its children
+  see their pipes close and go."""
+  import time
+  out = subprocess.run(
+      [sys.executable, os.path.abspath(__file__), '--killed-parent'],
+      capture_output=True, text=True, timeout=120,
+      env=dict(os.environ, PYTHONPATH=os.pathsep.join(
+          [os.path.dirname(os.path.dirname(os.path.abspath(__file__)))]
+          + sys.path)))
+  assert out.returncode == -9, (out.returncode, out.stderr[-2000:])
+  parent, *children = map(int, out.stdout.split('PIDS')[1].split())
+  assert len(children) == 3
+  assert not _segments_of(parent)
+  deadline = time.monotonic() + 30
+  while time.monotonic() < deadline and any(
+      os.path.exists(f'/proc/{pid}') for pid in children):
+    time.sleep(0.1)
+  assert not [pid for pid in children if os.path.exists(f'/proc/{pid}')]
+
+
 def test_fleet_lifecycle():
   procs = [PyProcess(Calculator, dict(bias=i)) for i in range(4)]
   with py_process.hosted(procs) as started:
@@ -395,4 +686,7 @@ def test_stop_forkserver_leaves_no_child_behind():
 
 
 if __name__ == '__main__':
-  _platform_probe_main()
+  if '--killed-parent' in sys.argv:
+    _killed_parent_main()
+  else:
+    _platform_probe_main()

@@ -7,6 +7,7 @@ batches feeding the jitted train step. The reference never tests this
 glue (SURVEY §4); we do.
 """
 
+import os
 import threading
 import time
 
@@ -951,7 +952,7 @@ class _ScriptedStatePolicy:
 
   def __call__(self, prev_action, env_output, core_state):
     from scalable_agent_tpu.structs import AgentOutput
-    frame, _ = env_output.observation
+    frame = env_output.observation[0]
     if np.ndim(prev_action) == 0:
       self.calls.append(1)
       carry = core_state.snapshot() if self._cache else core_state
@@ -1105,6 +1106,199 @@ class TestActorGroup:
     group.close()  # a member no unroll took in is closed with the rest
     if cache:
       assert b._core_state.released and c._core_state.released
+
+
+class _NoSpecEnv:
+  """A hosted env that declares nothing: FakeEnv's steps, no
+  `_tensor_specs`."""
+
+  def __init__(self, **kw):
+    self._env = FakeEnv(**kw)
+
+  def initial(self):
+    return self._env.initial()
+
+  def step(self, action):
+    return self._env.step(action)
+
+
+class _TruncatingEnv(FakeEnv):
+  """Its third step returns a frame a row short of its declared spec
+  (`fault='shape'`), or ends the process (`fault='die'`)."""
+
+  def __init__(self, fault=None, **kw):
+    super().__init__(**kw)
+    self._fault, self._steps = fault, 0
+
+  def step(self, action):
+    reward, done, (frame, instr) = super().step(action)
+    self._steps += 1
+    if self._steps == 3 and self._fault == 'shape':
+      frame = frame[:-1]
+    if self._steps == 3 and self._fault == 'die':
+      import os
+      os._exit(1)
+    return reward, done, (frame, instr)
+
+
+def _hosted_group(env_class, kwargs_list, policy, T, **process_kwargs):
+  from scalable_agent_tpu.runtime import py_process
+  from scalable_agent_tpu.runtime.actor import ActorGroup
+  envs = [py_process.ProxyEnv(
+      py_process.PyProcess(env_class, kw, **process_kwargs).start())
+      for kw in kwargs_list]
+  return ActorGroup([
+      Actor(env, policy, policy.initial_core_state(), T,
+            num_action_repeats=2, level_name_id=i)
+      for i, env in enumerate(envs)])
+
+
+def _block_group_cases():
+  from scalable_agent_tpu.envs.tokens import TokenEnv
+  image = dict(height=H, width=W, num_actions=A, episode_length=5)
+  tokens = dict(vocab_size=A, episode_length=6, prompt_length=2)
+  return [pytest.param(cls, kw, k, id=f'{name}-k{k}')
+          for name, cls, kw in [('image', FakeEnv, image),
+                                ('tokens', TokenEnv, tokens)]
+          for k in (1, 3, 32)]
+
+
+class TestGroupOnASharedBlock:
+  """PR 33: hosted envs of a declared `step` spec step into a block in
+  shared memory that their group owns; everything else stays as it
+  was, and which it is follows from what the members offer."""
+
+  @pytest.mark.parametrize('env_class,kwargs,k', _block_group_cases())
+  def test_block_and_pipe_unrolls_are_bitwise_equal(self, env_class,
+                                                    kwargs, k):
+    """The same envs, the same seeds, the same policy: a group on the
+    block and one on the pipe produce the same ActorOutputs, which are
+    what each env produces in this process, alone."""
+    from scalable_agent_tpu import telemetry
+    T = 7
+    kwargs_list = [dict(kwargs, seed=i) for i in range(k)]
+    policies = [_ScriptedStatePolicy(cache=False) for _ in range(3)]
+    on_block = _hosted_group(env_class, kwargs_list, policies[0], T)
+    on_pipe = _hosted_group(env_class, kwargs_list, policies[1], T,
+                            step_block=False)
+    alone = [Actor(env_class(**kw), policies[2],
+                   policies[2].initial_core_state(), T,
+                   num_action_repeats=2, level_name_id=i)
+             for i, kw in enumerate(kwargs_list)]
+    counted = telemetry.registry().get('actors/block_steps').value
+    try:
+      for n in range(3):
+        blocked, piped = on_block.unroll(), on_pipe.unroll()
+        for j in range(k):
+          _assert_unrolls_bitwise_equal(blocked[j], piped[j])
+          if j < 3:
+            _assert_unrolls_bitwise_equal(blocked[j], alone[j].unroll())
+      steps = [a._env._process.block_steps for a in on_block.actors]
+      assert steps == [3 * T] * k
+      assert not any(a._env._process.block_steps for a in on_pipe.actors)
+      # Counted once a group step, by k.
+      assert (telemetry.registry().get('actors/block_steps').value
+              == counted + 3 * T * k)
+      # What an unroll holds is its own: the next unroll reuses the
+      # group's arrays and must not show through.
+      held = blocked[0].env_outputs.observation[0].copy()
+      on_block.unroll()
+      np.testing.assert_array_equal(
+          blocked[0].env_outputs.observation[0], held)
+    finally:
+      on_block.close()
+      on_pipe.close()
+
+  def test_an_env_without_a_spec_stays_on_the_pipe(self):
+    """Nothing declared, nothing to lay a block out from: the group
+    steps by pickled calls, and the counters say so."""
+    from scalable_agent_tpu import telemetry
+    T = 4
+    kwargs_list = [dict(height=H, width=W, num_actions=A, seed=i)
+                   for i in range(2)]
+    policy = _ScriptedStatePolicy(cache=False)
+    group = _hosted_group(_NoSpecEnv, kwargs_list, policy, T)
+    alone = [Actor(FakeEnv(**kw), policy, policy.initial_core_state(),
+                   T, num_action_repeats=2, level_name_id=i)
+             for i, kw in enumerate(kwargs_list)]
+    counted = telemetry.registry().get('actors/block_steps').value
+    piped = telemetry.registry().get('actors/pipe_calls').value
+    try:
+      for unroll, actor in zip(group.unroll(), alone):
+        _assert_unrolls_bitwise_equal(unroll, actor.unroll())
+      processes = [a._env._process for a in group.actors]
+      assert [p.block_steps for p in processes] == [0, 0]
+      assert [p.pipe_calls for p in processes] == [1 + T] * 2
+      assert telemetry.registry().get('actors/block_steps').value == counted
+      assert (telemetry.registry().get('actors/pipe_calls').value
+              == piped + 2 * T)  # the `initial`s came before the reading
+    finally:
+      group.close()
+
+  @pytest.mark.parametrize('fault,error', [
+      ('shape', 'SpecMismatchError'), ('die', 'ProcessClosed')])
+  def test_a_failing_member_is_named_and_its_mates_collected(
+      self, fault, error):
+    """A member whose step breaks its spec, or whose process dies
+    between wake-up and answer: the group says which, the error is
+    what the pipe gave, and no mate is left mid-call."""
+    from scalable_agent_tpu.runtime import py_process
+    kwargs_list = [dict(height=H, width=W, num_actions=A, seed=i,
+                        fault=fault if i == 1 else None)
+                   for i in range(3)]
+    group = _hosted_group(_TruncatingEnv, kwargs_list,
+                          _ScriptedStatePolicy(cache=False), 6)
+    try:
+      with pytest.raises(getattr(py_process, error),
+                         match='_TruncatingEnv'):
+        group.unroll()
+      assert group.failed is group.actors[1]
+      assert group.waiting_on is None
+      for actor in group.actors[0::2]:
+        process = actor._env._process
+        assert process._pending is None and process.block_steps == 3
+        actor._env.initial()  # the pipe is in step: answered
+    finally:
+      group.close()
+
+  def test_a_new_member_is_mapped_into_a_new_block(self):
+    """Membership moves between unrolls: the group lays a block out
+    for the members as they are, every child maps it, and the unrolls
+    stay what each env produces alone."""
+    from scalable_agent_tpu.runtime import py_process
+    T = 5
+    kwargs_list = [dict(height=H, width=W, num_actions=A, seed=i,
+                        episode_length=4) for i in range(3)]
+    policy = _ScriptedStatePolicy(cache=False)
+    group = _hosted_group(FakeEnv, kwargs_list, policy, T)
+    alone_policy = _ScriptedStatePolicy(cache=False)
+    alone = [Actor(FakeEnv(**kw), alone_policy,
+                   alone_policy.initial_core_state(), T,
+                   num_action_repeats=2, level_name_id=i)
+             for i, kw in enumerate(kwargs_list)]
+    late = group.actors.pop()
+    group.names = ['a', 'b']
+    try:
+      first = group.unroll()
+      block = group._rollout.block
+      assert group._rollout.shared and block.columns == 2
+      group.join(late, 'c')
+      group.admit()
+      second = group.unroll()
+      assert group._rollout.block is not block
+      assert group._rollout.shared and group._rollout.block.columns == 3
+      group.leave(group.actors[0])
+      third = group.unroll()
+      assert group._rollout.block.columns == 2
+      for got, actor in [(first[0], alone[0]), (second[0], alone[0]),
+                         (first[1], alone[1]), (second[1], alone[1]),
+                         (third[0], alone[1]),
+                         (second[2], alone[2]), (third[1], alone[2])]:
+        _assert_unrolls_bitwise_equal(got, actor.unroll())
+      assert not [name for name in os.listdir(py_process._BLOCK_DIR)
+                  if name.startswith(f'step_block_{os.getpid()}_')]
+    finally:
+      group.close()
 
 
 class TestGroupedPolicyCall:
