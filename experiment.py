@@ -201,8 +201,21 @@ flags.DEFINE_float('seq_rope_theta', _DEFAULTS.seq_rope_theta,
                    'Sequence agent: RoPE base.')
 flags.DEFINE_float('seq_norm_eps', _DEFAULTS.seq_norm_eps,
                    'Sequence agent: RMSNorm epsilon.')
+flags.DEFINE_string('seq_layer_pattern', _DEFAULTS.seq_layer_pattern,
+                    'Sequence agent: given, the core is grouped-query '
+                    'attention (--seq_num_kv_heads groups of '
+                    '--seq_head_dim) in this pattern of layers, one '
+                    'letter a layer, repeated over the depth: L attends '
+                    'to the last --seq_window tokens and keeps a ring of '
+                    'them, G to the whole episode and keeps it; with the '
+                    'dense and routed-expert feed-forward layers of the '
+                    'latent core\'s flags.')
+flags.DEFINE_integer('seq_window', _DEFAULTS.seq_window,
+                     'Window layers: the tokens one attends to, its own '
+                     'among them.', lower_bound=1)
 flags.DEFINE_integer('seq_kv_lora_rank', _DEFAULTS.seq_kv_lora_rank,
-                     'Sequence agent: 0 for the power-retention core; '
+                     'Sequence agent: 0 for the power-retention core (or '
+                     'the one --seq_layer_pattern names); '
                      'above 0 the core is latent attention (MLA) with '
                      'this latent width, over a per-session latent cache, '
                      'with dense and routed-expert feed-forward layers '
@@ -268,11 +281,11 @@ flags.DEFINE_float('seq_rope_mscale_all_dim',
                    _DEFAULTS.seq_rope_mscale_all_dim,
                    'Latent core: YaRN mscale_all_dim.')
 flags.DEFINE_integer('seq_cache_capacity', _DEFAULTS.seq_cache_capacity,
-                     'Latent core: tokens of an episode a session\'s '
-                     'latent cache holds (at least --episode_length).',
+                     'Latent core, full layers: tokens of an episode a '
+                     'session\'s cache holds (at least --episode_length).',
                      lower_bound=1)
 flags.DEFINE_integer('seq_prefill_chunk', _DEFAULTS.seq_prefill_chunk,
-                     'Latent core: tokens one prefill call takes; an '
+                     'Cores with a cache: tokens one prefill call takes; an '
                      'episode\'s prompt reaches the server in such '
                      'blocks.', lower_bound=1)
 flags.DEFINE_integer('token_prompt_length',

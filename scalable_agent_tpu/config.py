@@ -135,11 +135,20 @@ class Config:
   seq_mlp_size: int = 128
   seq_rope_theta: float = 1e6
   seq_norm_eps: float = 1e-6
-  # The sequence agent's core. seq_kv_lora_rank 0: power-retention
-  # blocks (the fields above). Above 0: latent attention (MLA) over a
-  # per-session latent cache, dense and routed-expert feed-forward
-  # layers (models/latent_moe.py); seq_num_kv_heads and seq_head_dim
-  # are then unused. Defaults: the tiny size the CPU tests run.
+  # The sequence agent's core (`seq_core` reads which). Neither
+  # seq_layer_pattern nor seq_kv_lora_rank: power-retention blocks (the
+  # fields above). seq_kv_lora_rank above 0: latent attention (MLA)
+  # over a per-session latent cache, dense and routed-expert
+  # feed-forward layers (models/latent_moe.py); seq_num_kv_heads and
+  # seq_head_dim are then unused. seq_layer_pattern given (one letter a
+  # layer, repeated over the depth: L a layer that attends to the last
+  # seq_window tokens and keeps a ring of them, G one that attends to
+  # the whole episode and keeps it): grouped-query attention of
+  # seq_num_kv_heads groups of seq_head_dim over those two kinds of
+  # cache, the same feed-forward layers (models/hybrid_attention.py).
+  # Defaults: the tiny size the CPU tests run.
+  seq_layer_pattern: str = ''
+  seq_window: int = 8
   seq_kv_lora_rank: int = 0
   seq_q_lora_rank: int = 24
   seq_qk_nope_head_dim: int = 8
@@ -822,6 +831,14 @@ class Config:
     return min(self.unroll_length, 16)
 
   @property
+  def seq_core(self) -> str:
+    """The sequence agent's core, as the seq_* widths name it:
+    'retention' | 'latent' | 'hybrid'."""
+    if self.seq_layer_pattern:
+      return 'hybrid'
+    return 'latent' if self.seq_kv_lora_rank > 0 else 'retention'
+
+  @property
   def resolved_use_instruction(self) -> bool:
     """`use_instruction` with the None-auto rule applied (must be
     deterministic in the config alone: train, evaluate, and remote
@@ -1213,7 +1230,12 @@ def validate_runtime(config: Config) -> List[str]:
     if config.use_popart or config.pixel_control_cost > 0:
       raise ValueError('--agent=sequence has neither PopArt value '
                        'columns nor a pixel-control head')
-    if config.seq_kv_lora_rank > 0:
+    if config.seq_layer_pattern and config.seq_kv_lora_rank > 0:
+      raise ValueError(
+          '--seq_layer_pattern names the core of window and full '
+          'attention layers, --seq_kv_lora_rank the latent-attention '
+          'core: give one of them')
+    if config.seq_core != 'retention':
       if config.episode_length > config.seq_cache_capacity:
         raise ValueError(
             f'an episode of {config.episode_length} tokens does not fit '

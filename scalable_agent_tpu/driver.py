@@ -58,6 +58,7 @@ from scalable_agent_tpu.config import (Config, validate_controller,
 from scalable_agent_tpu.envs import factory, suites
 from scalable_agent_tpu.models import (ImpalaAgent, SequenceAgent,
                                       init_params)
+from scalable_agent_tpu.models import hybrid_attention
 from scalable_agent_tpu.models import latent_moe
 from scalable_agent_tpu.parallel import mesh as mesh_lib
 from scalable_agent_tpu.parallel import sharding as sharding_lib
@@ -114,15 +115,18 @@ def build_agent(config: Config, num_actions: int, num_tasks: int = 1):
   dtype = (jnp.bfloat16 if config.compute_dtype == 'bfloat16'
            else jnp.float32)
   if config.agent == 'sequence':
-    latent = None
-    if config.seq_kv_lora_rank > 0:
-      # Every field of the latent core's widths is the flag of its name.
-      latent = latent_moe.LatentMoEDims(**{
+    core_dims = None
+    widths = {'latent': latent_moe.LatentMoEDims,
+              'hybrid': hybrid_attention.HybridAttentionDims}.get(
+                  config.seq_core)
+    if widths is not None:
+      # Every field of a core's widths is the flag of its name.
+      core_dims = widths(**{
           field.name: getattr(config, f'seq_{field.name}')
-          for field in dataclasses.fields(latent_moe.LatentMoEDims)})
-      latent.check()
+          for field in dataclasses.fields(widths)})
+      core_dims.check()
     return SequenceAgent(
-        latent=latent,
+        core_dims=core_dims,
         num_actions=num_actions, num_layers=config.seq_num_layers,
         hidden_size=config.seq_hidden_size,
         num_heads=config.seq_num_heads,

@@ -116,7 +116,8 @@ def make_env_spec(config: Config, level_name: str, seed: int,
     # and its state lives in the server's arena, the prompt goes over
     # as a block (envs/tokens.py), sized for the fleet's longest.
     fleet = max(config.num_actors, 1)
-    chunked = config.seq_kv_lora_rank > 0 and config.inference_state_cache
+    chunked = (config.seq_core != 'retention' and
+               config.inference_state_cache)
     kwargs = dict(vocab_size=config.num_actions,
                   episode_length=config.episode_length,
                   prompt_length=(config.token_prompt_length +

@@ -87,9 +87,20 @@ class RecurrentCore(nn.Module):
     carry, out = step(gather_rows(arena, slots), x, done)
     return scatter_rows(arena, slots, carry), out
 
+  # What the agent and the inference server ask of a core; the
+  # defaults are a state of fixed size that counts nothing.
   # Tokens a `chunk` call takes where the core computes a chunk at
   # once; 0: the core has no form of its own, and nobody chunks for it.
   chunk_size = 0
+  # Tokens of an episode a session's state holds where it is written
+  # at a position (a cache attention reads); 0: a state of fixed size.
+  cache_capacity = 0
+  # Tokens a layer that keeps only its episode's last ones holds (a
+  # ring beside the cache); 0: no such layer.
+  cache_window = 0
+  # The per-call counters the core's layers sow (collection
+  # 'counters'), which the server reads with a call's outputs.
+  counters = ()
 
   def chunk(self, carry, xs, n_valid, reset, slot=None):
     """The chunk form by the scan of `step`: `carry` is the session's
@@ -111,6 +122,94 @@ class RecurrentCore(nn.Module):
     if slot is None:
       return rows, outs
     return scatter_rows(carry, slot[None], rows), outs
+
+
+class PositionedCore(RecurrentCore):
+  """A stack of layers whose state is written AT A POSITION: carry
+  `{'pos': i32 [B], 'layers': (leaf [B, ...], ...)}`, one leaf a layer,
+  which the layer reads as far as the row's position and no further.
+  `done` resets a row's POSITION; what the row held stays where it is
+  and is never read again. The arena is the carry with a row a slot
+  and one more, advanced in place. A subclass gives `initial_state`,
+  `cache_capacity`, `chunk_size` and THE compact method
+
+      _blocks(x, leaves, rows, pos, live, prefill) -> (x, leaves)
+
+  for token n at position `pos[n]` of the row `rows[n]` (both in range;
+  `live[n]` false: the row is no session's). `prefill` None: N rows of
+  N sessions, one token each; `(row, pos0, n_valid)`: N tokens of one
+  session."""
+
+  def arena(self, num_slots):
+    """One row more than there are slots: the row that padded rows of
+    a merged call (and a chunk for no session: the warm-up's) are
+    written to (ops/mla_pallas.py)."""
+    return self.initial_state(num_slots + 1)
+
+  @staticmethod
+  def _rows(slots, sessions):
+    """(rows to read and write, which of them are some session's):
+    an id out of range goes to the arena's last row."""
+    live = slots < sessions
+    return jnp.where(live, slots, sessions), live
+
+  def step(self, carry, x, done, slots=None):
+    """One token a row: the carry's own rows, or with `slots` the rows
+    of the arena they name. A position beyond the capacity overwrites
+    the last column (the configuration keeps episodes inside it)."""
+    if slots is None:
+      slots = rows = jnp.arange(x.shape[0])
+      live = jnp.ones(x.shape[:1], bool)
+    else:
+      rows, live = self._rows(slots, carry['pos'].shape[0] - 1)
+    pos = jnp.where(done, 0, carry['pos'][rows])
+    x, layers = self._blocks(
+        x, carry['layers'], rows,
+        jnp.minimum(pos, self.cache_capacity - 1), live, None)
+    new_pos = carry['pos'].at[slots].set(pos + 1, mode='drop')
+    return {'pos': new_pos, 'layers': layers}, x
+
+  def chunk(self, carry, xs, n_valid, reset, slot=None):
+    """C tokens of one session at once."""
+    c = xs.shape[0]
+    if slot is None:
+      slot = row = jnp.zeros((), jnp.int32)
+      live = jnp.ones((), bool)
+    else:
+      row, live = self._rows(slot, carry['pos'].shape[0] - 1)
+    pos0 = jnp.where(reset, 0, carry['pos'][row])
+    x, layers = self._blocks(
+        xs, carry['layers'], jnp.full((c,), row), pos0 + jnp.arange(c),
+        (jnp.arange(c) < n_valid) & live, (row, pos0, n_valid))
+    new_pos = carry['pos'].at[slot].set(pos0 + n_valid, mode='drop')
+    return {'pos': new_pos, 'layers': layers}, x
+
+
+def running_softmax(carry, scores, values_fn):
+  """One block of a softmax taken in blocks: `scores [..., S]` (masked
+  columns at -inf), `values_fn(p)` the block's weighted values."""
+  m, l, acc = carry
+  m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
+  p = jnp.exp(scores - m_new[..., None])
+  corr = jnp.exp(m - m_new)
+  return (m_new, l * corr + jnp.sum(p, axis=-1),
+          acc * corr[..., None] + values_fn(p))
+
+
+def write_chunk(cache, entry, slot, pos0, n_valid, live):
+  """`entry [C, W]`: its first `n_valid` tokens written as the columns
+  `pos0..` of row `slot`, where `live [C]` and as far as the capacity;
+  in place, as one window of C columns (shifted back where `pos0 + C`
+  would pass the capacity, the tokens rolled to their columns)."""
+  c, capacity = entry.shape[0], cache.shape[2]
+  start = jnp.clip(pos0, 0, capacity - c)
+  at = (slot, 0, start)
+  window = jax.lax.dynamic_slice(cache, at, (1, cache.shape[1], c))
+  token = jnp.arange(c) - (pos0 - start)   # the token a column takes
+  rolled = jnp.roll(entry.T, pos0 - start, axis=1)
+  write = (token >= 0) & (token < n_valid) & jnp.roll(live, pos0 - start)
+  return jax.lax.dynamic_update_slice(
+      cache, jnp.where(write[None, None, :], rolled[None], window), at)
 
 
 def unroll(core, carry, xs, dones, scan_unroll=1):

@@ -41,19 +41,9 @@ prefill form in XLA's own operations as far as the chunk's last token,
 the decode form in a kernel that reads each row's cache as far as that
 row's position (ops/mla_pallas.py).
 
-Feed-forward: `FFN_w(x) = W_down(silu(W_gate x) * W_up x)`. The first
-`first_dense_layers` blocks have the dense width. The others route:
-`s = sigmoid(x W_g)` over ALL `routed_experts` (float32 operands),
-choice on `s + bias` within the `expert_groups_kept` best of
-`expert_groups` groups (a group scores the sum of its two largest),
-the `experts_per_token` largest among them; weights `routed_scale s_e
-/ sum_chosen s`. The layer is told which experts it holds
-(`experts_held` from `expert_offset`): it computes their part of the
-result and the shared expert's, `y = FFN_shared(x) + sum_{e chosen and
-held} g_e FFN_e(x)`; what the absent experts would add is left out, as
-one chip of an expert-parallel deployment leaves it to the others. An
-expert nobody routed to in a call is skipped, weights unread; no token
-is dropped whatever the load.
+Feed-forward (models/moe.py, shared with models/hybrid_attention.py):
+the first `first_dense_layers` blocks have the dense SwiGLU width, the
+others the shared expert and this chip's share of the routed ones.
 
 Precision, as the configuration states it: parameters and cache in
 `param_dtype`, the operands of every product rounded to `dtype`,
@@ -71,6 +61,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from scalable_agent_tpu.models import core as core_lib
+from scalable_agent_tpu.models.core import running_softmax, write_chunk
+from scalable_agent_tpu.models.moe import (
+    COUNTERS, RoutedExperts, RoutingDims, _FFNWeights, _Kernel, ffn)
 from scalable_agent_tpu.models.retention import _Linear, _Scale
 from scalable_agent_tpu.ops import mla_pallas
 
@@ -79,31 +72,21 @@ from scalable_agent_tpu.ops import mla_pallas
 # prefill form.
 DECODE_BLOCK = 1024
 PREFILL_BLOCK = 1024
-# The per-call counters a routed layer sows (collection 'counters');
-# the inference server sums them over a call's layers.
-COUNTERS = ('routed_rows_held', 'experts_hit')
-HIGHEST = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass(frozen=True)
-class LatentMoEDims:
-  """What the latent core adds to `SequenceAgent`'s own widths; the
-  defaults are the tiny size the CPU tests run."""
+class LatentMoEDims(RoutingDims):
+  """What the latent core adds to `SequenceAgent`'s own widths (after
+  the routed layer's: models/moe.py :: RoutingDims); the defaults are
+  the tiny size the CPU tests run."""
+  expert_groups: int = 4
+  expert_groups_kept: int = 2
   q_lora_rank: int = 24
   kv_lora_rank: int = 16
   qk_nope_head_dim: int = 8
   qk_rope_head_dim: int = 4
   v_head_dim: int = 8
   first_dense_layers: int = 1
-  moe_size: int = 32               # an expert's width
-  routed_experts: int = 16         # the router's outputs
-  experts_held: int = 4            # of them computed here ...
-  expert_offset: int = 0           # ... from this one on
-  experts_per_token: int = 4
-  expert_groups: int = 4
-  expert_groups_kept: int = 2
-  routed_scale: float = 2.5
-  shared_experts: int = 1
   rope_factor: float = 40.0        # YaRN
   rope_original_max: int = 4096
   rope_beta_fast: float = 32.0
@@ -117,20 +100,12 @@ class LatentMoEDims:
   def cache_width(self):
     return self.kv_lora_rank + self.qk_rope_head_dim
 
+  def stack(self, **fields):
+    """The core these widths name, for `SequenceAgent.core`."""
+    return LatentMoEStack(dims=self, **fields)
+
   def check(self):
-    group = self.routed_experts // max(self.expert_groups, 1)
-    if (self.routed_experts % self.expert_groups or group < 2
-        or self.expert_groups_kept > self.expert_groups
-        or self.expert_groups_kept * group < self.experts_per_token):
-      raise ValueError(
-          'the router chooses experts_per_token among the kept groups '
-          'of at least two experts each: '
-          f'{self.routed_experts} experts, {self.expert_groups} groups, '
-          f'{self.expert_groups_kept} kept, {self.experts_per_token} a '
-          'token')
-    if not (0 <= self.expert_offset and 0 < self.experts_held and
-            self.expert_offset + self.experts_held <= self.routed_experts):
-      raise ValueError('the experts held lie among the routed ones')
+    self.check_routing()
     if self.qk_rope_head_dim % 2:
       raise ValueError('rotary dimensions come in pairs')
     for block in (DECODE_BLOCK, PREFILL_BLOCK):
@@ -185,91 +160,6 @@ def rotate(x, pos, d, theta):
       [-x[..., half:], x[..., :half]], -1) * sin
 
 
-def route(scores, bias, d):
-  """scores f32 [N, routed_experts], the sigmoid outputs -> (chosen i32
-  [N, k], weights f32 [N, k]). The bias moves the choice and not the
-  weight; ties go to the lower index (`lax.top_k`)."""
-  n, e = scores.shape
-  groups = d.expert_groups
-  biased = scores + bias.astype(jnp.float32)
-  by_group = biased.reshape(n, groups, e // groups)
-  group_score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
-  _, kept = jax.lax.top_k(group_score, d.expert_groups_kept)
-  keep = jnp.zeros((n, groups), bool).at[
-      jnp.arange(n)[:, None], kept].set(True)
-  among = jnp.where(jnp.repeat(keep, e // groups, axis=1), biased,
-                    -jnp.inf)
-  _, chosen = jax.lax.top_k(among, d.experts_per_token)
-  picked = jnp.take_along_axis(scores, chosen, axis=1)
-  weights = d.routed_scale * picked / (
-      jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
-  return chosen.astype(jnp.int32), weights
-
-
-def _dot(a, kernel, dtype):
-  return jnp.dot(a.astype(dtype), kernel.astype(dtype),
-                 preferred_element_type=jnp.float32)
-
-
-def ffn(x, weights, dtype):
-  gate, up, down = weights
-  return _dot(jax.nn.silu(_dot(x, gate, dtype)) * _dot(x, up, dtype),
-              down, dtype)
-
-
-class _Kernel(nn.Module):
-  """A matrix the caller multiplies itself (inside a `lax.cond`, or
-  reassociated), initialised as `_Linear`'s."""
-  shape: Any
-  param_dtype: Any = jnp.float32
-
-  @nn.compact
-  def __call__(self):
-    return self.param(
-        'kernel', nn.initializers.variance_scaling(
-            1.0, 'fan_in', 'normal'), tuple(self.shape), self.param_dtype)
-
-
-class _FFNWeights(nn.Module):
-  hidden_size: int
-  width: int
-  param_dtype: Any = jnp.float32
-
-  @nn.compact
-  def __call__(self):
-    h, w = self.hidden_size, self.width
-    return (_Kernel((h, w), self.param_dtype, name='gate_proj')(),
-            _Kernel((h, w), self.param_dtype, name='up_proj')(),
-            _Kernel((w, h), self.param_dtype, name='down_proj')())
-
-
-def _running_softmax(carry, scores, values_fn):
-  """One block of a softmax taken in blocks: `scores [..., S]` (masked
-  columns at -inf), `values_fn(p)` the block's weighted values."""
-  m, l, acc = carry
-  m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
-  p = jnp.exp(scores - m_new[..., None])
-  corr = jnp.exp(m - m_new)
-  return (m_new, l * corr + jnp.sum(p, axis=-1),
-          acc * corr[..., None] + values_fn(p))
-
-
-def write_chunk(cache, entry, slot, pos0, n_valid, live):
-  """`entry [C, W]`: its first `n_valid` tokens written as the columns
-  `pos0..` of row `slot`, where `live [C]` and as far as the capacity;
-  in place, as one window of C columns (shifted back where `pos0 + C`
-  would pass the capacity, the tokens rolled to their columns)."""
-  c, capacity = entry.shape[0], cache.shape[2]
-  start = jnp.clip(pos0, 0, capacity - c)
-  at = (slot, 0, start)
-  window = jax.lax.dynamic_slice(cache, at, (1, cache.shape[1], c))
-  token = jnp.arange(c) - (pos0 - start)   # the token a column takes
-  rolled = jnp.roll(entry.T, pos0 - start, axis=1)
-  write = (token >= 0) & (token < n_valid) & jnp.roll(live, pos0 - start)
-  return jax.lax.dynamic_update_slice(
-      cache, jnp.where(write[None, None, :], rolled[None], window), at)
-
-
 def attend_prefill(q_c, q_r, cache, slot, pos0, n_valid, w_ukv, scale,
                    dtype):
   """The prefill form for C tokens of the session in row `slot`, at
@@ -305,7 +195,7 @@ def attend_prefill(q_c, q_r, cache, slot, pos0, n_valid, w_ukv, scale,
     columns = j * block + jnp.arange(block)
     valid = columns[None, :] <= q_pos[:, None]
     scores = jnp.where(valid[None], scores, -jnp.inf)
-    return _running_softmax(
+    return running_softmax(
         carry, scores, lambda p: jnp.einsum(
             'hts,shv->htv', p.astype(dtype), values,
             preferred_element_type=jnp.float32))
@@ -315,61 +205,6 @@ def attend_prefill(q_c, q_r, cache, slot, pos0, n_valid, w_ukv, scale,
           jnp.zeros((heads, c, v_dim), jnp.float32))
   _, l, acc = jax.lax.fori_loop(0, last // block + 1, body, init)
   return jnp.swapaxes(acc / l[..., None], 0, 1)
-
-
-class RoutedExperts(nn.Module):
-  """The shared expert and this chip's share of the routed ones."""
-  dims: LatentMoEDims
-  hidden_size: int
-  dtype: Any = jnp.float32
-  param_dtype: Any = jnp.float32
-
-  @nn.compact
-  def __call__(self, x, live):
-    """x f32 [N, hidden] (normed); live bool [N]: the rows that are
-    some session's token (a padded row routes nowhere)."""
-    d = self.dims
-    with jax.named_scope('moe'):
-      with jax.named_scope('router'):
-        w_g = _Kernel((self.hidden_size, d.routed_experts),
-                      self.param_dtype, name='router')()
-        # Seeded small and non-zero, so that the choice it moves is
-        # exercised [assumed: a trained model's is learned].
-        bias = self.param('e_score_correction_bias',
-                          nn.initializers.normal(0.02),
-                          (d.routed_experts,), jnp.float32)
-        scores = jax.nn.sigmoid(jnp.dot(
-            x, w_g.astype(jnp.float32), precision=HIGHEST))
-        chosen, weights = route(scores, bias, d)
-        local = chosen - d.expert_offset
-        held = (local >= 0) & (local < d.experts_held) & live[:, None]
-        gates = jnp.sum(
-            jax.nn.one_hot(local, d.experts_held, dtype=jnp.float32) *
-            jnp.where(held, weights, 0.0)[..., None], axis=1)  # [N, held]
-        hit = jnp.any(gates > 0, axis=0)
-        self.sow('counters', 'routed_rows_held',
-                 jnp.sum(held).astype(jnp.int32),
-                 reduce_fn=lambda a, b: a + b,
-                 init_fn=lambda: jnp.zeros((), jnp.int32))
-        self.sow('counters', 'experts_hit',
-                 jnp.sum(hit).astype(jnp.int32),
-                 reduce_fn=lambda a, b: a + b,
-                 init_fn=lambda: jnp.zeros((), jnp.int32))
-      with jax.named_scope('shared'):
-        y = ffn(x, _FFNWeights(self.hidden_size,
-                               d.moe_size * d.shared_experts,
-                               self.param_dtype, name='shared_expert')(),
-                self.dtype)
-      with jax.named_scope('experts'):
-        for e in range(d.experts_held):
-          expert = _FFNWeights(self.hidden_size, d.moe_size,
-                               self.param_dtype, name=f'expert_{e}')()
-          y = y + jax.lax.cond(
-              hit[e],
-              lambda w=expert, g=gates[:, e]: ffn(x, w, self.dtype) *
-              g[:, None],
-              lambda: jnp.zeros_like(x))
-    return y
 
 
 class LatentMoEBlock(nn.Module):
@@ -455,11 +290,11 @@ class LatentMoEBlock(nn.Module):
     return x, cache
 
 
-class LatentMoEStack(core_lib.RecurrentCore):
+class LatentMoEStack(core_lib.PositionedCore):
   """N latent-attention blocks as one recurrent core. Carry: `{'pos':
   i32 [B], 'layers': (cache [B, rank + rope, capacity] param-dtype,
   ...)}`; the arena is the same with a row a slot and one more,
-  advanced in place."""
+  advanced in place (models/core.py :: PositionedCore)."""
   num_layers: int
   hidden_size: int
   num_heads: int
@@ -470,9 +305,15 @@ class LatentMoEStack(core_lib.RecurrentCore):
   dtype: Any = jnp.float32
   param_dtype: Any = jnp.float32
 
+  counters = COUNTERS
+
   @property
   def chunk_size(self):
     return self.dims.prefill_chunk
+
+  @property
+  def cache_capacity(self):
+    return self.dims.cache_capacity
 
   def initial_state(self, batch):
     d = self.dims
@@ -495,47 +336,3 @@ class LatentMoEStack(core_lib.RecurrentCore):
           name=f'block_{i}')(x, cache, slots, pos, live, prefill)
       new.append(cache)
     return x, tuple(new)
-
-  def arena(self, num_slots):
-    """One row more than there are slots: the row that padded rows of
-    a merged call (and a chunk for no session: the warm-up's) are
-    written to (ops/mla_pallas.py)."""
-    return self.initial_state(num_slots + 1)
-
-  @staticmethod
-  def _rows(slots, sessions):
-    """(rows to read and write, which of them are some session's):
-    an id out of range goes to the arena's last row."""
-    live = slots < sessions
-    return jnp.where(live, slots, sessions), live
-
-  def step(self, carry, x, done, slots=None):
-    """One token a row: the carry's own rows, or with `slots` the rows
-    of the arena they name. A position beyond the capacity overwrites
-    the last column (the configuration keeps episodes inside it)."""
-    capacity = self.dims.cache_capacity
-    if slots is None:
-      slots = rows = jnp.arange(x.shape[0])
-      live = jnp.ones(x.shape[:1], bool)
-    else:
-      rows, live = self._rows(slots, carry['pos'].shape[0] - 1)
-    pos = jnp.where(done, 0, carry['pos'][rows])
-    x, layers = self._blocks(x, carry['layers'], rows,
-                             jnp.minimum(pos, capacity - 1), live, None)
-    new_pos = carry['pos'].at[slots].set(pos + 1, mode='drop')
-    return {'pos': new_pos, 'layers': layers}, x
-
-  def chunk(self, carry, xs, n_valid, reset, slot=None):
-    """The prefill form: C tokens of one session at once."""
-    c = xs.shape[0]
-    if slot is None:
-      slot = row = jnp.zeros((), jnp.int32)
-      live = jnp.ones((), bool)
-    else:
-      row, live = self._rows(slot, carry['pos'].shape[0] - 1)
-    pos0 = jnp.where(reset, 0, carry['pos'][row])
-    x, layers = self._blocks(
-        xs, carry['layers'], jnp.full((c,), row), pos0 + jnp.arange(c),
-        (jnp.arange(c) < n_valid) & live, (row, pos0, n_valid))
-    new_pos = carry['pos'].at[slot].set(pos0 + n_valid, mode='drop')
-    return {'pos': new_pos, 'layers': layers}, x

@@ -1,7 +1,11 @@
 """A sequence policy: token embedding -> recurrent core -> norm ->
-policy head over the vocabulary and a value head. The core is the
-stack of power-retention blocks, or with `latent` given (the widths of
-latent attention and routed experts: models/latent_moe.py) that stack.
+policy head over the vocabulary and a value head. One of three cores:
+the stack of power-retention blocks (models/retention.py), or the one
+`core_dims` names by its type: latent attention over a latent cache
+with routed experts (models/latent_moe.py :: LatentMoEDims), or
+grouped-query attention in a pattern of window and full layers, two
+kinds of cache a session, with routed experts (models/
+hybrid_attention.py :: HybridAttentionDims).
 
 It answers the same call as `ImpalaAgent` (`prev_actions, env_outputs,
 core_state, sample_rng`), so the inference server, the actors and
@@ -23,6 +27,10 @@ flag:
   (`prefill_chunk`, from `RecurrentCore.chunk_size`), and the serving
   path then hands a session's prompt over in blocks through
   `prefill`: embedding and core, no head;
+- a core whose state grows with the episode says how far
+  (`cache_capacity`), one that keeps a ring of an episode's last tokens
+  beside it how many (`cache_window`), and the server follows what a
+  call's rows read of each;
 - a core that counts what a call did (`call_counters`) sows the
   counts, and the inference server reads them with the call's outputs.
 
@@ -31,14 +39,13 @@ The model has no value head of its own: `baseline` is this system's
 """
 
 import functools
-from typing import Any, Optional
+from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from scalable_agent_tpu.models import core as core_lib
-from scalable_agent_tpu.models import latent_moe
 from scalable_agent_tpu.models import retention
 from scalable_agent_tpu.parallel.sharding import (
     merge_time_batch, split_time_batch)
@@ -58,9 +65,10 @@ class SequenceAgent(nn.Module):
   scan_unroll: int = 1
   dtype: Any = jnp.float32        # the projections' operands
   param_dtype: Any = jnp.float32  # bfloat16 when served at full width
-  # Given: the core is the latent-attention stack (`num_kv_heads` and
-  # `head_dim` are then the retention stack's and unused).
-  latent: Optional[latent_moe.LatentMoEDims] = None
+  # Given: the widths of another core than the retention stack's, which
+  # builds it (`core_dims.stack`); `num_kv_heads` and `head_dim` are the
+  # retention stack's.
+  core_dims: Any = None
 
   # The leaves of `StepOutput.observation`, in order.
   observation_names = ('token',)
@@ -69,11 +77,12 @@ class SequenceAgent(nn.Module):
     """The core the widths name: detached (for its shapes), or named
     in `__call__`."""
     placement = placement or {'parent': None}
-    if self.latent is not None:
-      return latent_moe.LatentMoEStack(
-          self.num_layers, self.hidden_size, self.num_heads,
-          self.mlp_size, self.rope_theta, self.norm_eps, self.latent,
-          self.dtype, self.param_dtype, **placement)
+    if self.core_dims is not None:
+      return self.core_dims.stack(
+          num_layers=self.num_layers, hidden_size=self.hidden_size,
+          num_heads=self.num_heads, mlp_size=self.mlp_size,
+          rope_theta=self.rope_theta, norm_eps=self.norm_eps,
+          dtype=self.dtype, param_dtype=self.param_dtype, **placement)
     return retention.PowerRetentionStack(
         self.num_layers, self.hidden_size, self.num_heads,
         self.num_kv_heads, self.head_dim, self.mlp_size, self.rope_theta,
@@ -95,11 +104,17 @@ class SequenceAgent(nn.Module):
   def cache_capacity(self):
     """Tokens of an episode a session's state holds; 0: a state of
     fixed size, whatever the episode's length."""
-    return self.latent.cache_capacity if self.latent is not None else 0
+    return self.core().cache_capacity
+
+  @property
+  def cache_window(self):
+    """Tokens a layer that keeps a ring of the episode's last ones
+    holds; 0: the core has no such layer."""
+    return self.core().cache_window
 
   @property
   def call_counters(self):
-    return latent_moe.COUNTERS if self.latent is not None else ()
+    return self.core().counters
 
   def prefill(self, tokens, core_state, slot, n_valid, reset):
     """The chunk call without the head: session `slot` of the arena

@@ -33,18 +33,9 @@ a token, so a block fetched once serves 128 heads: scores `[H, block]`
 and the weighted sum `[H, rank]` are two MXU products a block under a
 running softmax held in fast memory.
 
-`write_rows` puts a call's new tokens where they belong: a token is
-one column, 576 numbers at a stride of the capacity, so a row's step
-takes the 128-lane block that holds its column, sets the column and
-puts the block back, the arena aliased to the output. (XLA's own
-scatter or `dynamic_update_slice` of a column asks for the 576 numbers
-along the lanes and copies the whole leaf there and back to get them.)
-Consecutive steps must not touch one block, or the pipeline fetches it
-for the second before the first has written it: live rows differ in
-their slot, and every padded row of a merged call (slot id out of
-range) goes to the arena's LAST row, which belongs to no session and
-exists to absorb them (`LatentMoEStack.arena`), as in
-ops/retention_pallas.py.
+`write_rows` puts a call's new tokens where they belong, a token one
+column of 576 numbers: ops/cache_columns.py's column write, which knows
+no width, under this cache's name in the trace.
 
 On the CPU the same kernel runs interpreted (the tests' path).
 """
@@ -56,20 +47,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from scalable_agent_tpu.ops import cache_columns
+
 # The kernels' names in the device trace's operation names.
 KERNEL_NAME = 'mla_decode_attend'
 WRITE_KERNEL_NAME = 'mla_cache_write'
-_LANES = 128
-
-
-def _interpret_on(platform):
-  if platform == 'tpu':
-    return False
-  if platform == 'cpu':
-    return True
-  raise NotImplementedError(
-      'the latent-attention kernel runs compiled on tpu or interpreted '
-      f'on cpu; no path for {platform!r}')
+_LANES = cache_columns.LANES
 
 
 def _kernel(slots_ref, pos_ref, q_ref, cache_ref, out_ref, m_ref, l_ref,
@@ -108,43 +91,9 @@ def _kernel(slots_ref, pos_ref, q_ref, cache_ref, out_ref, m_ref, l_ref,
     out_ref[0] = acc_ref[...] / l_ref[...][:, :1]
 
 
-def _write_kernel(slots_ref, pos_ref, entry_ref, cache_ref, out_ref, *,
-                  lanes):
-  del slots_ref  # used by the index maps only
-  lane = pos_ref[pl.program_id(0)] % lanes
-  block = cache_ref[0]                               # [W, lanes]
-  at = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1)
-  out_ref[0] = jnp.where(at == lane, entry_ref[0], block)
-
-
-@jax.jit
-def write_rows(cache, entry, slots, pos):
-  """`entry [N, W]` written as column `pos[n]` of row `slots[n]` of
-  `cache [S, W, capacity]`, in place (donate the cache); every id and
-  position IN RANGE, no two rows on one block (module docstring)."""
-  n, width = entry.shape
-  capacity = cache.shape[2]
-  lanes = min(_LANES, capacity)
-  assert capacity % lanes == 0, capacity
-
-  def cache_block(i, slots_ref, pos_ref):
-    return slots_ref[i], 0, pos_ref[i] // lanes
-
-  return pl.pallas_call(
-      functools.partial(_write_kernel, lanes=lanes),
-      grid_spec=pltpu.PrefetchScalarGridSpec(
-          num_scalar_prefetch=2,
-          grid=(n,),
-          in_specs=[
-              pl.BlockSpec((1, width, 1), lambda i, *_: (i, 0, 0)),
-              pl.BlockSpec((1, width, lanes), cache_block)],
-          out_specs=pl.BlockSpec((1, width, lanes), cache_block)),
-      out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
-      # Operands 0 and 1 are the scalar prefetch; the arena is operand 3.
-      input_output_aliases={3: 0},
-      interpret=_interpret_on(jax.default_backend()),
-      name=WRITE_KERNEL_NAME,
-  )(slots, pos, entry.astype(cache.dtype)[..., None], cache)
+# The column write under the latent cache's name in the trace.
+write_rows = functools.partial(cache_columns.write_rows,
+                               name=WRITE_KERNEL_NAME)
 
 
 @functools.partial(jax.jit, static_argnames=('rank', 'block'))
@@ -177,6 +126,6 @@ def attend_rows(q, cache, slots, pos, *, rank, block):
       out_shape=jax.ShapeDtypeStruct((n, heads, rank), jnp.float32),
       compiler_params=pltpu.CompilerParams(
           dimension_semantics=('parallel', 'arbitrary')),
-      interpret=_interpret_on(jax.default_backend()),
+      interpret=cache_columns.interpret_on(jax.default_backend()),
       name=KERNEL_NAME,
   )(slots, pos, q, cache)

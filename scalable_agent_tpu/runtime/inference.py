@@ -119,7 +119,11 @@ flight; `warmup` compiles it. Such a state is written AT A POSITION:
 `done` resets the position in-graph, not the row, and the server
 follows every slot's position on the host (it sees each call's `done`
 rows and each block's length) to count the cached tokens the calls
-read. Counters an agent's layers sow a call (`agent.call_counters`)
+read: `cache_tokens_read`, each live row's position and one, and beside
+it, where the agent's core keeps a ring of an episode's last
+`cache_window` tokens in some layers (PR 35: models/
+hybrid_attention.py), `window_tokens_read`, the same capped at the
+ring. Counters an agent's layers sow a call (`agent.call_counters`)
 come back with the call's own readback.
 """
 
@@ -173,6 +177,7 @@ _CALL_COUNTERS = {
 _PREFILL_TOKENS = telemetry.counter('serving/prefill_tokens')
 _PREFILL_CHUNKS = telemetry.counter('serving/prefill_chunks')
 _CACHE_TOKENS_READ = telemetry.counter('serving/cache_tokens_read')
+_WINDOW_TOKENS_READ = telemetry.counter('serving/window_tokens_read')
 _CACHE_CAPACITY = telemetry.gauge('serving/cache_capacity')
 
 # Admission priority classes (lower = served first): a released slot
@@ -451,6 +456,7 @@ class InferenceServer:
   _prefill_tokens: guarded_by('_stats_lock')
   _prefill_chunks: guarded_by('_stats_lock')
   _cache_tokens_read: guarded_by('_stats_lock')
+  _window_tokens_read: guarded_by('_stats_lock')
   _call_counts: guarded_by('_stats_lock')
 
   def __init__(self, agent, params, config, seed=0, mesh=None,
@@ -592,6 +598,7 @@ class InferenceServer:
     # A state written at a position (module docstring, PR 32).
     self.prefill_chunk = int(getattr(agent, 'prefill_chunk', 0))
     self._cache_capacity = int(getattr(agent, 'cache_capacity', 0))
+    self._cache_window = int(getattr(agent, 'cache_window', 0))
     self._counter_names = tuple(getattr(agent, 'call_counters', ()))
     self._call_counts = {name: 0 for name in self._counter_names
                          if name in _CALL_COUNTERS}
@@ -601,6 +608,7 @@ class InferenceServer:
     self._prefill_tokens = 0
     self._prefill_chunks = 0
     self._cache_tokens_read = 0
+    self._window_tokens_read = 0
     self._slot_pos = np.zeros((0,), np.int64)
     if (self.prefill_chunk or self._counter_names) and not (
         self._state_cache):
@@ -1173,8 +1181,15 @@ class InferenceServer:
             cache_reads = int(np.sum(np.minimum(
                 reads, self._cache_capacity)))
             self._cache_tokens_read += cache_reads
+            # A layer that keeps a ring of the episode's last tokens
+            # beside the cache reads that many of them at most.
+            window_reads = int(np.sum(np.minimum(
+                reads, self._cache_window)))
+            self._window_tokens_read += window_reads
         if self._cache_capacity:
           _CACHE_TOKENS_READ.inc(cache_reads)
+          if window_reads:
+            _WINDOW_TOKENS_READ.inc(window_reads)
         if resets:
           _STATE_RESETS.inc(resets)
         with self._params_lock:
@@ -1469,6 +1484,7 @@ class InferenceServer:
       prefill_tokens = self._prefill_tokens
       prefill_chunks = self._prefill_chunks
       cache_tokens_read = self._cache_tokens_read
+      window_tokens_read = self._window_tokens_read
       call_counts = dict(self._call_counts)
     with self._params_lock:
       resident = len(self._versions)
@@ -1513,12 +1529,16 @@ class InferenceServer:
         # over in blocks and the chunk programs they took; the cached
         # tokens the merged calls' live rows read (host arithmetic on
         # each call's `done` rows and each block's length); what a
-        # slot's cache holds at most (0: a state of fixed size); and
-        # the per-call counters the agent's layers sow, summed.
+        # slot's cache holds at most (0: a state of fixed size); the
+        # same of a ring of the episode's last `cache_window` tokens
+        # that some layers keep beside it (0: none does); and the
+        # per-call counters the agent's layers sow, summed.
         'prefill_tokens': prefill_tokens,
         'prefill_chunks': prefill_chunks,
         'cache_tokens_read': cache_tokens_read,
         'cache_capacity': self._cache_capacity,
+        'window_tokens_read': window_tokens_read,
+        'cache_window': self._cache_window,
         **call_counts,
         # Admission/overload telemetry (round 9): the shed fraction is
         # sheds / acquires — the serving-plane overload SLO number.

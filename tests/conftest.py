@@ -57,15 +57,24 @@ def pytest_configure(config):
 # section 7), so the test is marked an expected failure here, and
 # tests/benchmark/test_dots_cell.py holds what stays true of that
 # cell's entries (`test_the_cell_before_keeps_its_entries`).
-_OUTDATED = ('test_brumby_cell.py::test_new_entries_keep_to_the_contract',)
+# PR 35 appended the next cell, and test_dots_cell.py's two tests of
+# position read "last" too (`workloads[-1]`, `per_layer[-14:-7]`):
+# both are marked as well, and tests/benchmark/test_exaone_cell.py
+# holds what stays true of all three served cells BY POSITION FROM EACH
+# CELL'S OWN ENTRY (`test_new_entries_keep_to_the_contract`,
+# `test_the_cells_before_keep_their_entries`), which the next cell
+# does not break.
+_OUTDATED = ('test_brumby_cell.py::test_new_entries_keep_to_the_contract',
+             'test_dots_cell.py::test_new_entries_keep_to_the_contract',
+             'test_dots_cell.py::test_the_cell_before_keeps_its_entries')
 
 
 def pytest_collection_modifyitems(items):
   for item in items:
     if item.nodeid.endswith(_OUTDATED):
       item.add_marker(pytest.mark.xfail(
-          reason='asserts that the cell of PR 27 is the last one; see '
-                 'tests/conftest.py', strict=False))
+          reason='asserts that an earlier PR\'s cell is the last one; '
+                 'see tests/conftest.py', strict=False))
 
 
 # --- Tier-1 wall sentinel (round 23): the tier-1 lane runs under a

@@ -20,7 +20,8 @@ import jax.numpy as jnp
 
 from scalable_agent_tpu.models import (LSTMCore, PowerRetentionStack,
                                       SequenceAgent, init_params)
-from scalable_agent_tpu.models import latent_moe
+from scalable_agent_tpu.models import core as core_lib
+from scalable_agent_tpu.models import latent_moe, moe
 from scalable_agent_tpu.models import latent_moe_reference as reference
 from scalable_agent_tpu.models.latent_moe import LatentMoEDims
 from scalable_agent_tpu.ops import mla_pallas
@@ -37,7 +38,7 @@ DIMS = LatentMoEDims(cache_capacity=64, prefill_chunk=8)
 def _agent(dims=DIMS, **kw):
   return SequenceAgent(num_actions=VOCAB, num_layers=3, hidden_size=32,
                        num_heads=HEADS, mlp_size=48, rope_theta=THETA,
-                       latent=dims, **kw)
+                       core_dims=dims, **kw)
 
 
 def _params(agent, seed=0):
@@ -216,7 +217,7 @@ def test_kernels_against_plain_numpy():
   # A chunk's window at the capacity's edge: the tokens land on their
   # own columns, none before them moves.
   chunk = jnp.asarray(rng.randn(8, width), jnp.float32)
-  edge = np.asarray(latent_moe.write_chunk(
+  edge = np.asarray(core_lib.write_chunk(
       jnp.asarray(cache), chunk, jnp.int32(2), jnp.int32(59), jnp.int32(4),
       jnp.arange(8) < 4))
   want = cache.copy()
@@ -229,7 +230,7 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
   routed experts (one expert each), the routed parts summed and the
   shared expert counted once are the uncut reference's whole layer."""
   whole = dataclasses.replace(DIMS, experts_held=16)
-  layer = latent_moe.RoutedExperts(whole, 32)
+  layer = moe.RoutedExperts(whole, 32)
   rng = np.random.RandomState(2)
   x = jnp.asarray(rng.randn(24, 32), jnp.float32)
   live = jnp.ones((24,), bool)
@@ -250,7 +251,7 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
       mine = {k: v for k, v in params.items()
               if not k.startswith('expert_')}
       mine['expert_0'] = params[f'expert_{share}']
-      part = latent_moe.RoutedExperts(dims, 32).apply(
+      part = moe.RoutedExperts(dims, 32).apply(
           {'params': mine}, x, live)
       total = total + (part - shared)  # what every chip computes alike
     # Every token's 4 experts were somebody's: nothing is lost.
@@ -279,7 +280,7 @@ def test_routing_by_hand():
       # All alike: ties go to the lower index, groups and experts.
       [0.4] * 8,
   ], jnp.float32)
-  chosen, weights = latent_moe.route(scores, zero, dims)
+  chosen, weights = moe.route(scores, zero, dims)
   assert chosen.tolist() == [[4, 0], [0, 1]]
   np.testing.assert_allclose(
       weights, [[2.5 * 0.6 / 1.1, 2.5 * 0.5 / 1.1], [1.25, 1.25]],
@@ -288,11 +289,11 @@ def test_routing_by_hand():
   # it the second choice of row 0 (0.3 + 0.2 = 0.5 ties expert 0's and
   # loses to the lower index; +0.21 wins), weighed by its own 0.3.
   bias = zero.at[5].set(0.21)
-  chosen, weights = latent_moe.route(scores[:1], bias, dims)
+  chosen, weights = moe.route(scores[:1], bias, dims)
   assert chosen.tolist() == [[4, 5]]
   np.testing.assert_allclose(weights, [[2.5 * 0.6 / 0.9, 2.5 * 0.3 / 0.9]],
                              rtol=1e-6)
-  chosen, _ = latent_moe.route(scores[:1], zero.at[5].set(0.2), dims)
+  chosen, _ = moe.route(scores[:1], zero.at[5].set(0.2), dims)
   assert chosen.tolist() == [[4, 0]]
   # The reference routes the same, and reports how near a choice was.
   kernel = jnp.eye(8)

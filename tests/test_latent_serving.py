@@ -40,7 +40,7 @@ DIMS = LatentMoEDims(cache_capacity=64, prefill_chunk=8)
 def _agent(dims=DIMS, **kw):
   return SequenceAgent(num_actions=VOCAB, num_layers=3, hidden_size=32,
                        num_heads=HEADS, mlp_size=48, rope_theta=THETA,
-                       latent=dims, **kw)
+                       core_dims=dims, **kw)
 
 
 def _params(agent, seed=0):
@@ -210,10 +210,10 @@ def test_flags_build_the_core_the_widths_name():
   config = _config()
   validate_runtime(config)
   agent = driver.build_agent(config, VOCAB)
-  assert agent.latent == DIMS and agent.prefill_chunk == 8
+  assert agent.core_dims == DIMS and agent.prefill_chunk == 8
   assert isinstance(agent.core(), latent_moe.LatentMoEStack)
   plain = driver.build_agent(_config(seq_kv_lora_rank=0), VOCAB)
-  assert plain.latent is None and isinstance(plain.core(),
+  assert plain.core_dims is None and isinstance(plain.core(),
                                              PowerRetentionStack)
   with pytest.raises(ValueError, match='does not fit a cache'):
     validate_runtime(_config(episode_length=65))
