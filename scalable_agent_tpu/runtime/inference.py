@@ -50,6 +50,12 @@ Round 7 overhaul (docs/INFERENCE.md) — three independent levers:
    (`Batcher.get_batch_into`) — no per-call np.concatenate, no
    per-call allocation — and the PRNG key lives on device, split
    in-graph by the jitted step instead of per-call on the host.
+   Since PR 36 a staging position is ONE flat buffer, the per-input
+   arrays views of it, and a merged call crosses the host boundary as
+   one transfer each way: the step unpacks its batch inputs from that
+   buffer and packs its outputs into one array (runtime/packing.py;
+   docs/INFERENCE.md "One buffer each way"; stats()
+   ['h2d_buffers_per_call'], ['d2h_buffers_per_call']).
 
 Weights: the server holds a params snapshot updated via
 `update_params` (the reference's gRPC weight fetch becomes an on-host
@@ -132,6 +138,7 @@ import logging
 import queue
 import threading
 import time
+import types
 
 import numpy as np
 
@@ -145,6 +152,7 @@ from scalable_agent_tpu.observability import LatencyReservoir
 from scalable_agent_tpu.ops import dynamic_batching
 from scalable_agent_tpu.runtime import codec as codec_lib
 from scalable_agent_tpu.runtime import faults as faults_lib
+from scalable_agent_tpu.runtime import packing
 from scalable_agent_tpu.runtime.remote import Backoff
 from scalable_agent_tpu.structs import (AgentOutput, StepOutput,
                                         observation_leaves)
@@ -179,6 +187,10 @@ _PREFILL_CHUNKS = telemetry.counter('serving/prefill_chunks')
 _CACHE_TOKENS_READ = telemetry.counter('serving/cache_tokens_read')
 _WINDOW_TOKENS_READ = telemetry.counter('serving/window_tokens_read')
 _CACHE_CAPACITY = telemetry.gauge('serving/cache_capacity')
+# Host arrays handed to a merged call's step and arrays fetched from it
+# (PR 36: one each; observed where they cross, never set).
+_H2D_BUFFERS = telemetry.counter('serving/h2d_buffers')
+_D2H_BUFFERS = telemetry.counter('serving/d2h_buffers')
 
 # Admission priority classes (lower = served first): a released slot
 # is handed to the best-priority parked waiter, so background churn
@@ -265,6 +277,13 @@ def _params_fingerprint(params):
                 for l in leaves))
 
 
+def _wire_dtype(dtype):
+  """The dtype an observation leaf crosses in: the one jit would give
+  the array were it an argument of its own (int64 from an env is int32
+  on the device), because its BYTES are what the step reads now."""
+  return np.dtype(jax.dtypes.canonicalize_dtype(dtype))
+
+
 def percentile_ms(sorted_secs_or_ms, q, scale=1.0):
   """q-th percentile of an ascending list (nearest-rank, clamped) ×
   scale — the ONE implementation behind stats() and the bench rows, so
@@ -273,6 +292,161 @@ def percentile_ms(sorted_secs_or_ms, q, scale=1.0):
     return 0.0
   n = len(sorted_secs_or_ms)
   return sorted_secs_or_ms[min(n - 1, int(n * q))] * scale
+
+
+def _planar_bytes():
+  """Whether a step takes an image's interleaved bytes apart on their
+  way in (runtime/packing.py): where its program runs on a TPU."""
+  return jax.default_backend() == 'tpu'
+
+
+def step_functions(agent, state_cache, planar=False, note_outputs=None,
+                   rows_sharding=None):
+  """The programs a server runs for `agent`, as plain functions (the
+  server jits them; tests/test_tpu_compile.py compiles them for a
+  described chip without a server):
+
+  `step`: one merged call. Its batch inputs come as ONE buffer and its
+  outputs leave as ONE (runtime/packing.py, PR 36): `packed` is a
+  staging position's flat buffer as it lies on the host, `layout`
+  (static) says which `[padded, ...]` array lies where.
+  `carry_step(params, key, packed, layout) -> (key, packed outputs)`
+  where the state rides with the call, `cache_step(params, key, arena,
+  packed, layout) -> (key, arena, packed outputs)` where it lives in
+  the (donated) arena; the program is named for which.
+  `note_outputs(layout, output layout)` is told, as a step is traced,
+  which arrays it packs, for the host to read them by.
+  `shadow_step`: the same arguments but the key, the logits alone.
+  `carry_rows`: the carry-passing body, an argument an array.
+  `prefill_chunk`: models with a chunk form (module docstring, PR 32).
+
+  `planar`: `packing.unpack`'s. `rows_sharding`: on a mesh; one buffer
+  cannot be split by rows, so it is placed whole on every device and
+  each array takes the rows' sharding as it is unpacked."""
+  num_obs = len(agent.observation_names)
+  state_treedef = jax.tree_util.tree_structure(
+      jax.eval_shape(lambda: agent.initial_state(1)))
+  counter_names = tuple(getattr(agent, 'call_counters', ()))
+
+  def _apply(params, sub, prev_action, reward, done, obs, core_state,
+             slots=None, counters=False):
+    # Int8-resident versions (publish_codec=int8) dequantize HERE,
+    # in-graph: XLA fuses the per-leaf multiply into the step, so
+    # serving a quantized version costs no host round trip. Identity
+    # for plain trees.
+    params = codec_lib.dequantize_tree(params)
+    env_output = StepOutput(
+        reward=reward[None], info=None, done=done[None],
+        observation=tuple(o[None] for o in obs))
+    # With `slots` the agent's core advances those rows of the arena
+    # (`core_state`) and hands the arena back: models/core.py.
+    kwargs = {} if slots is None else {'state_slots': slots}
+    if counters:
+      # What the agent's layers sowed this call, summed by name over
+      # the layers, goes out with the call's outputs.
+      (out, new_state), sown = agent.apply(
+          params, prev_action[None], env_output, core_state,
+          sample_rng=sub, mutable=['counters'], **kwargs)
+      totals = dict.fromkeys(counter_names, 0)
+      for path, value in flax.traverse_util.flatten_dict(
+          sown.get('counters', {})).items():
+        totals[path[-1]] += value
+      return (out.action[0], out.policy_logits[0], out.baseline[0],
+              new_state, *[jnp.asarray(totals[name], jnp.int32)
+                           for name in counter_names])
+    out, new_state = agent.apply(
+        params, prev_action[None], env_output, core_state,
+        sample_rng=sub, **kwargs)
+    return (out.action[0], out.policy_logits[0], out.baseline[0],
+            new_state)
+
+  def unflatten(leaves):
+    return jax.tree_util.tree_unflatten(state_treedef, leaves)
+
+  def carry_rows(params, key, prev_action, reward, done, *rest):
+    key, sub = jax.random.split(key)
+    action, logits, baseline, new_state = _apply(
+        params, sub, prev_action, reward, done, rest[:num_obs],
+        unflatten(rest[num_obs:]))
+    return (key, action, logits, baseline,
+            *jax.tree_util.tree_leaves(new_state))
+
+  def unpack(packed, layout):
+    inputs = packing.unpack(packed, layout, planar)
+    if rows_sharding is not None:
+      inputs = [jax.lax.with_sharding_constraint(x, rows_sharding)
+                for x in inputs]
+    return inputs
+
+  def pack(layout, outputs):
+    packed, out_layout = packing.pack(outputs)
+    if note_outputs is not None:
+      note_outputs(layout, out_layout)
+    return packed
+
+  def carry_step(params, key, packed, layout):
+    key, *outputs = carry_rows(params, key, *unpack(packed, layout))
+    return key, pack(layout, outputs)
+
+  def cache_step(params, key, arena, packed, layout):
+    slot_ids, prev_action, reward, done, *obs = unpack(packed, layout)
+    key, sub = jax.random.split(key)
+    # Each row's state is the arena's row `slot_ids[row]`. Padded
+    # rows carry _PAD_SLOT_ID (out of range for any arena size,
+    # grown or not) and never touch a live slot: the default core
+    # gathers with a clamp (their compute is sliced away) and
+    # scatters with mode='drop'; a core that updates its arena in
+    # place sends them to a row of their own.
+    action, logits, baseline, arena, *counts = _apply(
+        params, sub, prev_action, reward, done, obs, arena,
+        slots=slot_ids, counters=bool(counter_names))
+    return key, arena, pack(layout, [action, logits, baseline, *counts])
+
+  # Shadow step (round 21): PURE — no key split chained back, no
+  # arena scatter — so replaying a merged call against a shadow
+  # version can never perturb the live fleet's RNG stream or
+  # carries. Scored on GREEDY agreement downstream, so the fixed
+  # sample key is irrelevant to the gauge.
+  def shadow_carry(params, packed, layout):
+    prev_action, reward, done, *rest = unpack(packed, layout)
+    sub = jax.random.PRNGKey(0)
+    _, logits, _, _ = _apply(params, sub, prev_action, reward, done,
+                             rest[:num_obs], unflatten(rest[num_obs:]))
+    return logits
+
+  def shadow_cache(params, arena, packed, layout):
+    slot_ids, prev_action, reward, done, *obs = unpack(packed, layout)
+    sub = jax.random.PRNGKey(0)
+    _, logits, _, _ = _apply(params, sub, prev_action, reward, done,
+                             obs, arena, slots=slot_ids)
+    return logits
+
+  def prefill_chunk(params, arena, slot, tokens, n_valid, reset):
+    # One session's slot advanced by the first `n_valid` of `tokens`
+    # (from an empty state where `reset`): embedding and core, no
+    # head. The arena is donated, as to the step.
+    return agent.apply(
+        codec_lib.dequantize_tree(params), tokens, arena, slot,
+        n_valid, reset, method=agent.prefill)
+
+  return types.SimpleNamespace(
+      step=cache_step if state_cache else carry_step,
+      shadow_step=shadow_cache if state_cache else shadow_carry,
+      carry_rows=carry_rows, prefill_chunk=prefill_chunk)
+
+
+class _Staging(list):
+  """One position of a bucket's staging ring: the per-input arrays the
+  batcher writes through (this list) are VIEWS of ONE flat buffer,
+  `words`, which is what crosses to the device; `layout` says where
+  each lies in it (runtime/packing.py)."""
+
+  __slots__ = ('words', 'layout')
+
+  def __init__(self, layout):
+    self.layout = layout
+    self.words = np.zeros((layout.words,), packing.WORD)
+    super().__init__(packing.host_views(self.words, layout))
 
 
 class _SlotHandle:
@@ -458,6 +632,8 @@ class InferenceServer:
   _cache_tokens_read: guarded_by('_stats_lock')
   _window_tokens_read: guarded_by('_stats_lock')
   _call_counts: guarded_by('_stats_lock')
+  _h2d_buffers: guarded_by('_stats_lock')
+  _d2h_buffers: guarded_by('_stats_lock')
 
   def __init__(self, agent, params, config, seed=0, mesh=None,
                pad_batch_to=None, fleet_size=None):
@@ -544,8 +720,9 @@ class InferenceServer:
         getattr(config, 'serving_shadow_fraction', 0.0))
     self._shadow_key = None  # None = auto: newest non-live resident
     self._shadow_acc = 0.0
-    # Per-bucket AOT serving executables (round 21): (padded bucket,
-    # params-structure fingerprint) -> compiled step. Populated by
+    # Per-bucket AOT serving executables (round 21): (the padded
+    # bucket's layout, params-structure fingerprint) -> compiled step
+    # (a step takes the layout as a static argument). Populated by
     # _precompile_params at publish/warmup time; _dispatch falls back
     # to the jit cache (and counts the miss) when absent.
     self._serving_aot = bool(getattr(config, 'serving_aot', False))
@@ -585,6 +762,9 @@ class InferenceServer:
     # for the stats() p50/p99 — bounded so a week-long run's stats
     # reflect RECENT service time, not the cumulative history.
     self._latencies = collections.deque(maxlen=512)
+    # What crossed the host boundary for each of the same calls.
+    self._h2d_buffers = collections.deque(maxlen=512)
+    self._d2h_buffers = collections.deque(maxlen=512)
     # _key is a DEVICE array chained through the jitted step (split
     # in-graph); the lock orders warmup (caller thread) against the
     # dispatch thread. Same split sequence as the old host-side
@@ -643,78 +823,19 @@ class InferenceServer:
     if mesh is not None:
       self._key = jax.device_put(self._key, self._replicated)
 
-    counted = bool(self._counter_names)
-
-    def _apply(params, sub, prev_action, reward, done, obs, core_state,
-               slots=None, counters=False):
-      # Int8-resident versions (publish_codec=int8) dequantize HERE,
-      # in-graph: XLA fuses the per-leaf multiply into the step, so
-      # serving a quantized version costs no host round trip. Identity
-      # for plain trees.
-      params = codec_lib.dequantize_tree(params)
-      env_output = StepOutput(
-          reward=reward[None], info=None, done=done[None],
-          observation=tuple(o[None] for o in obs))
-      # With `slots` the agent's core advances those rows of the arena
-      # (`core_state`) and hands the arena back: models/core.py.
-      kwargs = {} if slots is None else {'state_slots': slots}
-      if counters:
-        # What the agent's layers sowed this call, summed by name over
-        # the layers, goes out with the call's outputs.
-        (out, new_state), sown = agent.apply(
-            params, prev_action[None], env_output, core_state,
-            sample_rng=sub, mutable=['counters'], **kwargs)
-        totals = dict.fromkeys(self._counter_names, 0)
-        for path, value in flax.traverse_util.flatten_dict(
-            sown.get('counters', {})).items():
-          totals[path[-1]] += value
-        return (out.action[0], out.policy_logits[0], out.baseline[0],
-                new_state, *[jnp.asarray(totals[name], jnp.int32)
-                             for name in self._counter_names])
-      out, new_state = agent.apply(
-          params, prev_action[None], env_output, core_state,
-          sample_rng=sub, **kwargs)
-      return (out.action[0], out.policy_logits[0], out.baseline[0],
-              new_state)
-
-    def unflatten(leaves):
-      return jax.tree_util.tree_unflatten(self._state_treedef, leaves)
-
-    def carry_step(params, key, prev_action, reward, done, *rest):
-      key, sub = jax.random.split(key)
-      action, logits, baseline, new_state = _apply(
-          params, sub, prev_action, reward, done, rest[:num_obs],
-          unflatten(rest[num_obs:]))
-      return (key, action, logits, baseline,
-              *jax.tree_util.tree_leaves(new_state))
-
-    def cache_step(params, key, arena, slot_ids, prev_action, reward,
-                   done, *obs):
-      key, sub = jax.random.split(key)
-      # Each row's state is the arena's row `slot_ids[row]`. Padded
-      # rows carry _PAD_SLOT_ID (out of range for any arena size,
-      # grown or not) and never touch a live slot: the default core
-      # gathers with a clamp (their compute is sliced away) and
-      # scatters with mode='drop'; a core that updates its arena in
-      # place sends them to a row of their own.
-      action, logits, baseline, arena, *counts = _apply(
-          params, sub, prev_action, reward, done, obs, arena,
-          slots=slot_ids, counters=counted)
-      return (key, arena, action, logits, baseline, *counts)
-
-    def prefill_chunk(params, arena, slot, tokens, n_valid, reset):
-      # One session's slot advanced by the first `n_valid` of `tokens`
-      # (from an empty state where `reset`): embedding and core, no
-      # head. The arena is donated, as to the step.
-      return agent.apply(
-          codec_lib.dequantize_tree(params), tokens, arena, slot,
-          n_valid, reset, method=agent.prefill)
+    # The programs (`step_functions`): `self._out_layouts` is where a
+    # step notes, as it is traced, the arrays it packs, for the host
+    # to read them by.
+    self._out_layouts = {}
+    steps = step_functions(
+        agent, self._state_cache, planar=_planar_bytes(),
+        note_outputs=self._out_layouts.__setitem__,
+        rows_sharding=None if mesh is None else self._batch_sharding)
 
     self._prefill_step = (
-        jax.jit(prefill_chunk, donate_argnums=(1,))
+        jax.jit(steps.prefill_chunk, donate_argnums=(1,))
         if self.prefill_chunk else None)
 
-    step = cache_step if self._state_cache else carry_step
     num_batch_args = 3 + num_obs + (
         1 if self._state_cache else len(state_leaves))
     # The arena is DONATED: the step's output takes its buffers, so a
@@ -723,51 +844,26 @@ class InferenceServer:
     # therefore dispatches its read under `_arena_lock`, before the
     # next step can take the buffers (`_read_slot`).
     donate = (2,) if self._state_cache else ()
+    static = (4,) if self._state_cache else (3,)
     if mesh is None:
-      self._step = jax.jit(step, donate_argnums=donate)
+      self._step = jax.jit(steps.step, donate_argnums=donate,
+                           static_argnums=static)
     else:
-      # params keep their (replicated) placement; the key (and the
-      # state arena) are replicated; batch args shard dim 0 over the
-      # data axis.
-      if self._state_cache:
-        in_shardings = (None, self._replicated, self._replicated) + \
-            (self._batch_sharding,) * num_batch_args
-        out_shardings = (self._replicated,) * 2 + \
-            (self._batch_sharding,) * 3 + \
-            (self._replicated,) * len(self._counter_names)
-      else:
-        in_shardings = (None, self._replicated) + \
-            (self._batch_sharding,) * num_batch_args
-        out_shardings = (self._replicated,) + \
-            (self._batch_sharding,) * (3 + len(state_leaves))
-      self._step = jax.jit(step, in_shardings=in_shardings,
-                           out_shardings=out_shardings,
-                           donate_argnums=donate)
-
-    # Shadow step (round 21): PURE — no key split chained back, no
-    # arena scatter — so replaying a merged call against a shadow
-    # version can never perturb the live fleet's RNG stream or
-    # carries. Scored on GREEDY agreement downstream, so the fixed
-    # sample key is irrelevant to the gauge.
-    def shadow_carry(params, prev_action, reward, done, *rest):
-      sub = jax.random.PRNGKey(0)
-      _, logits, _, _ = _apply(params, sub, prev_action, reward, done,
-                               rest[:num_obs], unflatten(rest[num_obs:]))
-      return logits
-
-    def shadow_cache(params, arena, slot_ids, prev_action, reward, done,
-                     *obs):
-      sub = jax.random.PRNGKey(0)
-      _, logits, _, _ = _apply(params, sub, prev_action, reward, done,
-                               obs, arena, slots=slot_ids)
-      return logits
-
-    self._shadow_step = jax.jit(
-        shadow_cache if self._state_cache else shadow_carry)
+      # params keep their (replicated) placement; the key, the state
+      # arena and the one buffer each way are replicated (`unpack`
+      # shards the rows).
+      self._step = jax.jit(
+          steps.step,
+          out_shardings=(self._replicated,) * (2 + len(donate)),
+          donate_argnums=donate, static_argnums=static)
+    # The shadow step takes the live step's arguments but the key.
+    self._shadow_step = jax.jit(steps.shadow_step,
+                                static_argnums=(static[0] - 1,))
     # Routed-serving step (serve_remote): always carry-passing — the
     # remote caller owns its carry; a cross-host request must never
-    # consume a local arena slot.
-    self._remote_step = jax.jit(carry_step)
+    # consume a local arena slot. It comes as a dict of arrays off the
+    # wire, not through the staging ring: the per-array form.
+    self._remote_step = jax.jit(steps.carry_rows)
     # AOT lower/compile inputs (see _precompile_params): the key's
     # spec is fixed at construction; _step is the jit object lowered.
     self._key_spec = jax.ShapeDtypeStruct(
@@ -1070,32 +1166,31 @@ class InferenceServer:
   def _staging_for(self, total_rows):
     """Padded staging buffers for a merged batch of total_rows rows.
 
-    Per padded bucket, a ring of depth+1 preallocated buffer lists:
-    with at most `depth` batches dispatched-but-uncompleted (the
-    semaphore) and completions released in FIFO order, a ring slot is
-    reused only after the batch that last used it has completed — its
-    host buffers are free to overwrite."""
+    Per padded bucket, a ring of depth+1 preallocated positions, each
+    ONE flat buffer and the per-input `[padded, ...]` views of it the
+    batcher's merge-copy lands in (`_Staging`): with at most `depth`
+    batches dispatched-but-uncompleted (the semaphore) and completions
+    released in FIFO order, a ring slot is reused only after the batch
+    that last used it has completed — its host buffer is free to
+    overwrite."""
     padded = self._padded_size(total_rows)
-    meta = self._batcher.input_meta()
     ring = self._staging.get(padded)
     if ring is None:
-      ring = [[np.zeros((padded,) + tuple(trail), dtype)
-               for dtype, trail in meta]
-              for _ in range(self._depth + 1)]
+      layout = packing.Layout.of_rows(self._batcher.input_meta(), padded)
+      ring = [_Staging(layout) for _ in range(self._depth + 1)]
       self._staging[padded] = ring
       self._staging_calls[padded] = 0
     i = self._staging_calls[padded] % len(ring)
     self._staging_calls[padded] += 1
     return ring[i]
 
-  def _aot_lookup(self, params, inputs):
-    """The pre-compiled serving executable for this (padded bucket,
-    params structure), or None — in which case _dispatch falls back to
-    the jit cache and the miss is counted (a miss on the serve path is
-    exactly the first-call compile stall the AOT table exists to
-    remove)."""
-    padded = int(np.shape(inputs[0])[0])
-    k = (padded, _params_fingerprint(params))
+  def _aot_lookup(self, params, layout):
+    """The pre-compiled serving executable for this (padded bucket's
+    layout, params structure), or None — in which case _dispatch falls
+    back to the jit cache and the miss is counted (a miss on the serve
+    path is exactly the first-call compile stall the AOT table exists
+    to remove)."""
+    k = (layout, _params_fingerprint(params))
     with self._aot_lock:
       compiled = self._aot.get(k)
     if compiled is None:
@@ -1104,33 +1199,46 @@ class InferenceServer:
       _AOT_MISSES.inc()
     return compiled
 
-  def _dispatch(self, params, inputs, shadow_params=None):
-    """Dispatch one padded batch through the jitted step, chaining the
-    device-resident key (and arena) — returns the (async) caller-
-    visible output arrays plus the shadow version's logits (or None).
-    The shadow step runs BEFORE the live step so both read the same
-    pre-step arena carries."""
+  def _dispatch(self, params, staging, shadow_params=None):
+    """Dispatch one padded batch (a `_Staging`: its flat buffer is the
+    step's ONE batch argument) through the jitted step, chaining the
+    device-resident key (and arena) — returns the (async) packed
+    outputs plus the shadow version's logits (or None). The shadow
+    step runs BEFORE the live step so both read the same pre-step
+    arena carries."""
     step = self._step  # read per call: tests monkeypatch it
-    compiled = (self._aot_lookup(params, inputs)
+    packed, layout = staging.words, staging.layout
+    crossing = sum(isinstance(leaf, np.ndarray)
+                   for leaf in jax.tree_util.tree_leaves(packed))
+    if self._mesh is not None:
+      # Explicit placement: under multi-process JAX, jit refuses
+      # numpy args with non-trivial shardings — and the local eval
+      # mesh is exactly that. All its devices are process-local,
+      # so the transfer itself is ordinary.
+      packed = jax.device_put(packed, self._replicated)
+    compiled = (self._aot_lookup(params, layout)
                 if self._serving_aot else None)
+    # A compiled executable has its static argument inside it.
+    static = () if compiled is not None else (layout,)
     fn = compiled if compiled is not None else step
+    with self._stats_lock:
+      self._h2d_buffers.append(crossing)
+    _H2D_BUFFERS.inc(crossing)
     with self._key_lock:
       if self._state_cache:
         with self._arena_lock:
           shadow_out = None
           if shadow_params is not None:
             shadow_out = self._shadow_step(
-                shadow_params, self._arena, *inputs)
-          outs = fn(params, self._key, self._arena, *inputs)
-          self._key = outs[0]
-          self._arena = outs[1]
-          return outs[2:], shadow_out
+                shadow_params, self._arena, packed, layout)
+          self._key, self._arena, out = fn(
+              params, self._key, self._arena, packed, *static)
+          return out, shadow_out
       shadow_out = None
       if shadow_params is not None:
-        shadow_out = self._shadow_step(shadow_params, *inputs)
-      outs = fn(params, self._key, *inputs)
-      self._key = outs[0]
-      return outs[1:], shadow_out
+        shadow_out = self._shadow_step(shadow_params, packed, layout)
+      self._key, out = fn(params, self._key, packed, *static)
+      return out, shadow_out
 
   def _dispatch_loop(self):
     while True:
@@ -1195,17 +1303,10 @@ class InferenceServer:
         with self._params_lock:
           params, _ = self._pick_live_locked()
           shadow_params = self._pick_shadow_locked()
-        inputs = tuple(bufs)
-        if self._mesh is not None:
-          # Explicit placement: under multi-process JAX, jit refuses
-          # numpy args with non-trivial shardings — and the local eval
-          # mesh is exactly that. All its devices are process-local,
-          # so the transfer itself is ordinary.
-          inputs = jax.device_put(inputs, self._batch_sharding)
         self._sem.acquire()
         try:
           payload, shadow_out = self._dispatch(
-              params, inputs, shadow_params)
+              params, bufs, shadow_params)
           with self._stats_lock:
             self._inflight += 1
             self._inflight_peak = max(self._inflight_peak,
@@ -1213,7 +1314,8 @@ class InferenceServer:
         except BaseException:
           self._sem.release()
           raise
-        self._completion_q.put((batch_id, n, t0, payload, shadow_out))
+        self._completion_q.put(
+            (batch_id, n, t0, payload, shadow_out, bufs.layout))
       except Exception as e:  # propagate to the parked callers
         self._batcher.set_error(batch_id, f'{type(e).__name__}: {e}')
       finally:
@@ -1224,28 +1326,33 @@ class InferenceServer:
       item = self._completion_q.get()
       if item is None:
         return
-      batch_id, n, t0, payload, shadow_out = item
+      batch_id, n, t0, payload, shadow_out, layout = item
       try:
         # Observability for the sharded-eval contract: how many
         # devices the last merged call actually spanned (read before
         # device_get turns the arrays into host numpy).
         try:
-          devices = len(payload[0].sharding.device_set)
+          devices = len(payload.sharding.device_set)
         except Exception:
           devices = 1
-        # ONE device_get for all outputs: each separate device→host
-        # readback is a full round trip, so batching the transfer is
-        # strictly better.
+        # ONE device_get of ONE array: the step packed its outputs
+        # (each separate device→host readback is a full round trip);
+        # the host reads them as views of it, by the layout the
+        # program noted when it was traced.
+        fetched = len(jax.tree_util.tree_leaves(payload))
         with telemetry.span('inference/readback', id=batch_id):
-          host = jax.device_get(payload)
+          host = packing.host_views(jax.device_get(payload),
+                                    self._out_layouts[layout])
+        with self._stats_lock:
+          self._d2h_buffers.append(fetched)
+        _D2H_BUFFERS.inc(fetched)
         counts = ()
         if self._counter_names:
           # The call's counters rode its readback, behind its outputs.
           split = len(host) - len(self._counter_names)
           host, counts = host[:split], host[split:]
         with telemetry.span('inference/unpark', id=batch_id):
-          self._batcher.set_outputs(
-              batch_id, [np.asarray(o)[:n] for o in host])
+          self._batcher.set_outputs(batch_id, [o[:n] for o in host])
         if counts:
           with self._stats_lock:
             for name, count in zip(self._counter_names, counts):
@@ -1257,7 +1364,7 @@ class InferenceServer:
           # must never add device_get latency to the live path. Logits
           # sit at payload index 1 in both step modes.
           try:
-            live_logits = np.asarray(host[1])[:n]
+            live_logits = host[1][:n]
             shadow_logits = np.asarray(jax.device_get(shadow_out))[:n]
             divergence = 1.0 - codec_lib.greedy_agreement(
                 live_logits, shadow_logits)
@@ -1410,38 +1517,35 @@ class InferenceServer:
         continue
       padded_done.add(padded)
       params = self.live_params()
-      inputs = (
-          np.zeros((padded,), np.int32),
-          np.zeros((padded,), np.float32),
-          np.zeros((padded,), bool)) + tuple(
-              np.zeros((padded,) + shape, dtype)
-              for shape, dtype in obs_leaves)
+      # What a policy call's rows will be (`policy`), zeroed.
+      meta = [(np.dtype(np.int32), ()), (np.dtype(np.float32), ()),
+              (np.dtype(bool), ())] + [
+                  (_wire_dtype(dtype), tuple(shape))
+                  for shape, dtype in obs_leaves]
+      if self._state_cache:
+        meta.insert(0, (np.dtype(np.int32), ()))
+      else:
+        meta += [(np.dtype(l.dtype), tuple(l.shape[1:]))
+                 for l in jax.tree_util.tree_leaves(self._state_spec)]
+      staging = _Staging(packing.Layout.of_rows(meta, padded))
       if self._state_cache:
         # Warmup must not touch live carries: out-of-range slot ids
         # make every scatter a drop (same compiled program — shapes
         # and dtypes are what XLA specializes on, not values).
-        ids = np.full((padded,), _PAD_SLOT_ID, np.int32)
-        inputs = (ids,) + inputs
-      else:
-        inputs = inputs + tuple(
-            np.zeros((padded,) + l.shape[1:], l.dtype)
-            for l in jax.tree_util.tree_leaves(self._state_spec))
+        staging[0][:] = _PAD_SLOT_ID
       # Record the input meta + warmed bucket for the AOT table —
       # _precompile_params re-derives argument specs from these when a
       # NEW params structure publishes later (the version-flip-
       # without-compile guarantee needs exactly this memo).
       with self._aot_lock:
         if self._warm_meta is None:
-          self._warm_meta = tuple(
-              (a.dtype, tuple(a.shape[1:])) for a in inputs)
+          self._warm_meta = tuple(meta)
         self._warm_buckets.add(padded)
       if self._serving_aot:
         # Pre-compile BEFORE dispatching, so warmup itself serves
         # from the AOT table (aot_misses stays 0 end to end).
         self._precompile_params(params)
-      if self._mesh is not None:
-        inputs = jax.device_put(inputs, self._batch_sharding)
-      payload, _ = self._dispatch(params, inputs)
+      payload, _ = self._dispatch(params, staging)
       jax.block_until_ready(payload)
 
   def stats(self):
@@ -1486,6 +1590,7 @@ class InferenceServer:
       cache_tokens_read = self._cache_tokens_read
       window_tokens_read = self._window_tokens_read
       call_counts = dict(self._call_counts)
+      h2d, d2h = list(self._h2d_buffers), list(self._d2h_buffers)
     with self._params_lock:
       resident = len(self._versions)
       live_label = self._versions[self._live_key].label()
@@ -1512,6 +1617,11 @@ class InferenceServer:
         'devices_last_call': devices,
         'latency_p50_ms': round(p50, 3),
         'latency_p99_ms': round(p99, 3),
+        # Over the same last ≤512 calls: host arrays handed to the
+        # step a call, arrays fetched from it a call (one each since
+        # PR 36; counted where they cross).
+        'h2d_buffers_per_call': (sum(h2d) / len(h2d)) if h2d else 0.0,
+        'd2h_buffers_per_call': (sum(d2h) / len(d2h)) if d2h else 0.0,
         'pipeline_depth': self._depth,
         'state_cache': self._state_cache,
         'inflight_peak': peak,
@@ -1690,16 +1800,16 @@ class InferenceServer:
           lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
           self._arena_now()),)
     for padded in buckets:
-      cache_key = (padded, fingerprint)
+      layout = packing.Layout.of_rows(meta, padded)
+      cache_key = (layout, fingerprint)
       with self._aot_lock:
         if cache_key in self._aot:
           continue
-      in_sds = tuple(
-          jax.ShapeDtypeStruct((padded,) + trail, dtype)
-          for dtype, trail in meta)
+      packed_sds = jax.ShapeDtypeStruct((layout.words,), packing.WORD)
       try:
         compiled = self._step.lower(
-            params_sds, self._key_spec, *arena_sds, *in_sds).compile()
+            params_sds, self._key_spec, *arena_sds, packed_sds,
+            layout).compile()
       except Exception:
         log.exception(
             'serving AOT compile failed (bucket %d) — the jit cache '
@@ -1898,6 +2008,8 @@ class InferenceServer:
 
     def rows(x, dtype=None):
       x = np.asarray(x, dtype)
+      if dtype is None:  # an observation leaf, as its env made it
+        x = x.astype(_wire_dtype(x.dtype), copy=False)
       return x if grouped else x[None]
 
     inputs = [

@@ -1,13 +1,9 @@
 """The latent-attention policy behind the inference server (PR 32): the
 prefill entry, positions and counters, the token env's prompt block,
 the actor's hand-over, the flags, and that an agent without a chunk
-form lowers to the parent commit's programs. (The core itself:
+form is served what the parent commit's program computed. (The core itself:
 tests/test_latent_moe.py, whose tiny sizes these share.)
 """
-
-import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -27,8 +23,6 @@ from scalable_agent_tpu.runtime.actor import Actor, ActorGroup
 from scalable_agent_tpu.runtime.inference import InferenceServer
 from scalable_agent_tpu.structs import StepOutput
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-PARENT = '9c515802c84355ad32105429fc563fe2f37aa7a7'
 VOCAB = 97
 HEADS = 4
 THETA = 1e4
@@ -335,53 +329,69 @@ def test_an_actor_hands_the_prompt_over_where_an_episode_begins(
     server.close()
 
 
-_LOWER = '''
-import hashlib
-import jax, numpy as np
-from scalable_agent_tpu import driver
-from scalable_agent_tpu.config import Config
-from scalable_agent_tpu.models import init_params
-from scalable_agent_tpu.runtime.inference import InferenceServer
-config = Config(agent='sequence', env_backend='tokens', num_actions=97,
-                inference_state_cache=True, inference_state_slots=2)
-agent = driver.build_agent(config, 97)
-obs = {'leaves': (((), np.int32),)}
-params = init_params(agent, jax.random.PRNGKey(0), obs)
-server = InferenceServer(agent, params, config, seed=1)
-try:
-  row = lambda dtype: np.zeros((2,), dtype)
-  text = server._step.lower(
-      params, server._key, server._arena, row(np.int32), row(np.int32),
-      row(np.float32), row(bool), row(np.int32)).as_text()
-finally:
-  server.close()
-print('LOWERED', len(text), hashlib.sha256(text.encode()).hexdigest())
-'''
+def test_the_retention_programs_are_the_parents():
+  """(h), rewritten for PR 36 (the cache-mode case of tests/
+  test_serving.py :: test_packed_step_is_the_per_array_body_bit_for_bit
+  for an agent without a chunk form): `jit_cache_step` of the
+  power-retention agent at the rehearsal's widths takes ONE buffer
+  and gives ONE back, and what it computes is what PR 32's parent
+  computed from five arrays, written out below: outputs, key and
+  arena bit for bit, the padded row included. (The lowered text this
+  test compared with that of commit 9c51580 changes with the step's
+  arguments; the values do not.)"""
+  from scalable_agent_tpu.runtime import packing
+  config = Config(agent='sequence', env_backend='tokens', num_actions=VOCAB,
+                  inference_state_cache=True, inference_state_slots=3,
+                  inference_min_batch=1, inference_timeout_ms=20)
+  agent = driver.build_agent(config, VOCAB)
+  params = _params(agent)
+  server = InferenceServer(agent, params, config, seed=1)
+  real, calls = server._step, []
+  host = lambda tree: jax.tree_util.tree_map(np.array, tree)  # noqa: E731
 
+  def recording_step(params_, key, arena, packed, layout):
+    call = (host(key), host(arena), packed.copy(), layout)
+    outs = real(params_, key, arena, packed, layout)
+    calls.append(call + (host(outs),))
+    return outs
 
-def test_the_retention_programs_are_the_parents(tmp_path):
-  """(h): `jit_cache_step` of the power-retention agent at the
-  rehearsal's widths lowers to the parent commit's text, to the byte:
-  what this PR adds is declared by the latent core and asked of no
-  other."""
-  archive = subprocess.run(
-      ['git', 'archive', PARENT, 'scalable_agent_tpu'], cwd=REPO,
-      capture_output=True)
-  if archive.returncode != 0:
-    pytest.skip('the parent commit is not in this checkout')
-  subprocess.run(['tar', '-x', '-C', str(tmp_path)], input=archive.stdout,
-                 check=True)
-  children = [
-      subprocess.Popen(
-          [sys.executable, '-c', _LOWER], cwd=root, text=True,
-          env=dict(os.environ, PYTHONPATH=root, JAX_PLATFORMS='cpu'),
-          stdout=subprocess.PIPE, stderr=subprocess.PIPE)
-      for root in (str(tmp_path), REPO)]
-  lowered = []
-  for child in children:
-    out, err = child.communicate(timeout=300)
-    lines = [l for l in out.splitlines() if l.startswith('LOWERED')]
-    assert lines, err[-2000:]
-    lowered.append(lines[0])
-  assert lowered[0] == lowered[1]
-  assert int(lowered[0].split()[1]) > 10_000
+  @jax.jit
+  def parent_cache_step(params_, key, arena, slot_ids, prev_action, reward,
+                        done, token):
+    key, sub = jax.random.split(key)
+    env_output = StepOutput(reward=reward[None], info=None,
+                            done=done[None], observation=(token[None],))
+    out, arena = agent.apply(params_, prev_action[None], env_output, arena,
+                             sample_rng=sub, state_slots=slot_ids)
+    return key, arena, out.action[0], out.policy_logits[0], out.baseline[0]
+
+  server._step = recording_step
+  try:
+    assert server.prefill_chunk == 0
+    handles = [server.initial_core_state() for _ in range(3)]
+    rng = np.random.RandomState(5)
+    prev = np.zeros(3, np.int32)
+    for t in range(4):
+      out, _ = server.policy(
+          prev, StepOutput(rng.randn(3).astype(np.float32), None,
+                           np.array([False, t == 2, False]),
+                           (rng.randint(VOCAB, size=3).astype(np.int32),)),
+          handles)
+      prev = np.asarray(out.action, np.int32)
+    stats = server.stats()
+  finally:
+    server.close()
+  assert len(calls) == 4
+  assert stats['h2d_buffers_per_call'] == stats['d2h_buffers_per_call'] == 1
+  for key, arena, packed, layout, (new_key, new_arena, packed_out) in calls:
+    assert packed.shape == (layout.words,) and packed.dtype == np.uint32
+    assert [shape for _, shape in layout.specs] == [(4,)] * 5  # 3 + a pad
+    want = parent_cache_step(params, key, arena,
+                             *packing.host_views(packed, layout))
+    got = (new_key, new_arena, *packing.host_views(
+        packed_out, server._out_layouts[layout]))
+    want, got = (jax.tree_util.tree_leaves(x) for x in (want, got))
+    assert len(want) == len(got) > 5
+    for g, w in zip(got, want):
+      assert g.dtype == w.dtype and g.shape == w.shape
+      np.testing.assert_array_equal(g, np.asarray(w))

@@ -267,16 +267,18 @@ class TestInferenceServer:
     from scalable_agent_tpu.models.instruction import MAX_INSTRUCTION_LEN
     server = InferenceServer(agent, params, cfg, seed=3,
                              pad_batch_to=6)
-    # Record FULL input shapes: "one compile" means one shape tuple —
-    # a batch-rows-only probe would miss a second compile from any
-    # other dimension (e.g. an instr-length mismatch between warmup
-    # and live traffic).
+    # Record the FULL layout of the one buffer the step takes (PR 36:
+    # every input's dtype and shape, rows included): "one compile"
+    # means one layout — a batch-rows-only probe would miss a second
+    # compile from any other dimension (e.g. an instr-length mismatch
+    # between warmup and live traffic).
     seen_shapes = set()
     real_step = server._step
 
-    def recording_step(params_, rng, *batch_args):
-      seen_shapes.add(tuple(a.shape for a in batch_args))
-      return real_step(params_, rng, *batch_args)
+    def recording_step(params_, rng, packed, layout):
+      assert packed.shape == (layout.words,)
+      seen_shapes.add(tuple(shape for _, shape in layout.specs))
+      return real_step(params_, rng, packed, layout)
 
     server._step = recording_step
     try:
@@ -780,7 +782,9 @@ class TestInferencePlaneStats:
         env_out = _scripted_inputs(4)
         _drive(server, env_out, 2)  # healthy warm path
         real_step = server._step
-        n_outs = 6  # both modes: key + 5 / key + 2 arenas + 3
+        # key + the packed outputs; the arena between them with the
+        # state cache (PR 36: one array out whatever the outputs).
+        n_outs = 3 if cache else 2
         state = {'poisoned': False}
 
         def failing_step(*args):

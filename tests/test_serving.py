@@ -23,6 +23,7 @@ from scalable_agent_tpu.config import Config
 from scalable_agent_tpu.models import ImpalaAgent, init_params
 from scalable_agent_tpu.models.instruction import MAX_INSTRUCTION_LEN
 from scalable_agent_tpu.runtime import codec
+from scalable_agent_tpu.runtime import packing
 from scalable_agent_tpu.runtime import remote
 from scalable_agent_tpu.runtime import ring_buffer
 from scalable_agent_tpu.runtime import routing
@@ -349,6 +350,236 @@ class TestShadowAndAot:
       assert snap['aot_compiled'] >= 1
     finally:
       server.close()
+
+
+def _record_calls(server):
+  """Wraps the server's step: of every merged call, what it was given
+  (key, arena, the ONE buffer as it lay on the host, its layout) and
+  what it gave back, all as host copies (the arena is donated)."""
+  real, calls = server._step, []
+  host = lambda tree: jax.tree_util.tree_map(np.array, tree)  # noqa: E731
+
+  def recording_step(params, key, *rest):
+    *arena, packed, layout = rest
+    assert isinstance(packed, np.ndarray) and packed.ndim == 1
+    call = {'params': params, 'key': host(key), 'arena': host(arena),
+            'packed': packed.copy(), 'layout': layout}
+    outs = real(params, key, *rest)
+    call['outs'] = host(outs)
+    calls.append(call)
+    return outs
+
+  server._step = recording_step
+  return calls
+
+
+def _rows_step(agent, state_cache):
+  """The per-array body a merged call computed before PR 36 (and
+  `serve_remote` still does), written out: one argument an input, one
+  result an output."""
+
+  def step(params, key, *rest):
+    rest = list(rest)
+    arena = rest.pop(0) if state_cache else None
+    slots = rest.pop(0) if state_cache else None
+    prev_action, reward, done, frame, instr, *carry = rest
+    key, sub = jax.random.split(key)
+    env_output = StepOutput(reward=reward[None], info=None,
+                            done=done[None],
+                            observation=(frame[None], instr[None]))
+    if state_cache:
+      out, arena = agent.apply(params, prev_action[None], env_output,
+                               arena, sample_rng=sub, state_slots=slots)
+      return key, arena, out.action[0], out.policy_logits[0], out.baseline[0]
+    out, carry = agent.apply(params, prev_action[None], env_output,
+                             tuple(carry), sample_rng=sub)
+    return (key, out.action[0], out.policy_logits[0], out.baseline[0],
+            *carry)
+
+  return jax.jit(step)
+
+
+def _group_traffic(server, k, steps=3, seed=0):
+  """One caller, `k` rows a request (scalar form for k = 1)."""
+  rng = np.random.RandomState(seed)
+  grouped = k > 1
+  shape = (k,) if grouped else ()
+  if server.stats()['state_cache']:
+    state = [server.initial_core_state() for _ in range(k)]
+    state = state if grouped else state[0]
+  else:
+    state = jax.tree_util.tree_map(
+        lambda l: np.repeat(l, k, axis=0), server.initial_core_state())
+  prev = np.zeros(shape, np.int32)
+  for t in range(steps):
+    env_output = StepOutput(
+        reward=np.asarray(rng.standard_normal(shape), np.float32),
+        info=StepOutputInfo(np.float32(0), np.int32(0)),
+        done=np.asarray(rng.random_sample(shape) < 0.3),
+        observation=(
+            rng.randint(0, 255, shape + (H, W, 3)).astype(np.uint8),
+            rng.randint(0, 9, shape + (MAX_INSTRUCTION_LEN,)).astype(
+                np.int32)))
+    out, state = server.policy(prev, env_output, state)
+    prev = np.asarray(out.action, np.int32)
+
+
+def _merged_traffic(server, callers=3, steps=2):
+  """`callers` threads of one row each, in step: one merge a step."""
+  barrier = threading.Barrier(callers)
+  errors = []
+
+  def run(i):
+    try:
+      barrier.wait(timeout=60)
+      _group_traffic(server, 1, steps=steps, seed=10 + i)
+    except Exception as e:  # noqa: BLE001 — reported below
+      errors.append(e)
+
+  threads = [threading.Thread(target=run, args=(i,))
+             for i in range(callers)]
+  for t in threads:
+    t.start()
+  for t in threads:
+    t.join(timeout=120)
+  assert not errors, errors
+
+
+class TestPackedCall:
+  """PR 36: a merged call's inputs cross as ONE buffer and its outputs
+  come back as ONE, unpacked and packed inside the jitted step."""
+
+  @pytest.mark.parametrize('traffic,rows,padded', [
+      ('one_row', 1, 1), ('group_of_3', 3, 4), ('merge_of_3', 3, 4)])
+  @pytest.mark.parametrize('state_cache', [False, True],
+                           ids=['carry', 'cache'])
+  def test_packed_step_is_the_per_array_body_bit_for_bit(
+      self, state_cache, traffic, rows, padded):
+    """Every merged call's packed outputs (padded rows included), its
+    new key and, with the state cache, its new arena against the
+    per-array body on the same key and the same staged arrays."""
+    cfg = Config(inference_min_batch=3 if traffic == 'merge_of_3' else 1,
+                 inference_max_batch=8, inference_timeout_ms=2000,
+                 inference_state_cache=state_cache,
+                 inference_state_slots=4)
+    server = InferenceServer(_AGENT, _PARAMS, cfg, seed=7)
+    calls = _record_calls(server)
+    try:
+      if traffic == 'merge_of_3':
+        _merged_traffic(server)
+      else:
+        _group_traffic(server, rows)
+      stats = server.stats()
+    finally:
+      server.close()
+    assert calls and stats['mean_batch'] == rows
+    reference = _rows_step(_AGENT, state_cache)
+    for call in calls:
+      layout = call['layout']
+      assert all(shape[0] == padded for _, shape in layout.specs)
+      staged = packing.host_views(call['packed'], layout)
+      want = reference(call['params'], call['key'], *call['arena'],
+                       *staged)
+      *chained, packed_out = call['outs']
+      got = chained + packing.host_views(
+          packed_out, server._out_layouts[layout])
+      want = jax.tree_util.tree_leaves(want)
+      got = jax.tree_util.tree_leaves(got)
+      assert len(got) == len(want)
+      for g, w in zip(got, want):
+        w = np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(g, w)
+
+  def test_staging_views_are_one_buffer_and_a_ring(self):
+    """The per-input arrays the batcher writes through are views of
+    ONE flat buffer a ring position, at offsets aligned to 128 B; with
+    completions held, at most depth + 1 positions are handed out, all
+    distinct: none is reused before its call completes."""
+    cfg = Config(inference_min_batch=1, inference_max_batch=8,
+                 inference_timeout_ms=5, inference_pipeline_depth=2)
+    # One bucket whatever merges: one ring.
+    server = InferenceServer(_AGENT, _PARAMS, cfg, seed=7, pad_batch_to=4)
+    handed, release = [], threading.Event()
+    real_staging, real_outputs = (server._staging_for,
+                                  server._batcher.set_outputs)
+
+    def staging_for(total_rows):
+      staging = real_staging(total_rows)
+      handed.append(staging)
+      return staging
+
+    def held_outputs(batch_id, arrays):
+      release.wait(timeout=60)
+      return real_outputs(batch_id, arrays)
+
+    server._staging_for = staging_for
+    try:
+      _group_traffic(server, 1, steps=1)
+      staging = handed[0]
+      assert staging.words.dtype == np.uint32 and staging.words.ndim == 1
+      raw = staging.words.view(np.uint8)
+      for view, (offset, nbytes) in zip(staging, staging.layout.regions[0]):
+        assert np.shares_memory(view, staging.words)
+        assert offset % packing.ALIGN == 0 and view.nbytes == nbytes
+        assert (view.ctypes.data - raw.ctypes.data) == offset
+      staging[3][...] = 7  # the frame: written through to the buffer
+      offset, nbytes = staging.layout.regions[0][3]
+      assert (raw[offset:offset + nbytes] == 7).all()
+      # Hold every completion: the semaphore stops the third dispatch,
+      # whose batch is already staged in the ring's last position.
+      del handed[:]
+      server._batcher.set_outputs = held_outputs
+      callers = [threading.Thread(target=_group_traffic,
+                                  args=(server, 1), kwargs={'steps': 1})
+                 for _ in range(5)]
+      for t in callers:
+        t.start()
+        time.sleep(0.2)  # one merge a caller
+      time.sleep(0.5)
+      in_flight = list(handed)
+      assert len(in_flight) == cfg.inference_pipeline_depth + 1
+      assert len({id(s.words) for s in in_flight}) == len(in_flight)
+      release.set()
+      for t in callers:
+        t.join(timeout=60)
+      assert not any(t.is_alive() for t in callers)
+      # The callers still queued were served from the same positions.
+      assert len(handed) > len(in_flight)
+      assert len({id(s.words) for s in handed}) == len(in_flight)
+    finally:
+      release.set()
+      server.close()
+
+  @pytest.mark.parametrize('state_cache', [False, True],
+                           ids=['carry', 'cache'])
+  def test_one_buffer_crosses_each_way(self, state_cache):
+    """The counters that say the mechanism engages are observed where
+    the arrays cross: numpy leaves handed to the step, arrays fetched
+    from it."""
+    from scalable_agent_tpu import telemetry
+    cfg = Config(inference_min_batch=1, inference_max_batch=8,
+                 inference_timeout_ms=5,
+                 inference_state_cache=state_cache)
+    before = {name: telemetry.registry().get(name).value
+              for name in ('serving/h2d_buffers', 'serving/d2h_buffers')}
+    server = InferenceServer(_AGENT, _PARAMS, cfg, seed=7)
+    try:
+      assert server.stats()['h2d_buffers_per_call'] == 0.0
+      server.warmup(OBS, sizes=[1])
+      _group_traffic(server, 1, steps=4)
+      _group_traffic(server, 3, steps=4)
+      stats = server.stats()
+    finally:
+      server.close()
+    assert stats['calls'] == 8
+    assert stats['h2d_buffers_per_call'] == 1.0
+    assert stats['d2h_buffers_per_call'] == 1.0
+    # The warm-up call hands its buffer over too and fetches nothing.
+    assert (telemetry.registry().get('serving/h2d_buffers').value
+            - before['serving/h2d_buffers']) == 9
+    assert (telemetry.registry().get('serving/d2h_buffers').value
+            - before['serving/d2h_buffers']) == 8
 
 
 class _FakeChannel:

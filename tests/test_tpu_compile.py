@@ -4,7 +4,9 @@ cannot show. The retention kernel at the published widths passes
 Mosaic (tiling, fast memory); the server's whole `cache_step` at the
 benchmark cell's sizes keeps ONE copy of the 4.5 GB state arena (the
 kernel's in-place update holds through the donated step) and fits the
-chip. Nothing runs: no result, no time.
+chip. The steps are the server's own (`inference.step_functions`, PR
+36): each takes its batch as ONE flat parameter. Nothing runs: no
+result, no time.
 
 All compile-only tests live in this one file, the topology is described
 inside a fixture (never at import), and every compile happens in the
@@ -18,11 +20,15 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from scalable_agent_tpu.models import (HybridAttentionDims, LatentMoEDims,
-                                      SequenceAgent, init_params)
+import numpy as np
+
+from scalable_agent_tpu.models import (HybridAttentionDims, ImpalaAgent,
+                                      LatentMoEDims, SequenceAgent,
+                                      init_params)
+from scalable_agent_tpu.models.instruction import MAX_INSTRUCTION_LEN
 from scalable_agent_tpu.ops import (cache_columns, gqa_pallas, mla_pallas,
                                     retention_pallas)
-from scalable_agent_tpu.structs import StepOutput
+from scalable_agent_tpu.runtime import inference, packing
 
 WIDTHS = dict(num_actions=151936, num_layers=4, hidden_size=5120,
               num_heads=40, num_kv_heads=8, head_dim=128, mlp_size=17408,
@@ -62,6 +68,77 @@ def _on(sharding, tree):
       tree)
 
 
+TOKEN_ROWS = [(np.int32, ())] * 2 + [(np.float32, ()), (np.bool_, ()),
+                                     (np.int32, ())]  # + slot ids
+
+
+def _lower_step(agent, one_chip, state_cache, rows_meta, params,
+                arena=()):
+  """(lowered step, its batch layout): the server's merged-call
+  program for `agent` (the interleaved bytes taken apart, as on a
+  TPU), SESSIONS rows, every argument on the described chip."""
+  steps = inference.step_functions(agent, state_cache, planar=True)
+  layout = packing.Layout.of_rows(rows_meta, SESSIONS)
+  key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+  packed = jax.ShapeDtypeStruct((layout.words,), jnp.uint32,
+                                sharding=one_chip)
+  step = jax.jit(steps.step, donate_argnums=(2,) if state_cache else (),
+                 static_argnums=(3 + len(arena),))
+  return step.lower(_on(one_chip, params), _on(one_chip, key),
+                    *_on(one_chip, arena), packed, layout), layout
+
+
+def _one_flat_batch_parameter(compiled, layout, others):
+  """The entry computation takes `others` parameters (weights, key,
+  arena leaves) and ONE more: the batch, `u32[words]`, flat (no
+  dimension to pad), its bytes on the device within 1% (or one tile:
+  `decode32`'s five arrays are 544 B) of what the arrays in it hold."""
+  entry = compiled.as_text().split('ENTRY ')[1]
+  parameters = [line for line in entry.splitlines()
+                if ' parameter(' in line]
+  flat = [line for line in parameters
+          if f' = u32[{layout.words}]{{0' in line]
+  assert len(flat) == 1 and len(parameters) == others + 1, (
+      len(flat), len(parameters), others)
+  tile = 1024 * 4  # a flat vector is tiled by 1,024 words
+  on_device = -(-layout.words * 4 // tile) * tile
+  assert on_device - layout.logical_bytes <= max(
+      0.01 * layout.logical_bytes, tile)
+  return flat[0]
+
+
+def test_carry_step_takes_one_flat_buffer_at_the_fleets_sizes(
+    one_chip, compiled_kernel):
+  """`jit_carry_step` of the paper's deep agent for 32 rows of 72 x 96
+  frames (`deep_dmlab.fleet32`): seven arrays in one parameter of
+  731,520 B; as a parameter of its own the frame alone is laid out
+  channels apart with W padded to 128, 884,736 B for 663,552."""
+  agent = ImpalaAgent(num_actions=9, torso='deep', use_instruction=True,
+                      dtype=jnp.bfloat16)
+  params = jax.eval_shape(lambda: init_params(
+      agent, jax.random.PRNGKey(0),
+      {'frame': (72, 96, 3), 'instr_len': MAX_INSTRUCTION_LEN}))
+  meta = [(np.int32, ()), (np.float32, ()), (np.bool_, ()),
+          (np.uint8, (72, 96, 3)), (np.int32, (MAX_INSTRUCTION_LEN,)),
+          (np.float32, (256,)), (np.float32, (256,))]
+  lowered, layout = _lower_step(agent, one_chip, False, meta, params)
+  assert layout.logical_bytes == 731424 and layout.words * 4 == 731520
+  compiled = lowered.compile()
+  _one_flat_batch_parameter(
+      compiled, layout, len(jax.tree_util.tree_leaves(params)) + 1)
+  text = compiled.as_text()
+  assert 'u8[32,72,96,3]' not in text.split('ENTRY ')[1].split('\n')[0]
+  # The frame reaches the first convolution channels apart, as XLA
+  # lays out a parameter of its own, by way of the matrix unit: no
+  # relayout of the C-minor bytes (a `reshape` to bf16[32,72,96,3] and
+  # a convolution twelve times slower: PERF.md section 6, PR 36).
+  assert 'bf16[32,72,96,3]' not in text
+  memory = compiled.memory_analysis()
+  assert memory.temp_size_in_bytes < 0.15e9
+  # One array out too: key and packed outputs.
+  assert memory.output_size_in_bytes < 72_000  # 66,944 B of outputs
+
+
 def test_retention_kernel_compiles_at_the_published_widths(
     one_chip, compiled_kernel):
   rows, kv, dv, dk, groups, b = SESSIONS + 1, 8, 128, 128, 5, SESSIONS
@@ -87,23 +164,12 @@ def test_cache_step_holds_one_arena_and_fits_the_chip(
   params = jax.eval_shape(lambda: init_params(
       agent, jax.random.PRNGKey(0), {'leaves': (((), 'int32'),)}))
   arena = jax.eval_shape(lambda: agent.state_arena(SESSIONS))
-  key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
-
-  def cache_step(params, key, arena, slot_ids, prev_action, reward, done,
-                 token):  # runtime/inference.py's, for this agent
-    key, sub = jax.random.split(key)
-    env_output = StepOutput(reward=reward[None], info=None,
-                            done=done[None], observation=(token[None],))
-    out, arena = agent.apply(params, prev_action[None], env_output, arena,
-                             sample_rng=sub, state_slots=slot_ids)
-    return key, arena, out.action[0], out.policy_logits[0], out.baseline[0]
-
-  row = lambda dtype: jax.ShapeDtypeStruct(  # noqa: E731
-      (SESSIONS,), dtype, sharding=one_chip)
-  compiled = jax.jit(cache_step, donate_argnums=(2,)).lower(
-      _on(one_chip, params), _on(one_chip, key), _on(one_chip, arena),
-      row(jnp.int32), row(jnp.int32), row(jnp.float32), row(jnp.bool_),
-      row(jnp.int32)).compile()
+  lowered, layout = _lower_step(agent, one_chip, True, TOKEN_ROWS, params,
+                                arena=(arena,))
+  compiled = lowered.compile()
+  # `decode32`'s 32 slot ids and tokens: 544 B in one parameter.
+  _one_flat_batch_parameter(
+      compiled, layout, len(jax.tree_util.tree_leaves((params, arena))) + 1)
   memory = compiled.memory_analysis()
   arena_bytes = sum(l.size * l.dtype.itemsize
                     for l in jax.tree_util.tree_leaves(arena))
@@ -135,42 +201,26 @@ LATENT = dict(
 
 def _serving_programs(agent, one_chip, chunk):
   """(params bytes, arena bytes, {name: compiled}) of the two programs
-  the inference server runs for `agent`: `cache_step` for a merged call
-  of 32 rows and the prefill chunk, the arena donated to both."""
+  the inference server runs for `agent` (its own:
+  `inference.step_functions`): `cache_step` for a merged call of 32
+  rows and the prefill chunk, the arena donated to both."""
   params = jax.eval_shape(lambda: init_params(
       agent, jax.random.PRNGKey(0), {'leaves': (((), 'int32'),)}))
   arena = jax.eval_shape(lambda: agent.state_arena(SESSIONS))
-  key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
   nbytes = lambda tree: sum(  # noqa: E731
       l.size * l.dtype.itemsize for l in jax.tree_util.tree_leaves(tree))
 
-  def cache_step(params, key, arena, slot_ids, prev_action, reward, done,
-                 token):  # runtime/inference.py's, for this agent
-    key, sub = jax.random.split(key)
-    env_output = StepOutput(reward=reward[None], info=None,
-                            done=done[None], observation=(token[None],))
-    (out, arena), counters = agent.apply(
-        params, prev_action[None], env_output, arena, sample_rng=sub,
-        state_slots=slot_ids, mutable=['counters'])
-    return (key, arena, out.action[0], out.policy_logits[0],
-            out.baseline[0], counters)
-
-  def prefill_chunk(params, arena, slot, tokens, n_valid, reset):
-    return agent.apply(params, tokens, arena, slot, n_valid, reset,
-                       method=agent.prefill)
-
+  steps = inference.step_functions(agent, True, planar=True)
   spec = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
       shape, dtype, sharding=one_chip)
-  row = lambda dtype: spec((SESSIONS,), dtype)  # noqa: E731
   lowered = {
-      'cache_step': jax.jit(cache_step, donate_argnums=(2,)).lower(
-          _on(one_chip, params), _on(one_chip, key), _on(one_chip, arena),
-          row(jnp.int32), row(jnp.int32), row(jnp.float32),
-          row(jnp.bool_), row(jnp.int32)),
-      'prefill_chunk': jax.jit(prefill_chunk, donate_argnums=(1,)).lower(
-          _on(one_chip, params), _on(one_chip, arena), spec((), jnp.int32),
-          spec((chunk,), jnp.int32), spec((), jnp.int32),
-          spec((), jnp.bool_))}
+      'cache_step': _lower_step(agent, one_chip, True, TOKEN_ROWS, params,
+                                arena=(arena,))[0],
+      'prefill_chunk': jax.jit(
+          steps.prefill_chunk, donate_argnums=(1,)).lower(
+              _on(one_chip, params), _on(one_chip, arena),
+              spec((), jnp.int32), spec((chunk,), jnp.int32),
+              spec((), jnp.int32), spec((), jnp.bool_))}
   return nbytes(params), nbytes(arena), {
       name: program.compile() for name, program in lowered.items()}
 
