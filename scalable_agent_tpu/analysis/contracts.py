@@ -103,8 +103,9 @@ def registered_metric_names(ctx: CheckContext,
   `counter`/`gauge`/`histogram` either bare (telemetry.py itself) or
   as an attribute of `telemetry`/`_telemetry` — `writer.histogram`
   (the summary stream API) is a different surface and excluded, same
-  as the ci.sh heredoc this replaces. With `kinds=('span', 'park')`:
-  the span recorder's sites, whose names share the docs' spelling."""
+  as the ci.sh heredoc this replaces. With `kinds=('span', 'park',
+  'activity')`: the span recorder's sites, whose names share the docs'
+  spelling."""
   out: Dict[str, Tuple[str, int]] = {}
   for rel in ctx.package_sources():
     for node in ast.walk(ctx.tree(rel)):
@@ -143,7 +144,8 @@ def _documented_metric_names(ctx: CheckContext) -> Set[str]:
          'no documented name is orphaned')
 def check_metric_names(ctx: CheckContext) -> List[Finding]:
   registered = registered_metric_names(ctx)
-  spans = registered_metric_names(ctx, kinds=('span', 'park'))
+  spans = registered_metric_names(
+      ctx, kinds=('span', 'park', 'activity'))
   documented = _documented_metric_names(ctx)
   findings = []
   for names, what, where in ((registered, 'registered metric',

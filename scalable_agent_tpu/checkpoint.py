@@ -49,7 +49,7 @@ def _one_scan_of_installed_files():
 with _one_scan_of_installed_files():
   import orbax.checkpoint as ocp
 
-from scalable_agent_tpu import integrity
+from scalable_agent_tpu import integrity, telemetry
 from scalable_agent_tpu.learner import TrainState
 from scalable_agent_tpu.runtime import faults as faults_lib
 
@@ -190,7 +190,6 @@ class Checkpointer:
     # the ladder counters — same numbers as the driver summaries, read
     # by the drain manifest / flight recorder / remote 'stats' from
     # one source of truth.
-    from scalable_agent_tpu import telemetry
     self._gauges = [
         telemetry.gauge('checkpoint/save_errors',
                         fn=lambda: self.save_errors),
@@ -222,6 +221,14 @@ class Checkpointer:
       step = int(jax.device_get(state.update_steps))
     if step in self._manager.all_steps():
       return False  # force=True raises StepAlreadyExistsError otherwise
+    # Seconds of the calling thread (the learner's): an activity, so
+    # that an actor step held up under it can be laid at its door
+    # (telemetry.excess).
+    with telemetry.activity('learner/checkpoint'):
+      return self._write(state, step, force)
+
+  def _write(self, state: TrainState, step: int, force: bool) -> bool:
+    """`save`, past the check that the step is new."""
     saved = bool(self._manager.save(
         step, args=ocp.args.StandardSave(state), force=force))
     if not saved:
@@ -748,7 +755,6 @@ class Checkpointer:
     self._manager.close()
     # Drop the registry's fn-gauge hold on this instance (identity-
     # checked — a newer checkpointer's registration survives).
-    from scalable_agent_tpu import telemetry
     for gauge in self._gauges:
       telemetry.registry().unregister(gauge.name, gauge)
 

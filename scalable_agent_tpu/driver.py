@@ -1223,6 +1223,10 @@ def train(config: Config, max_steps: Optional[int] = None,
   # lie between. A `park`, as the wait it holds: seconds long, so kept
   # where it straddles an end of a capture.
   iteration = None
+  # The learner thread's rare, heavy work, as activities (remembered
+  # with the recorder off: telemetry.excess asks them when a step of
+  # an actor thread ran long). Ended in the `finally` too.
+  publish = summaries = telemetry.NO_SPAN
   try:
     while True:
       if iteration is None:
@@ -1412,7 +1416,7 @@ def train(config: Config, max_steps: Optional[int] = None,
       run.state = state
 
       if steps_done % config.publish_params_every == 0:
-        publish = telemetry.span('learner/publish')
+        publish = telemetry.activity('learner/publish')
         # actor_params is a cross-host collective in multi-host-TP
         # mode: it must run UNCONDITIONALLY here (lockstep branch),
         # never inside the per-host time-gated ingest publish below.
@@ -1453,7 +1457,7 @@ def train(config: Config, max_steps: Optional[int] = None,
       now = time.monotonic()
       if now - last_summary >= config.summary_secs:
         last_summary = now
-        summaries = telemetry.span('learner/summaries')
+        summaries = telemetry.activity('learner/summaries')
         # One-step-delayed stacked read (round 8): the previous step's
         # metrics land in a single transfer of already-computed values.
         # Written at step_now — one step stale, immaterial at summary
@@ -1980,6 +1984,8 @@ def train(config: Config, max_steps: Optional[int] = None,
   finally:
     if iteration is not None:
       iteration.end()  # the loop was left mid-pass
+    publish.end()
+    summaries.end()
     # One robustness roll-up while the fleet still runs (stats after
     # stop() would read an all-dead fleet): what the run's failure
     # domain absorbed, in the same counters the summaries carry.

@@ -184,6 +184,8 @@ class _Rollout:
     envs = [a._env for a in actors]
     self.sends = [getattr(env, 'step_send', None) for env in envs]
     self.receives = [getattr(env, 'step_receive', None) for env in envs]
+    # A hosted env's own time in the step last received, off a block.
+    self.busy = [getattr(env, 'step_busy_ns', None) for env in envs]
     self.agent = None  # AgentOutput of [T+1, k, ...]: see `begin`
 
   def begin(self):
@@ -384,6 +386,13 @@ class ActorGroup:
     self.waiting_on: Optional[Actor] = None
     self._joining = collections.deque()  # (actor, name), any thread
     self._rollout: Optional[_Rollout] = None  # of the members as they are
+    # The group's steps, always on (telemetry.CycleRecord; docs/
+    # OBSERVABILITY.md "Cycle records"): three stamps a step (begin,
+    # policy returned, envs stepped and accounted: one step's last is
+    # the next one's first within an unroll) and the slowest member's
+    # own time in its env's `step` (`StepBlock.busy_ns`).
+    self.steps = telemetry.CycleRecord(('policy_wait', 'env'),
+                                       extras=('env_child_ns',))
 
   def join(self, actor, name):
     """Hand the group (one that has `names`) one more member, from
@@ -428,13 +437,19 @@ class ActorGroup:
       if rollout is None or rollout.actors != actors:
         rollout = self._rollout = _Rollout(self)
       rollout.begin()
+      busy_ns, write_step = rollout.block.busy_ns, self.steps.write
+      t_begin = time.perf_counter_ns()
       for t in range(rollout.rows - 1):
         with telemetry.span('actor/step'):
           with telemetry.span('actor/policy_call'):
             actions = rollout.act(t)
+          t_policy = time.perf_counter_ns()
           with telemetry.span('actor/env_step'):
             self._env_step(rollout, t + 1, actions)
           rollout.record(t + 1)
+        t_end = time.perf_counter_ns()
+        write_step(t_begin, t_policy, t_end, int(busy_ns.max()))
+        t_begin = t_end
       return rollout.finish(span_ids)
 
   @staticmethod
@@ -492,7 +507,10 @@ class ActorGroup:
       self.waiting_on = actor
       try:
         if send is None:
-          rollout._write(t, j, *actor._env.step(actions[j]))
+          t0 = time.perf_counter_ns()
+          step = actor._env.step(actions[j])
+          rollout.block.busy_ns[j] = time.perf_counter_ns() - t0
+          rollout._write(t, j, *step)
         else:
           send(actions[j])
           sent.append(j)
@@ -505,14 +523,14 @@ class ActorGroup:
         step = rollout.receives[j]()
         if not rollout.shared:
           rollout._write(t, j, *step)
+          if rollout.busy[j] is not None:
+            rollout.block.busy_ns[j] = rollout.busy[j]()
       except BaseException as e:
         failure = failure or (actors[j], e)
     self.waiting_on = None
     if failure is not None:
       self.failed, exc = failure
       raise exc
-    if rollout.shared:
-      py_process.BLOCK_STEPS.inc(len(sent))
 
   def close(self):
     """Close every member, those `join` brought and no unroll took in

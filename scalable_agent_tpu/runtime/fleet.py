@@ -42,6 +42,9 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional
 
+import numpy as np
+
+from scalable_agent_tpu import telemetry
 from scalable_agent_tpu.analysis.runtime import guarded_by, make_lock
 from scalable_agent_tpu.runtime import py_process, ring_buffer
 from scalable_agent_tpu.runtime.actor import Actor, ActorGroup
@@ -78,6 +81,24 @@ _MAX_ENVS_PER_THREAD = 32
 # its Actor and its PyProcess (None: hosted in this process).
 _Member = collections.namedtuple('_Member',
                                  'slot generation actor process')
+
+# `stats()`'s step percentiles: over this many newest steps a thread.
+_RECENT_STEPS = 4096
+
+
+def _step_counts(records):
+  """What the groups' step records (`ActorGroup.steps`) sum to, as the
+  flat cumulative keys of `ActorFleet.stats`."""
+  totals = [r.totals() for r in records]
+  ms = lambda key: sum(t[key] for t in totals) / 1e6  # noqa: E731
+  return {
+      'group_steps': sum(t['cycles'] for t in totals),
+      'step_ms': ms('policy_wait_ns') + ms('env_ns'),
+      'step_policy_wait_ms': ms('policy_wait_ns'),
+      'step_env_ms': ms('env_ns'),
+      'step_env_child_ms': ms('env_child_ns'),
+      **telemetry.excess_ms(
+          'step_', *[telemetry.excess(r) for r in records])}
 
 
 class _Group:
@@ -164,6 +185,8 @@ class ActorFleet:
   _slots_rehabilitated: guarded_by('_lock')
   _rehabilitations: guarded_by('_lock')
   _groups: guarded_by('_lock')
+  _step_records: guarded_by('_lock')
+  _steps_retired: guarded_by('_lock')
 
   def __init__(self, make_actor: Callable, buffer, num_actors: int,
                quarantine_after: int = 5,
@@ -182,6 +205,10 @@ class ActorFleet:
     self._envs_per_thread = max(1, min(
         _MAX_ENVS_PER_THREAD, max_policy_rows or _MAX_ENVS_PER_THREAD))
     self._groups: List[_Group] = []  # running, with a key
+    # The step records of the threads that run (`ActorGroup.steps`),
+    # and what those of the threads that ended had counted.
+    self._step_records: List[telemetry.CycleRecord] = []
+    self._steps_retired = collections.Counter()
 
   @property
   def stop_event(self):
@@ -281,6 +308,7 @@ class ActorFleet:
         self._carry(member.slot, group)
       if key is not None:
         self._groups.append(group)
+      self._step_records.append(group.actors.steps)
     group.thread.start()
 
   @staticmethod
@@ -354,7 +382,10 @@ class ActorFleet:
       run_actor_loop(group.actors, self._buffer, self._stop,
                      on_unroll=on_unroll, on_failure=on_failure)
     finally:
+      counted = _step_counts([group.actors.steps])
       with self._lock:
+        self._step_records.remove(group.actors.steps)
+        self._steps_retired.update(counted)
         group.closed = True
         members = list(group.members.values())
         if not self._stop.is_set():
@@ -643,7 +674,9 @@ class ActorFleet:
       # like a dying plane to the fleet_healthy_fraction objective.
       active = sum(1 for s in self._slots if not s.parked)
       threads = len({id(s.thread) for s in alive})
-      return {
+      records = list(self._step_records)
+      steps = collections.Counter(self._steps_retired)
+      counts = {
           # Slots alive per running thread (PR 26): 1.0 while every
           # env has a thread of its own, k where groups of k share one.
           'actor_threads': threads,
@@ -677,6 +710,20 @@ class ActorFleet:
           'rehabilitations': self._rehabilitations,
           'slots_rehabilitated': self._slots_rehabilitated,
       }
+    # The group steps (PR 37; docs/OBSERVABILITY.md "Cycle records"),
+    # summed over the threads, ended ones included, off the lock: the
+    # count, the cumulative ms of a step and of its two phases, of the
+    # slowest member's own time in its env's `step`, and of what the
+    # steps lay over their thread's median, by the activity they lay
+    # under (`telemetry.excess`); percentiles over the running
+    # threads' newest steps.
+    steps.update(_step_counts(records))
+    recent = [r.lengths(r.held(last=_RECENT_STEPS)[1]) for r in records]
+    recent = np.sort(np.concatenate(recent or [np.zeros(0, np.int64)]))
+    rank = lambda q: telemetry.nearest_rank(recent, q) / 1e6  # noqa: E731
+    counts.update(steps, step_ms_p50=rank(0.5), step_ms_p95=rank(0.95),
+                  step_ms_max=rank(1.0))
+    return counts
 
   def _join_all(self, timeout: float, what: str,
                 consequence: str) -> Dict[str, List[int]]:

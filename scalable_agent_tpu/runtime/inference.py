@@ -164,33 +164,21 @@ log = logging.getLogger('scalable_agent_tpu')
 # SLO objective — admission is its actuator (controller.DEFAULT_RULES).
 _SERVE_LATENCY = telemetry.histogram('serving/latency_ms')
 _SHADOW_DIVERGENCE = telemetry.gauge('serving/shadow_divergence')
-_SHADOW_CALLS = telemetry.counter('serving/shadow_calls')
-_AB_CALLS = telemetry.counter('serving/ab_calls')
-_EVICTIONS = telemetry.counter('serving/evictions')
-_VERSION_FLIPS = telemetry.counter('serving/version_flips')
-_RESIDENT_VERSIONS = telemetry.gauge('serving/resident_versions')
-_AOT_MISSES = telemetry.counter('serving/aot_misses')
-# The recurrent state the server holds for its sessions (PR 27).
-_STATE_BYTES_PER_SLOT = telemetry.gauge('serving/state_bytes_per_slot')
-_ARENA_BYTES = telemetry.gauge('serving/arena_bytes')
-_STATE_RESETS = telemetry.counter('serving/state_resets')
-# A state written at a position (PR 32): the prompt tokens handed over
-# in blocks and the chunk programs they took, the cached tokens the
-# merged calls' live rows read, what a slot's cache holds at most, and
-# the sums of the per-call counters an agent's layers may sow
-# (`agent.call_counters` names some of these).
-_CALL_COUNTERS = {
-    'routed_rows_held': telemetry.counter('serving/routed_rows_held'),
-    'experts_hit': telemetry.counter('serving/experts_hit')}
-_PREFILL_TOKENS = telemetry.counter('serving/prefill_tokens')
-_PREFILL_CHUNKS = telemetry.counter('serving/prefill_chunks')
-_CACHE_TOKENS_READ = telemetry.counter('serving/cache_tokens_read')
-_WINDOW_TOKENS_READ = telemetry.counter('serving/window_tokens_read')
-_CACHE_CAPACITY = telemetry.gauge('serving/cache_capacity')
-# Host arrays handed to a merged call's step and arrays fetched from it
-# (PR 36: one each; observed where they cross, never set).
-_H2D_BUFFERS = telemetry.counter('serving/h2d_buffers')
-_D2H_BUFFERS = telemetry.counter('serving/d2h_buffers')
+# Every other count of the serving plane is a key of `stats()` and
+# nowhere else (PR 37: the registry's copies had no reader).
+# The per-call counters an agent's layers may sow
+# (`agent.call_counters` names some of these), summed in `stats()`.
+_CALL_COUNTERS = ('routed_rows_held', 'experts_hit')
+
+# A merged call's stamps (`InferenceServer._cycles`; docs/
+# OBSERVABILITY.md "Cycle records") and what else is kept of it.
+_CALL_PHASES = ('wait_batch', 'dispatch', 'in_flight_and_readback',
+                'unpark')
+_CALL_EXTRAS = ('h2d', 'd2h')
+# The stamps a call's latency lies between.
+_IN_HAND, _UNPARKED = 1, len(_CALL_PHASES)
+# `latency_p*_ms`, `*_buffers_per_call`: over this many newest calls.
+_RECENT_CALLS = 512
 
 # Admission priority classes (lower = served first): a released slot
 # is handed to the best-priority parked waiter, so background churn
@@ -617,7 +605,6 @@ class InferenceServer:
   _admission_timeouts: guarded_by('_stats_lock')
   _arena_grows: guarded_by('_stats_lock')
   _unjoined_threads: guarded_by('_stats_lock')
-  _latencies: guarded_by('_stats_lock')
   _chain_recoveries: guarded_by('_stats_lock')
   _state_resets: guarded_by('_stats_lock')
   _version_flips: guarded_by('_stats_lock')
@@ -632,8 +619,6 @@ class InferenceServer:
   _cache_tokens_read: guarded_by('_stats_lock')
   _window_tokens_read: guarded_by('_stats_lock')
   _call_counts: guarded_by('_stats_lock')
-  _h2d_buffers: guarded_by('_stats_lock')
-  _d2h_buffers: guarded_by('_stats_lock')
 
   def __init__(self, agent, params, config, seed=0, mesh=None,
                pad_batch_to=None, fleet_size=None):
@@ -758,13 +743,17 @@ class InferenceServer:
     self._arena_grows = 0
     self._unjoined_threads = 0
     self._admission_wait_reservoir = LatencyReservoir(maxlen=1024)
-    # Per-merged-call latency ring (assembly start → callers unparked)
-    # for the stats() p50/p99 — bounded so a week-long run's stats
-    # reflect RECENT service time, not the cumulative history.
-    self._latencies = collections.deque(maxlen=512)
-    # What crossed the host boundary for each of the same calls.
-    self._h2d_buffers = collections.deque(maxlen=512)
-    self._d2h_buffers = collections.deque(maxlen=512)
+    # The merged calls' cycle record: five stamps a call (the phases of
+    # `_CALL_PHASES` between them) and what crossed the host boundary
+    # each way, written whole by the completion thread. THE source of
+    # stats()'s latency percentiles (over its newest `_RECENT_CALLS`
+    # rows: recent service time, not a week's history), of the
+    # cumulative `call_*_ms` and of `serving/latency_ms`.
+    self._cycles = telemetry.CycleRecord(
+        _CALL_PHASES, extras=_CALL_EXTRAS, cycle_from=_IN_HAND)
+    # Callers' time parked in `policy`'s `compute`, ns (unlocked, as
+    # `_batcher_requests` is).
+    self._batcher_wait_ns = 0
     # _key is a DEVICE array chained through the jitted step (split
     # in-graph); the lock orders warmup (caller thread) against the
     # dispatch thread. Same split sequence as the old host-side
@@ -1071,9 +1060,6 @@ class InferenceServer:
     arena = self._agent.state_arena(num_slots)
     if self._mesh is not None:
       arena = jax.device_put(arena, self._replicated)
-    _STATE_BYTES_PER_SLOT.set(float(self._state_bytes))
-    _ARENA_BYTES.set(float(_tree_nbytes(arena)))
-    _CACHE_CAPACITY.set(float(self._cache_capacity))
     return arena
 
   # One slot's row of every leaf, rewritten IN PLACE (the arena is
@@ -1095,7 +1081,6 @@ class InferenceServer:
     with self._stats_lock:
       self._state_resets += 1
       self._slot_pos[slot] = 0
-    _STATE_RESETS.inc()
 
   def prefill(self, handle, tokens):
     """Begin an episode in `handle`'s slot with `tokens` i32 [n] behind
@@ -1115,7 +1100,7 @@ class InferenceServer:
       block = np.zeros((size,), np.int32)
       valid = min(size, len(tokens) - lo)
       block[:valid] = tokens[lo:lo + valid]
-      with telemetry.span('inference/prefill', id=int(slot)):
+      with telemetry.activity('inference/prefill', id=int(slot)):
         with self._arena_lock:
           self._arena = self._prefill_step(
               params, self._arena, slot, block, np.int32(valid),
@@ -1125,8 +1110,6 @@ class InferenceServer:
       self._slot_pos[handle.slot] = len(tokens)
       self._prefill_tokens += len(tokens)
       self._prefill_chunks += chunks
-    _PREFILL_TOKENS.inc(len(tokens))
-    _PREFILL_CHUNKS.inc(chunks)
 
   def _read_slot(self, slot):
     if self._state_bytes > MAX_HOST_STATE_BYTES:
@@ -1196,16 +1179,15 @@ class InferenceServer:
     if compiled is None:
       with self._stats_lock:
         self._aot_misses += 1
-      _AOT_MISSES.inc()
     return compiled
 
   def _dispatch(self, params, staging, shadow_params=None):
     """Dispatch one padded batch (a `_Staging`: its flat buffer is the
     step's ONE batch argument) through the jitted step, chaining the
     device-resident key (and arena) — returns the (async) packed
-    outputs plus the shadow version's logits (or None). The shadow
-    step runs BEFORE the live step so both read the same pre-step
-    arena carries."""
+    outputs, the shadow version's logits (or None) and how many host
+    arrays the step was handed. The shadow step runs BEFORE the live
+    step so both read the same pre-step arena carries."""
     step = self._step  # read per call: tests monkeypatch it
     packed, layout = staging.words, staging.layout
     crossing = sum(isinstance(leaf, np.ndarray)
@@ -1221,9 +1203,6 @@ class InferenceServer:
     # A compiled executable has its static argument inside it.
     static = () if compiled is not None else (layout,)
     fn = compiled if compiled is not None else step
-    with self._stats_lock:
-      self._h2d_buffers.append(crossing)
-    _H2D_BUFFERS.inc(crossing)
     with self._key_lock:
       if self._state_cache:
         with self._arena_lock:
@@ -1233,12 +1212,12 @@ class InferenceServer:
                 shadow_params, self._arena, packed, layout)
           self._key, self._arena, out = fn(
               params, self._key, self._arena, packed, *static)
-          return out, shadow_out
+          return out, shadow_out, crossing
       shadow_out = None
       if shadow_params is not None:
         shadow_out = self._shadow_step(shadow_params, packed, layout)
       self._key, out = fn(params, self._key, packed, *static)
-      return out, shadow_out
+      return out, shadow_out, crossing
 
   def _dispatch_loop(self):
     while True:
@@ -1263,7 +1242,7 @@ class InferenceServer:
         self._completion_q.put(None)
         return
       batch_id, n, bufs = item
-      t0 = time.perf_counter()
+      t_hand = time.perf_counter_ns()
       dispatch = telemetry.span('inference/dispatch', id=batch_id)
       try:
         if self._state_cache:
@@ -1294,19 +1273,15 @@ class InferenceServer:
             window_reads = int(np.sum(np.minimum(
                 reads, self._cache_window)))
             self._window_tokens_read += window_reads
-        if self._cache_capacity:
-          _CACHE_TOKENS_READ.inc(cache_reads)
-          if window_reads:
-            _WINDOW_TOKENS_READ.inc(window_reads)
-        if resets:
-          _STATE_RESETS.inc(resets)
         with self._params_lock:
           params, _ = self._pick_live_locked()
           shadow_params = self._pick_shadow_locked()
         self._sem.acquire()
         try:
-          payload, shadow_out = self._dispatch(
+          payload, shadow_out, crossing = self._dispatch(
               params, bufs, shadow_params)
+          # The jitted call has returned: the `dispatch` phase ends.
+          t_dispatched = time.perf_counter_ns()
           with self._stats_lock:
             self._inflight += 1
             self._inflight_peak = max(self._inflight_peak,
@@ -1315,18 +1290,37 @@ class InferenceServer:
           self._sem.release()
           raise
         self._completion_q.put(
-            (batch_id, n, t0, payload, shadow_out, bufs.layout))
+            (batch_id, n, (wait.t0, t_hand, t_dispatched), crossing,
+             payload, shadow_out, bufs.layout))
       except Exception as e:  # propagate to the parked callers
         self._batcher.set_error(batch_id, f'{type(e).__name__}: {e}')
       finally:
         dispatch.end()
 
   def _completion_loop(self):
+    """Reads each dispatched call's result back, in order, unparks
+    its callers and writes the call's row of `_cycles`: the dispatch
+    thread's three stamps (its wait's begin, batch in hand, jitted
+    call returned) and this thread's two (host views in hand, callers
+    unparked). The first is moved up to the call before's last where
+    the wait began while that call was still in flight, which it
+    always does at pipeline depth 1: the four phases of consecutive
+    calls then do not overlap, and sum to the cycle. ONE phase from
+    the jitted call's return to the host views: a `block_until_ready`
+    before the `device_get` would part the launch and the device's
+    time from the copy's, and was measured (PERF.md section 6, PR 37:
+    in_flight 1.79 ms and readback 0.51 of `fleet32`'s mean call) at
+    one more wake-up a call, 4.8% of `policy_call_p50_ms`: not kept."""
+    t_unparked = 0
     while True:
       item = self._completion_q.get()
       if item is None:
         return
-      batch_id, n, t0, payload, shadow_out, layout = item
+      (batch_id, n, (t_wait, t_hand, t_dispatched), crossing, payload,
+       shadow_out, layout) = item
+      t_read = None
+      fetched = 0
+      t_wait = min(max(t_wait, t_unparked), t_hand)
       try:
         # Observability for the sharded-eval contract: how many
         # devices the last merged call actually spanned (read before
@@ -1343,9 +1337,7 @@ class InferenceServer:
         with telemetry.span('inference/readback', id=batch_id):
           host = packing.host_views(jax.device_get(payload),
                                     self._out_layouts[layout])
-        with self._stats_lock:
-          self._d2h_buffers.append(fetched)
-        _D2H_BUFFERS.inc(fetched)
+        t_read = time.perf_counter_ns()
         counts = ()
         if self._counter_names:
           # The call's counters rode its readback, behind its outputs.
@@ -1353,12 +1345,11 @@ class InferenceServer:
           host, counts = host[:split], host[split:]
         with telemetry.span('inference/unpark', id=batch_id):
           self._batcher.set_outputs(batch_id, [o[:n] for o in host])
+        t_unparked = time.perf_counter_ns()
         if counts:
           with self._stats_lock:
             for name, count in zip(self._counter_names, counts):
               self._call_counts[name] += int(count)
-          for name, count in zip(self._counter_names, counts):
-            _CALL_COUNTERS[name].inc(int(count))
         if shadow_out is not None:
           # Shadow scoring AFTER the callers are answered: the gauge
           # must never add device_get latency to the live path. Logits
@@ -1378,7 +1369,6 @@ class InferenceServer:
                 self._shadow_divergence = (
                     0.9 * self._shadow_divergence + 0.1 * divergence)
               ewma = self._shadow_divergence
-            _SHADOW_CALLS.inc()
             _SHADOW_DIVERGENCE.set(ewma)
           except Exception:
             log.exception('inference: shadow scoring failed')
@@ -1398,14 +1388,17 @@ class InferenceServer:
             self._batcher.set_error(batch_id, f'{type(e).__name__}: {e}')
           except Exception:
             pass
+          # A failed call's row ends where its callers had their error.
+          t_unparked = time.perf_counter_ns()
+          t_read = t_read or t_unparked
       finally:
         self._sem.release()
-      lat_ms = (time.perf_counter() - t0) * 1e3
-      _SERVE_LATENCY.observe(lat_ms)
+      self._cycles.write(t_wait, t_hand, t_dispatched, t_read,
+                         t_unparked, crossing, fetched)
+      _SERVE_LATENCY.observe((t_unparked - t_hand) / 1e6)
       with self._stats_lock:
         self._inflight -= 1
         self._devices_last_call = devices
-        self._latencies.append(lat_ms)
 
   def _recover_chain(self):
     """Re-anchor the device-chained state after a failed execution.
@@ -1545,28 +1538,55 @@ class InferenceServer:
         # Pre-compile BEFORE dispatching, so warmup itself serves
         # from the AOT table (aot_misses stays 0 end to end).
         self._precompile_params(params)
-      payload, _ = self._dispatch(params, staging)
+      payload, _, _ = self._dispatch(params, staging)
       jax.block_until_ready(payload)
 
   def stats(self):
-    """Merge + service telemetry.
+    """Merge + service telemetry, flat; docs/OBSERVABILITY.md "Cycle
+    records" and docs/INFERENCE.md list every key. By group:
 
-    {'calls', 'requests', 'batcher_requests', 'mean_batch',
-     'params_version',
-     'publishes_skipped', 'devices_last_call', 'latency_p50_ms',
-     'latency_p99_ms', 'pipeline_depth', 'state_cache',
-     'inflight_peak', 'slots_free'}.
+    - the merge: `calls`, `requests` (rows), `batcher_requests`
+      (policy() calls), `mean_batch`, `pipeline_depth`,
+      `inflight_peak`, `devices_last_call`, `chain_recoveries`,
+      `unjoined_threads`; `batcher_wait_ms` (cumulative: the callers'
+      time parked in the batcher);
+    - a merged call's time, from `_cycles`: cumulative
+      `call_wait_batch_ms`, `call_dispatch_ms`,
+      `call_in_flight_and_readback_ms`, `call_unpark_ms` (their change
+      over a window by the change of `calls` is the window's mean);
+      `latency_p50_ms`, `latency_p95_ms`, `latency_p99_ms` (batch in
+      hand -> callers unparked, over the newest 512 calls: the
+      benchmark's `inference.call_host_ms_p50` reads the first);
+      `h2d_buffers_per_call`, `d2h_buffers_per_call` (the same calls);
+      cumulative `call_excess_ms` (what the calls' latencies lay over
+      their median, `telemetry.excess`), `call_excess_ms_in_<activity>`
+      a name of `telemetry.ACTIVITIES`, `call_excess_ms_unnamed`,
+      `call_cycles_lost`;
+    - parameters: `params_version`, `publishes_skipped`,
+      `resident_versions`, `live_version`, `serve_counts`,
+      `version_flips`, `evictions`, `ab_calls`, `shadow_calls`,
+      `shadow_divergence`, `aot_misses`, `aot_compiled`;
+    - the sessions' state: `state_cache`, `slots_free`,
+      `state_bytes_per_slot`, `arena_bytes`, `state_resets`,
+      `prefill_tokens`, `prefill_chunks`, `cache_tokens_read`,
+      `cache_capacity`, `window_tokens_read`, `cache_window`, and the
+      agent's per-call counters by their names;
+    - admission: `admission`, `acquires`, `admission_waits`, `sheds`,
+      `admission_timeouts`, `admission_wait_p99_ms`, `arena_grows`,
+      `waitlist_depth`.
 
     mean_batch near 1.0 means the batcher is not merging (the
     reference's ~3x single-machine win comes precisely from this
-    number being high — paper Table 1); watch it when tuning
-    inference_{min_batch,timeout_ms}. The latency percentiles cover
-    the last ≤512 merged calls, assembly start → callers unparked
-    (the benchmark's `inference.call_host_ms_p50` reads it).
+    number being high, paper Table 1); watch it when tuning
+    inference_{min_batch,timeout_ms}.
     """
+    _, recent = self._cycles.held(last=_RECENT_CALLS)
+    lat = sorted(((recent[:, _UNPARKED] - recent[:, _IN_HAND])
+                  / 1e6).tolist())
+    h2d, d2h = recent[:, _UNPARKED + 1], recent[:, _UNPARKED + 2]
+    call_totals = self._cycles.totals()
     with self._stats_lock:
       calls, reqs = self._calls, self._merged_requests
-      lat = sorted(self._latencies)
       devices = self._devices_last_call
       version = self._params_version
       skipped = self._publishes_skipped
@@ -1590,7 +1610,6 @@ class InferenceServer:
       cache_tokens_read = self._cache_tokens_read
       window_tokens_read = self._window_tokens_read
       call_counts = dict(self._call_counts)
-      h2d, d2h = list(self._h2d_buffers), list(self._d2h_buffers)
     with self._params_lock:
       resident = len(self._versions)
       live_label = self._versions[self._live_key].label()
@@ -1602,8 +1621,6 @@ class InferenceServer:
       waitlist_depth = len(self._waiters)
       admission = self._admission
     (wait_p99_ms,) = self._admission_wait_reservoir.percentile_ms(0.99)
-    p50 = percentile_ms(lat, 0.5)
-    p99 = percentile_ms(lat, 0.99)
     return {
         'calls': calls,
         'requests': reqs,
@@ -1615,13 +1632,18 @@ class InferenceServer:
         'params_version': version,
         'publishes_skipped': skipped,
         'devices_last_call': devices,
-        'latency_p50_ms': round(p50, 3),
-        'latency_p99_ms': round(p99, 3),
-        # Over the same last ≤512 calls: host arrays handed to the
-        # step a call, arrays fetched from it a call (one each since
-        # PR 36; counted where they cross).
-        'h2d_buffers_per_call': (sum(h2d) / len(h2d)) if h2d else 0.0,
-        'd2h_buffers_per_call': (sum(d2h) / len(d2h)) if d2h else 0.0,
+        'batcher_wait_ms': self._batcher_wait_ns / 1e6,
+        **{f'call_{phase}_ms': call_totals[phase + '_ns'] / 1e6
+           for phase in _CALL_PHASES},
+        'latency_p50_ms': round(percentile_ms(lat, 0.5), 3),
+        'latency_p95_ms': round(percentile_ms(lat, 0.95), 3),
+        'latency_p99_ms': round(percentile_ms(lat, 0.99), 3),
+        # Over the same newest calls: host arrays handed to the step
+        # a call, arrays fetched from it a call (one each since PR 36;
+        # counted where they cross).
+        'h2d_buffers_per_call': float(h2d.mean()) if len(h2d) else 0.0,
+        'd2h_buffers_per_call': float(d2h.mean()) if len(d2h) else 0.0,
+        **telemetry.excess_ms('call_', telemetry.excess(self._cycles)),
         'pipeline_depth': self._depth,
         'state_cache': self._state_cache,
         'inflight_peak': peak,
@@ -1714,7 +1736,6 @@ class InferenceServer:
           entry = cand
           with self._stats_lock:
             self._ab_calls += 1
-          _AB_CALLS.inc()
     entry.serves += 1
     entry.tick = self._serve_tick
     return entry.params, entry.key
@@ -1746,7 +1767,6 @@ class InferenceServer:
     self._versions.move_to_end(key)
     self._live_key = key
     self._evict_locked()
-    _RESIDENT_VERSIONS.set(float(len(self._versions)))
 
   def _evict_locked(self):
     while True:
@@ -1774,7 +1794,6 @@ class InferenceServer:
       del self._versions[victim.key]
       with self._stats_lock:
         self._evictions += 1
-      _EVICTIONS.inc()
       log.info('serving: evicted resident version %s (LRU; %d left)',
                victim.label(), len(self._versions))
 
@@ -1868,7 +1887,6 @@ class InferenceServer:
           with self._stats_lock:
             self._version_flips += 1
             self._params_version += 1
-          _VERSION_FLIPS.inc()
           return
     params = jax.tree_util.tree_map(jnp.copy, params)
     if self._quantize_resident:
@@ -1915,7 +1933,6 @@ class InferenceServer:
       with self._stats_lock:
         self._version_flips += 1
         self._params_version += 1
-      _VERSION_FLIPS.inc()
 
   def set_ab(self, version, fraction):
     """Route `fraction` of merged calls to `version` (None = the
@@ -2038,8 +2055,10 @@ class InferenceServer:
     # No lock for a counter on every caller's path: a count lost to
     # two callers' race is within what stats() promises of it.
     self._batcher_requests += 1
+    t0 = time.perf_counter_ns()
     with telemetry.span('batcher/compute'):
       outs = self._batcher.compute(inputs)
+    self._batcher_wait_ns += time.perf_counter_ns() - t0
     if not self._state_cache:
       new_state = jax.tree_util.tree_unflatten(self._state_treedef,
                                                outs[3:])

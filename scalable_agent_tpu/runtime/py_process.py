@@ -49,6 +49,7 @@ import os
 import sys
 import tempfile
 import threading
+import time
 import traceback
 from multiprocessing.pool import ThreadPool
 from multiprocessing.reduction import ForkingPickler
@@ -124,9 +125,6 @@ _STEPPED, _FAILED = b'K', b'X'
 _BLOCK_STEP = object()
 _BLOCK_DIR = '/dev/shm'
 
-BLOCK_STEPS = telemetry.counter('actors/block_steps')
-_PIPE_CALLS = telemetry.counter('actors/pipe_calls')
-
 
 def pin_process_to_cpu():
   """Keep THIS process off the accelerator: a chip belongs to one
@@ -189,10 +187,13 @@ class StepBlock:
   what it wants (`action`, the `row` to write, and `want`, a number
   no earlier step of this block had) and the child says what it did
   (`seq` = `want`, written after the row), so that a row left from an
-  earlier step can never be taken for the one asked for. The file is
-  named only until every child has mapped it (`unlink`): nothing is
-  left to clean up, however the processes end. `private` is the same
-  layout in this process's own memory, for envs that cannot map it.
+  earlier step can never be taken for the one asked for. `busy_ns`
+  is each column's own time in its env's `step`, the last one's: a
+  duration on the clock of whoever stepped the env (the child, for a
+  hosted one), so the clocks need not agree. The file is named only
+  until every child has mapped it (`unlink`): nothing is left to clean
+  up, however the processes end. `private` is the same layout in this
+  process's own memory, for envs that cannot map it.
   """
 
   def __init__(self, leaf_specs, rows, columns, buffer, path=None):
@@ -204,16 +205,16 @@ class StepBlock:
         np.frombuffer(buffer, dtype, int(np.prod(shape, dtype=np.int64)),
                       offset).reshape(shape)
         for shape, dtype, offset in fields]
-    (self.action, self.row, self.want, self.seq, self.reward,
-     self.done) = arrays[:6]
-    self.leaves = arrays[6:]
+    (self.action, self.row, self.want, self.seq, self.busy_ns,
+     self.reward, self.done) = arrays[:7]
+    self.leaves = arrays[7:]
 
   @staticmethod
   def _layout(leaf_specs, rows, columns):
     """[(shape, dtype, offset)] of the fields, and the bytes in all."""
     shapes = [((columns,), 'i4'), ((columns,), 'i4'), ((columns,), 'i8'),
-              ((columns,), 'i8'), ((rows, columns), 'f4'),
-              ((rows, columns), '?')]
+              ((columns,), 'i8'), ((columns,), 'i8'),
+              ((rows, columns), 'f4'), ((rows, columns), '?')]
     shapes += [((rows, columns) + shape, dtype)
                for shape, dtype in leaf_specs]
     fields, offset = [], 0
@@ -226,7 +227,9 @@ class StepBlock:
   @classmethod
   def private(cls, leaf_specs, rows, columns):
     _, size = cls._layout(leaf_specs, rows, columns)
-    return cls(leaf_specs, rows, columns, np.empty(size, np.uint8))
+    block = cls(leaf_specs, rows, columns, np.empty(size, np.uint8))
+    block.busy_ns[:] = 0  # a column nobody times took none
+    return block
 
   @classmethod
   def create(cls, leaf_specs, rows, columns):
@@ -282,12 +285,15 @@ class _BlockStepper:
     self._row = block.row[column:column + 1]
     self._want = block.want[column:column + 1]
     self._seq = block.seq[column:column + 1]
+    self._busy_ns = block.busy_ns[column:column + 1]
     self._reward = block.reward[:, column]
     self._done = block.done[:, column]
     self._columns = [leaf[:, column] for leaf in block.leaves]
 
   def step(self):
+    t0 = time.perf_counter_ns()
     result = self._obj.step(int(self._action[0]))
+    self._busy_ns[0] = time.perf_counter_ns() - t0  # the env's own
     # The one check of the reply: here, beside the env, and not on the
     # thread that steps the whole group.
     _validate_specs(result, self._specs, self._name)
@@ -340,6 +346,7 @@ def _worker(conn, type_, constructor_kwargs):
         conn.send(('exception', _serialize_error(e)))
       break
     try:
+      t0 = time.perf_counter_ns()
       if method == _ATTACH:
         validate, *where = args
         specs = (type_._tensor_specs('step', {}, constructor_kwargs)
@@ -349,7 +356,8 @@ def _worker(conn, type_, constructor_kwargs):
         result = None
       else:
         result = getattr(obj, method)(*args, **kwargs)
-      conn.send(('ok', result))
+      # With the method's own time, on this process's clock.
+      conn.send(('ok', result, time.perf_counter_ns() - t0))
     except Exception as e:  # keep serving — reference semantics
       conn.send(('exception', _serialize_error(e)))
   try:
@@ -450,6 +458,9 @@ class PyProcess:
     # the pickled pipe (read by `ActorFleet.stats`).
     self.block_steps = 0
     self.pipe_calls = 0
+    # The child's own time in the last pickled call's method, ns (a
+    # step through a block leaves its own in the block's `busy_ns`).
+    self.busy_ns = 0
 
   @property
   def proxy(self):
@@ -524,7 +535,6 @@ class PyProcess:
           # be must leave the child expecting nothing.
           request = ForkingPickler.dumps((method, args, kwargs))
           self.pipe_calls += 1
-          _PIPE_CALLS.inc()
           os.write(self._conn.fileno(), _CALL)
           self._conn.send_bytes(request)
       except (EOFError, OSError, BrokenPipeError) as e:
@@ -583,7 +593,9 @@ class PyProcess:
     finally:
       self._pending = None
       self._lock.release()
-    status, payload = reply
+    status, payload, *busy_ns = reply
+    if busy_ns:
+      self.busy_ns = busy_ns[0]
     block = self.block if method is _BLOCK_STEP else None
     if (block is not None and status == 'ok'
         and block.seq[self.column] == block.step_seq):
@@ -657,7 +669,6 @@ class PyProcess:
         os.write(conn.fileno(), _CALL)
         conn.send((_CLOSE, (), {}))
         self.pipe_calls += 1
-        _PIPE_CALLS.inc()
         if conn.poll(timeout):
           conn.recv()
       except (EOFError, OSError, BrokenPipeError):
@@ -762,6 +773,11 @@ class ProxyEnv:
 
   def step_block_specs(self):
     return self._process.step_block_specs()
+
+  def step_busy_ns(self):
+    """The child's own time in the `step` last received, where that
+    went down the pipe."""
+    return self._process.busy_ns
 
   def attach_block(self, block, column):
     self._process.attach_block(block, column)

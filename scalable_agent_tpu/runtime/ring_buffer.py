@@ -837,7 +837,7 @@ class BatchPrefetcher:
         # stamp and opens the batch's entry in the tracer's FIFO
         # (serve/step stamps follow in this same FIFO order).
         tracer.on_batch(items, n_fresh)
-      with telemetry.span('staging/stage'):
+      with telemetry.activity('staging/stage'):
         batch = batch_unrolls(items)
         if self._fresh_aware:
           return self._place_fn(batch, n_fresh), n_fresh
@@ -855,9 +855,9 @@ class BatchPrefetcher:
       with telemetry.park('staging/wait_unrolls'):
         unroll = self._buffer.get()
       fresh_items.append(unroll)
-      with telemetry.span('staging/stage'):
+      with telemetry.activity('staging/stage'):
         self._stager.add(unroll)
-    with telemetry.span('staging/stage'):
+    with telemetry.activity('staging/stage'):
       for unroll in replayed:
         self._stager.add(unroll, peel_view=False)
       if tracer is not None:

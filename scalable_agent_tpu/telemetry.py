@@ -64,7 +64,13 @@ telemetry behind it. This module is that layer, in three pieces:
    arms it (observability.ProfilerCapture, the benchmark's traced
    slice); the clock pair read at arming joins its rows to the hop
    stamps above, a landmark program joins them to the device trace.
-   Span names are listed in docs/OBSERVABILITY.md ("Spans").
+   Span names are listed in docs/OBSERVABILITY.md ("Spans"). Two
+   things of it are ALWAYS on (PR 37), because the cycles that cost a
+   mean its median does not show happen in runs nobody traced: a
+   `CycleRecord` (the last N cycles of one loop, a few stamps each, on
+   the same clock) and the ring of finished `activity` parks (the
+   rare, heavy work of other threads); `excess` lays one over the
+   other. docs/OBSERVABILITY.md "Cycle records".
 
 Costs are measured, not assumed: the feed pipeline was run with
 tracing on and off, and the always-on default is an accept/reject call
@@ -78,6 +84,7 @@ before (or without) jax initialization.
 import collections
 import json
 import math
+import operator
 import os
 import threading
 import time
@@ -316,6 +323,16 @@ _span_ids = threading.local()
 # Parks under way, armed or not: a set of _Park (add/discard and
 # list() are atomic under the GIL).
 _open_parks = set()
+# The last finished `activity` parks, armed or not: (name, t0, t1) on
+# the perf clock (append and list() are atomic under the GIL). A few
+# a second in a training run: minutes of them.
+_ACTIVITY_RING = 4096
+_activities = collections.deque(maxlen=_ACTIVITY_RING)
+# The sites that are activities, so that a reader finds each name at
+# zero before its first park ends. A name not listed here is taken up
+# when it first appears.
+ACTIVITIES = ('learner/publish', 'learner/summaries',
+              'learner/checkpoint', 'staging/stage', 'inference/prefill')
 
 
 class _Site:
@@ -392,25 +409,33 @@ def span(name: str, id=None):  # noqa: A002 — the row's field name
 
 
 class _Park(_Site):
-  __slots__ = ('name', 'id', 't0', 'thread')
+  __slots__ = ('name', 'id', 't0', 'thread', 'remembered')
 
-  def __init__(self, name, span_id):
+  def __init__(self, name, span_id, remembered=False):
     self.name = name
     self.id = span_id
+    self.remembered = remembered
     self.thread = threading.get_ident()
     self.t0 = time.perf_counter_ns()
     _open_parks.add(self)
 
   def end(self):
+    if self not in _open_parks:
+      return  # ended already
     _open_parks.discard(self)
     recorder = _recorder
+    if recorder is None and not self.remembered:
+      return
+    t1 = time.perf_counter_ns()
+    if self.remembered:
+      _activities.append((self.name, self.t0, t1))
     if recorder is not None:
       span_id = self.id
       if span_id is None:
         span_id = getattr(_span_ids, 'id', None)
       # A wait that began before arming is kept from arming on.
-      recorder.add(self.name, max(self.t0, recorder.perf_ns),
-                   time.perf_counter_ns(), span_id)
+      recorder.add(self.name, max(self.t0, recorder.perf_ns), t1,
+                   span_id)
 
 
 def park(name: str, id=None):  # noqa: A002
@@ -426,6 +451,35 @@ def park(name: str, id=None):  # noqa: A002
   not. Only for the few sites that run under ~100 times a second. Its
   `id` may be set inside the block (`with park(..) as p: p.id = ..`)."""
   return _Park(name, id)
+
+
+def activity(name: str, id=None):  # noqa: A002
+  """A park for WORK that is rare and heavy (a parameter publish, a
+  batch staged, a checkpoint written: about one a second each), used
+  like `park` and kept by an armed recorder like one. Finished, it
+  also leaves `(name, t0_ns, t1_ns)` in a bounded ring of the last
+  few thousand, recorder on or off: what `excess` asks when a cycle of
+  some OTHER thread's loop ran long. Not for waits (a wait holds
+  nothing anyone else needs) and not for sites that run hundreds of
+  times a second."""
+  return _Park(name, id, remembered=True)
+
+
+def activities(since_ns: int = 0,
+               now_ns: Optional[int] = None) -> List[Tuple]:
+  """The finished activities the ring still holds that ended at
+  `since_ns` or later, oldest first, then those under way, each closed
+  at `now_ns` (default: now): `(name, t0_ns, t1_ns)` on the perf
+  clock."""
+  if now_ns is None:
+    now_ns = time.perf_counter_ns()
+  finished = list(_activities)  # in the order they ended
+  first = len(finished)
+  while first and finished[first - 1][2] >= since_ns:
+    first -= 1
+  under_way = [(p.name, p.t0, now_ns) for p in list(_open_parks)
+               if p.remembered and p.t0 < now_ns]
+  return finished[first:] + under_way
 
 
 def arm_spans(max_spans: int = 400_000) -> Dict:
@@ -462,6 +516,214 @@ def take_spans() -> Optional[Dict]:
           'taken_ns': taken_ns, 'spans': recorder.rows,
           'threads': recorder.threads,
           'dropped': _SPANS_DROPPED.value - recorder.dropped_before}
+
+
+# --- Cycle records: the recorder's always-on part (PR 37). ---
+
+_NS_PER_MS = 1e6
+# `excess` judges a cycle against the median of this many newest rows.
+_EXCESS_REFERENCE = 4096
+
+
+def nearest_rank(ascending, q):
+  """runtime/inference.percentile_ms's rule, on an ascending array."""
+  n = len(ascending)
+  return float(ascending[min(n - 1, int(n * q))]) if n else 0.0
+
+
+class CycleRecord:
+  """The last `rows` cycles of ONE loop, always on: each cycle a row of
+  `len(phases) + 1` stamps on `time.perf_counter_ns()` (the span
+  recorder's clock: a row can be laid beside armed spans and, through
+  the landmark, the device trace; phase k runs from stamp k to stamp
+  k + 1) and of `extras`, further int64 numbers of the cycle (a count,
+  a duration measured elsewhere). Beside the ring, what never wraps:
+  the count of cycles and the sum of every phase and extra, so that a
+  window's means come from their change between two readings and not
+  from the rows that happen to be held.
+
+  One thread writes (`write`, a whole row at once: no lock, no array
+  made); any thread reads (`summary`, `held`, `excess`). A reader
+  never takes a half-written row for a cycle: the writer announces a
+  row before it overwrites its place in the ring and counts it after,
+  and a reader drops what was announced while it copied.
+
+  `cycle_from` is the stamp a cycle's LENGTH counts from, for a loop
+  whose first phase is a wait for work (the length `summary` gives as
+  'cycle' and `excess` judges)."""
+
+  def __init__(self, phases, extras=(), rows=32768, cycle_from=0):
+    self.phases = tuple(phases)
+    self.extras = tuple(extras)
+    self.cycle_from = cycle_from
+    self._stamps = len(self.phases) + 1
+    self._ring = np.zeros((rows, self._stamps + len(self.extras)),
+                          np.int64)
+    self._announced = 0  # rows begun, ever
+    self._written = 0    # rows whole, ever
+    # Column sums over every row ever written (Python ints: a phase's
+    # total is the difference of two stamps' sums).
+    self._sums = (0,) * (self._stamps + len(self.extras))
+    # `excess`: the rows it has judged, and what it found, ever.
+    self._excess_lock = threading.Lock()
+    self._judged = 0
+    self._lost = 0
+    self._excess_ns = 0.0
+    self._unnamed_ns = 0.0
+    self._in_ns = {name: 0.0 for name in ACTIVITIES}
+
+  def write(self, *values):
+    """One cycle: its stamps in order, then its extras."""
+    i = self._written
+    self._announced = i + 1
+    self._ring[i % len(self._ring)] = values
+    self._sums = tuple(map(operator.add, self._sums, values))
+    self._written = i + 1
+
+  @property
+  def cycles(self) -> int:
+    return self._written
+
+  def totals(self) -> Dict:
+    """{'cycles', '<phase>_ns' a phase, '<extra>' an extra}: sums over
+    every cycle ever written (read while the writer writes, the count
+    and the sums may be one cycle apart)."""
+    sums, cycles = self._sums, self._written
+    out = {'cycles': cycles}
+    for k, name in enumerate(self.phases):
+      out[name + '_ns'] = sums[k + 1] - sums[k]
+    for name, total in zip(self.extras, sums[self._stamps:]):
+      out[name] = total
+    return out
+
+  def held(self, since=0, last=None):
+    """(index of the first row returned, a copy of the whole rows
+    `since` .. newest that the ring still holds; at most the `last`
+    newest)."""
+    ring = self._ring
+    n = len(ring)
+    end = self._written
+    start = max(since, end - n, 0)
+    if last is not None:
+      start = max(start, end - last)
+    rows = np.take(ring, np.arange(start, end) % n, axis=0)
+    # What the writer began while the copy was made is not whole.
+    start2 = max(start, self._announced - n)
+    return start2, rows[start2 - start:]
+
+  def lengths(self, rows):
+    """The cycle lengths of `held` rows, ns."""
+    return rows[:, self._stamps - 1] - rows[:, self.cycle_from]
+
+  def summary(self) -> Dict:
+    """Off the writing path: {'cycles' (ever), 'held', and per phase,
+    and for 'cycle' (stamp `cycle_from` to the last): {'mean', 'p50',
+    'p95', 'max'}} in ms over the rows held."""
+    _, rows = self.held()
+    out = {'cycles': self._written, 'held': len(rows)}
+    columns = {name: rows[:, k + 1] - rows[:, k]
+               for k, name in enumerate(self.phases)}
+    columns['cycle'] = self.lengths(rows)
+    for name, ns in columns.items():
+      ascending = np.sort(ns)
+      out[name] = {
+          'mean': float(ns.mean()) / _NS_PER_MS if len(ns) else 0.0,
+          'p50': nearest_rank(ascending, 0.5) / _NS_PER_MS,
+          'p95': nearest_rank(ascending, 0.95) / _NS_PER_MS,
+          'max': float(ascending[-1]) / _NS_PER_MS if len(ns) else 0.0}
+    return out
+
+
+def _covered(starts, ends, t):
+  """How much of (-inf, t] the disjoint ascending intervals cover."""
+  before = np.concatenate([[0], np.cumsum(ends - starts)])
+  i = np.searchsorted(starts, t, side='right')  # intervals begun by t
+  inside = np.minimum(t, ends[np.maximum(i - 1, 0)]) - starts[
+      np.maximum(i - 1, 0)]
+  return before[np.maximum(i - 1, 0)] + np.where(i > 0, inside, 0)
+
+
+def _overlap(intervals, t0, t1):
+  """ns of each cycle [t0, t1] that lie under the union of
+  `intervals` (an [m, 2] array in any order)."""
+  if not len(intervals):
+    return np.zeros(len(t0), np.int64)
+  order = np.argsort(intervals[:, 0])
+  starts, ends = intervals[order, 0], intervals[order, 1]
+  ends = np.maximum.accumulate(ends)  # the union, made disjoint:
+  starts = np.maximum(starts, np.concatenate([[starts[0]], ends[:-1]]))
+  ends = np.maximum(ends, starts)
+  return _covered(starts, ends, t1) - _covered(starts, ends, t0)
+
+
+def excess(record: CycleRecord, parks=None) -> Dict:
+  """What `record`'s mean pays over its median, and under what: the
+  cycles written since the last call are judged and the sums, which
+  are CUMULATIVE like the record's own, returned (all ns):
+
+    {'excess_ns', 'in_ns': {activity name: ..}, 'unnamed_ns',
+     'cycles_judged', 'cycles_lost'}
+
+  A cycle's excess is its length less the record's median (over its
+  newest 4,096 rows now), where that is positive. No threshold: one
+  cycle held for 145 ms and twenty cycles at twice their length both
+  count, and the record's `max` tells them apart. Of a cycle's excess, the part
+  charged to an activity's name is excess x (what of the cycle lay
+  under that name's parks / its length); `unnamed_ns` is the part
+  under no activity at all (by their union), so the names' parts and
+  `unnamed_ns` sum to `excess_ns` unless two activities ran at once.
+  Rows that a lap of the ring took before any call judged them are
+  counted (`cycles_lost`), not guessed. `parks`: (name, t0_ns, t1_ns)
+  rows to judge against instead of `activities()` (a test's)."""
+  with record._excess_lock:
+    first, rows = record.held(since=record._judged)
+    record._lost += first - record._judged
+    record._judged = first + len(rows)
+    if len(rows):
+      _, reference = record.held(last=_EXCESS_REFERENCE)
+      held = record.lengths(reference)
+      middle = min(len(held) - 1, len(held) // 2)  # `nearest_rank`'s
+      median = float(np.partition(held, middle)[middle])
+      t0 = rows[:, record.cycle_from]
+      t1 = rows[:, record._stamps - 1]
+      length = t1 - t0
+      over = np.maximum(length - median, 0.0)
+      share = over / np.maximum(length, 1)
+      if parks is None:
+        parks = activities(since_ns=int(t0.min()), now_ns=int(t1.max()))
+      by_name = {}
+      for name, a, b in parks:
+        by_name.setdefault(name, []).append((a, b))
+      every = np.asarray([(a, b) for _, a, b in parks],
+                         np.int64).reshape(-1, 2)
+      record._excess_ns += float(over.sum())
+      record._unnamed_ns += float(
+          (share * (length - _overlap(every, t0, t1))).sum())
+      for name, intervals in by_name.items():
+        under = _overlap(np.asarray(intervals, np.int64), t0, t1)
+        record._in_ns[name] = (record._in_ns.get(name, 0.0) +
+                               float((share * under).sum()))
+    return {'excess_ns': record._excess_ns,
+            'in_ns': dict(record._in_ns),
+            'unnamed_ns': record._unnamed_ns,
+            'cycles_judged': record._judged - record._lost,
+            'cycles_lost': record._lost}
+
+
+def excess_ms(prefix: str, *results: Dict) -> Dict:
+  """`excess` results, summed, as the flat keys a `stats()` carries:
+  `<prefix>excess_ms`, `<prefix>excess_ms_in_<activity>` a name,
+  `<prefix>excess_ms_unnamed`, `<prefix>cycles_lost`."""
+  names = sorted({name for r in results for name in r['in_ns']} |
+                 set(ACTIVITIES))
+  total = lambda key: sum(r[key] for r in results)  # noqa: E731
+  out = {prefix + 'excess_ms': total('excess_ns') / _NS_PER_MS}
+  for name in names:
+    out[f'{prefix}excess_ms_in_{name}'] = sum(
+        r['in_ns'].get(name, 0.0) for r in results) / _NS_PER_MS
+  out[prefix + 'excess_ms_unnamed'] = total('unnamed_ns') / _NS_PER_MS
+  out[prefix + 'cycles_lost'] = total('cycles_lost')
+  return out
 
 
 # --------------------------------------------------------------------

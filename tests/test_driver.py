@@ -255,7 +255,10 @@ SPAN_NAMES = {
     'inference/wait_batch', 'inference/dispatch', 'inference/readback',
     'inference/unpark', 'staging/wait_unrolls', 'staging/stage',
     'learner/wait_batch', 'learner/iteration', 'learner/step_dispatch',
-    'learner/publish', 'learner/summaries'}
+    'learner/publish', 'learner/summaries',
+    # An activity since PR 37: these runs checkpoint on the learner's
+    # thread (`Checkpointer.save`).
+    'learner/checkpoint'}
 
 
 def test_a_fleet_run_records_every_span_at_its_boundary(tmp_path,

@@ -391,6 +391,49 @@ def test_block_step_is_bitwise_the_pickled_step(env_class, kwargs):
     py_process.close_all([e._process for e in piped + envs])
 
 
+class SleepyEnv(FakeEnv):
+  """FakeEnv whose `step` takes `nap_ms` of its own."""
+
+  def __init__(self, nap_ms=2.0, **kw):
+    super().__init__(**kw)
+    self._nap = nap_ms / 1e3
+
+  def step(self, action):
+    import time
+    time.sleep(self._nap)
+    return super().step(action)
+
+
+def test_a_step_carries_the_childs_own_time_by_block_and_by_pipe():
+  """PR 37: the hosted env's worker times its `env.step` alone, on its
+  own clock: into its column of the block's `busy_ns`, or, for a call
+  down the pipe, behind the reply. A duration, so whoever reads it
+  needs no clock in common with the child."""
+  import time
+  kwargs = dict(height=8, width=8, episode_length=30)
+  kwargs_list = [dict(kwargs, seed=j, nap_ms=2.0 + 4.0 * j)
+                 for j in range(2)]
+  piped = ProxyEnv(PyProcess(SleepyEnv, kwargs_list[1],
+                             step_block=False).start())
+  envs, block = _attached(SleepyEnv, kwargs_list, rows=3)
+  try:
+    for env in envs + [piped]:
+      env.initial()
+    assert piped.step_busy_ns() < 2e6  # `initial` took no nap
+    for t in range(3):
+      t0 = time.perf_counter_ns()
+      _block_step(envs, block, 1 + t % 2, [0, 1])
+      round_trip = time.perf_counter_ns() - t0
+      # Each column its own child's, the slowest under the round trip.
+      assert 2e6 <= block.busy_ns[0] < 6e6 <= block.busy_ns[1]
+      assert block.busy_ns[1] <= round_trip
+      t0 = time.perf_counter_ns()
+      piped.step(0)
+      assert 6e6 <= piped.step_busy_ns() <= time.perf_counter_ns() - t0
+  finally:
+    py_process.close_all([e._process for e in envs + [piped]])
+
+
 def test_other_calls_ride_the_pipe_between_block_steps():
   """A pickled call (`prompt_block`: a block of tokens; `initial`; the
   whole `step`) between two steps through the block: raw bytes and

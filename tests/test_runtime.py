@@ -1167,6 +1167,114 @@ def _block_group_cases():
           for k in (1, 3, 32)]
 
 
+class _SleepyEnv(FakeEnv):
+  """FakeEnv whose `step` takes 2 ms of its own."""
+
+  def step(self, action):
+    time.sleep(0.002)
+    return super().step(action)
+
+
+class TestGroupStepRecord:
+  """PR 37: a group's steps in its always-on record, three stamps a
+  step and the slowest member's own time in its env; the fleet's
+  `stats()` sums them and lays the long ones beside the activities of
+  other threads."""
+
+  @pytest.mark.parametrize('hosting', ['block', 'pipe', 'in_process'])
+  def test_the_childs_own_step_time_reaches_the_record(self, hosting):
+    from scalable_agent_tpu.runtime import fleet as fleet_lib
+    from scalable_agent_tpu.runtime.actor import ActorGroup
+    T, k = 5, 3
+    kwargs_list = [dict(height=H, width=W, num_actions=A, seed=i)
+                   for i in range(k)]
+    policy = _ScriptedStatePolicy(cache=False)
+    if hosting == 'in_process':
+      group = ActorGroup([
+          Actor(_SleepyEnv(**kw), policy, policy.initial_core_state(), T)
+          for kw in kwargs_list])
+    else:
+      group = _hosted_group(_SleepyEnv, kwargs_list, policy, T,
+                            step_block=hosting == 'block')
+    try:
+      for _ in range(2):
+        group.unroll()
+      assert group._rollout.shared == (hosting == 'block')
+      counts = fleet_lib._step_counts([group.steps])
+      _, rows = group.steps.held()
+    finally:
+      group.close()
+    steps = counts['group_steps']
+    assert steps == 2 * T == len(rows)
+    # The slowest member's own 2 ms, every step; the step's env phase
+    # holds it (the children nap at once, envs in this process in turn).
+    assert (rows[:, 3] >= 2e6).all()
+    assert counts['step_env_child_ms'] >= 2.0 * steps
+    assert counts['step_env_ms'] >= counts['step_env_child_ms']
+    if hosting == 'in_process':
+      assert counts['step_env_ms'] >= 2.0 * k * steps
+    else:
+      assert counts['step_env_ms'] < 2.0 * k * steps
+    assert counts['step_ms'] == pytest.approx(
+        counts['step_policy_wait_ms'] + counts['step_env_ms'])
+    # Within an unroll one step's last stamp is the next one's first.
+    in_unroll = np.arange(1, steps) % T != 0
+    assert (rows[1:, 0] == rows[:-1, 2])[in_unroll].all()
+    assert (np.diff(rows[:, :3], axis=1) > 0).all()
+
+  def test_a_fleet_lays_its_long_steps_at_the_door_of_a_publish(self):
+    """One thread's fast steps, and a `learner/publish` that another
+    thread spends 50 ms in without letting go of the GIL: the steps
+    held up under it are the excess, and `stats()` says under what."""
+    from scalable_agent_tpu import telemetry
+    from scalable_agent_tpu.runtime.fleet import ActorFleet
+    policy = _ScriptedStatePolicy(cache=False)
+
+    def make_actor(i):
+      env = FakeEnv(height=H, width=W, num_actions=A, seed=i)
+      return env, None, Actor(env, policy, policy.initial_core_state(),
+                              unroll_length=50)
+
+    class _Sink:  # a buffer that never fills
+      closed = False
+
+      def put(self, unroll, timeout=None):
+        pass
+
+      def close(self):
+        self.closed = True
+
+    fleet = ActorFleet(make_actor, _Sink(), num_actors=1)
+    first = fleet.stats()
+    assert first['group_steps'] == 0 and first['step_ms'] == 0.0
+    assert first['step_excess_ms_in_learner/publish'] == 0.0
+    assert first['step_ms_p50'] == first['step_ms_max'] == 0.0
+    fleet.start()
+    try:
+      time.sleep(0.2)
+      opened = fleet.stats()
+      with telemetry.activity('learner/publish'):
+        deadline = time.perf_counter() + 0.05
+        while time.perf_counter() < deadline:  # holds the GIL
+          sum(range(1000))
+      time.sleep(0.1)
+      closed = fleet.stats()
+    finally:
+      fleet.stop()
+    delta = lambda key: closed[key] - opened[key]  # noqa: E731
+    assert delta('group_steps') > 100
+    # Half of the 50 ms and more, whatever else the machine did; no
+    # other activity was under way.
+    assert delta('step_excess_ms_in_learner/publish') >= 15.0
+    assert delta('step_excess_ms') >= delta(
+        'step_excess_ms_in_learner/publish')
+    assert delta('step_excess_ms_in_staging/stage') == 0.0
+    assert closed['step_ms_max'] >= 4.0 > closed['step_ms_p50']
+    assert closed['step_ms_p95'] >= closed['step_ms_p50'] > 0
+    # A thread that ended leaves its counts behind.
+    assert fleet.stats()['group_steps'] >= closed['group_steps']
+
+
 class TestGroupOnASharedBlock:
   """PR 33: hosted envs of a declared `step` spec step into a block in
   shared memory that their group owns; everything else stays as it
@@ -1178,7 +1286,6 @@ class TestGroupOnASharedBlock:
     """The same envs, the same seeds, the same policy: a group on the
     block and one on the pipe produce the same ActorOutputs, which are
     what each env produces in this process, alone."""
-    from scalable_agent_tpu import telemetry
     T = 7
     kwargs_list = [dict(kwargs, seed=i) for i in range(k)]
     policies = [_ScriptedStatePolicy(cache=False) for _ in range(3)]
@@ -1189,7 +1296,6 @@ class TestGroupOnASharedBlock:
                    policies[2].initial_core_state(), T,
                    num_action_repeats=2, level_name_id=i)
              for i, kw in enumerate(kwargs_list)]
-    counted = telemetry.registry().get('actors/block_steps').value
     try:
       for n in range(3):
         blocked, piped = on_block.unroll(), on_pipe.unroll()
@@ -1200,9 +1306,12 @@ class TestGroupOnASharedBlock:
       steps = [a._env._process.block_steps for a in on_block.actors]
       assert steps == [3 * T] * k
       assert not any(a._env._process.block_steps for a in on_pipe.actors)
-      # Counted once a group step, by k.
-      assert (telemetry.registry().get('actors/block_steps').value
-              == counted + 3 * T * k)
+      # The group's own record counts a step once, whatever k, and
+      # every step carries its slowest child's own time.
+      for group in (on_block, on_pipe):
+        assert group.steps.cycles == 3 * T
+        _, rows = group.steps.held()
+        assert (rows[:, -1] > 0).all()
       # What an unroll holds is its own: the next unroll reuses the
       # group's arrays and must not show through.
       held = blocked[0].env_outputs.observation[0].copy()
@@ -1216,7 +1325,6 @@ class TestGroupOnASharedBlock:
   def test_an_env_without_a_spec_stays_on_the_pipe(self):
     """Nothing declared, nothing to lay a block out from: the group
     steps by pickled calls, and the counters say so."""
-    from scalable_agent_tpu import telemetry
     T = 4
     kwargs_list = [dict(height=H, width=W, num_actions=A, seed=i)
                    for i in range(2)]
@@ -1225,17 +1333,12 @@ class TestGroupOnASharedBlock:
     alone = [Actor(FakeEnv(**kw), policy, policy.initial_core_state(),
                    T, num_action_repeats=2, level_name_id=i)
              for i, kw in enumerate(kwargs_list)]
-    counted = telemetry.registry().get('actors/block_steps').value
-    piped = telemetry.registry().get('actors/pipe_calls').value
     try:
       for unroll, actor in zip(group.unroll(), alone):
         _assert_unrolls_bitwise_equal(unroll, actor.unroll())
       processes = [a._env._process for a in group.actors]
       assert [p.block_steps for p in processes] == [0, 0]
       assert [p.pipe_calls for p in processes] == [1 + T] * 2
-      assert telemetry.registry().get('actors/block_steps').value == counted
-      assert (telemetry.registry().get('actors/pipe_calls').value
-              == piped + 2 * T)  # the `initial`s came before the reading
     finally:
       group.close()
 

@@ -557,12 +557,9 @@ class TestPackedCall:
     """The counters that say the mechanism engages are observed where
     the arrays cross: numpy leaves handed to the step, arrays fetched
     from it."""
-    from scalable_agent_tpu import telemetry
     cfg = Config(inference_min_batch=1, inference_max_batch=8,
                  inference_timeout_ms=5,
                  inference_state_cache=state_cache)
-    before = {name: telemetry.registry().get(name).value
-              for name in ('serving/h2d_buffers', 'serving/d2h_buffers')}
     server = InferenceServer(_AGENT, _PARAMS, cfg, seed=7)
     try:
       assert server.stats()['h2d_buffers_per_call'] == 0.0
@@ -575,11 +572,76 @@ class TestPackedCall:
     assert stats['calls'] == 8
     assert stats['h2d_buffers_per_call'] == 1.0
     assert stats['d2h_buffers_per_call'] == 1.0
-    # The warm-up call hands its buffer over too and fetches nothing.
-    assert (telemetry.registry().get('serving/h2d_buffers').value
-            - before['serving/h2d_buffers']) == 9
-    assert (telemetry.registry().get('serving/d2h_buffers').value
-            - before['serving/d2h_buffers']) == 8
+    # Two columns of the calls' cycle record (the warm-up call is no
+    # merged call and writes no row).
+    totals = server._cycles.totals()
+    assert (totals['cycles'], totals['h2d'], totals['d2h']) == (8, 8, 8)
+
+
+class TestCallCycleRecord:
+  """PR 37: every merged call leaves a row of five stamps in the
+  server's always-on record, THE source of its latency percentiles."""
+
+  @pytest.mark.parametrize('state_cache', [False, True],
+                           ids=['carry', 'cache'])
+  def test_four_phases_close_the_call_and_the_loop(self, state_cache):
+    from scalable_agent_tpu.runtime import inference
+    cfg = Config(inference_min_batch=1, inference_max_batch=8,
+                 inference_timeout_ms=5, inference_pipeline_depth=1,
+                 inference_state_cache=state_cache)
+    server = InferenceServer(_AGENT, _PARAMS, cfg, seed=7)
+    try:
+      first = server.stats()
+      # Every key a metric reads is there before the first call.
+      for phase in inference._CALL_PHASES:
+        assert first[f'call_{phase}_ms'] == 0.0
+      assert first['call_excess_ms'] == first['batcher_wait_ms'] == 0.0
+      assert first['call_excess_ms_in_learner/publish'] == 0.0
+      assert first['call_excess_ms_unnamed'] == 0.0
+      assert first['latency_p95_ms'] == 0.0
+      def settled():
+        # A call's row is written after its callers are unparked.
+        stats = server.stats()
+        while server._cycles.cycles < stats['calls']:
+          time.sleep(0.001)
+        return server.stats()
+
+      server.warmup(OBS, sizes=[3])
+      _group_traffic(server, 3, steps=5)  # whatever the first calls pay
+      opened, t0 = settled(), time.perf_counter()
+      _group_traffic(server, 3, steps=200, seed=1)  # a closed loop
+      wall_ms = (time.perf_counter() - t0) * 1e3
+      closed = settled()
+      _, rows = server._cycles.held()
+    finally:
+      server.close()
+    assert closed['calls'] - opened['calls'] == 200
+    stamps = rows[:, :5]
+    assert (np.diff(stamps, axis=1) >= 0).all()  # no phase is negative
+    # The three phases after `wait_batch` ARE the latency, call by call
+    # and in the sums; and the percentiles are the parent's arithmetic
+    # (`percentile_ms` of the ascending last 512) on these stamps.
+    lat_ms = (stamps[:, 4] - stamps[:, 1]) / 1e6
+    delta = lambda key: closed[key] - opened[key]  # noqa: E731
+    served = sum(delta(f'call_{phase}_ms')
+                 for phase in inference._CALL_PHASES[1:])
+    assert served == pytest.approx(lat_ms[-200:].sum(), rel=1e-9)
+    for q, key in [(0.5, 'latency_p50_ms'), (0.95, 'latency_p95_ms'),
+                   (0.99, 'latency_p99_ms')]:
+      assert closed[key] == round(inference.percentile_ms(
+          sorted(lat_ms.tolist()), q), 3)
+    # At depth 1 with one caller a call's wait begins where the call
+    # before was unparked (or where its own batch was in hand, if the
+    # caller came back before that stamp was taken): rows abut, and
+    # phases + wait_batch are the loop's wall time.
+    assert (stamps[1:, 0] >= np.minimum(stamps[:-1, 4],
+                                        stamps[1:, 1])).all()
+    assert served + delta('call_wait_batch_ms') == pytest.approx(
+        wall_ms, rel=0.05)
+    # What the caller sees of it, in `policy`'s park, lies between.
+    assert served < delta('batcher_wait_ms') < wall_ms
+    assert closed['call_excess_ms'] >= closed['call_excess_ms_unnamed'] >= 0
+    assert closed['call_cycles_lost'] == 0
 
 
 class _FakeChannel:

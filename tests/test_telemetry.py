@@ -901,3 +901,174 @@ def test_the_feed_pipeline_records_its_waits_and_its_staging(
   # Its next wait was still under way at the take: closed there.
   assert staging[-1][0] == 'staging/wait_unrolls'
   assert staging[-1][2] == taken['taken_ns']
+
+
+# --------------------------------------------------------------------
+# Cycle records, activities and the mean's excess (PR 37).
+# --------------------------------------------------------------------
+
+
+def test_a_cycle_record_wraps_and_keeps_its_cumulative_sums():
+  record = telemetry.CycleRecord(('wait', 'work'), extras=('rows',),
+                                 rows=8, cycle_from=1)
+  for c in range(21):  # two laps and five
+    t = 1_000 * c
+    record.write(t, t + 10, t + 10 + c, 3)
+  totals = record.totals()
+  assert totals == {'cycles': 21, 'wait_ns': 210,
+                    'work_ns': sum(range(21)), 'rows': 63}
+  first, rows = record.held()
+  assert first == 13 and len(rows) == 8  # the ring's, oldest first
+  assert rows[:, 0].tolist() == [1_000 * c for c in range(13, 21)]
+  assert record.held(since=19)[0] == 19 and len(record.held(last=2)[1]) == 2
+  summary = record.summary()
+  assert (summary['cycles'], summary['held']) == (21, 8)
+  # A cycle's length counts from `cycle_from`: the work, not the wait.
+  assert summary['cycle'] == summary['work']
+  assert summary['work']['max'] == pytest.approx(20 / 1e6)
+  assert summary['work']['p50'] == pytest.approx(17 / 1e6)  # nearest rank
+  assert summary['wait']['mean'] == pytest.approx(10 / 1e6)
+  empty = telemetry.CycleRecord(('a',)).summary()
+  assert empty['held'] == 0 and empty['cycle']['p95'] == 0.0
+
+
+def test_a_reader_never_takes_a_half_written_row_for_a_cycle():
+  """The writer laps a ring of 16 rows thousands of times while a
+  reader copies it: every row the reader gets is one the writer wrote
+  whole (its three stamps 7 apart, its extra their sum)."""
+  record = telemetry.CycleRecord(('a', 'b'), extras=('check',), rows=16)
+  stop = threading.Event()
+
+  def write():
+    c = 0
+    while not stop.is_set():
+      record.write(c, c + 7, c + 14, 3 * c + 21)
+      c += 1
+
+  writer = threading.Thread(target=write)
+  writer.start()
+  try:
+    seen = 0
+    deadline = time.monotonic() + 20
+    while seen < 20_000 and time.monotonic() < deadline:
+      first, rows = record.held()
+      assert (rows[:, 1] - rows[:, 0] == 7).all()
+      assert (rows[:, 2] - rows[:, 1] == 7).all()
+      assert (rows[:, 3] == rows[:, :3].sum(axis=1)).all()
+      assert (np.diff(rows[:, 0]) == 1).all()  # consecutive cycles
+      assert not len(rows) or rows[0, 0] == first
+      summary = record.summary()
+      if summary['held']:  # (a lap during the copy may leave none)
+        assert summary['a']['max'] == summary['b']['p50'] == 7 / 1e6
+      seen += len(rows)
+  finally:
+    stop.set()
+    writer.join()
+  assert seen >= 20_000 and record.cycles > 16
+
+
+def _ten_short_one_long(t0, t1):
+  """Ten cycles of 10 ns, then the cycle [t0, t1]: median 10."""
+  record = telemetry.CycleRecord(('step',), rows=64)
+  for c in range(10):
+    record.write(100 * c, 100 * c + 10)
+  record.write(t0, t1)
+  return record
+
+
+@pytest.mark.parametrize('parks,named,unnamed', [
+    # The long cycle wholly inside a park: all of its excess is there.
+    ([('learner/publish', 4_900, 5_500)], {'learner/publish': 100.0}, 0.0),
+    # A park wholly inside the long cycle: its share of the cycle.
+    ([('learner/publish', 5_022, 5_055)], {'learner/publish': 30.0}, 70.0),
+    # Two parks at once: each is charged what lay under it, `unnamed`
+    # what lay under neither (by their union: 5,011..5,077).
+    ([('learner/publish', 5_011, 5_066), ('staging/stage', 5_044, 5_077)],
+     {'learner/publish': 50.0, 'staging/stage': 30.0}, 40.0),
+    # None: the excess has no name.
+    ([], {}, 100.0),
+    # A park that ended before the cycle began, and one of a short
+    # cycle (no excess there to charge).
+    ([('learner/publish', 4_000, 4_990), ('staging/stage', 300, 310)],
+     {}, 100.0),
+], ids=['cycle_in_park', 'park_in_cycle', 'two_parks', 'none', 'elsewhere'])
+def test_excess_on_intervals_made_by_hand(parks, named, unnamed):
+  record = _ten_short_one_long(5_000, 5_110)  # 110 long: 100 over
+  found = telemetry.excess(record, parks=parks)
+  assert found['excess_ns'] == pytest.approx(100.0)
+  assert found['unnamed_ns'] == pytest.approx(unnamed)
+  for name in telemetry.ACTIVITIES:  # every known name, at zero too
+    assert found['in_ns'][name] == pytest.approx(named.get(name, 0.0))
+  assert (found['cycles_judged'], found['cycles_lost']) == (11, 0)
+  flat = telemetry.excess_ms('step_', found)
+  assert flat['step_excess_ms'] == pytest.approx(100.0 / 1e6)
+  assert flat['step_excess_ms_unnamed'] == pytest.approx(unnamed / 1e6)
+  assert flat['step_excess_ms_in_learner/publish'] == pytest.approx(
+      named.get('learner/publish', 0.0) / 1e6)
+  # Cumulative, and each cycle judged once: a second call adds nothing.
+  again = telemetry.excess(record, parks=parks)
+  assert again == found
+
+
+def test_excess_is_cumulative_and_counts_the_rows_a_lap_took():
+  record = telemetry.CycleRecord(('step',), rows=8)
+  for c in range(8):
+    record.write(100 * c, 100 * c + 10)
+  assert telemetry.excess(record, parks=[])['excess_ns'] == 0.0
+  record.write(1_000, 1_030)  # 20 over the median, under a park of its own
+  first = telemetry.excess(record, parks=[('x/y', 0, 2_000)])
+  assert first['excess_ns'] == first['in_ns']['x/y'] == pytest.approx(20.0)
+  # Twenty more before anyone asks: twelve are gone with the lap.
+  for c in range(20):
+    record.write(2_000 + 100 * c, 2_010 + 100 * c)
+  later = telemetry.excess(record, parks=[])
+  assert (later['cycles_lost'], later['cycles_judged']) == (12, 17)
+  assert later['excess_ns'] == pytest.approx(20.0)  # kept from before
+  assert telemetry.excess_ms('a_', first, later)['a_cycles_lost'] == 12
+
+
+def test_a_finished_activity_is_remembered_with_the_recorder_off(
+    recorder_off):
+  assert telemetry.take_spans() is None
+  before = len(telemetry.activities())
+  with telemetry.activity('learner/publish', id=7):
+    time.sleep(0.002)
+    under_way = telemetry.activities()[-1]
+    assert under_way[0] == 'learner/publish'  # closed at `now` meanwhile
+  with telemetry.park('learner/wait_batch'):  # a wait is not work
+    pass
+  with telemetry.span('actor/step'):
+    pass
+  kept = telemetry.activities()
+  assert len(kept) == before + 1
+  name, t0, t1 = kept[-1]
+  assert name == 'learner/publish' and t1 - t0 >= 2_000_000
+  assert t0 == under_way[1] and under_way[2] <= t1
+  # Ended twice (a `finally` after the block's own end): kept once.
+  site = telemetry.activity('learner/summaries')
+  site.end()
+  site.end()
+  assert [a[0] for a in telemetry.activities()[before:]] == [
+      'learner/publish', 'learner/summaries']
+
+
+def test_an_armed_capture_sees_an_activity_as_the_span_it_was(
+    recorder_off):
+  telemetry.arm_spans()
+  with telemetry.span('learner/step_dispatch', id=('learner', 3)):
+    with telemetry.activity('learner/publish'):
+      time.sleep(0.001)
+  with telemetry.activity('inference/prefill', id=5):
+    pass
+  taken = telemetry.take_spans()
+  rows = {row[0]: row for row in taken['spans']}
+  assert set(rows) == {'learner/step_dispatch', 'learner/publish',
+                       'inference/prefill'}
+  outer, publish = rows['learner/step_dispatch'], rows['learner/publish']
+  # Name, both stamps inside its parent's, thread, and the id its
+  # enclosing span gave it; its own id where it was given one.
+  assert outer[1] <= publish[1] < publish[2] <= outer[2]
+  assert publish[3] == threading.get_ident()
+  assert publish[4] == ('learner', 3) and rows['inference/prefill'][4] == 5
+  # And the ring has the same two, on the same clock.
+  assert telemetry.activities()[-2][1:] == (publish[1], publish[2])
