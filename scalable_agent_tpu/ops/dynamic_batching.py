@@ -135,9 +135,17 @@ class Batcher:
 
   # -- caller side --
 
-  def compute(self, arrays: Sequence[np.ndarray]) -> List[np.ndarray]:
-    """Submit rows, block until the computation thread answers."""
-    arrays = _as_contiguous(arrays)
+  @property
+  def closed(self) -> bool:
+    return self._closed
+
+  def check(self, arrays: Sequence[np.ndarray]) -> int:
+    """A request's rows, once its tensors are checked against the
+    family the first request fixed (`input_meta`; the first request
+    fixes it). `compute` checks every request so; a caller that
+    answers a request without the batcher (the inference server's
+    inline call) checks it the same way, and the meta its staging is
+    laid out by is the same."""
     if len(arrays) != self._num_tensors:
       raise ValueError(
           f'expected {self._num_tensors} tensors, got {len(arrays)}')
@@ -155,6 +163,12 @@ class Batcher:
             raise ValueError(
                 f'tensor mismatch: got {a.dtype}{a.shape[1:]}, '
                 f'expected {dtype}{trail}')
+    return rows
+
+  def compute(self, arrays: Sequence[np.ndarray]) -> List[np.ndarray]:
+    """Submit rows, block until the computation thread answers."""
+    arrays = _as_contiguous(arrays)
+    rows = self.check(arrays)
 
     i64 = ctypes.c_longlong
     n = self._num_tensors

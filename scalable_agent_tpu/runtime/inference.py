@@ -45,6 +45,14 @@ Round 7 overhaul (docs/INFERENCE.md) — three independent levers:
    device while batch k computes — the actor-plane mirror of
    `BatchPrefetcher`'s H2D/compute overlap. Depth 1 reproduces the
    old serialized assemble→dispatch→device_get loop.
+   The inline call (PR 39): a k-row request (an `ActorGroup`'s) whose
+   rows alone reach the merge floor and the pad floor (`pad_batch_to`),
+   with no shadow version live, has nothing to wait for and nothing to
+   overlap (its group cannot step until its actions are back), so its
+   caller's own thread stages it in a buffer of its own, dispatches it
+   under the same semaphore and locks and reads it back:
+   no batcher, no dispatch or completion thread, no copy of the
+   results (stats()['inline_calls']; docs/INFERENCE.md section 2).
 3. Zero-copy merge staging: the C++ batcher's merge-copy lands
    directly in preallocated per-bucket padded staging buffers
    (`Batcher.get_batch_into`) — no per-call np.concatenate, no
@@ -592,7 +600,12 @@ class InferenceServer:
   # The grow path swaps the arena (and its size) holding BOTH
   # _slot_lock and _arena_lock, so readers under either are safe.
   _num_slots: guarded_by('_slot_lock', '_arena_lock')
+  # PR 39: the calls' cycle record is written by the completion thread
+  # and by inline callers, each under _cycles_lock, which nests with no
+  # other lock.
+  _call_end: guarded_by('_cycles_lock')
   _calls: guarded_by('_stats_lock')
+  _inline_calls: guarded_by('_stats_lock')
   _merged_requests: guarded_by('_stats_lock')
   _params_version: guarded_by('_stats_lock')
   _publishes_skipped: guarded_by('_stats_lock')
@@ -634,6 +647,14 @@ class InferenceServer:
     if min_batch == 0:
       min_batch = max(fleet_size or 1, 1)
     self._min_batch = min(min_batch, config.inference_max_batch)
+    # A request of this many rows fills both the merge floor and the
+    # padded bucket's floor alone: no other caller's rows could join
+    # its call without a wait or a larger bucket, so its caller serves
+    # it (`_call_inline`). Groups under it ride the batcher and merge:
+    # an eval fleet's groups under `pad_batch_to`, a fleet's groups
+    # under the auto floor.
+    self._inline_rows = min(max(self._min_batch, pad_batch_to or 1),
+                            config.inference_max_batch)
     self._agent = agent
     # One observation is `num_obs` arrays (the agent says which); one
     # session's recurrent state is a pytree of `[1, ...]` leaves.
@@ -728,6 +749,7 @@ class InferenceServer:
     self._shadow_divergence = 0.0
     self._aot_misses = 0
     self._calls = 0
+    self._inline_calls = 0
     self._merged_requests = 0
     self._batcher_requests = 0
     self._params_version = 0
@@ -751,6 +773,11 @@ class InferenceServer:
     # cumulative `call_*_ms` and of `serving/latency_ms`.
     self._cycles = telemetry.CycleRecord(
         _CALL_PHASES, extras=_CALL_EXTRAS, cycle_from=_IN_HAND)
+    # It has one writer at a time: the completion thread and inline
+    # callers write under this lock. `_call_end`: the newest row's
+    # last stamp, where the next call's wait began at the earliest.
+    self._cycles_lock = make_lock('inference._cycles_lock')
+    self._call_end = 0
     # Callers' time parked in `policy`'s `compute`, ns (unlocked, as
     # `_batcher_requests` is).
     self._batcher_wait_ns = 0
@@ -867,6 +894,7 @@ class InferenceServer:
     # FIFO order and unparks the callers. The semaphore bounds
     # dispatched-but-uncompleted batches at `depth`. ---
     self._staging = {}        # padded size -> ring of buffer lists
+    self._inline_staging = threading.local()  # padded size -> _Staging
     self._staging_calls = {}  # padded size -> calls (ring index)
     self._batcher = dynamic_batching.Batcher(
         num_tensors=num_batch_args,
@@ -1152,10 +1180,11 @@ class InferenceServer:
     Per padded bucket, a ring of depth+1 preallocated positions, each
     ONE flat buffer and the per-input `[padded, ...]` views of it the
     batcher's merge-copy lands in (`_Staging`): with at most `depth`
-    batches dispatched-but-uncompleted (the semaphore) and completions
-    released in FIFO order, a ring slot is reused only after the batch
-    that last used it has completed — its host buffer is free to
-    overwrite."""
+    calls dispatched-but-uncompleted (the semaphore, which inline calls
+    take too) and merged calls completed in FIFO order, a ring slot is
+    reused only after the batch that last used it has completed — its
+    host buffer is free to overwrite. Only the dispatch thread takes
+    from the rings."""
     padded = self._padded_size(total_rows)
     ring = self._staging.get(padded)
     if ring is None:
@@ -1166,6 +1195,20 @@ class InferenceServer:
     i = self._staging_calls[padded] % len(ring)
     self._staging_calls[padded] += 1
     return ring[i]
+
+  def _inline_staging_for(self, rows):
+    """The calling thread's own staging for an inline call of `rows`
+    rows, one a padded bucket. A caller has one call at a time and
+    reads it back before it returns, so its buffer is free to overwrite
+    at its next call: no ring, no lock, and inline calls that complete
+    out of order never touch the dispatch thread's rings."""
+    padded = self._padded_size(rows)
+    own = self._inline_staging.__dict__
+    staging = own.get(padded)
+    if staging is None:
+      staging = own[padded] = _Staging(packing.Layout.of_rows(
+          self._batcher.input_meta(), padded))
+    return staging
 
   def _aot_lookup(self, params, layout):
     """The pre-compiled serving executable for this (padded bucket's
@@ -1219,6 +1262,108 @@ class InferenceServer:
       self._key, out = fn(params, self._key, packed, *static)
       return out, shadow_out, crossing
 
+  def _account(self, staging, n, inline=False):
+    """A call's accounting, whichever path runs it: its pad rows
+    pointed out of range, then the call, its rows, the `done` rows
+    that reset their session's state in-graph and the cached tokens
+    its rows read."""
+    if self._state_cache:
+      # The staging ring reuses buffers: rows [n:] may hold slot
+      # ids from an earlier (larger) merge — point them out of
+      # range so the in-graph scatter drops them. The sentinel is
+      # a constant (not num_slots): a concurrent 'grow' admission
+      # must not turn a just-stamped pad id into a live slot.
+      staging[0][n:] = _PAD_SLOT_ID
+    resets = int(np.count_nonzero(
+        staging[3 if self._state_cache else 2][:n]))
+    with self._stats_lock:
+      self._calls += 1
+      self._inline_calls += inline
+      self._merged_requests += n
+      self._state_resets += resets
+      if self._cache_capacity:
+        # Each live row reads its cache up to the token this call
+        # writes: its position (0 again where `done`) and one.
+        slots = staging[0][:n]
+        reads = np.where(staging[3][:n], 0, self._slot_pos[slots]) + 1
+        self._slot_pos[slots] = reads
+        cache_reads = int(np.sum(np.minimum(
+            reads, self._cache_capacity)))
+        self._cache_tokens_read += cache_reads
+        # A layer that keeps a ring of the episode's last tokens
+        # beside the cache reads that many of them at most.
+        window_reads = int(np.sum(np.minimum(
+            reads, self._cache_window)))
+        self._window_tokens_read += window_reads
+
+  def _note_dispatched(self):
+    with self._stats_lock:
+      self._inflight += 1
+      self._inflight_peak = max(self._inflight_peak, self._inflight)
+
+  @staticmethod
+  def _fetched(payload):
+    """(devices the call spanned, arrays to fetch) of a call's outputs,
+    read before `device_get` turns them into host numpy: the
+    sharded-eval contract's observability, and the d2h column."""
+    try:
+      devices = len(payload.sharding.device_set)
+    except Exception:
+      devices = 1
+    return devices, len(jax.tree_util.tree_leaves(payload))
+
+  def _read_back(self, payload, layout, call_id=None):
+    """A dispatched call's outputs as host views, its counters summed.
+    ONE device_get of ONE array: the step packed its outputs (each
+    separate device→host readback is a full round trip); the host
+    reads them as views of it, by the layout the program noted when it
+    was traced."""
+    with telemetry.span('inference/readback', id=call_id):
+      host = packing.host_views(jax.device_get(payload),
+                                self._out_layouts[layout])
+    if self._counter_names:
+      # The call's counters rode its readback, behind its outputs.
+      split = len(host) - len(self._counter_names)
+      host, counts = host[:split], host[split:]
+      with self._stats_lock:
+        for name, count in zip(self._counter_names, counts):
+          self._call_counts[name] += int(count)
+    return host
+
+  def _fail_call(self, error, answer):
+    """A failed execution poisons everything CHAINED from its outputs —
+    the device key, and in cache mode the arena — which _dispatch
+    already swapped in. Re-anchor them BEFORE `answer(message)` reaches
+    the call's callers: an answered caller retries immediately, and
+    that retry's dispatch must never inherit the poisoned chain (on a
+    loaded 1-core host the retry used to win the race and fail on the
+    poisoned key). The answer is in the finally so a recovery failure
+    can't strand callers."""
+    try:
+      self._recover_chain()
+    finally:
+      answer(f'{type(error).__name__}: {error}')
+
+  def _close_call(self, t_wait, t_hand, t_dispatched, t_read, t_unparked,
+                  crossing, fetched, devices):
+    """A dispatched call's end, whichever path ran it: its row of
+    `_cycles` (five stamps: its wait's begin, batch in hand, jitted call
+    returned, host views in hand, callers answered), its latency, the
+    in-flight count. The wait's begin is moved up to the newest row's
+    end where it began before that (which it always does at pipeline
+    depth 1; an inline call gives 0: its wait begins there): the four
+    phases of consecutive calls then do not overlap, and sum to the
+    cycle."""
+    with self._cycles_lock:
+      began = max(t_wait, self._call_end) or t_hand
+      self._cycles.write(min(began, t_hand), t_hand, t_dispatched, t_read,
+                         t_unparked, crossing, fetched)
+      self._call_end = max(self._call_end, t_unparked)
+    _SERVE_LATENCY.observe((t_unparked - t_hand) / 1e6)
+    with self._stats_lock:
+      self._inflight -= 1
+      self._devices_last_call = devices
+
   def _dispatch_loop(self):
     while True:
       try:
@@ -1245,34 +1390,7 @@ class InferenceServer:
       t_hand = time.perf_counter_ns()
       dispatch = telemetry.span('inference/dispatch', id=batch_id)
       try:
-        if self._state_cache:
-          # The staging ring reuses buffers: rows [n:] may hold slot
-          # ids from an earlier (larger) merge — point them out of
-          # range so the in-graph scatter drops them. The sentinel is
-          # a constant (not num_slots): a concurrent 'grow' admission
-          # must not turn a just-stamped pad id into a live slot.
-          bufs[0][n:] = _PAD_SLOT_ID
-        # `done` rows reset their session's state in-graph.
-        resets = int(np.count_nonzero(
-            bufs[3 if self._state_cache else 2][:n]))
-        with self._stats_lock:
-          self._calls += 1
-          self._merged_requests += n
-          self._state_resets += resets
-          if self._cache_capacity:
-            # Each live row reads its cache up to the token this call
-            # writes: its position (0 again where `done`) and one.
-            slots = bufs[0][:n]
-            reads = np.where(bufs[3][:n], 0, self._slot_pos[slots]) + 1
-            self._slot_pos[slots] = reads
-            cache_reads = int(np.sum(np.minimum(
-                reads, self._cache_capacity)))
-            self._cache_tokens_read += cache_reads
-            # A layer that keeps a ring of the episode's last tokens
-            # beside the cache reads that many of them at most.
-            window_reads = int(np.sum(np.minimum(
-                reads, self._cache_window)))
-            self._window_tokens_read += window_reads
+        self._account(bufs, n)
         with self._params_lock:
           params, _ = self._pick_live_locked()
           shadow_params = self._pick_shadow_locked()
@@ -1282,16 +1400,13 @@ class InferenceServer:
               params, bufs, shadow_params)
           # The jitted call has returned: the `dispatch` phase ends.
           t_dispatched = time.perf_counter_ns()
-          with self._stats_lock:
-            self._inflight += 1
-            self._inflight_peak = max(self._inflight_peak,
-                                      self._inflight)
+          self._note_dispatched()
         except BaseException:
           self._sem.release()
           raise
         self._completion_q.put(
             (batch_id, n, (wait.t0, t_hand, t_dispatched), crossing,
-             payload, shadow_out, bufs.layout))
+             payload, shadow_out, bufs))
       except Exception as e:  # propagate to the parked callers
         self._batcher.set_error(batch_id, f'{type(e).__name__}: {e}')
       finally:
@@ -1299,57 +1414,27 @@ class InferenceServer:
 
   def _completion_loop(self):
     """Reads each dispatched call's result back, in order, unparks
-    its callers and writes the call's row of `_cycles`: the dispatch
-    thread's three stamps (its wait's begin, batch in hand, jitted
-    call returned) and this thread's two (host views in hand, callers
-    unparked). The first is moved up to the call before's last where
-    the wait began while that call was still in flight, which it
-    always does at pipeline depth 1: the four phases of consecutive
-    calls then do not overlap, and sum to the cycle. ONE phase from
-    the jitted call's return to the host views: a `block_until_ready`
+    its callers and closes the call (`_close_call`) with the dispatch
+    thread's three stamps and this thread's two. ONE phase from the
+    jitted call's return to the host views: a `block_until_ready`
     before the `device_get` would part the launch and the device's
     time from the copy's, and was measured (PERF.md section 6, PR 37:
     in_flight 1.79 ms and readback 0.51 of `fleet32`'s mean call) at
     one more wake-up a call, 4.8% of `policy_call_p50_ms`: not kept."""
-    t_unparked = 0
     while True:
       item = self._completion_q.get()
       if item is None:
         return
       (batch_id, n, (t_wait, t_hand, t_dispatched), crossing, payload,
-       shadow_out, layout) = item
+       shadow_out, staging) = item
       t_read = None
-      fetched = 0
-      t_wait = min(max(t_wait, t_unparked), t_hand)
+      devices, fetched = self._fetched(payload)
       try:
-        # Observability for the sharded-eval contract: how many
-        # devices the last merged call actually spanned (read before
-        # device_get turns the arrays into host numpy).
-        try:
-          devices = len(payload.sharding.device_set)
-        except Exception:
-          devices = 1
-        # ONE device_get of ONE array: the step packed its outputs
-        # (each separate device→host readback is a full round trip);
-        # the host reads them as views of it, by the layout the
-        # program noted when it was traced.
-        fetched = len(jax.tree_util.tree_leaves(payload))
-        with telemetry.span('inference/readback', id=batch_id):
-          host = packing.host_views(jax.device_get(payload),
-                                    self._out_layouts[layout])
+        host = self._read_back(payload, staging.layout, batch_id)
         t_read = time.perf_counter_ns()
-        counts = ()
-        if self._counter_names:
-          # The call's counters rode its readback, behind its outputs.
-          split = len(host) - len(self._counter_names)
-          host, counts = host[:split], host[split:]
         with telemetry.span('inference/unpark', id=batch_id):
           self._batcher.set_outputs(batch_id, [o[:n] for o in host])
         t_unparked = time.perf_counter_ns()
-        if counts:
-          with self._stats_lock:
-            for name, count in zip(self._counter_names, counts):
-              self._call_counts[name] += int(count)
         if shadow_out is not None:
           # Shadow scoring AFTER the callers are answered: the gauge
           # must never add device_get latency to the live path. Logits
@@ -1373,32 +1458,71 @@ class InferenceServer:
           except Exception:
             log.exception('inference: shadow scoring failed')
       except Exception as e:
-        # A failed execution poisons everything CHAINED from its
-        # outputs — the device key, and in cache mode the arena —
-        # which _dispatch already swapped in. Re-anchor them BEFORE
-        # answering the parked callers: an unparked caller retries
-        # immediately, and that retry's dispatch must never inherit
-        # the poisoned chain (on a loaded 1-core host the retry used
-        # to win the race and fail on the poisoned key). set_error is
-        # in the finally so a recovery failure can't strand callers.
-        try:
-          self._recover_chain()
-        finally:
+
+        def answer(message):
           try:
-            self._batcher.set_error(batch_id, f'{type(e).__name__}: {e}')
+            self._batcher.set_error(batch_id, message)
           except Exception:
             pass
-          # A failed call's row ends where its callers had their error.
-          t_unparked = time.perf_counter_ns()
-          t_read = t_read or t_unparked
+
+        self._fail_call(e, answer)
+        # A failed call's row ends where its callers had their error.
+        t_unparked = time.perf_counter_ns()
+        t_read = t_read or t_unparked
       finally:
         self._sem.release()
-      self._cycles.write(t_wait, t_hand, t_dispatched, t_read,
-                         t_unparked, crossing, fetched)
-      _SERVE_LATENCY.observe((t_unparked - t_hand) / 1e6)
-      with self._stats_lock:
-        self._inflight -= 1
-        self._devices_last_call = devices
+      self._close_call(t_wait, t_hand, t_dispatched, t_read, t_unparked,
+                       crossing, fetched, devices)
+
+  def _call_inline(self, inputs):
+    """One k-row request whose rows alone fill the merge, served on its
+    caller's thread (module docstring, 2.): staged into the caller's
+    own buffer (`_inline_staging_for`), accounted, dispatched under the same semaphore and
+    locks as a merged call, read back with one `device_get`. Returns
+    views of what it fetched, or None where a shadow version is live:
+    shadow scoring follows the callers' answer, on the batched path.
+    Any failure raises `BatcherError`, the batched path's answer to its
+    callers; a failed execution re-anchors the chain first."""
+    n = self._batcher.check(inputs)
+    with self._params_lock:
+      if self._shadow_entry_locked() is not None:
+        return None
+      params, _ = self._pick_live_locked()
+    self._sem.acquire()
+    try:
+      staging = self._inline_staging_for(n)
+      for view, rows in zip(staging, inputs):
+        view[:n] = rows
+      t_hand = time.perf_counter_ns()
+      self._account(staging, n, inline=True)
+      with telemetry.span('inference/dispatch'):
+        payload, _, crossing = self._dispatch(params, staging)
+      t_dispatched = time.perf_counter_ns()
+    except BaseException as e:
+      self._sem.release()
+      if not isinstance(e, Exception):
+        raise
+      raise dynamic_batching.BatcherError(
+          f'{type(e).__name__}: {e}') from e
+    self._note_dispatched()
+    devices, fetched = self._fetched(payload)
+    t_read = None
+    try:
+      host = self._read_back(payload, staging.layout)
+      t_read = time.perf_counter_ns()
+      with telemetry.span('inference/unpark'):  # the answer: no copy
+        return [o[:n] for o in host]
+    except Exception as e:
+
+      def answer(message):
+        raise dynamic_batching.BatcherError(message) from e
+
+      self._fail_call(e, answer)
+    finally:
+      t_unparked = time.perf_counter_ns()
+      self._sem.release()
+      self._close_call(0, t_hand, t_dispatched, t_read or t_unparked,
+                       t_unparked, crossing, fetched, devices)
 
   def _recover_chain(self):
     """Re-anchor the device-chained state after a failed execution.
@@ -1546,7 +1670,8 @@ class InferenceServer:
     records" and docs/INFERENCE.md list every key. By group:
 
     - the merge: `calls`, `requests` (rows), `batcher_requests`
-      (policy() calls), `mean_batch`, `pipeline_depth`,
+      (policy() calls), `inline_calls` (calls answered on their
+      caller's thread, PR 39), `mean_batch`, `pipeline_depth`,
       `inflight_peak`, `devices_last_call`, `chain_recoveries`,
       `unjoined_threads`; `batcher_wait_ms` (cumulative: the callers'
       time parked in the batcher);
@@ -1587,6 +1712,7 @@ class InferenceServer:
     call_totals = self._cycles.totals()
     with self._stats_lock:
       calls, reqs = self._calls, self._merged_requests
+      inline_calls = self._inline_calls
       devices = self._devices_last_call
       version = self._params_version
       skipped = self._publishes_skipped
@@ -1628,6 +1754,9 @@ class InferenceServer:
         # policy() calls that carried them: rows per call is 1 for
         # lone actors, k for an ActorGroup of k.
         'batcher_requests': self._batcher_requests,
+        # Calls answered on their caller's thread, of `calls`: a
+        # group's request that alone filled the merge and pad floors.
+        'inline_calls': inline_calls,
         'mean_batch': (reqs / calls) if calls else 0.0,
         'params_version': version,
         'publishes_skipped': skipped,
@@ -1740,16 +1869,25 @@ class InferenceServer:
     entry.tick = self._serve_tick
     return entry.params, entry.key
 
-  def _pick_shadow_locked(self):
-    """The shadow version's params for this merged call, or None —
-    sampled at serving_shadow_fraction by the same accumulator
-    scheme. The shadow is set_shadow's key, else the newest non-live
-    resident; never the live entry (zero divergence by construction
-    would only dilute the gauge)."""
+  def _shadow_entry_locked(self):
+    """The shadow version's entry while one is live, else None: a
+    fraction is set and there is a version to replay against,
+    set_shadow's key, else the newest non-live resident; never the
+    live entry (zero divergence by construction would only dilute the
+    gauge)."""
     if self._shadow_fraction <= 0.0:
       return None
     entry = self._entry_for_locked(self._shadow_key)
     if entry is None or entry.key == self._live_key:
+      return None
+    return entry
+
+  def _pick_shadow_locked(self):
+    """The shadow version's params for this merged call, or None —
+    sampled at serving_shadow_fraction by the same accumulator
+    scheme."""
+    entry = self._shadow_entry_locked()
+    if entry is None:
       return None
     self._shadow_acc += self._shadow_fraction
     if self._shadow_acc < 1.0:
@@ -2014,7 +2152,9 @@ class InferenceServer:
     leaf of `env_output` and of the returned AgentOutput has a leading
     axis of k, and the k rows ride the batcher as ONE request (one
     park, one wake, one copy of the results), never split across
-    merged calls. Row by row the two forms compute the same.
+    merged calls; where they alone fill the merge and pad floors they
+    are ONE call on this thread, and the outputs are read-only views of
+    its readback. Row by row the two forms compute the same.
 
     Carry-passing mode: core_state is the numeric carry, a pytree of
     `[1, ...]` leaves (`[k, ...]` for k rows; the LSTM's `(c, h)`),
@@ -2057,7 +2197,17 @@ class InferenceServer:
     self._batcher_requests += 1
     t0 = time.perf_counter_ns()
     with telemetry.span('batcher/compute'):
-      outs = self._batcher.compute(inputs)
+      # A group's request that alone fills the merge and pad floors has
+      # nothing to merge with or wait for: its own thread serves it
+      # (module docstring, 2.). A lone actor's row, a group under either
+      # floor and any call while a shadow version is live ride the
+      # batcher.
+      outs = None
+      if (grouped and self._inline_rows <= len(inputs[0]) <= self._max_batch
+          and not self._batcher.closed):
+        outs = self._call_inline(inputs)
+      if outs is None:
+        outs = self._batcher.compute(inputs)
     self._batcher_wait_ns += time.perf_counter_ns() - t0
     if not self._state_cache:
       new_state = jax.tree_util.tree_unflatten(self._state_treedef,

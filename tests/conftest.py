@@ -64,9 +64,16 @@ def pytest_configure(config):
 # CELL'S OWN ENTRY (`test_new_entries_keep_to_the_contract`,
 # `test_the_cells_before_keep_their_entries`), which the next cell
 # does not break.
+# PR 39 appended a metric after PR 37's thirteen, which
+# tests/benchmark/test_cycle_metrics.py reads as `per_layer[-13:]`:
+# marked as well; tests/benchmark/test_inline_call_metric.py holds every
+# assertion of it from the block's first entry
+# (`test_pr37_entries_keep_their_place_from_their_first`).
 _OUTDATED = ('test_brumby_cell.py::test_new_entries_keep_to_the_contract',
              'test_dots_cell.py::test_new_entries_keep_to_the_contract',
-             'test_dots_cell.py::test_the_cell_before_keeps_its_entries')
+             'test_dots_cell.py::test_the_cell_before_keeps_its_entries',
+             'test_cycle_metrics.py::'
+             'test_new_entries_are_appended_and_keep_to_the_contract')
 
 
 def pytest_collection_modifyitems(items):

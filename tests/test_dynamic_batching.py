@@ -193,6 +193,30 @@ class TestErrors:
     finally:
       f.close()
 
+  def test_check_holds_a_request_to_the_family_compute_does(self):
+    """`Batcher.check` (PR 39: what a server that answers a request
+    without the batcher checks it by): the first request fixes the
+    family `input_meta` reports and a later compute is held to it."""
+    b = db.Batcher(num_tensors=2, maximum_batch_size=8, timeout_ms=10)
+    try:
+      assert b.input_meta() is None
+      assert b.check([np.zeros((3, 2), np.float32),
+                      np.zeros((3,), np.int32)]) == 3
+      assert b.input_meta() == [(np.dtype(np.float32), (2,)),
+                                (np.dtype(np.int32), ())]
+      with pytest.raises(ValueError, match='expected 2 tensors'):
+        b.check([np.zeros((3, 2), np.float32)])
+      with pytest.raises(ValueError, match='inconsistent leading'):
+        b.check([np.zeros((3, 2), np.float32), np.zeros((2,), np.int32)])
+      with pytest.raises(ValueError, match='mismatch'):
+        b.compute([np.zeros((1, 4), np.float32),
+                   np.zeros((1,), np.int32)])
+      assert not b.closed
+      b.close()
+      assert b.closed
+    finally:
+      b.close()
+
   def test_scalar_input_rejected(self):
     @db.batch_fn
     def f(a):

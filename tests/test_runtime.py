@@ -439,6 +439,22 @@ class TestInferenceServer:
     finally:
       server.close()
 
+class _Poisoned:
+  """Stand-in for an array whose execution failed: any host
+  materialization or readiness check raises (jax semantics for
+  outputs of a failed computation)."""
+
+  def block_until_ready(self):
+    raise RuntimeError('computation failed (simulated)')
+
+  def __array__(self, dtype=None):
+    raise RuntimeError('computation failed (simulated)')
+
+
+class _Interrupt(BaseException):
+  """Not an Exception: what a KeyboardInterrupt is to a call."""
+
+
 def _cfg_variant(**kw):
   base = dict(batch_size=2, unroll_length=6, num_action_repeats=1,
               inference_min_batch=1, inference_max_batch=8,
@@ -762,17 +778,6 @@ class TestInferencePlaneStats:
     outputs of the failed step — the server re-anchors them instead of
     serving the poisoned chain to every later call forever."""
     from scalable_agent_tpu.ops.dynamic_batching import BatcherError
-
-    class _Poisoned:
-      """Stand-in for an array whose execution failed: any host
-      materialization or readiness check raises (jax semantics for
-      outputs of a failed computation)."""
-
-      def block_until_ready(self):
-        raise RuntimeError('computation failed (simulated)')
-
-      def __array__(self, dtype=None):
-        raise RuntimeError('computation failed (simulated)')
 
     for cache in (False, True):
       agent, params, cfg = _mk(**_cfg_variant(
@@ -1167,11 +1172,16 @@ def _block_group_cases():
           for k in (1, 3, 32)]
 
 
+# Long beside what a loaded host adds to a hosted step's exchange
+# (~4 ms a step seen under a full tier-1 run, where 2 ms naps failed).
+_NAP_MS = 10.0
+
+
 class _SleepyEnv(FakeEnv):
-  """FakeEnv whose `step` takes 2 ms of its own."""
+  """FakeEnv whose `step` takes `_NAP_MS` of its own."""
 
   def step(self, action):
-    time.sleep(0.002)
+    time.sleep(_NAP_MS / 1e3)
     return super().step(action)
 
 
@@ -1206,15 +1216,15 @@ class TestGroupStepRecord:
       group.close()
     steps = counts['group_steps']
     assert steps == 2 * T == len(rows)
-    # The slowest member's own 2 ms, every step; the step's env phase
+    # The slowest member's own nap, every step; the step's env phase
     # holds it (the children nap at once, envs in this process in turn).
-    assert (rows[:, 3] >= 2e6).all()
-    assert counts['step_env_child_ms'] >= 2.0 * steps
+    assert (rows[:, 3] >= _NAP_MS * 1e6).all()
+    assert counts['step_env_child_ms'] >= _NAP_MS * steps
     assert counts['step_env_ms'] >= counts['step_env_child_ms']
     if hosting == 'in_process':
-      assert counts['step_env_ms'] >= 2.0 * k * steps
+      assert counts['step_env_ms'] >= _NAP_MS * k * steps
     else:
-      assert counts['step_env_ms'] < 2.0 * k * steps
+      assert counts['step_env_ms'] < _NAP_MS * k * steps
     assert counts['step_ms'] == pytest.approx(
         counts['step_policy_wait_ms'] + counts['step_env_ms'])
     # Within an unroll one step's last stamp is the next one's first.
@@ -1515,17 +1525,24 @@ class TestGroupedPolicyCall:
     finally:
       server.close()
 
-  def test_mixed_row_counts_under_contention(self):
+  @pytest.mark.parametrize('inline', [True, False],
+                           ids=['groups_inline', 'all_batched'])
+  def test_mixed_row_counts_under_contention(self, inline):
     """More callers than cores, scalar and k-row requests mixed, a
     short switch interval: every caller gets ITS rows back (the row
     count and the echo of its own frame's logits shape), and the two
-    counters add up: rows in `requests`, calls in `batcher_requests`."""
+    counters add up: rows in `requests`, calls in `batcher_requests`.
+    At a floor of 1 the groups' requests go inline; with the inline
+    call taken away every request rides the batcher, which merges
+    k-row requests with scalar ones."""
     import sys
     agent, params, _ = _mk()
     cfg = Config(**_cfg_variant(inference_min_batch=1,
                                 inference_max_batch=16,
                                 inference_timeout_ms=2))
     server = InferenceServer(agent, params, cfg, seed=3)
+    if not inline:
+      server._call_inline = lambda inputs: None  # the batched path
     env_out = _scripted_inputs(4)
     widths = [0, 1, 2, 3] * 3  # 0: the scalar form
     calls_each, errors = 15, []
@@ -1567,6 +1584,282 @@ class TestGroupedPolicyCall:
       assert stats['batcher_requests'] == len(widths) * calls_each
       assert stats['requests'] == calls_each * sum(
           max(k, 1) for k in widths)
+      assert (stats['inline_calls'] > 0) == inline
     finally:
       sys.setswitchinterval(interval)
       server.close()
+
+
+def _group_request(k, t=0, seed=0, done_rows=()):
+  """The k-row form of `policy`'s env output: row j is
+  `_scripted_inputs(.., seed + j)` at step t, `done` where listed."""
+  rows = [_scripted_inputs(t + 1, seed=seed + j)(t) for j in range(k)]
+  stacked = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *rows)
+  done = np.zeros((k,), bool)
+  done[list(done_rows)] = True
+  return stacked._replace(done=done)
+
+
+class TestInlineCall:
+  """PR 39: a group's request whose rows alone fill the merge floor is
+  ONE call on its caller's thread (runtime/inference.py ::
+  `_call_inline`), with the batched path's accounting, locks, ring and
+  answers; every other request rides the batcher as before."""
+
+  @pytest.mark.parametrize('cache', [False, True],
+                           ids=['carry_passing', 'state_cache'])
+  def test_inline_and_batched_paths_answer_alike(self, cache):
+    """One seeded server, one 3-row request at floor 3 (a pad row in
+    the bucket of 4), `done` resets on two steps: the inline path and
+    the batched path give the same actions, logits, baselines, new
+    carries and counts, bit for bit."""
+    k = 3
+    agent, params, _ = _mk()
+    cfg = Config(**_cfg_variant(inference_state_cache=cache,
+                                inference_min_batch=k))
+    server = InferenceServer(agent, params, cfg, seed=3)
+    try:
+      rng = np.random.RandomState(5)
+      prev0 = rng.randint(0, A, (k,)).astype(np.int32)
+      carries = [tuple(rng.randn(1, 256).astype(np.float32)
+                       for _ in range(2)) for _ in range(k)]
+      handles = ([server.initial_core_state() for _ in range(k)]
+                 if cache else None)
+      requests = [_group_request(k, t, done_rows=done)
+                  for t, done in enumerate([(), (1,), (), (0, 2)])]
+
+      def run():
+        with server._key_lock:
+          server._key = jax.random.PRNGKey(17)
+        if cache:
+          for handle, carry in zip(handles, carries):
+            handle.write(carry)
+          core = handles
+        else:
+          core = tuple(np.concatenate(xs) for xs in zip(*carries))
+        opened, answers, prev = server.stats(), [], prev0
+        for env_out in requests:
+          out, core = server.policy(prev, env_out, core)
+          carry = ([h.snapshot() for h in handles] if cache else core)
+          answers.append(jax.tree_util.tree_map(
+              np.array, (tuple(out), carry)))
+          prev = np.asarray(out.action, np.int32)
+        closed = server.stats()
+        counts = {key: closed[key] - opened[key] for key in (
+            'calls', 'requests', 'inline_calls', 'state_resets')}
+        return answers, counts
+
+      inline, inline_counts = run()
+      server._call_inline = lambda inputs: None  # the batched path
+      batched, batched_counts = run()
+    finally:
+      server.close()
+    steps = len(requests)
+    assert inline_counts == dict(calls=steps, requests=k * steps,
+                                 inline_calls=steps, state_resets=3)
+    assert batched_counts == dict(inline_counts, inline_calls=0)
+    for got, want in zip(jax.tree_util.tree_leaves(inline),
+                         jax.tree_util.tree_leaves(batched)):
+      assert got.dtype == want.dtype and got.shape == want.shape
+      np.testing.assert_array_equal(got, want)
+
+  def test_the_floor_decides_the_path(self):
+    """At the floor and above: inline. Two groups under the floor
+    still merge into ONE batched call; a lone actor's row rides the
+    batcher whatever the floor; a group over the merge's maximum is
+    refused as the batcher refuses it."""
+    agent, params, _ = _mk()
+    cfg = Config(**_cfg_variant(inference_min_batch=4,
+                                inference_timeout_ms=60_000))
+    server = InferenceServer(agent, params, cfg, seed=3)
+
+    def call(k, seed=0):
+      carry = tuple(np.zeros((k, 256), np.float32) for _ in range(2))
+      out, _ = server.policy(np.zeros(k, np.int32),
+                             _group_request(k, seed=seed), carry)
+      assert np.asarray(out.action).shape == (k,)
+
+    try:
+      call(4)
+      call(5)
+      stats = server.stats()
+      assert stats['calls'] == stats['inline_calls'] == 2
+      pair = [threading.Thread(target=call, args=(2, seed))
+              for seed in (1, 2)]
+      for t in pair:
+        t.start()
+      for t in pair:
+        t.join(timeout=60)
+      stats = server.stats()
+      assert (stats['calls'], stats['inline_calls']) == (3, 2)
+      assert stats['requests'] == 4 + 5 + 2 + 2
+      assert stats['batcher_requests'] == 4
+      with pytest.raises(ValueError, match='maximum_batch_size'):
+        call(9)
+      assert server.stats()['inline_calls'] == 2
+    finally:
+      server.close()
+    cfg = Config(**_cfg_variant())  # floor 1
+    server = InferenceServer(agent, params, cfg, seed=3)
+    try:
+      env_out = _scripted_inputs(2)
+      _drive(server, env_out, 2)
+      assert server.stats()['inline_calls'] == 0
+      call(1)
+      stats = server.stats()
+      assert (stats['calls'], stats['inline_calls']) == (3, 1)
+    finally:
+      server.close()
+
+  def test_groups_under_the_pad_floor_ride_the_batcher(self):
+    """The eval server's case: a floor of 1 and `pad_batch_to` the
+    fleet. A group's request reaches the floor but not the pad floor,
+    so it rides the batcher, where groups queued together merge into a
+    call padded to the fleet, as before the inline call; a request that
+    fills the pad floor alone goes inline."""
+    agent, params, _ = _mk()
+    cfg = Config(**_cfg_variant(inference_min_batch=1))
+    server = InferenceServer(agent, params, cfg, seed=3, pad_batch_to=4)
+
+    def call(k, seed=0):
+      carry = tuple(np.zeros((k, 256), np.float32) for _ in range(2))
+      out, _ = server.policy(np.zeros(k, np.int32),
+                             _group_request(k, seed=seed), carry)
+      assert np.asarray(out.action).shape == (k,)
+
+    try:
+      call(2)
+      pair = [threading.Thread(target=call, args=(2, seed))
+              for seed in (1, 2)]
+      for t in pair:
+        t.start()
+      for t in pair:
+        t.join(timeout=60)
+      stats = server.stats()
+      assert stats['inline_calls'] == 0 and stats['requests'] == 6
+      assert stats['batcher_requests'] == 3
+      assert list(server._staging) == [4]  # every call padded to 4
+      call(4)
+      assert server.stats()['inline_calls'] == 1
+    finally:
+      server.close()
+
+  @pytest.mark.parametrize('cache', [False, True],
+                           ids=['carry_passing', 'state_cache'])
+  def test_a_failed_inline_call_raises_in_its_caller(self, cache):
+    """A failed execution re-anchors the key (and arena) and raises
+    BatcherError in the caller, as the batched path answers its parked
+    callers; a failed dispatch raises the same way and re-anchors
+    nothing. Either way the semaphore and the staging position come
+    back, and the next call is served; an interrupt (not an Exception)
+    goes to the caller as it is, and gives the semaphore back too."""
+    from scalable_agent_tpu.ops.dynamic_batching import BatcherError
+    k = 2
+    agent, params, cfg = _mk(**_cfg_variant(inference_state_cache=cache,
+                                            inference_min_batch=k))
+    server = InferenceServer(agent, params, cfg, seed=3)
+    try:
+      core = ([server.initial_core_state() for _ in range(k)] if cache
+              else tuple(np.zeros((k, 256), np.float32)
+                         for _ in range(2)))
+
+      def call(t):
+        out, new_core = server.policy(np.zeros(k, np.int32),
+                                      _group_request(k, t), core)
+        assert np.isfinite(np.asarray(out.policy_logits)).all()
+        return new_core
+
+      core = call(0)
+      real_step = server._step
+      failure = {}
+
+      def failing_step(*args):
+        how = failure.pop('how', None)
+        if how == 'execution':
+          # key + the packed outputs; the arena between them.
+          return tuple(_Poisoned() for _ in range(3 if cache else 2))
+        if how == 'dispatch':
+          raise ValueError('refused at dispatch (simulated)')
+        if how == 'interrupt':
+          raise _Interrupt()
+        return real_step(*args)
+
+      server._step = failing_step
+      failure['how'] = 'execution'
+      with pytest.raises(BatcherError, match='RuntimeError: computation'):
+        call(1)
+      assert server.stats()['chain_recoveries'] == 1
+      core = call(2)  # the chain was re-anchored
+      failure['how'] = 'dispatch'
+      with pytest.raises(BatcherError, match='ValueError: refused'):
+        call(3)
+      core = call(4)
+      failure['how'] = 'interrupt'
+      with pytest.raises(_Interrupt):
+        call(5)
+      core = call(6)
+      stats = server.stats()
+      assert stats['chain_recoveries'] == 1
+      assert stats['calls'] == stats['inline_calls'] == 7
+      # A row for every executed call, failed or not: none for the
+      # calls refused at dispatch (the batched path's rule too).
+      assert server._cycles.cycles == 5
+      assert server._sem._value == cfg.inference_pipeline_depth
+    finally:
+      server.close()
+
+  def test_inline_callers_at_once(self):
+    """Two groups at a floor of one, calling at once under a short
+    switch interval: each gets ITS rows back, `calls` is the number of
+    requests, every row of the calls' record is whole, and each caller
+    staged in a buffer of its own, none in the dispatch thread's rings."""
+    import sys
+    k, n = 2, 40
+    agent, params, _ = _mk()
+    cfg = Config(**_cfg_variant(inference_min_batch=k,
+                                inference_pipeline_depth=2))
+    server = InferenceServer(agent, params, cfg, seed=3)
+    zero = tuple(np.zeros((k, 256), np.float32) for _ in range(2))
+    requests = [_group_request(k, seed=10 * i) for i in range(2)]
+    # Logits follow the inputs and the carry alone: these are the
+    # answers each group must get back, from one call on its own.
+    want = [np.array(server.policy(np.zeros(k, np.int32), r,
+                                   zero)[0].policy_logits)
+            for r in requests]
+    errors = []
+
+    def caller(i):
+      try:
+        for _ in range(n):
+          out, _ = server.policy(np.zeros(k, np.int32), requests[i],
+                                 zero)
+          np.testing.assert_array_equal(out.policy_logits, want[i])
+      except BaseException as e:  # noqa: BLE001 — reported below
+        errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+      threads = [threading.Thread(target=caller, args=(i,))
+                 for i in range(2)]
+      for t in threads:
+        t.start()
+      for t in threads:
+        t.join(timeout=120)
+      assert not any(t.is_alive() for t in threads)
+      assert not errors, errors
+      stats = server.stats()
+    finally:
+      sys.setswitchinterval(interval)
+      server.close()
+    calls = 2 * n + 2
+    assert stats['calls'] == stats['inline_calls'] == calls
+    assert stats['requests'] == k * calls
+    totals = server._cycles.totals()
+    assert (totals['cycles'], totals['h2d'], totals['d2h']) == (
+        calls, calls, calls)
+    _, rows = server._cycles.held()
+    assert len(rows) == calls
+    assert (np.diff(rows[:, :5], axis=1) >= 0).all()
+    assert (rows[:, 5:] == 1).all()
+    assert server._staging == {}

@@ -333,6 +333,29 @@ class TestShadowAndAot:
     finally:
       server.close()
 
+  def test_a_live_shadow_sends_a_groups_call_through_the_batcher(self):
+    """PR 39: shadow scoring follows the callers' answer, on the
+    completion thread. While a shadow version is live a group's call
+    that fills the merge floor rides the batcher and is scored; with
+    none it is answered on its caller's thread."""
+    server = _server(serving_resident_versions=2)
+    try:
+      _group_traffic(server, 2, steps=2)
+      assert server.stats()['inline_calls'] == 2
+      server.update_params(_fresh(), version=1)  # the seed: the shadow
+      server.set_shadow(None, 1.0)
+      _group_traffic(server, 2, steps=3)
+      self._wait_shadow(server, 3)
+      stats = server.stats()
+      assert (stats['calls'], stats['inline_calls']) == (5, 2)
+      server.set_shadow(None, 0.0)
+      _group_traffic(server, 2, steps=1)
+      stats = server.stats()
+      assert (stats['calls'], stats['inline_calls']) == (6, 3)
+      assert stats['shadow_calls'] == 3
+    finally:
+      server.close()
+
   @pytest.mark.slow  # tier-1 wall trim (PR 21); ci.sh full-suite lane runs it
   def test_aot_flip_serves_without_recompile(self):
     # int8-resident publishes change the params leaf DTYPES — without
