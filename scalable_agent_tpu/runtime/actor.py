@@ -153,10 +153,11 @@ class _Rollout:
   unroll is the column `x[:, j]`. The env's side (reward, done, the
   observation's leaves) is a `py_process.StepBlock`: in shared memory
   where every member is a hosted env that can map it, and then each
-  child writes its own column and a step costs its pipe a byte each
-  way; otherwise in this process's memory, written here from what
-  each `step` returns. Which it is follows from what the members
-  offer (`step_block_specs`, `attach_block`), never from a setting.
+  child writes its own column and a group step is one
+  `py_process.StepPass` over the block, a byte each way a pipe;
+  otherwise in this process's memory, written here from what each
+  `step` returns. Which it is follows from what the members offer
+  (`step_block_specs`, `attach_block`), never from a setting.
 
   The members keep their own state BETWEEN unrolls (`begin` reads it,
   `finish` writes it back), so the arrays are reused from unroll to
@@ -171,16 +172,17 @@ class _Rollout:
     leaves, self.treedef = jax.tree_util.tree_flatten(
         actors[0]._env_output.observation)
     leaf_specs = [(np.shape(x), np.asarray(x).dtype.str) for x in leaves]
-    self.block = group._shared_block(leaf_specs, self.rows)
-    self.shared = self.block is not None
-    if not self.shared:
-      self.block = py_process.StepBlock.private(leaf_specs, self.rows, k)
+    self.step_pass = group._step_pass(leaf_specs, self.rows)
+    self.shared = self.step_pass is not None
+    self.block = (self.step_pass.block if self.shared else
+                  py_process.StepBlock.private(leaf_specs, self.rows, k))
     self.episode_return = np.zeros((self.rows, k), np.float32)
     self.episode_step = np.zeros((self.rows, k), np.int32)
     self.repeats = np.asarray([a._num_action_repeats for a in actors],
                               np.int32)
     self.handles = hasattr(actors[0]._core_state, 'snapshot')
-    # Hosted envs step in two halves, the others in one piece.
+    # Off a shared block, hosted envs step in two halves, the others
+    # in one piece.
     envs = [a._env for a in actors]
     self.sends = [getattr(env, 'step_send', None) for env in envs]
     self.receives = [getattr(env, 'step_receive', None) for env in envs]
@@ -254,8 +256,8 @@ class _Rollout:
     return done
 
   def act(self, t):
-    """The policy call of step t -> the k actions; its outputs are row
-    t + 1 of `agent`."""
+    """The policy call of step t -> the k actions (row t + 1 of
+    `agent.action`, as are the call's other outputs)."""
     out, self.states = _call_policy(
         self.actors[0]._policy, self.agent.action[t],
         self.env_output(t, self.hand_over_prompts(t)), self.states)
@@ -267,7 +269,7 @@ class _Rollout:
           for x, y in zip(self.agent, out)])
     for x, y in zip(self.agent, out):
       x[t + 1] = y
-    return self.agent.action[t + 1].tolist()
+    return self.agent.action[t + 1]
 
   def record(self, t):
     """Flow-style episode accounting of the step now in row t (the
@@ -362,10 +364,11 @@ class ActorGroup:
   concatenated on axis 0 and opaque handles (`snapshot`) go as a list
   (`InferenceServer.policy` honours both). The request's leaves are
   rows of the group's `_Rollout` (views: the policy must have done
-  with them when it returns). Members whose env has
-  `step_send`/`step_receive` (process-hosted: `py_process.ProxyEnv`)
-  step concurrently, and through a shared block where every member
-  can map one (PR 33); any other env steps in turn on this thread.
+  with them when it returns). Where every member can map a shared
+  block (process-hosted: `py_process.ProxyEnv`) a group step is
+  one pass over it (`py_process.StepPass`); otherwise members whose env
+  has `step_send`/`step_receive` step concurrently, and any other env
+  steps in turn on this thread.
 
   Membership moves only between unrolls, on the rolling thread: a
   member `leave`s (and is closed) there, and an Actor that any thread
@@ -381,18 +384,30 @@ class ActorGroup:
     # The member whose env failed the group's last unroll, if the
     # failure was one env's (else None): the fleet charges that slot.
     self.failed: Optional[Actor] = None
-    # The member whose env this thread is blocked on right now, if it
-    # is: of a group that stalls, the one that hangs.
-    self.waiting_on: Optional[Actor] = None
+    # Off a pass (`waiting_on`): the member whose env this thread is
+    # blocked on right now.
+    self._waiting_on: Optional[Actor] = None
     self._joining = collections.deque()  # (actor, name), any thread
     self._rollout: Optional[_Rollout] = None  # of the members as they are
     # The group's steps, always on (telemetry.CycleRecord; docs/
     # OBSERVABILITY.md "Cycle records"): three stamps a step (begin,
     # policy returned, envs stepped and accounted: one step's last is
-    # the next one's first within an unroll) and the slowest member's
-    # own time in its env's `step` (`StepBlock.busy_ns`).
-    self.steps = telemetry.CycleRecord(('policy_wait', 'env'),
-                                       extras=('env_child_ns',))
+    # the next one's first within an unroll), the slowest member's
+    # own time in its env's `step` (`StepBlock.busy_ns`), and 1 where
+    # the envs stepped in one pass over a shared block.
+    self.steps = telemetry.CycleRecord(
+        ('policy_wait', 'env'), extras=('env_child_ns', 'pass_steps'))
+
+  @property
+  def waiting_on(self) -> Optional[Actor]:
+    """The member whose env this thread is blocked on right now, if it
+    is: of a group that stalls, the one that hangs."""
+    rollout = self._rollout
+    if rollout is not None and rollout.shared:
+      j = rollout.step_pass.waiting
+      if j is not None:
+        return rollout.actors[j]
+    return self._waiting_on
 
   def join(self, actor, name):
     """Hand the group (one that has `names`) one more member, from
@@ -438,6 +453,7 @@ class ActorGroup:
         rollout = self._rollout = _Rollout(self)
       rollout.begin()
       busy_ns, write_step = rollout.block.busy_ns, self.steps.write
+      one_pass = int(rollout.shared)
       t_begin = time.perf_counter_ns()
       for t in range(rollout.rows - 1):
         with telemetry.span('actor/step'):
@@ -448,7 +464,7 @@ class ActorGroup:
             self._env_step(rollout, t + 1, actions)
           rollout.record(t + 1)
         t_end = time.perf_counter_ns()
-        write_step(t_begin, t_policy, t_end, int(busy_ns.max()))
+        write_step(t_begin, t_policy, t_end, int(busy_ns.max()), one_pass)
         t_begin = t_end
       return rollout.finish(span_ids)
 
@@ -468,12 +484,12 @@ class ActorGroup:
     for j, actor in enumerate(actors):
       actor._primed(out.policy_logits[j])
 
-  def _shared_block(self, leaf_specs, rows):
-    """A StepBlock in shared memory that every member's env has
-    mapped, if every member is a hosted env whose `step` reply is
-    declared as what the members' observations are, and the machine
-    has the memory to share; else None. A member that fails here
-    fails the unroll, as it would in a step."""
+  def _step_pass(self, leaf_specs, rows):
+    """A StepPass over a StepBlock in shared memory that every
+    member's env has mapped, if every member is a hosted env whose
+    `step` reply is declared as what the members' observations are,
+    and the machine has the memory to share; else None. A member that
+    fails here fails the unroll, as it would in a step."""
     envs = [a._env for a in self.actors]
     if not all(hasattr(env, 'attach_block') and
                env.step_block_specs() == leaf_specs for env in envs):
@@ -484,27 +500,36 @@ class ActorGroup:
       return None
     try:
       for j, (actor, env) in enumerate(zip(self.actors, envs)):
-        self.waiting_on = actor
+        self._waiting_on = actor
         try:
           env.attach_block(block, j)
         except BaseException:
           self.failed = actor
           raise
     finally:
-      self.waiting_on = None
+      self._waiting_on = None
       block.unlink()  # mapped by all, or of no more use
-    return block
+    return py_process.StepPass(block, envs)
 
   def _env_step(self, rollout, t, actions):
-    """Step every member's env into row `t`. Every send goes out
-    before the first reply is waited for, so hosted envs step at once;
-    every reply sent for is collected, whatever failed, so no child is
-    left mid-call. The first failure is raised."""
-    actors, sent, failure = rollout.actors, [], None
+    """Step every member's env into row `t` with `actions`: in one
+    pass over the shared block where there is one, else member by
+    member, every send out before the first reply is waited for, so
+    that hosted envs step at once. Either way every reply sent for is
+    collected, whatever failed, so no child is left mid-call, and the
+    first failure is raised."""
+    actors = rollout.actors
     if rollout.shared:
-      rollout.block.begin_step(t)
+      try:
+        rollout.step_pass.step(t, actions)
+      except BaseException:
+        j = rollout.step_pass.failed
+        self.failed = None if j is None else actors[j]
+        raise
+      return
+    actions, sent, failure = actions.tolist(), [], None
     for j, (actor, send) in enumerate(zip(actors, rollout.sends)):
-      self.waiting_on = actor
+      self._waiting_on = actor
       try:
         if send is None:
           t0 = time.perf_counter_ns()
@@ -518,16 +543,14 @@ class ActorGroup:
         failure = (actor, e)
         break
     for j in sent:
-      self.waiting_on = actors[j]
+      self._waiting_on = actors[j]
       try:
-        step = rollout.receives[j]()
-        if not rollout.shared:
-          rollout._write(t, j, *step)
-          if rollout.busy[j] is not None:
-            rollout.block.busy_ns[j] = rollout.busy[j]()
+        rollout._write(t, j, *rollout.receives[j]())
+        if rollout.busy[j] is not None:
+          rollout.block.busy_ns[j] = rollout.busy[j]()
       except BaseException as e:
         failure = failure or (actors[j], e)
-    self.waiting_on = None
+    self._waiting_on = None
     if failure is not None:
       self.failed, exc = failure
       raise exc

@@ -93,6 +93,7 @@ def _step_counts(records):
   ms = lambda key: sum(t[key] for t in totals) / 1e6  # noqa: E731
   return {
       'group_steps': sum(t['cycles'] for t in totals),
+      'pass_steps': sum(t['pass_steps'] for t in totals),
       'step_ms': ms('policy_wait_ns') + ms('env_ns'),
       'step_policy_wait_ms': ms('policy_wait_ns'),
       'step_env_ms': ms('env_ns'),
@@ -712,11 +713,12 @@ class ActorFleet:
       }
     # The group steps (PR 37; docs/OBSERVABILITY.md "Cycle records"),
     # summed over the threads, ended ones included, off the lock: the
-    # count, the cumulative ms of a step and of its two phases, of the
-    # slowest member's own time in its env's `step`, and of what the
-    # steps lay over their thread's median, by the activity they lay
-    # under (`telemetry.excess`); percentiles over the running
-    # threads' newest steps.
+    # count (and of those, `pass_steps`: the steps taken as one pass
+    # over a shared block), the cumulative ms of a step and of its two
+    # phases, of the slowest member's own time in its env's `step`,
+    # and of what the steps lay over their thread's median, by the
+    # activity they lay under (`telemetry.excess`); percentiles over
+    # the running threads' newest steps.
     steps.update(_step_counts(records))
     recent = [r.lengths(r.held(last=_RECENT_STEPS)[1]) for r in records]
     recent = np.sort(np.concatenate(recent or [np.zeros(0, np.int64)]))

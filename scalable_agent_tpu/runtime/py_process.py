@@ -9,8 +9,10 @@ to wrap because on the TPU build env stepping is host Python already
 (runtime/actor.py); what survives is the process-hosting contract:
 
 - `PyProcess(type_, constructor_kwargs)` + `.proxy.<method>(*args)` —
-  the call is sent over a `multiprocessing.Pipe`, the caller blocks on
-  the reply (reference `_TFProxy.__getattr__` ≈L50).
+  the call is sent over a pipe, the caller blocks on the reply
+  (reference `_TFProxy.__getattr__` ≈L50). Two one-way OS pipes, one
+  down and one up, not a socket pair: a byte each way costs the kernel
+  about half as much on a pipe.
 - `_tensor_specs(method_name, kwargs, constructor_kwargs)` protocol —
   classes declare the dtypes/shapes of method results; the parent
   validates replies against the declaration (the reference needed this
@@ -26,8 +28,10 @@ to wrap because on the TPU build env stepping is host Python already
 - `step` of an env whose declared reply has a fixed spec can go through
   a `StepBlock` in shared memory instead (PR 33): the action and the
   reply live in a block that the caller's group owns and the child has
-  mapped (`attach_block`), and the pipe carries one byte each way.
-  Every other call stays a pickled message on the same pipe.
+  mapped (`attach_block`), and the pipe carries one byte each way. A
+  `StepPass` steps every env of the block at once, in one pass over
+  its columns. Every other call stays a pickled message on the same
+  pipe.
 
 Start method: `forkserver` by default. The driver builds env processes
 AFTER JAX's inference warmup, i.e. from a parent already running JAX
@@ -121,8 +125,6 @@ _ATTACH = '__process_attach_block__'
 # call's two halves together.
 _CALL, _STEP = b'C', b'S'
 _STEPPED, _FAILED = b'K', b'X'
-# A step through a block, where a call's method name goes.
-_BLOCK_STEP = object()
 _BLOCK_DIR = '/dev/shm'
 
 
@@ -306,34 +308,36 @@ class _BlockStepper:
     self._seq[0] = self._want[0]  # last: the row is whole
 
 
-def _worker(conn, type_, constructor_kwargs):
-  """Worker loop: construct, then serve requests: a pickled (method,
-  args, kwargs), or a step through the block it was attached to."""
+def _worker(down, up, type_, constructor_kwargs):
+  """Worker loop: construct, then serve what comes `down` the parent's
+  pipe, answering `up` the other: a pickled (method, args, kwargs), or
+  a step through the block it was attached to."""
   pin_process_to_cpu()
   try:
     obj = type_(**constructor_kwargs)
   except Exception as e:  # ctor failure → reported on first proxy call
-    conn.send(('exception', _serialize_error(e)))
-    conn.close()
+    up.send(('exception', _serialize_error(e)))
+    up.close()
+    down.close()
     return
-  fd, stepper = conn.fileno(), None
+  fd_down, fd_up, stepper = down.fileno(), up.fileno(), None
   while True:
     try:
-      tag = os.read(fd, 1)
+      tag = os.read(fd_down, 1)
       if tag == _STEP:
         try:
           stepper.step()
-          os.write(fd, _STEPPED)
+          os.write(fd_up, _STEPPED)
         except SpecMismatchError as e:
-          os.write(fd, _FAILED)
-          conn.send(('mismatch', str(e)))
+          os.write(fd_up, _FAILED)
+          up.send(('mismatch', str(e)))
         except Exception as e:  # keep serving, as after any method
-          os.write(fd, _FAILED)
-          conn.send(('exception', _serialize_error(e)))
+          os.write(fd_up, _FAILED)
+          up.send(('exception', _serialize_error(e)))
         continue
       if not tag:
         break  # parent died/closed: fall through to close the object
-      request = conn.recv()
+      request = down.recv()
     except (EOFError, OSError):
       break
     method, args, kwargs = request
@@ -341,9 +345,9 @@ def _worker(conn, type_, constructor_kwargs):
       try:
         if hasattr(obj, 'close'):
           obj.close()
-        conn.send(('ok', None))
+        up.send(('ok', None))
       except Exception as e:
-        conn.send(('exception', _serialize_error(e)))
+        up.send(('exception', _serialize_error(e)))
       break
     try:
       t0 = time.perf_counter_ns()
@@ -357,13 +361,14 @@ def _worker(conn, type_, constructor_kwargs):
       else:
         result = getattr(obj, method)(*args, **kwargs)
       # With the method's own time, on this process's clock.
-      conn.send(('ok', result, time.perf_counter_ns() - t0))
+      up.send(('ok', result, time.perf_counter_ns() - t0))
     except Exception as e:  # keep serving — reference semantics
-      conn.send(('exception', _serialize_error(e)))
-  try:
-    conn.close()
-  except OSError:
-    pass
+      up.send(('exception', _serialize_error(e)))
+  for conn in (up, down):
+    try:
+      conn.close()
+    except OSError:
+      pass
 
 
 def _serialize_error(e):
@@ -442,7 +447,10 @@ class PyProcess:
         context or DEFAULT_START_METHOD)
     self._validate = validate_specs and hasattr(type_, '_tensor_specs')
     self._step_block = step_block
-    self._conn = None
+    # The parent's ends of the pipe down to the child and of the one
+    # up from it (`multiprocessing` Connections: None until started
+    # and once closed).
+    self._down = self._up = None
     self._process = None
     self._lock = threading.Lock()  # pipes are not thread-safe
     # A call between its two halves (_send, _receive): (the thread
@@ -450,10 +458,6 @@ class PyProcess:
     # hand, the env/pipe span).
     self._pending = None
     self._closed = False
-    # The StepBlock this env's `step` goes through and its column
-    # there (`attach_block`); None: `step` is a pickled call.
-    self.block = None
-    self.column = None
     # Env steps that went through a block, and calls that went down
     # the pickled pipe (read by `ActorFleet.stats`).
     self.block_steps = 0
@@ -469,13 +473,17 @@ class PyProcess:
   def start(self):
     if self._process is not None:
       raise RuntimeError('already started')
-    self._conn, child_conn = self._ctx.Pipe(duplex=True)
+    child_down, self._down = self._ctx.Pipe(duplex=False)
+    self._up, child_up = self._ctx.Pipe(duplex=False)
     self._process = self._ctx.Process(
         target=_worker,
-        args=(child_conn, self._type, self._constructor_kwargs),
+        args=(child_down, child_up, self._type, self._constructor_kwargs),
         daemon=True)
     self._process.start()
-    child_conn.close()  # parent keeps one end only
+    # The parent keeps one end of each: the child's death is EOF up,
+    # and a write down after it is EPIPE.
+    child_down.close()
+    child_up.close()
     return self
 
   def step_block_specs(self):
@@ -488,13 +496,12 @@ class PyProcess:
         'step', {}, self._constructor_kwargs))
 
   def attach_block(self, block, column):
-    """From here on `step_send` / `step_receive` step the env into
-    `column` of `block` (one made by `StepBlock.create`, not yet
-    unlinked), which the child maps now. Every other call, `step`
-    in one piece among them, stays a pickled call."""
+    """The child maps `block` (one made by `StepBlock.create`, not yet
+    unlinked) now, and from here on a `StepPass` over the block steps
+    the env into `column`. Every other call, `step` among them, stays
+    a pickled call."""
     self._call(_ATTACH, (self._validate, block.path, block.leaf_specs,
                          block.rows, block.columns, column), {})
-    self.block, self.column = block, column
 
   def _call(self, method, args, kwargs):
     self._send(method, args, kwargs)
@@ -507,36 +514,27 @@ class PyProcess:
     failure here gives it back), so calls still never interleave on
     the pipe and `close()` still finds a call in flight. Between the
     halves the caller may send to OTHER processes: that is how one
-    actor thread has k children stepping at once.
-
-    `method` `_BLOCK_STEP` steps the env through its block: `args` is
-    the action, written to the block's column, and one byte wakes the
-    child."""
+    actor thread has k children stepping at once."""
     me = threading.get_ident()
     pending = self._pending
     if pending is not None and pending[0] == me:
       # The lock is not reentrant: this would park the thread for ever.
       raise RuntimeError(
-          f'{self._type.__name__}.{self._name(method)}: send before '
-          f'the receive of {self._name(pending[1])!r}')
+          f'{self._type.__name__}.{method}: send before the receive of '
+          f'{pending[1]!r}')
     self._lock.acquire()
     try:
-      if self._closed or self._conn is None:
+      if self._closed or self._down is None:
         raise ProcessClosed(f'{self._type.__name__} process not running')
       reply = None
       pipe = telemetry.span('env/pipe')  # send -> reply in hand
       try:
-        if method is _BLOCK_STEP:
-          self.block.action[self.column] = args
-          self.block_steps += 1
-          os.write(self._conn.fileno(), _STEP)
-        else:
-          # Pickled before the first byte goes: a request that cannot
-          # be must leave the child expecting nothing.
-          request = ForkingPickler.dumps((method, args, kwargs))
-          self.pipe_calls += 1
-          os.write(self._conn.fileno(), _CALL)
-          self._conn.send_bytes(request)
+        # Pickled before the first byte goes: a request that cannot be
+        # must leave the child expecting nothing.
+        request = ForkingPickler.dumps((method, args, kwargs))
+        self.pipe_calls += 1
+        os.write(self._down.fileno(), _CALL)
+        self._down.send_bytes(request)
       except (EOFError, OSError, BrokenPipeError) as e:
         reply = self._buffered_reply_or_closed(e)
       except Exception as e:
@@ -550,15 +548,10 @@ class PyProcess:
       raise
     self._pending = (me, method, kwargs, reply, pipe)
 
-  @staticmethod
-  def _name(method):
-    return 'step' if method is _BLOCK_STEP else method
-
   def _receive(self):
     """Second half of a call: block for the reply to this thread's
     `_send`, give the lock back, and turn the reply into the result
-    or the remote exception. Of a step through the block the result
-    is None: it is in the block's row."""
+    or the remote exception."""
     pending = self._pending
     if pending is None or pending[0] != threading.get_ident():
       raise RuntimeError(
@@ -567,40 +560,35 @@ class PyProcess:
     _, method, kwargs, reply, pipe = pending
     try:
       if reply is None:
-        try:
-          if method is _BLOCK_STEP:
-            # One byte: the row is written, or a pickled failure
-            # follows.
-            answer = os.read(self._conn.fileno(), 1)
-            if answer == _STEPPED:
-              reply = 'ok', None
-            elif answer != _FAILED:
-              raise EOFError('the child closed its end')
-          if reply is None:
-            reply = self._conn.recv()
-        except (EOFError, OSError, BrokenPipeError) as e:
-          reply = self._buffered_reply_or_closed(e)
-        except Exception as e:
-          # The reply arrived but failed to unpickle (e.g. an exception
-          # class whose __reduce__ pickles but can't reconstruct). The
-          # message was fully consumed, so the pipe is still in sync —
-          # report it as a remote failure instead of leaking a bare
-          # unpickling error with no context.
-          raise RemoteError(
-              f'in hosted {self._type.__name__}.{self._name(method)}: '
-              f'reply could not be deserialized ({e!r})') from e
+        reply = self._read_reply(method)
       pipe.end()
     finally:
       self._pending = None
       self._lock.release()
+    return self._result(method, kwargs, reply)
+
+  def _read_reply(self, method):
+    """The pickled reply to the call in flight (the call lock held)."""
+    try:
+      return self._up.recv()
+    except (EOFError, OSError, BrokenPipeError) as e:
+      return self._buffered_reply_or_closed(e)
+    except Exception as e:
+      # The reply arrived but failed to unpickle (e.g. an exception
+      # class whose __reduce__ pickles but can't reconstruct). The
+      # message was fully consumed, so the pipe is still in sync —
+      # report it as a remote failure instead of leaking a bare
+      # unpickling error with no context.
+      raise RemoteError(
+          f'in hosted {self._type.__name__}.{method}: reply could not '
+          f'be deserialized ({e!r})') from e
+
+  def _result(self, method, kwargs, reply):
+    """A reply in hand -> the result, or the remote exception."""
     status, payload, *busy_ns = reply
     if busy_ns:
       self.busy_ns = busy_ns[0]
-    block = self.block if method is _BLOCK_STEP else None
-    if (block is not None and status == 'ok'
-        and block.seq[self.column] == block.step_seq):
-      return None  # the row asked for is there
-    name = f'{self._type.__name__}.{self._name(method)}'
+    name = f'{self._type.__name__}.{method}'
     if status == 'exception':
       exc, tb = payload
       err = RemoteError(f'in hosted {name}:\n{tb}')
@@ -609,17 +597,36 @@ class PyProcess:
       raise err
     if status == 'mismatch':  # found in the child, against the block
       raise SpecMismatchError(payload)
-    if block is not None:
-      # Never seen: what the number is there to catch.
-      raise RemoteError(
-          f'in hosted {name}: column {self.column} of the block holds '
-          f'step {int(block.seq[self.column])}, not step '
-          f'{block.step_seq}: a row from before')
     if self._validate:
       specs = self._type._tensor_specs(method, kwargs,
                                        self._constructor_kwargs)
       _validate_specs(payload, specs, name)
     return payload
+
+  def _step_failure(self, answer, block, column):
+    """Raise what a step through `block` that did not come back whole
+    stands for, the call lock held: `answer` is the byte the child
+    sent (`_FAILED`: its pickled failure follows, and is read here so
+    that the pipe stays in step; b'': its end closed), `_STEPPED` over
+    a row from before, or None where the process was closed before
+    the byte could go."""
+    name = f'{self._type.__name__}.step'
+    if answer is None:
+      raise ProcessClosed(f'{self._type.__name__} process not running')
+    if answer == _STEPPED:
+      # Never seen: what the sequence number is there to catch.
+      raise RemoteError(
+          f'in hosted {name}: column {column} of the block holds step '
+          f'{int(block.seq[column])}, not step {block.step_seq}: a row '
+          'from before')
+    if answer == _FAILED:
+      reply = self._read_reply('step')
+    else:
+      reply = self._buffered_reply_or_closed(
+          EOFError('the child closed its end'))
+    self._result('step', {}, reply)
+    raise RemoteError(f'in hosted {name}: a failed step answered '
+                      f'{reply[0]!r}')
 
   def _buffered_reply_or_closed(self, e):
     # A child whose ctor failed sends ('exception', ...) and closes
@@ -636,8 +643,8 @@ class PyProcess:
   def _drain_buffered_reply(self):
     """Return a reply the child pipelined before dying, if any."""
     try:
-      if self._conn is not None and self._conn.poll(0):
-        return self._conn.recv()
+      if self._up is not None and self._up.poll(0):
+        return self._up.recv()
     except (EOFError, OSError, BrokenPipeError):
       pass
     return None
@@ -660,23 +667,24 @@ class PyProcess:
       if self._closed:
         return
       self._closed = True
-      conn, process = self._conn, self._process
-      self._conn = None
+      down, up, process = self._down, self._up, self._process
+      self._down = self._up = None
     finally:
       self._lock.release()
-    if conn is not None:
+    if down is not None:
       try:
-        os.write(conn.fileno(), _CALL)
-        conn.send((_CLOSE, (), {}))
+        os.write(down.fileno(), _CALL)
+        down.send((_CLOSE, (), {}))
         self.pipe_calls += 1
-        if conn.poll(timeout):
-          conn.recv()
+        if up.poll(timeout):
+          up.recv()
       except (EOFError, OSError, BrokenPipeError):
         pass
-      try:
-        conn.close()
-      except OSError:
-        pass
+      for conn in (down, up):
+        try:
+          conn.close()
+        except OSError:
+          pass
     if process is not None:
       process.join(timeout)
       if process.is_alive():
@@ -760,23 +768,18 @@ class ProxyEnv:
   def step_send(self, action):
     """`step` in two halves, for an actor thread that steps several
     hosted envs at once: send to each, then `step_receive` from each.
-    Through the block once the env is attached to one."""
-    if self._process.block is not None:
-      self._process._send(_BLOCK_STEP, action, None)
-    else:
-      self._process._send('step', (action,), {})
+    (Envs attached to a block step together through a `StepPass`.)"""
+    self._process._send('step', (action,), {})
 
   def step_receive(self):
-    """(reward, done, observation); None of a step through the block,
-    whose row holds them."""
+    """(reward, done, observation)."""
     return self._process._receive()
 
   def step_block_specs(self):
     return self._process.step_block_specs()
 
   def step_busy_ns(self):
-    """The child's own time in the `step` last received, where that
-    went down the pipe."""
+    """The child's own time in the `step` last received."""
     return self._process.busy_ns
 
   def attach_block(self, block, column):
@@ -784,3 +787,96 @@ class ProxyEnv:
 
   def close(self):
     self._process.close()
+
+
+class StepPass:
+  """One step of every env of a shared block, as one pass over the
+  block's columns: the actions go in as one vector, one byte down each
+  child's pipe in a tight loop wakes them all, the one-byte answers
+  come back in a second loop, and one compare of the sequence numbers
+  says every row is whole. Only a column that did not come back whole
+  (its byte `_FAILED`, its pipe closed, its number not the step's) goes
+  through its process's own reply handling, which raises what a step
+  by itself would.
+
+  `envs` are `ProxyEnv`s attached to `block`, env j to column j; their
+  pipes' descriptors are taken once, here. While `step` blocks,
+  `waiting` is the column it waits for (None otherwise); after a step
+  that raised, `failed` is the column whose failure it raised (None:
+  not one env's)."""
+
+  def __init__(self, block, envs):
+    self.block = block
+    self._processes = [env._process for env in envs]
+    self._locks = [p._lock for p in self._processes]
+    self._downs = [p._down.fileno() for p in self._processes]
+    self._ups = [p._up.fileno() for p in self._processes]
+    self.waiting = None
+    self.failed = None
+
+  def step(self, row, actions):
+    """Step every env into `row` of the block, env j with `actions[j]`.
+    Each process's call lock is held from its byte out to its answer,
+    so `close()` from another thread still finds a call in flight.
+    Every answer sent for is read, whatever failed, so that no child
+    is left mid-call; then the first failure, by column, is raised."""
+    block, processes = self.block, self._processes
+    n = len(processes)
+    self.failed = None
+    block.begin_step(row)
+    block.action[:] = actions
+    # Per column: _STEP while its byte is out, then its answer (b'':
+    # the pipe closed; None: the process was closed, nothing sent).
+    answers = [_STEP] * n
+    for lock in self._locks:
+      lock.acquire()
+    try:
+      pipes = _pipe_spans(n)  # wake -> answer, while armed
+      for j, (process, fd) in enumerate(zip(processes, self._downs)):
+        if process._closed:  # its descriptors may be another's now
+          answers[j] = None
+          continue
+        process.block_steps += 1
+        try:
+          os.write(fd, _STEP)
+        except OSError:
+          answers[j] = b''
+      for j, fd in enumerate(self._ups):
+        if answers[j] is _STEP:
+          self.waiting = j
+          try:
+            answers[j] = os.read(fd, 1)
+          except OSError:
+            answers[j] = b''
+          if pipes is not None:
+            pipes[j].end()
+      if (answers.count(_STEPPED) != n or
+          not (block.seq == block.step_seq).all()):
+        self._raise_first_failure(answers)
+    finally:
+      self.waiting = None
+      for lock in self._locks:
+        lock.release()
+
+  def _raise_first_failure(self, answers):
+    block, failure = self.block, None
+    for j, (process, answer) in enumerate(zip(self._processes, answers)):
+      if answer == _STEPPED and block.seq[j] == block.step_seq:
+        continue
+      self.waiting = j
+      try:
+        process._step_failure(answer, block, j)
+      except BaseException as e:  # the first is raised, below
+        if failure is None:
+          failure = j, e
+    self.failed, exc = failure
+    raise exc
+
+
+def _pipe_spans(n):
+  """n `env/pipe` spans begun now, while the recorder is armed; None
+  while it is off."""
+  first = telemetry.span('env/pipe')
+  if first is telemetry.NO_SPAN:
+    return None
+  return [first] + [telemetry.span('env/pipe') for _ in range(n - 1)]

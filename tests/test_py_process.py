@@ -334,10 +334,7 @@ def _attached(env_class, kwargs_list, rows=4, **process_kwargs):
 
 
 def _block_step(envs, block, row, actions):
-  block.begin_step(row)
-  for env, action in zip(envs, actions):
-    env.step_send(action)
-  return [env.step_receive() for env in envs]
+  py_process.StepPass(block, envs).step(row, actions)
 
 
 def _segments_of(pid):
@@ -356,22 +353,30 @@ def _block_cases():
 @pytest.mark.parametrize('env_class,kwargs', _block_cases())
 def test_block_step_is_bitwise_the_pickled_step(env_class, kwargs):
   """Frame for frame, reward for reward, done for done, in their own
-  dtypes: k children stepping into a block against the same envs
-  stepped by pickled calls. Rows are reused as an unroll reuses them."""
+  dtypes: k children stepped in one pass over a block (`StepPass`)
+  against the same envs stepped by pickled calls, whole and in halves
+  (`step_send` to each, then `step_receive` from each, as a group
+  without a block steps them). Rows are reused as an unroll reuses
+  them, and one pass object serves every step."""
   k, rows = 3, 4
   kwargs_list = [dict(kwargs, seed=i) for i in range(k)]
-  piped = [ProxyEnv(PyProcess(env_class, kw, step_block=False).start())
-           for kw in kwargs_list]
+  piped, halves = [
+      [ProxyEnv(PyProcess(env_class, kw, step_block=False).start())
+       for kw in kwargs_list] for _ in range(2)]
   envs, block = _attached(env_class, kwargs_list, rows)
+  one_pass = py_process.StepPass(block, envs)
   try:
     assert piped[0].step_block_specs() is None  # the argument's doing
     assert not _segments_of(os.getpid())
-    for env in piped + envs:
+    for env in piped + halves + envs:
       env.initial()
     for t in range(9):
       row = 1 + t % (rows - 1)
       actions = [(t + j) % 3 for j in range(k)]
-      assert _block_step(envs, block, row, actions) == [None] * k
+      assert one_pass.step(row, np.asarray(actions, np.int32)) is None
+      for env, action in zip(halves, actions):
+        env.step_send(action)
+      halved = [env.step_receive() for env in halves]
       for j, env in enumerate(piped):
         reward, done, observation = env.step(actions[j])
         assert block.reward[row, j] == reward
@@ -381,14 +386,20 @@ def test_block_step_is_bitwise_the_pickled_step(env_class, kwargs):
         for leaf, column in zip(leaves, block.leaves):
           np.testing.assert_array_equal(column[row, j], leaf)
           assert column.dtype == np.asarray(leaf).dtype
+        other = list(py_process._leaves(halved[j]))
+        assert len(other) == 2 + len(leaves)
+        for x, y in zip(other, [reward, done] + leaves):
+          np.testing.assert_array_equal(x, y)
+          assert np.asarray(x).dtype == np.asarray(y).dtype
+    assert one_pass.waiting is None and one_pass.failed is None
     assert [e._process.block_steps for e in envs] == [9] * k
-    assert [e._process.block_steps for e in piped] == [0] * k
+    assert [e._process.block_steps for e in piped + halves] == [0] * 2 * k
     # `initial` and attaching went down the pipe, like every `step`
     # of the others.
     assert [e._process.pipe_calls for e in envs] == [2] * k
-    assert [e._process.pipe_calls for e in piped] == [10] * k
+    assert [e._process.pipe_calls for e in piped + halves] == [10] * 2 * k
   finally:
-    py_process.close_all([e._process for e in piped + envs])
+    py_process.close_all([e._process for e in piped + halves + envs])
 
 
 class SleepyEnv(FakeEnv):
@@ -477,64 +488,147 @@ def test_other_calls_ride_the_pipe_between_block_steps():
 @pytest.mark.parametrize('wrong', ['shape', 'dtype', 'structure'])
 def test_block_step_checks_the_spec_in_the_child(wrong):
   """A reply that breaks the declared spec never reaches the block:
-  the parent raises SpecMismatchError naming the method, as it does
-  for a pickled reply, and the child serves on."""
+  the pass raises SpecMismatchError naming the method, as a pickled
+  reply does, and the child serves on."""
   kwargs = dict(height=8, width=8, wrong=wrong)
   envs, block = _attached(WrongStepEnv, [kwargs, dict(height=8, width=8)])
+  one_pass = py_process.StepPass(block, envs)
   try:
     for t in range(2):
-      _block_step(envs, block, 1, [0, 0])
-    block.begin_step(2)
-    for env in envs:
-      env.step_send(1)
+      one_pass.step(1, [0, 0])
     with pytest.raises(SpecMismatchError, match='WrongStepEnv.step'):
-      envs[0].step_receive()
-    assert envs[1].step_receive() is None  # its mate's step is whole
+      one_pass.step(2, [1, 1])
+    assert one_pass.failed == 0
+    assert block.seq[1] == block.step_seq  # its mate's step is whole
     assert block.seq[0] != block.step_seq  # nothing was written
-    _block_step(envs, block, 3, [1, 1])
+    one_pass.step(3, [1, 1])
+    assert one_pass.failed is None
     assert block.seq[0] == block.seq[1] == block.step_seq
   finally:
     py_process.close_all([e._process for e in envs])
 
 
 def test_block_step_failures_keep_the_pipes_contract():
-  """An exception of the env's comes back as RemoteError and the
-  worker serves on; a child that dies between wake-up and answer is
-  ProcessClosed at the receive and at every send after; one that
-  hangs is killed by `close`, which breaks the parked receive."""
+  """In one pass: an exception of the env's comes back as RemoteError
+  and the worker serves on; a child that dies between wake-up and
+  answer is ProcessClosed, and so is every send to it after; one that
+  hangs is killed by `close`, which waits out its timeout for the call
+  lock the pass holds and so breaks the parked read. The first failure
+  by column is the one raised, after every answer is in."""
   import threading
   import time
   envs, block = _attached(WrongStepEnv, [
       dict(height=8, width=8, wrong=w) for w in ('raise', 'die', 'sleep')])
   raising, dying, hanging = envs
+  one_pass = py_process.StepPass(block, envs)
   try:
     for t in range(2):
-      _block_step(envs, block, 1, [0, 0, 0])
-    block.begin_step(2)
-    for env in envs:
-      env.step_send(1)
-    with pytest.raises(RuntimeError, match='send before the receive'):
-      raising.step_send(1)
-    with pytest.raises(RemoteError, match='step boom'):
-      raising.step_receive()
-    with pytest.raises(ProcessClosed):
-      dying.step_receive()
-    with pytest.raises(ProcessClosed):
-      dying.step_send(1)
-    # Parked on a child that hangs: `close` from another thread waits
-    # its timeout out for the call lock, then kills the child, which
-    # breaks the receive.
+      one_pass.step(1, [0, 0, 0])
     closer = threading.Timer(
         0.2, lambda: hanging._process.close(timeout=0.5))
     closer.start()
     t0 = time.monotonic()
-    with pytest.raises(ProcessClosed):
-      hanging.step_receive()
+    with pytest.raises(RemoteError, match='step boom'):
+      one_pass.step(2, [1, 1, 1])
     assert time.monotonic() - t0 < 10
     closer.join(timeout=10)
-    # The one that raised serves on, through the block.
-    _block_step([raising], block, 3, [1])
-    assert block.seq[0] == block.step_seq
+    assert one_pass.failed == 0 and one_pass.waiting is None
+    with pytest.raises(ProcessClosed):
+      dying.step_send(1)
+    with pytest.raises(ProcessClosed):
+      one_pass.step(3, [1, 1, 1])  # the dead are no one's column now
+    assert one_pass.failed == 1
+    # The one that raised serves on, its pipe in step: pickled halves
+    # now, and they still refuse a second send before the receive.
+    raising.step_send(1)
+    with pytest.raises(RuntimeError, match='send before the receive'):
+      raising.step_send(1)
+    reward, done, (frame, _) = raising.step_receive()
+    assert frame.shape == (8, 8, 3)
+  finally:
+    py_process.close_all([e._process for e in envs], timeout=1.0)
+
+
+def _step_within(one_pass, row, actions, limit_s=30.0):
+  """`one_pass.step` on a thread of its own -> (the exception it
+  raised or None, seconds taken); fails the test if it is not done
+  within `limit_s`."""
+  import threading
+  import time
+  out = {}
+
+  def run():
+    t0 = time.monotonic()
+    try:
+      one_pass.step(row, actions)
+    except BaseException as e:  # handed to the test
+      out['error'] = e
+    out['seconds'] = time.monotonic() - t0
+
+  thread = threading.Thread(target=run, daemon=True)
+  thread.start()
+  thread.join(limit_s)
+  assert not thread.is_alive(), f'the pass hung past {limit_s} s'
+  return out.get('error'), out['seconds']
+
+
+@pytest.mark.parametrize('wrongs,error', [
+    ((None, 'raise', 'shape'), RemoteError),
+    ((None, 'shape', 'raise'), SpecMismatchError),
+], ids=['raise_then_mismatch', 'mismatch_then_raise'])
+def test_a_pass_raises_the_first_failure_and_collects_the_rest(
+    wrongs, error):
+  """Two members fail one pass, each its own way: the first by column
+  is raised and named (`failed`), the other's pickled failure is read
+  off its pipe too, and a pickled call to each member afterwards is
+  answered as if nothing had happened."""
+  envs, block = _attached(WrongStepEnv, [
+      dict(height=8, width=8, wrong=w) for w in wrongs])
+  one_pass = py_process.StepPass(block, envs)
+  try:
+    for t in range(2):
+      one_pass.step(1, [0, 0, 0])
+    raised, _ = _step_within(one_pass, 2, [1, 1, 1])
+    assert type(raised) is error and 'WrongStepEnv.step' in str(raised)
+    assert one_pass.failed == 1 and one_pass.waiting is None
+    assert block.seq[0] == block.step_seq  # the healthy one is whole
+    for env in envs:
+      frame, _ = env.initial()
+      assert frame.shape == (8, 8, 3)
+      assert env._process._pending is None
+    one_pass.step(3, [1, 1, 1])  # and the next pass is whole
+    assert (block.seq == block.step_seq).all()
+  finally:
+    py_process.close_all([e._process for e in envs])
+
+
+@pytest.mark.parametrize('when', ['before', 'during'])
+def test_a_child_killed_before_or_during_a_pass_is_process_closed(when):
+  """A child killed outright (SIGKILL), before the pass wakes it or
+  while it steps: the pass raises ProcessClosed for its column within
+  the test's own time limit, not a hang, and its mates were answered."""
+  import signal
+  import threading
+  envs, block = _attached(WrongStepEnv, [
+      dict(height=8, width=8, wrong='sleep' if j == 1 else None)
+      for j in range(3)])
+  one_pass = py_process.StepPass(block, envs)
+  victim = envs[1]._process._process
+  try:
+    for t in range(2):
+      one_pass.step(1, [0, 0, 0])
+    if when == 'before':
+      victim.kill()
+      victim.join(10)
+    else:  # its third step sleeps for a minute: killed in it
+      threading.Timer(0.3, victim.kill).start()
+    raised, seconds = _step_within(one_pass, 2, [1, 1, 1])
+    assert isinstance(raised, ProcessClosed), raised
+    assert one_pass.failed == 1 and seconds < 20
+    victim.join(10)
+    assert victim.exitcode == -signal.SIGKILL
+    for env in envs[0::2]:
+      assert env.initial()[0].shape == (8, 8, 3)
   finally:
     py_process.close_all([e._process for e in envs], timeout=1.0)
 
@@ -544,12 +638,20 @@ def test_a_row_from_before_is_never_taken_for_the_step():
   earlier step wrote (here: the parent moved on without telling the
   child) is refused."""
   (env,), block = _attached(FakeEnv, [dict(height=8, width=8)])
-  try:
-    _block_step([env], block, 1, [0])
+  one_pass = py_process.StepPass(block, [env])
+  begin = block.begin_step
+
+  def forgetful(row):
+    begin(row)
     block.step_seq += 1  # `want` still says the step before
-    env.step_send(0)
+
+  try:
+    one_pass.step(1, [0])
+    block.begin_step = forgetful
     with pytest.raises(RemoteError, match='a row from before'):
-      env.step_receive()
+      one_pass.step(1, [0])
+    assert one_pass.failed == 0
+    assert env.initial()[0].shape == (8, 8, 3)  # the pipe is in step
   finally:
     env.close()
 

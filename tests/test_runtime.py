@@ -1133,7 +1133,8 @@ class _NoSpecEnv:
 
 class _TruncatingEnv(FakeEnv):
   """Its third step returns a frame a row short of its declared spec
-  (`fault='shape'`), or ends the process (`fault='die'`)."""
+  (`fault='shape'`), ends the process (`fault='die'`) or sleeps for a
+  minute (`fault='sleep'`)."""
 
   def __init__(self, fault=None, **kw):
     super().__init__(**kw)
@@ -1147,6 +1148,8 @@ class _TruncatingEnv(FakeEnv):
     if self._steps == 3 and self._fault == 'die':
       import os
       os._exit(1)
+    if self._steps == 3 and self._fault == 'sleep':
+      time.sleep(60)
     return reward, done, (frame, instr)
 
 
@@ -1321,7 +1324,7 @@ class TestGroupOnASharedBlock:
       for group in (on_block, on_pipe):
         assert group.steps.cycles == 3 * T
         _, rows = group.steps.held()
-        assert (rows[:, -1] > 0).all()
+        assert (rows[:, 3] > 0).all()
       # What an unroll holds is its own: the next unroll reuses the
       # group's arrays and must not show through.
       held = blocked[0].env_outputs.observation[0].copy()
@@ -1374,6 +1377,80 @@ class TestGroupOnASharedBlock:
       for actor in group.actors[0::2]:
         process = actor._env._process
         assert process._pending is None and process.block_steps == 3
+        actor._env.initial()  # the pipe is in step: answered
+    finally:
+      group.close()
+
+  @pytest.mark.parametrize('hosting', ['block', 'pipe', 'in_process'])
+  def test_pass_steps_count_group_steps_and_block_steps_members(
+      self, hosting):
+    """`pass_steps` counts the GROUP steps taken as one pass
+    over a shared block, every one of them where the members map one
+    and none where they do not; `block_steps` still counts each
+    member's own steps through the block."""
+    from scalable_agent_tpu.runtime import fleet as fleet_lib
+    from scalable_agent_tpu.runtime.actor import ActorGroup
+    T, k = 4, 3
+    kwargs_list = [dict(height=H, width=W, num_actions=A, seed=i)
+                   for i in range(k)]
+    policy = _ScriptedStatePolicy(cache=False)
+    if hosting == 'in_process':
+      group = ActorGroup([
+          Actor(FakeEnv(**kw), policy, policy.initial_core_state(), T)
+          for kw in kwargs_list])
+    else:
+      group = _hosted_group(FakeEnv, kwargs_list, policy, T,
+                            step_block=hosting == 'block')
+    try:
+      for _ in range(2):
+        group.unroll()
+      counts = fleet_lib._step_counts([group.steps])
+      steps = [getattr(a._env, '_process', None) for a in group.actors]
+      steps = [0 if p is None else p.block_steps for p in steps]
+    finally:
+      group.close()
+    assert counts['group_steps'] == 2 * T
+    on_block = hosting == 'block'
+    assert counts['pass_steps'] == (2 * T if on_block else 0)
+    assert steps == [2 * T if on_block else 0] * k
+
+  def test_waiting_on_names_the_member_a_pass_is_blocked_on(self):
+    """One member sleeps in its step while its mates have answered:
+    the group names it (`waiting_on`, what the fleet reads to charge a
+    hang to the member that hangs alone); closing it breaks the pass,
+    which raises ProcessClosed for that member and collects the rest."""
+    from scalable_agent_tpu.runtime import py_process
+    kwargs_list = [dict(height=H, width=W, num_actions=A, seed=i,
+                        fault='sleep' if i == 1 else None)
+                   for i in range(3)]
+    group = _hosted_group(_TruncatingEnv, kwargs_list,
+                          _ScriptedStatePolicy(cache=False), 6)
+    raised = []
+
+    def unroll():
+      try:
+        group.unroll()
+      except py_process.ProcessClosed as e:
+        raised.append(e)
+
+    thread = threading.Thread(target=unroll, daemon=True)
+    try:
+      thread.start()
+      deadline = time.monotonic() + 30
+      while (group.waiting_on is not group.actors[1] and
+             time.monotonic() < deadline):
+        time.sleep(0.01)
+      assert group.waiting_on is group.actors[1]
+      time.sleep(0.2)  # asleep still; the mates long answered
+      assert group.waiting_on is group.actors[1]
+      block = group._rollout.block
+      assert block.seq[0] == block.seq[2] == block.step_seq
+      group.actors[1]._env._process.close(timeout=0.5)
+      thread.join(30)
+      assert not thread.is_alive() and len(raised) == 1
+      assert group.failed is group.actors[1]
+      assert group.waiting_on is None
+      for actor in group.actors[0::2]:
         actor._env.initial()  # the pipe is in step: answered
     finally:
       group.close()
