@@ -209,7 +209,9 @@ flags.DEFINE_string('seq_layer_pattern', _DEFAULTS.seq_layer_pattern,
                     'to the last --seq_window tokens and keeps a ring of '
                     'them, G to the whole episode and keeps it; with the '
                     'dense and routed-expert feed-forward layers of the '
-                    'latent core\'s flags.')
+                    'latent core\'s flags. With an M (a Mamba-2 layer, '
+                    'the --seq_ssm_* flags) the core is Granite 4.0-H\'s '
+                    'hybrid of M and G layers, routed experts in each.')
 flags.DEFINE_integer('seq_window', _DEFAULTS.seq_window,
                      'Window layers: the tokens one attends to, its own '
                      'among them.', lower_bound=1)
@@ -264,6 +266,37 @@ flags.DEFINE_float('seq_routed_scale', _DEFAULTS.seq_routed_scale,
 flags.DEFINE_integer('seq_shared_experts', _DEFAULTS.seq_shared_experts,
                      'Latent core: shared experts (one SwiGLU of that '
                      'many times --seq_moe_size).', lower_bound=1)
+flags.DEFINE_enum('seq_router', _DEFAULTS.seq_router, ['sigmoid', 'softmax'],
+                  'Routed layers: sigmoid scores with a bias that moves the '
+                  'choice, or a softmax over the chosen logits.')
+flags.DEFINE_integer('seq_ssm_heads', _DEFAULTS.seq_ssm_heads,
+                     'Mamba-2 layers (an M in --seq_layer_pattern): heads.',
+                     lower_bound=1)
+flags.DEFINE_integer('seq_ssm_head_dim', _DEFAULTS.seq_ssm_head_dim,
+                     'Mamba-2 layers: a head\'s channels.', lower_bound=1)
+flags.DEFINE_integer('seq_ssm_state', _DEFAULTS.seq_ssm_state,
+                     'Mamba-2 layers: the state size (d_state).',
+                     lower_bound=1)
+flags.DEFINE_integer('seq_conv_width', _DEFAULTS.seq_conv_width,
+                     'Mamba-2 layers: the causal conv\'s width (d_conv).',
+                     lower_bound=2)
+flags.DEFINE_integer('seq_ssm_block', _DEFAULTS.seq_ssm_block,
+                     'Mamba-2 layers: tokens the chunk form takes at once.',
+                     lower_bound=1)
+flags.DEFINE_float('seq_embedding_multiplier',
+                   _DEFAULTS.seq_embedding_multiplier,
+                   'Core of Mamba-2 and attention layers: scale on the '
+                   'embedding.')
+flags.DEFINE_float('seq_residual_multiplier',
+                   _DEFAULTS.seq_residual_multiplier,
+                   'Core of Mamba-2 and attention layers: scale on each '
+                   'sublayer\'s output.')
+flags.DEFINE_float('seq_attention_multiplier',
+                   _DEFAULTS.seq_attention_multiplier,
+                   'Core of Mamba-2 and attention layers: scale on q . k.')
+flags.DEFINE_float('seq_logits_scaling', _DEFAULTS.seq_logits_scaling,
+                   'Core of Mamba-2 and attention layers: the logits are '
+                   'divided by it.')
 flags.DEFINE_float('seq_rope_factor', _DEFAULTS.seq_rope_factor,
                    'Latent core: YaRN context-extension factor (1: plain '
                    'rotary).')

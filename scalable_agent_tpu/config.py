@@ -146,7 +146,11 @@ class Config:
   # the whole episode and keeps it): grouped-query attention of
   # seq_num_kv_heads groups of seq_head_dim over those two kinds of
   # cache, the same feed-forward layers (models/hybrid_attention.py).
-  # Defaults: the tiny size the CPU tests run.
+  # seq_layer_pattern with an M (M a Mamba-2 layer of seq_ssm_heads
+  # heads of seq_ssm_head_dim and a state of seq_ssm_state, G a full
+  # attention layer without position encoding): Granite 4.0-H's hybrid,
+  # pre-norm blocks, routed experts in every layer
+  # (models/ssm_hybrid.py). Defaults: the tiny size the CPU tests run.
   seq_layer_pattern: str = ''
   seq_window: int = 8
   seq_kv_lora_rank: int = 0
@@ -164,6 +168,16 @@ class Config:
   seq_expert_groups_kept: int = 2
   seq_routed_scale: float = 2.5
   seq_shared_experts: int = 1
+  seq_router: str = 'sigmoid'             # sigmoid | softmax (models/moe.py)
+  seq_ssm_heads: int = 4
+  seq_ssm_head_dim: int = 8
+  seq_ssm_state: int = 128
+  seq_conv_width: int = 4
+  seq_ssm_block: int = 4                  # tokens the chunk form takes at once
+  seq_embedding_multiplier: float = 1.0
+  seq_residual_multiplier: float = 1.0
+  seq_attention_multiplier: float = 0.25  # on q . k
+  seq_logits_scaling: float = 1.0
   seq_rope_factor: float = 40.0           # YaRN; 1: plain rotary
   seq_rope_original_max: int = 4096
   seq_rope_beta_fast: float = 32.0
@@ -833,7 +847,9 @@ class Config:
   @property
   def seq_core(self) -> str:
     """The sequence agent's core, as the seq_* widths name it:
-    'retention' | 'latent' | 'hybrid'."""
+    'retention' | 'latent' | 'hybrid' | 'ssm'."""
+    if 'M' in self.seq_layer_pattern:
+      return 'ssm'
     if self.seq_layer_pattern:
       return 'hybrid'
     return 'latent' if self.seq_kv_lora_rank > 0 else 'retention'
@@ -1240,7 +1256,8 @@ def validate_runtime(config: Config) -> List[str]:
         raise ValueError(
             f'an episode of {config.episode_length} tokens does not fit '
             f'a cache of --seq_cache_capacity={config.seq_cache_capacity}')
-      if not 0 < config.seq_first_dense_layers <= config.seq_num_layers:
+      if config.seq_core != 'ssm' and not (
+          0 < config.seq_first_dense_layers <= config.seq_num_layers):
         raise ValueError('--seq_first_dense_layers lies in 1..'
                          '--seq_num_layers')
   longest_prompt = (config.token_prompt_length + config.token_prompt_stride

@@ -60,6 +60,7 @@ from scalable_agent_tpu.models import (ImpalaAgent, SequenceAgent,
                                       init_params)
 from scalable_agent_tpu.models import hybrid_attention
 from scalable_agent_tpu.models import latent_moe
+from scalable_agent_tpu.models import ssm_hybrid
 from scalable_agent_tpu.parallel import mesh as mesh_lib
 from scalable_agent_tpu.parallel import sharding as sharding_lib
 from scalable_agent_tpu.parallel import train_parallel
@@ -117,7 +118,8 @@ def build_agent(config: Config, num_actions: int, num_tasks: int = 1):
   if config.agent == 'sequence':
     core_dims = None
     widths = {'latent': latent_moe.LatentMoEDims,
-              'hybrid': hybrid_attention.HybridAttentionDims}.get(
+              'hybrid': hybrid_attention.HybridAttentionDims,
+              'ssm': ssm_hybrid.SSMHybridDims}.get(
                   config.seq_core)
     if widths is not None:
       # Every field of a core's widths is the flag of its name.

@@ -8,6 +8,8 @@ from scalable_agent_tpu.models.latent_moe import (  # noqa: F401
     LatentMoEDims, LatentMoEStack)
 from scalable_agent_tpu.models.hybrid_attention import (  # noqa: F401
     HybridAttentionDims, HybridAttentionStack)
+from scalable_agent_tpu.models.ssm_hybrid import (  # noqa: F401
+    SSMHybridDims, SSMHybridStack)
 from scalable_agent_tpu.models.sequence import SequenceAgent  # noqa: F401
 from scalable_agent_tpu.models.torsos import (  # noqa: F401
     DeepResNetTorso, ShallowTorso, TORSOS)

@@ -1,13 +1,16 @@
 """The feed-forward half of a block that the attention cores share
-(models/latent_moe.py, models/hybrid_attention.py): the SwiGLU FFN, the
-sigmoid router and this chip's share of the routed experts.
+(models/latent_moe.py, models/hybrid_attention.py, models/ssm_hybrid.py):
+the SwiGLU FFN, the router and this chip's share of the routed experts.
 
-`FFN_w(x) = W_down(silu(W_gate x) * W_up x)`. A routed layer scores
-`s = sigmoid(x W_g)` over ALL `routed_experts` (float32 operands),
-chooses on `s + bias` within the `expert_groups_kept` best of
-`expert_groups` groups (a group scores the sum of its two largest; one
-group: no limit), the `experts_per_token` largest among them; weights
-`routed_scale s_e / sum_chosen s`. The layer is told which experts it
+`FFN_w(x) = W_down(silu(W_gate x) * W_up x)`. A routed layer's router
+is one of two (`router`). `sigmoid`: it scores `s = sigmoid(x W_g)`
+over ALL `routed_experts` (float32 operands), chooses on `s + bias`
+within the `expert_groups_kept` best of `expert_groups` groups (a group
+scores the sum of its two largest; one group: no limit), the
+`experts_per_token` largest among them; weights `routed_scale s_e /
+sum_chosen s`. `softmax` (Granite's): the `experts_per_token` largest
+logits `x W_g` are chosen, weights `routed_scale softmax(chosen
+logits)`; no bias, one group. The layer is told which experts it
 holds (`experts_held` from `expert_offset`): it computes their part of
 the result and the shared expert's, `y = FFN_shared(x) + sum_{e chosen
 and held} g_e FFN_e(x)`; what the absent experts would add is left out,
@@ -29,6 +32,7 @@ import jax.numpy as jnp
 # The per-call counters a routed layer sows (collection 'counters');
 # the inference server sums them over a call's layers.
 COUNTERS = ('routed_rows_held', 'experts_hit')
+ROUTERS = ('sigmoid', 'softmax')
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -45,6 +49,7 @@ class RoutingDims:
   expert_groups_kept: int = 1
   routed_scale: float = 2.5
   shared_experts: int = 1
+  router: str = 'sigmoid'          # ROUTERS
 
   def check_routing(self):
     """The router's and the share's sizes fit together, or
@@ -60,6 +65,10 @@ class RoutingDims:
           f'{d.routed_experts} experts, {d.expert_groups} groups, '
           f'{d.expert_groups_kept} kept, {d.experts_per_token} a '
           'token')
+    if d.router not in ROUTERS or (
+        d.router == 'softmax' and d.expert_groups != 1):
+      raise ValueError(f'router {d.router!r}: one of {ROUTERS}; the '
+                       'softmax router chooses in one group')
     if not (0 <= d.expert_offset and 0 < d.experts_held and
             d.expert_offset + d.experts_held <= d.routed_experts):
       raise ValueError('the experts held lie among the routed ones')
@@ -84,6 +93,15 @@ def route(scores, bias, d):
   weights = d.routed_scale * picked / (
       jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
   return chosen.astype(jnp.int32), weights
+
+
+def route_softmax(logits, d):
+  """logits f32 [N, routed_experts] -> (chosen i32 [N, k], weights f32
+  [N, k]): the k largest logits (ties to the lower index), their
+  softmax times `routed_scale`."""
+  top, chosen = jax.lax.top_k(logits, d.experts_per_token)
+  return chosen.astype(jnp.int32), d.routed_scale * jax.nn.softmax(
+      top, axis=-1)
 
 
 def _dot(a, kernel, dtype):
@@ -147,14 +165,16 @@ class RoutedExperts(nn.Module):
         w_g = _Kernel((self.hidden_size, d.routed_experts),
                       self.param_dtype, 1.0 / self.x_square,
                       name='router')()
-        # Seeded small and non-zero, so that the choice it moves is
-        # exercised [assumed: a trained model's is learned].
-        bias = self.param('e_score_correction_bias',
-                          nn.initializers.normal(0.02),
-                          (d.routed_experts,), jnp.float32)
-        scores = jax.nn.sigmoid(jnp.dot(
-            x, w_g.astype(jnp.float32), precision=HIGHEST))
-        chosen, weights = route(scores, bias, d)
+        logits = jnp.dot(x, w_g.astype(jnp.float32), precision=HIGHEST)
+        if d.router == 'softmax':
+          chosen, weights = route_softmax(logits, d)
+        else:
+          # Seeded small and non-zero, so that the choice it moves is
+          # exercised [assumed: a trained model's is learned].
+          bias = self.param('e_score_correction_bias',
+                            nn.initializers.normal(0.02),
+                            (d.routed_experts,), jnp.float32)
+          chosen, weights = route(jax.nn.sigmoid(logits), bias, d)
         local = chosen - d.expert_offset
         held = (local >= 0) & (local < d.experts_held) & live[:, None]
         gates = jnp.sum(

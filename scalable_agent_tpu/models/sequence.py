@@ -1,11 +1,16 @@
 """A sequence policy: token embedding -> recurrent core -> norm ->
-policy head over the vocabulary and a value head. One of three cores:
+policy head over the vocabulary and a value head. One of four cores:
 the stack of power-retention blocks (models/retention.py), or the one
 `core_dims` names by its type: latent attention over a latent cache
-with routed experts (models/latent_moe.py :: LatentMoEDims), or
+with routed experts (models/latent_moe.py :: LatentMoEDims),
 grouped-query attention in a pattern of window and full layers, two
 kinds of cache a session, with routed experts (models/
-hybrid_attention.py :: HybridAttentionDims).
+hybrid_attention.py :: HybridAttentionDims), or Mamba-2 layers beside
+full attention layers, a fixed state and a growing cache a session,
+with routed experts (models/ssm_hybrid.py :: SSMHybridDims). Widths
+may scale the embedding (`embedding_multiplier`), tie the policy head
+to it (`tied_embeddings`: logits `n E^T`) and divide the logits
+(`logits_scaling`).
 
 It answers the same call as `ImpalaAgent` (`prev_actions, env_outputs,
 core_state, sample_rng`), so the inference server, the actors and
@@ -38,6 +43,7 @@ The model has no value head of its own: `baseline` is this system's
 `w.x + b` on the final norm's output [assumed].
 """
 
+import dataclasses
 import functools
 from typing import Any
 
@@ -50,6 +56,23 @@ from scalable_agent_tpu.models import retention
 from scalable_agent_tpu.parallel.sharding import (
     merge_time_batch, split_time_batch)
 from scalable_agent_tpu.structs import AgentOutput
+
+
+@dataclasses.dataclass(frozen=True)
+class _Widths:
+  """What a core's widths may say of the embedding and the head
+  (models/ssm_hybrid.py :: SSMHybridDims says all four); the
+  defaults leave both as they are."""
+  embedding_multiplier: float = 1.0
+  logits_scaling: float = 1.0
+  tied_embeddings: bool = False
+  embedding_init: float = 1.0     # the seeded table's standard deviation
+
+  @classmethod
+  def of(cls, core_dims):
+    return cls(**{f.name: getattr(core_dims, f.name)
+                  for f in dataclasses.fields(cls)
+                  if hasattr(core_dims, f.name)})
 
 
 class SequenceAgent(nn.Module):
@@ -137,11 +160,15 @@ class SequenceAgent(nn.Module):
     del prev_actions, level_ids, compute_pixel_control  # the token says it
     token = (env_outputs if chunk is not None
              else env_outputs.observation[0])
+    widths = _Widths.of(self.core_dims)
     with jax.named_scope('embed'):
-      table = self.param('embedding', nn.initializers.normal(1.0),
+      table = self.param('embedding',
+                         nn.initializers.normal(widths.embedding_init),
                          (self.num_actions, self.hidden_size),
                          self.param_dtype)
       x = jnp.take(table, token, axis=0).astype(jnp.float32)
+      if widths.embedding_multiplier != 1:
+        x = x * widths.embedding_multiplier
     core = self.core(name='core')
     if chunk is not None:  # `prefill`
       slot, n_valid, reset = chunk
@@ -163,9 +190,16 @@ class SequenceAgent(nn.Module):
     with jax.named_scope('lm_head'):
       n = retention._Scale(self.param_dtype, name='final_norm')(
           flat, self.norm_eps)
-      logits = retention._Linear(
-          self.num_actions, self.dtype, self.param_dtype,
-          name='policy_logits')(n)
+      if widths.tied_embeddings:
+        logits = jnp.einsum('bh,vh->bv', n.astype(self.dtype),
+                            table.astype(self.dtype),
+                            preferred_element_type=jnp.float32)
+      else:
+        logits = retention._Linear(
+            self.num_actions, self.dtype, self.param_dtype,
+            name='policy_logits')(n)
+      if widths.logits_scaling != 1:
+        logits = logits / widths.logits_scaling
       # float32 at full precision: on a TPU a float32 product is one
       # bfloat16 pass unless told otherwise, and 5,120 terms are cheap.
       baseline = nn.Dense(1, dtype=jnp.float32, name='baseline',

@@ -176,7 +176,7 @@ _SHADOW_DIVERGENCE = telemetry.gauge('serving/shadow_divergence')
 # nowhere else (PR 37: the registry's copies had no reader).
 # The per-call counters an agent's layers may sow
 # (`agent.call_counters` names some of these), summed in `stats()`.
-_CALL_COUNTERS = ('routed_rows_held', 'experts_hit')
+_CALL_COUNTERS = ('routed_rows_held', 'experts_hit', 'ssm_rows_stepped')
 
 # A merged call's stamps (`InferenceServer._cycles`; docs/
 # OBSERVABILITY.md "Cycle records") and what else is kept of it.

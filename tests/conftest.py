@@ -74,13 +74,20 @@ def pytest_configure(config):
 # tests/benchmark/test_one_pass_metric.py holds every assertion of it
 # by the entry's name
 # (`test_the_entry_keeps_to_the_contract[inline_call_share]`).
+# The cell of Mamba-2 layers appended five per-layer metrics after the
+# one-pass share, which test_one_pass_metric.py reads as `per_layer[-2:]`:
+# marked as well; tests/benchmark/test_granite_cell.py holds the same
+# from the entry's own place
+# (`test_the_one_pass_share_keeps_its_place_after_the_inline_share`).
 _OUTDATED = ('test_brumby_cell.py::test_new_entries_keep_to_the_contract',
              'test_dots_cell.py::test_new_entries_keep_to_the_contract',
              'test_dots_cell.py::test_the_cell_before_keeps_its_entries',
              'test_cycle_metrics.py::'
              'test_new_entries_are_appended_and_keep_to_the_contract',
              'test_inline_call_metric.py::'
-             'test_the_entry_is_appended_and_keeps_to_the_contract')
+             'test_the_entry_is_appended_and_keeps_to_the_contract',
+             'test_one_pass_metric.py::'
+             'test_the_entry_is_appended_after_the_inline_share')
 
 
 def pytest_collection_modifyitems(items):

@@ -23,11 +23,11 @@ from jax.sharding import SingleDeviceSharding
 import numpy as np
 
 from scalable_agent_tpu.models import (HybridAttentionDims, ImpalaAgent,
-                                      LatentMoEDims, SequenceAgent,
-                                      init_params)
+                                      LatentMoEDims, SSMHybridDims,
+                                      SequenceAgent, init_params)
 from scalable_agent_tpu.models.instruction import MAX_INSTRUCTION_LEN
 from scalable_agent_tpu.ops import (cache_columns, gqa_pallas, mla_pallas,
-                                    retention_pallas)
+                                    retention_pallas, ssm_pallas)
 from scalable_agent_tpu.runtime import inference, packing
 
 WIDTHS = dict(num_actions=151936, num_layers=4, hidden_size=5120,
@@ -305,3 +305,53 @@ def test_hybrid_programs_never_copy_the_cache_and_fit_the_chip(
       # (`bf16[8192,6144]`, 101 MB: PERF.md section 7) and little else;
       # a thirtieth of the full layer's leaf.
       assert memory.temp_size_in_bytes < 0.15e9
+
+
+# The policy of Mamba-2 and full attention layers at the published widths
+# of its benchmark cell: 10 layers `MMMMMGMMMM`, 9 of 72 experts, an
+# eighth of the vocabulary.
+GRANITE = dict(
+    num_actions=12544, num_layers=10, hidden_size=4096, num_heads=32,
+    norm_eps=1e-5, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+    core_dims=SSMHybridDims(
+        num_kv_heads=8, head_dim=128, layer_pattern='MMMMMGMMMM',
+        ssm_heads=128, ssm_head_dim=64, ssm_state=128, conv_width=4,
+        ssm_block=256, moe_size=768, routed_experts=72, experts_held=9,
+        experts_per_token=10, expert_groups=1, expert_groups_kept=1,
+        routed_scale=1.0, shared_experts=2, router='softmax',
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        attention_multiplier=0.0078125, logits_scaling=16.0,
+        cache_capacity=32768, prefill_chunk=512))
+
+
+def test_ssm_programs_never_copy_a_state_or_the_cache_and_fit_the_chip(
+    one_chip, compiled_kernel):
+  """Both programs of the core of Mamba-2 and full layers: the state
+  kernel passes Mosaic at the published widths (blocks of 32 heads of
+  64 by 128, float32, the decays in scalar memory); the 5.69 GB arena of
+  a fixed state beside a growing cache is aliased to the output and NO
+  copy of a Mamba layer's 139 MB state leaf or of the full layer's 4.4
+  GB cache is in either program (the state updated in place by the
+  kernel, a token a column of the cache); temporaries stay small;
+  weights, arena and temporaries fit, 9.8 GB of the chip's 16.9."""
+  params_bytes, arena_bytes, programs = _serving_programs(
+      SequenceAgent(**GRANITE), one_chip, 512)
+  assert 5.68e9 < arena_bytes < 5.7e9 and 4.11e9 < params_bytes < 4.12e9
+  for name, compiled in programs.items():
+    memory = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert memory.alias_size_in_bytes >= arena_bytes, name
+    for leaf in ('f32[33,128,64,128]', 'bf16[33,2048,32768]'):
+      copies = _copies_of(text, leaf)
+      assert not copies, (name, leaf, copies[:2])
+    assert memory.temp_size_in_bytes < 0.3e9, name
+    total = (memory.argument_size_in_bytes + memory.temp_size_in_bytes +
+             memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert total < 10.2e9, name
+    if name == 'cache_step':
+      # The state kernel in each of nine layers, the walk and the column
+      # write in the one full layer.
+      assert text.count(ssm_pallas.KERNEL_NAME) >= 9
+      assert text.count(gqa_pallas.KERNEL_NAME) >= 1
+      assert text.count(gqa_pallas.WRITE_KERNEL_NAME) >= 1
+      assert memory.temp_size_in_bytes < 0.1e9
